@@ -5,8 +5,8 @@ Public surface mirrors the reference FedML (``python/fedml/__init__.py``):
     import fedml_tpu as fedml
     args = fedml.init()
     device = fedml.device.get_device(args)
-    dataset, output_dim = fedml.data.load(args), args.output_dim
-    model = fedml.model.create(args, args.output_dim)
+    dataset, output_dim = fedml.data.load(args)
+    model = fedml.model.create(args, output_dim)
     fedml.FedMLRunner(args, device, dataset, model).run()
 
 or the one-liners ``run_simulation()`` / ``run_cross_silo_server()`` /
@@ -79,6 +79,10 @@ def init(args: Optional[Any] = None, override: Optional[Dict[str, Any]] = None) 
         num_processes=int(getattr(args, "num_processes", 0)) or None,
         process_id=int(_pid) if _pid is not None else None,
     )
+
+    from .utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     logging.basicConfig(
         level=logging.INFO, format="[fedml_tpu] %(asctime)s %(levelname)s %(name)s: %(message)s"

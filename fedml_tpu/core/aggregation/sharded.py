@@ -373,7 +373,15 @@ class ShardedFedOptServer:
         self.round_traces = 0
         # params live as native-dtype sharded group vecs from day one
         self._params_groups = engine.ingest(params_template).groups
-        self._state = jax.jit(self.tx.init)(self._params_groups)
+        # explicit out_shardings (as parallel/fsdp.py does): zeros_like does
+        # not inherit the input's NamedSharding, and moments left on device 0
+        # cost a retrace once round 1's outputs come back sharded
+        self._state = jax.jit(
+            self.tx.init,
+            out_shardings=jax.tree.map(
+                self._state_sharding,
+                jax.eval_shape(self.tx.init, self._params_groups)),
+        )(self._params_groups)
         self._book_shard_bytes()
 
         def _round(params_g, acc_g, opt_state):
@@ -401,18 +409,22 @@ class ShardedFedOptServer:
 
     @state.setter
     def state(self, value):
-        padded = {g.padded for g in self.layout.groups.values()}
-
         def put(v):
             if isinstance(v, jnp.ndarray) and not isinstance(v, np.ndarray):
                 return v
             arr = np.asarray(v)
-            sh = (self.layout.vec_sharding
-                  if arr.ndim == 1 and arr.shape[0] in padded
-                  else self.layout.repl_sharding)
-            return jax.device_put(arr, sh)
+            return jax.device_put(arr, self._state_sharding(arr))
 
         self._state = jax.tree.map(put, value)
+
+    def _state_sharding(self, leaf):
+        """Optimizer-state leaves that mirror a padded group vector (moments)
+        shard like it; everything else (step counts) replicates."""
+        shape = tuple(leaf.shape)
+        padded = {g.padded for g in self.layout.groups.values()}
+        if len(shape) == 1 and shape[0] in padded:
+            return self.layout.vec_sharding
+        return self.layout.repl_sharding
 
     def _book_shard_bytes(self) -> None:
         layout = self.layout
@@ -425,6 +437,14 @@ class ShardedFedOptServer:
             "fedopt_server",
             {str(d): per_dev for d in layout.mesh.devices.flat})
 
+    def params_view(self) -> PyTree:
+        """The current global params as the sharded tree view every round
+        step returns. Round loops start from THIS (not the caller's
+        single-device tree) so round 0 trains and evaluates on the same
+        layout as every later round — one compile of the local step, and no
+        first round with the whole model on device 0."""
+        return self.engine.tree_view(self._params_groups, self.layout)
+
     def round_step(self, acc_groups: Dict[str, jax.Array]) -> PyTree:
         """Fused finalize + FedOpt step over a DONATED f32 accumulator; the
         new global params come back as a sharded tree view for eval, and
@@ -432,7 +452,7 @@ class ShardedFedOptServer:
         with tel.span("agg.round_step_sharded", shards=self.layout.n_shards):
             self._params_groups, self.state = self._round(
                 self._params_groups, acc_groups, self.state)
-            return self.engine.tree_view(self._params_groups, self.layout)
+            return self.params_view()
 
     def apply(self, w_global: PyTree, w_avg: PyTree) -> PyTree:
         """FedOptServer-compatible entry: reshard the caller's trees into
@@ -440,7 +460,7 @@ class ShardedFedOptServer:
         params_g = self.engine._flatten_device_fn(self.layout)(w_global)
         acc_g = self.engine._flatten_device_fn(self.layout, to_f32=True)(w_avg)
         self._params_groups, self.state = self._round(params_g, acc_g, self.state)
-        return self.engine.tree_view(self._params_groups, self.layout)
+        return self.params_view()
 
     def materialize_broadcast(self) -> PyTree:
         """Host numpy tree of the current global params (one fetch per dtype

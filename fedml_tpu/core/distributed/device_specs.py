@@ -23,6 +23,8 @@ single-core v5e/v6e chips expose whole-chip numbers.
 
 from __future__ import annotations
 
+import glob
+import os
 from typing import Optional
 
 # Dense peak TFLOPS at bf16; f32 ≈ bf16/2 on every TPU generation here.
@@ -36,10 +38,6 @@ PEAK_BF16_TFLOPS = {
     "v6 lite": 918.0,   # trillium
     "v6e": 918.0,
 }
-
-# Unknown chip (CPU fallback runs in CI): assume a modest 2 TFLOPS so MFU
-# guards still trigger on absurd rates rather than dividing by peak=0.
-UNKNOWN_PEAK_TFLOPS = 2.0
 
 # Datasheet HBM per device; ordered so the most specific substring wins
 # ("v5 lite" and "v5litepod" before the bare "v5..." generations would
@@ -70,23 +68,33 @@ HBM_BANDWIDTH_BYTES_PER_S: list[tuple[str, float]] = [
     ("v2", 350e9),             # 700 GB/s/chip, 2 devices/chip
 ]
 
-# Unknown device (CPU CI): a host-DRAM-ish 50 GB/s keeps roofline verdicts
-# defined without pretending CPU memory behaves like HBM.
-UNKNOWN_BANDWIDTH_BYTES_PER_S = 50e9
+
+def _unknown(device_kind: str, what: str) -> None:
+    """A device that is not in the tables has no peak: callers report no MFU
+    or roofline for it (``None``) instead of one from an invented number. A
+    TPU that is not in the tables is an error — the table needs a row with
+    its source before anything is measured on that chip."""
+    if "tpu" in str(device_kind).lower():
+        raise ValueError(
+            f"no {what} recorded for TPU device_kind {device_kind!r}; add it to "
+            "core/distributed/device_specs.py with its datasheet source")
+    return None
 
 
-def peak_tflops(device_kind: str, dtype_bits: int = 16) -> float:
+def peak_tflops(device_kind: str, dtype_bits: int = 16) -> Optional[float]:
     """Dense peak TFLOPS for a ``device_kind`` string at the given matmul
-    width; substring match, :data:`UNKNOWN_PEAK_TFLOPS` when unrecognized."""
+    width; substring match. ``None`` for a non-TPU kind that is not in the
+    table (CPU), ``ValueError`` for an unlisted TPU."""
     kind = str(device_kind).lower()
     for key, bf16 in PEAK_BF16_TFLOPS.items():
         if key in kind:
             return bf16 if dtype_bits == 16 else bf16 / 2.0
-    return UNKNOWN_PEAK_TFLOPS if dtype_bits == 16 else UNKNOWN_PEAK_TFLOPS / 2.0
+    return _unknown(device_kind, "peak FLOP/s")
 
 
-def peak_flops_per_sec(device_kind: str, dtype_bits: int = 16) -> float:
-    return peak_tflops(device_kind, dtype_bits) * 1e12
+def peak_flops_per_sec(device_kind: str, dtype_bits: int = 16) -> Optional[float]:
+    tflops = peak_tflops(device_kind, dtype_bits)
+    return None if tflops is None else tflops * 1e12
 
 
 def device_hbm_bytes(device_kind: str) -> Optional[int]:
@@ -100,17 +108,33 @@ def device_hbm_bytes(device_kind: str) -> Optional[int]:
     return None
 
 
-def hbm_bandwidth_bytes_per_sec(device_kind: str) -> float:
+def hbm_bandwidth_bytes_per_sec(device_kind: str) -> Optional[float]:
     kind = str(device_kind).lower()
     for sub, bw in HBM_BANDWIDTH_BYTES_PER_S:
         if sub in kind:
             return bw
-    return UNKNOWN_BANDWIDTH_BYTES_PER_S
+    return _unknown(device_kind, "HBM bandwidth")
 
 
 def roofline_ridge_flops_per_byte(device_kind: str,
-                                  dtype_bits: int = 16) -> float:
+                                  dtype_bits: int = 16) -> Optional[float]:
     """Operational intensity (FLOPs/byte) at which the roofline's compute
     ceiling meets its bandwidth slope: programs above it are compute-bound,
-    below it bandwidth-bound."""
-    return peak_flops_per_sec(device_kind, dtype_bits) / hbm_bandwidth_bytes_per_sec(device_kind)
+    below it bandwidth-bound. ``None`` when the device has no recorded peak."""
+    peak = peak_flops_per_sec(device_kind, dtype_bits)
+    bandwidth = hbm_bandwidth_bytes_per_sec(device_kind)
+    if peak is None or bandwidth is None:
+        return None
+    return peak / bandwidth
+
+
+def local_chip_count() -> int:
+    """TPU chips attached to THIS host, counted from their device nodes
+    (``/dev/accel<n>``, or ``/dev/vfio/<n>`` on hosts that expose chips
+    through VFIO). Deliberately not ``jax.devices()``: initialising JAX takes
+    the chips, so a launcher (scheduler agent, replica controller) that
+    counted through JAX would starve the process it is about to start."""
+    accel = glob.glob("/dev/accel[0-9]*")
+    if accel:
+        return len(accel)
+    return sum(os.path.basename(p).isdigit() for p in glob.glob("/dev/vfio/*"))

@@ -9,9 +9,10 @@ narrow surfaces:
 - :func:`configure_server_mesh` / :func:`server_mesh`: resolve
   ``args.server_mesh`` / ``FEDML_SERVER_MESH`` ("auto", "fsdp:8",
   "dp:2,fsdp:4") into a named :class:`jax.sharding.Mesh` over the local
-  devices, or ``None`` when unset or only one device is visible — callers
-  fall back to the single-device path, so the sp CPU tier-1 path is
-  byte-identical with no mesh configured.
+  devices, or ``None`` when unset or the spec resolves to one device —
+  callers then keep the single-device path, so the sp CPU tier-1 path is
+  byte-identical with no mesh configured. A spec that asks for more devices
+  than are visible raises.
 - :func:`note_mesh` / :func:`current_topologies`: a plain-dict topology
   registry (axis names/sizes, device kinds) that the flight recorder and
   ``/statusz`` read without importing jax.
@@ -23,7 +24,6 @@ narrow surfaces:
 
 from __future__ import annotations
 
-import logging
 import os
 import threading
 from typing import Any, Dict, List, Optional, Tuple
@@ -107,7 +107,9 @@ def configured_spec() -> Optional[str]:
 def server_mesh(spec: Optional[str] = None):
     """Build (or fetch the cached) server Mesh for ``spec`` — defaulting to
     :func:`configured_spec` — or ``None`` when no spec is set or it resolves
-    to a single device (callers then keep the unsharded path)."""
+    to a single device (callers then keep the unsharded path). Raises
+    ``ValueError`` on a spec the visible devices cannot satisfy: an operator
+    who asked for ``fsdp:8`` must not get one device and a log line."""
     if spec is None:
         spec = configured_spec()
     if spec is None:
@@ -130,12 +132,12 @@ def server_mesh(spec: Optional[str] = None):
             s = max(1, len(devices) // fixed)
         resolved.append((name, s))
     total = int(np.prod([s for _, s in resolved]))
-    if total <= 1 or total > len(devices):
-        if total > len(devices):
-            logging.warning(
-                "server mesh spec %r needs %d devices but only %d are visible; "
-                "falling back to the single-device path", spec, total, len(devices))
-        mesh = None
+    if total > len(devices):
+        raise ValueError(
+            f"server mesh spec {spec!r} needs {total} devices but only "
+            f"{len(devices)} are visible")
+    if total <= 1:
+        mesh = None  # "auto" on a one-device host: the unsharded path IS the spec
     else:
         grid = np.asarray(devices[:total]).reshape([s for _, s in resolved])
         mesh = Mesh(grid, axis_names=tuple(n for n, _ in resolved))

@@ -43,14 +43,10 @@ def _load() -> ctypes.CDLL:
         if _build_error is not None:
             raise RuntimeError(_build_error)
         try:
-            if not os.path.exists(_LIB_PATH):
-                _build_library()
+            # always through make (a no-op when fresh): the binary comes from
+            # the tracked sources, never from whatever .so sits in build/
+            _build_library()
             lib = ctypes.CDLL(_LIB_PATH)
-            if not hasattr(lib, "edge_configure_conv_model"):
-                # stale prebuilt library from before conv support: rebuild
-                del lib
-                _build_library()
-                lib = ctypes.CDLL(_LIB_PATH)
         except Exception as e:
             _build_error = f"native edge engine unavailable: {e}"
             raise RuntimeError(_build_error) from e

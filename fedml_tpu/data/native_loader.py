@@ -45,14 +45,15 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _build_error is not None:
             return _lib
         try:
-            if not os.path.exists(_LIB_PATH):
-                proc = subprocess.run(
-                    ["make", "-C", _DP_DIR], capture_output=True, text=True
-                )
-                if proc.returncode != 0:
-                    _build_error = proc.stderr[-2000:]
-                    log.warning("native dataplane build failed; python fallback only")
-                    return None
+            # always through make (a no-op when fresh): the binary comes from
+            # the tracked sources, never from whatever .so sits in build/
+            proc = subprocess.run(
+                ["make", "-C", _DP_DIR], capture_output=True, text=True
+            )
+            if proc.returncode != 0:
+                _build_error = proc.stderr[-2000:]
+                log.warning("native dataplane build failed; python fallback only")
+                return None
             lib = ctypes.CDLL(_LIB_PATH)
         except Exception as e:  # no make on PATH, stale/partial .so, ...
             _build_error = f"{type(e).__name__}: {e}"

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ...core import telemetry as tel
 from ...models.model_hub import FedModel
 from ...utils.pytree import PyTree
 
@@ -47,6 +48,7 @@ def make_eval_fn(model: FedModel) -> Callable:
     """Returns jitted (loss_sum, correct, count) over one batch."""
 
     @jax.jit
+    @functools.partial(tel.track_compiles, name="eval_batch")
     def eval_batch(params: PyTree, x: jnp.ndarray, y: jnp.ndarray):
         logits = model.module.apply({"params": params}, x, train=False)
         if y.dtype in (jnp.int32, jnp.int64) and y.ndim == logits.ndim - 1:
@@ -126,6 +128,7 @@ def make_local_train_fn(model: FedModel, args: Any, *, grad_transform: Optional[
         return l
 
     @jax.jit
+    @functools.partial(tel.track_compiles, name="local_train")
     def local_train(params, x_all, y_all, idx, mask, rng, extras):
         """idx/mask: [E, nb, B]; x_all/y_all: full device-resident shard."""
         global_params = params

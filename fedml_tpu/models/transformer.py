@@ -17,12 +17,16 @@ inside each projection, split from the base tree by
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any, Callable, Optional, Tuple
+import functools
+import logging
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,7 +45,7 @@ class TransformerConfig:
     # dots: save matmul outputs, recompute elementwise only (the classic
     # MFU/memory middle ground — jax.checkpoint_policies)
     remat_policy: str = "full"   # full | dots
-    attention_impl: str = "auto"  # auto (pallas on TPU, xla elsewhere) | xla | pallas | ring
+    attention_impl: str = "auto"  # auto (see _auto_attention_impl) | xla | pallas | ring
     lora_rank: int = 0           # 0 = no adapters
     lora_alpha: float = 16.0
     lora_targets: Tuple[str, ...] = ("q_proj", "k_proj", "v_proj", "o_proj")
@@ -196,6 +200,48 @@ def xla_attention(q, k, v, causal: bool = True, mask: Optional[jnp.ndarray] = No
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+@functools.lru_cache(maxsize=None)
+def _auto_attention_impl(platform: str, seq_len: int) -> str:
+    """What ``attention_impl="auto"`` means, logged once per distinct case:
+    the pallas flash kernel where it runs COMPILED (platform ``tpu``) and its
+    128x128 blocks tile the sequence (``ops.flash_attention.tiles`` — on v5e
+    the kernel compiled and matched XLA at every such shape tried, T 8..16384,
+    head_dim 16..256, PR 21); XLA einsum attention otherwise (sequences the
+    blocks do not tile, and the CPU, where interpret-mode flash would be pure
+    overhead). An explicit ``"pallas"`` is never rerouted — it runs the
+    kernel or raises."""
+    from ..ops.flash_attention import tiles
+
+    impl = "pallas" if platform == "tpu" and tiles(seq_len) else "xla"
+    log.info("attention_impl=auto -> %s (platform=%s, seq_len=%d)",
+             impl, platform, seq_len)
+    return impl
+
+
+def _sharded_flash_attention(q, k, v):
+    """The pallas kernel under whatever mesh the train step is sharded over.
+
+    GSPMD has no partitioning rule for a Mosaic custom call: left bare inside
+    a sharded step it all-gathers q/k/v and runs the WHOLE batch on every
+    chip. Attention is independent per (batch row, head), so under an active
+    mesh the call is wrapped in ``shard_map`` over the batch axes
+    (``dp``/``fsdp``) and, when both head counts divide it, the ``tp`` axis —
+    each chip runs the kernel on exactly the block it already holds."""
+    from ..ops.flash_attention import flash_attention
+    from ..parallel.ring_attention import get_active_mesh
+
+    kernel = functools.partial(flash_attention, causal=True)
+    mesh = get_active_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v)
+    batch_axes = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
+    tp = mesh.shape.get("tp", 1)
+    head_axis = "tp" if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
+    spec = P(batch_axes or None, None, head_axis, None)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -219,16 +265,11 @@ class Attention(nn.Module):
             return self._decode_attention(q, k, v, B, T, attn_start, cache_idx)
         impl = cfg.attention_impl
         if impl == "auto":
-            # pallas only where it runs compiled: interpret-mode flash on CPU
-            # would be pure overhead, and numerics should not change under
-            # a platform fallback the user never asked for
-            impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+            impl = _auto_attention_impl(jax.default_backend(), T)
         if impl == "pallas":
             # GQA-native: the kernel maps query heads to kv heads itself —
             # repeat_kv here would materialize G copies of K/V in HBM
-            from ..ops.flash_attention import flash_attention
-
-            out = flash_attention(q, k, v, causal=True)
+            out = _sharded_flash_attention(q, k, v)
         elif impl == "ring":
             from ..parallel.ring_attention import ring_attention_inner
 

@@ -23,6 +23,12 @@ global row/col index, with block-level skipping on both sides of the
 diagonal (forward + dQ skip fully-masked k-blocks; dK/dV skips fully-masked
 q-blocks), so causal costs ~half the FLOPs of dense.
 
+On the chip (TPU v5e, jax 0.9.0 / libtpu 0.0.34, PR 21): all three kernels
+compile and match XLA attention for T 8..16384 and head_dim 16..256, MHA and
+GQA, bf16 and f32. K/V (and, in dK/dV, Q/dO) arrive as whole (1, T, D) VMEM
+blocks, so sequence length is bounded by VMEM: T=32768 fails at compile time
+with RESOURCE_EXHAUSTED in vmem — an error the caller sees, not a fallback.
+
 Reference parity: ``train/llm/models/attention.py`` (the reference's
 flash-attn flag on GPT-NeoX) — here the kernel is native to the framework
 rather than an external CUDA dependency.
@@ -31,127 +37,57 @@ rather than an external CUDA dependency.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
-
-try:  # pallas import kept soft so CPU-only environments can import the module
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
-
-def _compiler_params(dimension_semantics):
-    """Mosaic grid semantics ('parallel' dims can be pipelined/partitioned
-    freely; 'arbitrary' preserves iteration order — required for the dkv
-    kernel's accumulating revisits). None off-TPU (interpret ignores it)."""
-    if jax.default_backend() != "tpu":
-        return None
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:  # pragma: no cover - older pallas
-        return None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# Row-stat (lse/delta) lane layout. Default "narrow": stats live as
-# [..., block_q, 1] — legal per the Mosaic block rules (the block's last dim
-# equals the array's), zero HBM overhead. Hedge "wide" (the official jax
-# kernel's layout, flash_attention.py MIN_BLOCK_SIZE=128): stats broadcast
-# across 128 lanes — costs T*128*4 bytes per head but uses only layouts the
-# real compiler is KNOWN to accept. tools/tpu_smoke_flash.py tries narrow
-# first and falls back to wide on a Mosaic rejection; the bench honors its
-# verdict via this env var (ADVICE r3: narrow has never met real Mosaic).
-_WIDE_STATS_ENV = "FEDML_FLASH_WIDE_STATS"
+# Block sizes are the code's constants (callers may pass explicit ones);
+# a change to them is a measured perf change with a ledger entry, never an
+# environment variable or an untracked file.
+BLOCK_Q = 128
+BLOCK_K = 128
 
-# Block-size overrides (FEDML_FLASH_BLOCK_Q / FEDML_FLASH_BLOCK_K): the
-# bench's attention microbench sweeps configs on the live chip and records
-# the fastest to .bench_runtime/flash_blocks; the headline stage exports
-# these vars so the next window's train step runs the tuned kernel. Callers
-# passing explicit block sizes are never overridden. Invalid values (not a
-# positive multiple of the Mosaic tile granularity: 8 sublanes for block_q,
-# 128 lanes for block_k) are ignored with a warning rather than crashing a
-# training run over a bad env var.
-_BLOCK_Q_ENV = "FEDML_FLASH_BLOCK_Q"
-_BLOCK_K_ENV = "FEDML_FLASH_BLOCK_K"
+def _interpret() -> bool:
+    """Kernels run compiled on the TPU and interpreted on the CPU (tests,
+    ``chip_smoke.py --dry-run-cpu``). Any other platform is an error — never
+    a silent interpret-mode run that would pass for the real kernel."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash_attention supports platforms 'tpu' (compiled) and 'cpu' "
+        f"(interpreted), not {platform!r}")
 
 
-def _env_block(name: str, default: int, multiple: int) -> int:
-    raw = os.environ.get(name)
-    if not raw:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        val = -1
-    if val <= 0 or val % multiple:
-        import warnings
-
-        warnings.warn(f"{name}={raw!r} is not a positive multiple of "
-                      f"{multiple}; using default {default}")
-        return default
-    return val
+def _grid(*dimension_semantics):
+    """Mosaic grid semantics: 'parallel' dims can be pipelined/partitioned
+    freely; 'arbitrary' preserves iteration order (the dkv kernel's
+    accumulating revisits need it). Interpret mode ignores them."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
-def _stats_lanes(block_k: int) -> int:
-    if os.environ.get(_WIDE_STATS_ENV) == "1" and block_k % 128 == 0:
-        return 128
-    return 1
-
-
-def effective_blocks(seq_len: int, block_q: int | None = None,
-                     block_k: int | None = None) -> str:
-    """The '<bq>x<bk>' config flash_attention WILL actually run for this
-    sequence length — env-resolved defaults AND the min(block, T) clamp
-    applied, so artifact provenance records kernel truth, not the raw env
-    (a tiny-geometry run under a flagship '512 512' verdict executes
-    128x128, and must say so). Returns "xla-fallback" whenever the call
-    would actually take the einsum path — no pallas, clamped blocks that
-    don't tile seq_len, or wide-stats forced onto a block_k that can't host
-    128 lanes — mirroring the exact condition in flash_attention (an
-    artifact must not claim a kernel config for a dispatch that never ran
-    the kernel)."""
-    if block_q is None:
-        block_q = _env_block(_BLOCK_Q_ENV, 128, 8)
-    if block_k is None:
-        block_k = _env_block(_BLOCK_K_ENV, 128, 128)
+def tiles(seq_len: int, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> bool:
+    """The kernel's hard shape rule: the blocks (clamped to the sequence)
+    must tile it. An explicit ``"pallas"`` request that fails it raises."""
     bq, bk = min(block_q, seq_len), min(block_k, seq_len)
-    wide_requested = os.environ.get(_WIDE_STATS_ENV) == "1"
-    if (not _HAS_PALLAS or seq_len % bq or seq_len % bk
-            or (wide_requested and bk % 128 != 0)):
-        return "xla-fallback"
-    return f"{bq}x{bk}"
+    return seq_len % bq == 0 and seq_len % bk == 0
 
 
-def effective_stats_mode(seq_len: int, block_k: int | None = None) -> str:
-    """The stats layout flash_attention WILL actually use for these shapes —
-    the bench records this (not the raw env var) so artifacts can't claim
-    'wide' for a call whose effective block_k can't host 128 lanes (such a
-    call takes the einsum fallback when wide mode is forced — see
-    flash_attention). Only block_k matters: the stats lane count is a
-    function of the k-block width alone."""
-    if block_k is None:
-        block_k = _env_block(_BLOCK_K_ENV, 128, 128)
-    bk = min(block_k, seq_len)
-    if os.environ.get(_WIDE_STATS_ENV) == "1":
-        return "wide" if bk % 128 == 0 else "xla-fallback"
-    return "narrow"
-
-
-def _stats_to_cols(stat, block_k: int):
-    """[block_q, lanes] row-stat -> broadcastable against [block_q, block_k]
-    scores. lanes==1 broadcasts directly; wide stats (every lane equal) are
-    tiled to block_k the way the official kernel does (jnp.tile of the
-    128-wide value), avoiding a 1-wide lane slice Mosaic may reject."""
-    lanes = stat.shape[-1]
-    if lanes == 1:
-        return stat
-    return jnp.tile(stat, (1, block_k // lanes))
+def _mxu_precision(a):
+    """bf16 operands ride the MXU natively (f32 accumulation via
+    preferred_element_type) and that is the ONLY contraction Mosaic accepts
+    for them: under an ambient ``jax.default_matmul_precision("highest")`` a
+    bf16 matmul lowers to contract_precision<fp32> and fails to compile ("Bad
+    lhs type", seen on v5e). So bf16 pins DEFAULT; f32 operands keep the
+    ambient precision (``highest`` gives a true f32 contraction — what the
+    parity checks run under)."""
+    return jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else None
 
 
 def _dot_nt(a, b):
@@ -160,12 +96,14 @@ def _dot_nt(a, b):
     with f32 accumulation (preferred_element_type); an up-front
     .astype(f32) would force the ~4x-slower f32 matmul path."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=_mxu_precision(a),
                                preferred_element_type=jnp.float32)
 
 
 def _dot_nn(a, b):
     """[m, k] x [k, n] -> [m, n] f32 accumulate (see _dot_nt)."""
     return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=_mxu_precision(a),
                                preferred_element_type=jnp.float32)
 
 
@@ -178,7 +116,7 @@ def _causal_num_k(qi, num_k: int, block_q: int, block_k: int):
 # --- forward -----------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int, block_k: int,
-                causal: bool, scale: float, lanes: int):
+                causal: bool, scale: float):
     qi = pl.program_id(1)
     q = q_ref[0]  # [block_q, D], input dtype — matmuls accumulate in f32
     T = k_ref.shape[1]
@@ -219,8 +157,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int, block_k: i
     m, l, acc = jax.lax.fori_loop(0, num_k_eff, body, (m, l, acc))
     l_safe = jnp.maximum(l, 1e-20)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    # wide mode: broadcast the [block_q, 1] stat across the 128 lanes
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l_safe), (block_q, lanes))
+    lse_ref[0] = m + jnp.log(l_safe)
 
 
 def _kv_index(Hq: int, Hkv: int):
@@ -235,7 +172,7 @@ def _kv_index(Hq: int, Hkv: int):
 
 
 def _fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int, Hq: int,
-              Hkv: int, lanes: int):
+              Hkv: int):
     """q [B*Hq, T, D]; k/v [B*Hkv, T, D] -> (out [B*Hq, T, D], lse f32)."""
     BHq, T, D = q.shape
     scale = D ** -0.5
@@ -243,15 +180,15 @@ def _fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int, Hq: int,
     kv_idx = _kv_index(Hq, Hkv)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale, lanes=lanes),
+                          causal=causal, scale=scale),
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            # lanes=1 (default): trailing singleton lane dim — Mosaic
-            # requires the last two block dims be (8k, 128k) or equal the
-            # array dims; (block_q, 1) with an array whose last dim IS 1
-            # satisfies that at zero HBM cost. lanes=128: the official jax
-            # kernel's broadcast layout (the Mosaic-acceptance hedge).
-            jax.ShapeDtypeStruct((BHq, T, lanes), jnp.float32),
+            # row stats carry a trailing singleton lane dim: Mosaic requires
+            # the last two block dims be (8k, 128k) or equal the array dims,
+            # and (block_q, 1) on an array whose last dim IS 1 satisfies that
+            # at zero HBM cost (compiled and parity-checked on v5e, PR 21;
+            # the 128-lane broadcast layout it was hedged with is gone)
+            jax.ShapeDtypeStruct((BHq, T, 1), jnp.float32),
         ),
         grid=grid,
         in_specs=[
@@ -261,10 +198,10 @@ def _fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int, Hq: int,
         ],
         out_specs=(
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, lanes), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
         ),
-        compiler_params=_compiler_params(("parallel", "parallel")),
-        interpret=jax.default_backend() != "tpu",  # CPU tests run interpreted
+        compiler_params=_grid("parallel", "parallel"),
+        interpret=_interpret(),
     )(q, k, v)
 
 
@@ -275,9 +212,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     qi = pl.program_id(1)
     q = q_ref[0]                              # [block_q, D], input dtype
     do = do_ref[0]                            # [block_q, D], input dtype
-    # [block_q, lanes] -> broadcastable against [block_q, block_k]
-    lse = _stats_to_cols(lse_ref[0], block_k)
-    delta = _stats_to_cols(delta_ref[0], block_k)  # rowsum(dO * O)
+    lse = lse_ref[0]                          # [block_q, 1]
+    delta = delta_ref[0]                      # [block_q, 1] rowsum(dO * O)
     T = k_ref.shape[1]
 
     row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -325,10 +261,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk, dv = carry
         q_blk = q_ref[0, pl.ds(start * block_q, block_q), :]
         do_blk = do_ref[0, pl.ds(start * block_q, block_q), :]
-        lse_blk = _stats_to_cols(
-            lse_ref[0, pl.ds(start * block_q, block_q), :], block_k)
-        delta_blk = _stats_to_cols(
-            delta_ref[0, pl.ds(start * block_q, block_q), :], block_k)
+        lse_blk = lse_ref[0, pl.ds(start * block_q, block_q), :]
+        delta_blk = delta_ref[0, pl.ds(start * block_q, block_q), :]
         s = _dot_nt(q_blk, k) * scale          # [block_q, block_k] f32
         row = start * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         p = jnp.exp(s - lse_blk)
@@ -361,16 +295,13 @@ def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, block_q: int, block_k: int,
     BHkv = k.shape[0]
     G = Hq // Hkv
     scale = D ** -0.5
-    lanes = lse.shape[-1]  # layout decided at the forward (1 or 128)
     # delta = rowsum(dO * O): tiny elementwise reduce, XLA fuses it; feeding
-    # it in precomputed keeps both kernels single-pass. Lane layout matches
-    # lse (see _fwd_impl).
+    # it in precomputed keeps both kernels single-pass. Same [.., T, 1]
+    # layout as lse (see _fwd_impl).
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BHq, T, 1]
-    if lanes > 1:
-        delta = jnp.broadcast_to(delta, (BHq, T, lanes))
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
     kv_idx = _kv_index(Hq, Hkv)
 
     dq = pl.pallas_call(
@@ -383,20 +314,17 @@ def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, block_q: int, block_k: int,
             pl.BlockSpec((1, T, D), kv_idx),
             pl.BlockSpec((1, T, D), kv_idx),
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, lanes), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, lanes), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
-        compiler_params=_compiler_params(("parallel", "parallel")),
+        compiler_params=_grid("parallel", "parallel"),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
     # group dim as a grid axis (g fastest -> consecutive output revisits);
     # query head for program (i, j, g) is i*G + g
     def q_idx(i, j, g):
-        return (i * G + g, 0, 0)
-
-    def q_row_idx(i, j, g):
         return (i * G + g, 0, 0)
 
     dk, dv = pl.pallas_call(
@@ -412,15 +340,15 @@ def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, block_q: int, block_k: int,
             pl.BlockSpec((1, block_k, D), lambda i, j, g: (i, j, 0)),
             pl.BlockSpec((1, block_k, D), lambda i, j, g: (i, j, 0)),
             pl.BlockSpec((1, T, D), q_idx),
-            pl.BlockSpec((1, T, lanes), q_row_idx),
-            pl.BlockSpec((1, T, lanes), q_row_idx),
+            pl.BlockSpec((1, T, 1), q_idx),
+            pl.BlockSpec((1, T, 1), q_idx),
         ],
         out_specs=(
             pl.BlockSpec((1, block_k, D), lambda i, j, g: (i, j, 0)),
             pl.BlockSpec((1, block_k, D), lambda i, j, g: (i, j, 0)),
         ),
         # g accumulates into revisited output blocks -> must stay ordered
-        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_grid("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
@@ -428,20 +356,20 @@ def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, block_q: int, block_k: int,
 
 # --- custom_vjp wiring (on the [BH, T, D] layout) ----------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_r(q, k, v, causal, block_q, block_k, Hq, Hkv, lanes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_r(q, k, v, causal, block_q, block_k, Hq, Hkv):
     out, _ = _fwd_impl(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                       Hq=Hq, Hkv=Hkv, lanes=lanes)
+                       Hq=Hq, Hkv=Hkv)
     return out
 
 
-def _flash_r_fwd(q, k, v, causal, block_q, block_k, Hq, Hkv, lanes):
+def _flash_r_fwd(q, k, v, causal, block_q, block_k, Hq, Hkv):
     out, lse = _fwd_impl(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                         Hq=Hq, Hkv=Hkv, lanes=lanes)
+                         Hq=Hq, Hkv=Hkv)
     return out, (q, k, v, out, lse)
 
 
-def _flash_r_bwd(causal, block_q, block_k, Hq, Hkv, lanes, res, g):
+def _flash_r_bwd(causal, block_q, block_k, Hq, Hkv, res, g):
     q, k, v, o, lse = res
     return _bwd_impl(q, k, v, g, o, lse, causal=causal,
                      block_q=block_q, block_k=block_k, Hq=Hq, Hkv=Hkv)
@@ -456,36 +384,24 @@ def flash_attention(
     v: jnp.ndarray,
     *,
     causal: bool = True,
-    block_q: int | None = None,
-    block_k: int | None = None,
+    block_q: int = BLOCK_Q,
+    block_k: int = BLOCK_K,
 ) -> jnp.ndarray:
     """[B, T, Hq, D], [B, T, Hkv, D] x2 -> [B, T, Hq, D]. GQA-native: Hkv may
-    divide Hq; K/V are consumed at their own head count (no repeat). Falls
-    back to the einsum path when pallas is unavailable or shapes don't tile
-    (T % block != 0). Block sizes default to 128/128, overridable via
-    FEDML_FLASH_BLOCK_Q/K (see _BLOCK_Q_ENV above) when not passed."""
-    if block_q is None:
-        block_q = _env_block(_BLOCK_Q_ENV, 128, 8)
-    if block_k is None:
-        block_k = _env_block(_BLOCK_K_ENV, 128, 128)
+    divide Hq; K/V are consumed at their own head count (no repeat). Raises
+    when the blocks do not tile T (see :func:`tiles`) — a caller that asked
+    for this kernel never silently gets einsum attention instead."""
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
     if Hq % Hkv:
         raise ValueError(f"q heads {Hq} not a multiple of kv heads {Hkv}")
     bq, bk = min(block_q, T), min(block_k, T)
-    # wide-stats mode set = the smoke found Mosaic REJECTS the narrow
-    # (block_q, 1) layout on this chip; a shape too small to host 128 lanes
-    # must then take the einsum path, not silently attempt the rejected
-    # narrow layout and crash at compile time (e.g. short prefills)
-    wide_requested = os.environ.get(_WIDE_STATS_ENV) == "1"
-    if (not _HAS_PALLAS or T % bq or T % bk
-            or (wide_requested and bk % 128 != 0)):
-        from ..models.transformer import repeat_kv, xla_attention
-
-        k, v = repeat_kv(k, v, Hq)
-        return xla_attention(q, k, v, causal=causal)
+    if not tiles(T, block_q, block_k):
+        raise ValueError(
+            f"flash_attention: blocks ({bq}, {bk}) do not tile seq_len {T}; "
+            "pad the sequence or use attention_impl='xla'")
     qr = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * Hq, T, D)
     kr = jnp.transpose(k, (0, 2, 1, 3)).reshape(B * Hkv, T, D)
     vr = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * Hkv, T, D)
-    out = _flash_r(qr, kr, vr, causal, bq, bk, Hq, Hkv, _stats_lanes(bk))
+    out = _flash_r(qr, kr, vr, causal, bq, bk, Hq, Hkv)
     return jnp.transpose(out.reshape(B, Hq, T, D), (0, 2, 1, 3))

@@ -26,14 +26,7 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # jax >= 0.8
-
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 PyTree = Any
@@ -121,7 +114,7 @@ def pipeline_loss_fn(
         mesh=mesh,
         in_specs=in_axes,
         out_specs=P(),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
     def loss_fn(params, tokens, targets):
         embed_params, stage_params, head_params = params

@@ -44,14 +44,14 @@ def llm_bench_predictor():
 
     Three geometries (round 4, VERDICT r3 missing #4):
       * tiny (FEDML_BENCH_TINY=1): CPU test harness for the serving path;
-      * default: ~30M, two replicas fit one chip with big headroom;
+      * default: ~30M;
       * flagship (FEDML_BENCH_FLAGSHIP=1): the SAME 268M-class geometry the
         train bench measures (d_model 1024 / 16 layers / d_ff 2752), so the
         endpoint number is on the model class BASELINE config 5 intends
         (reference serves a real checkpoint per
         ``model_scheduler/device_model_deployment.py:68``). ~0.5GB bf16
-        params per replica; pair with FEDML_REPLICA_MEM_FRACTION so two
-        replicas + KV caches coexist deterministically on one chip.
+        params per replica. One subprocess replica per chip: a chip belongs
+        to one process (serving/replica_controller.py).
     """
     import jax
 

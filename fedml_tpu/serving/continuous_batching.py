@@ -21,7 +21,7 @@ barriering on it):
   checked host-side between chunks — so one executable per (cfg, B, C)
   serves every mix of prompt lengths, sampling settings, and stop tokens.
 
-Chunking amortizes dispatch: on a remote/tunnel backend one device call
+Chunking amortizes dispatch and the per-chunk host sync: one device call
 yields ``chunk`` tokens for every live slot. A slot that stops mid-chunk
 (EOS or budget) generates garbage until the chunk ends; the host discards
 it and the freed slot's cache leftovers are fully overwritten on the next

@@ -66,7 +66,8 @@ class JaxPredictor(FedMLPredictor):
 class LLMPredictor(FedMLPredictor):
     """LLM text-generation endpoint (BASELINE config 5 shape): KV-cache
     decode via train/llm/generation.py. Request: {"prompt": str,
-    "max_new_tokens": int?, "temperature": float?} -> {"text": str}.
+    "max_new_tokens": int?, "temperature": float?} -> {"text": str} (engine
+    modes add "token_ids": [int]).
 
     Build from a checkpoint dir (HF llama safetensors + tokenizer.json) or
     pass (params, cfg, tokenizer) directly."""
@@ -202,7 +203,10 @@ class LLMPredictor(FedMLPredictor):
                 eos_id=self._eos_id,
                 tenant=tenant,
             )
-            return {"text": self._tok.decode([int(t) for t in toks])}
+            ids = [int(t) for t in toks]
+            # token_ids ride along: the text alone cannot say how many tokens
+            # were generated (decode drops ids outside the tokenizer's vocab)
+            return {"text": self._tok.decode(ids), "token_ids": ids}
         text = generate_text(
             self._params,
             self._cfg,

@@ -25,49 +25,70 @@ import urllib.error
 import urllib.request
 import uuid
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 from ..core import telemetry as tel
+from ..core.distributed.device_specs import local_chip_count
 
 log = logging.getLogger(__name__)
+
+
+def _host_chips() -> Optional[range]:
+    """The TPU chips subprocess replicas may be pinned to, or None when there
+    is nothing to account for: children pinned to the CPU
+    (``JAX_PLATFORMS=cpu``, as the tests set) share the host freely, and a
+    host without chips has none to hand out."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    n = local_chip_count()
+    return range(n) if n else None
 
 
 class SubprocessReplica:
     """One replica = one child python process serving /predict + /ready."""
 
     def __init__(self, predictor_spec: str, *, model_path: Optional[str] = None,
-                 startup_timeout_s: float = 60.0, role: Optional[str] = None):
+                 startup_timeout_s: float = 60.0, role: Optional[str] = None,
+                 chip: Optional[int] = None):
         self.id = uuid.uuid4().hex[:8]
         self.predictor_spec = predictor_spec
         self.role = role or "mixed"
+        self.chip = chip
         self._port_file = os.path.join(tempfile.gettempdir(), f"fedml_replica_{self.id}.port")
+        # the child's stdout+stderr: the only place a replica that cannot
+        # open its chip (or crashes in model load) says why
+        self.log_path = os.path.join(tempfile.gettempdir(), f"fedml_replica_{self.id}.log")
         env = dict(os.environ)
         if role:
             # pool role reaches the child predictor (LLMPredictor sizes its
             # engine for prefill- vs decode-dominated traffic off this)
             env["FEDML_SERVE_ROLE"] = role
+        if chip is not None:
+            # a chip belongs to one process: pin this child to ITS chip so
+            # the first replica does not claim every chip of the host
+            env["TPU_VISIBLE_CHIPS"] = str(chip)
+            env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+            env["TPU_PROCESS_BOUNDS"] = "1,1,1"
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
-        # best-effort allocator cap for backends that honor it (the
-        # XLA_PYTHON_CLIENT_* knobs configure the GPU/CPU PJRT BFC
-        # allocator; TPU runtimes allocate on demand and ignore them).
-        # The HARD guarantee against r03-style HBM exhaustion is
-        # structural, not this env var: the bench runs serving LAST in its
-        # own process group, so replica memory can never sit under a later
-        # measurement, and a stage timeout killpg-reaps the whole tree
-        # (bench.py _spawn_stage).
-        mem_frac = os.environ.get("FEDML_REPLICA_MEM_FRACTION")
-        if mem_frac:
-            env["XLA_PYTHON_CLIENT_MEM_FRACTION"] = mem_frac
-            env.setdefault("XLA_PYTHON_CLIENT_PREALLOCATE", "false")
         cmd = [sys.executable, "-m", "fedml_tpu.serving.replica_main",
                "--predictor", predictor_spec, "--port-file", self._port_file]
         if model_path:
             cmd += ["--model-path", model_path]
-        self.proc = subprocess.Popen(cmd, env=env, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+        with open(self.log_path, "wb") as log_f:
+            self.proc = subprocess.Popen(cmd, env=env, stdout=log_f, stderr=subprocess.STDOUT)
         self.port = self._await_port(startup_timeout_s)
         self.url = f"http://127.0.0.1:{self.port}"
         self.consecutive_failures = 0
+
+    def log_tail(self, n_bytes: int = 2000) -> str:
+        try:
+            with open(self.log_path, "rb") as f:
+                f.seek(0, os.SEEK_END)
+                f.seek(max(0, f.tell() - n_bytes))
+                return f.read().decode(errors="replace")
+        except OSError:
+            return ""
 
     def _await_port(self, timeout_s: float) -> int:
         deadline = time.monotonic() + timeout_s
@@ -78,10 +99,14 @@ class SubprocessReplica:
                 except ValueError:
                     pass
             if self.proc.poll() is not None:
-                raise RuntimeError(f"replica {self.id} died during startup (rc={self.proc.returncode})")
+                raise RuntimeError(
+                    f"replica {self.id} died during startup (rc={self.proc.returncode}); "
+                    f"log {self.log_path} ends:\n{self.log_tail()}")
             time.sleep(0.05)  # fedlint: disable=bare-sleep subprocess startup poll, not a retry
         self.proc.kill()
-        raise TimeoutError(f"replica {self.id} did not report a port within {timeout_s}s")
+        raise TimeoutError(
+            f"replica {self.id} did not report a port within {timeout_s}s; "
+            f"log {self.log_path} ends:\n{self.log_tail()}")
 
     def ready(self, timeout_s: float = 2.0) -> bool:
         """Readiness probe (reference device_model_deployment.py:576)."""
@@ -95,16 +120,19 @@ class SubprocessReplica:
         return self.proc.poll() is None
 
     def stop(self) -> None:
+        stale = [self._port_file]
         if self.proc.poll() is None:
             self.proc.terminate()
             try:
                 self.proc.wait(timeout=5)
             except subprocess.TimeoutExpired:
                 self.proc.kill()
-        try:
-            os.unlink(self._port_file)
-        except OSError:
-            pass
+            stale.append(self.log_path)  # a replica that died by itself keeps its log
+        for path in stale:
+            try:
+                os.unlink(path)
+            except OSError:
+                pass
 
 
 class ReplicaSet:
@@ -114,7 +142,7 @@ class ReplicaSet:
 
     def __init__(self, predictor_spec: str, desired: int = 1, *, model_path: Optional[str] = None,
                  max_consecutive_failures: int = 3, startup_timeout_s: float = 60.0,
-                 role: Optional[str] = None):
+                 role: Optional[str] = None, chips: Optional[Sequence[int]] = None):
         self.predictor_spec = predictor_spec
         self.model_path = model_path
         self.role = role
@@ -125,6 +153,11 @@ class ReplicaSet:
         # the echo-predictor default before the port file appears
         self.startup_timeout_s = float(startup_timeout_s)
         self._lock = threading.RLock()
+        # one chip per replica, from `chips` (default: every chip of the
+        # host); None = no chip accounting (see _host_chips)
+        if chips is None:
+            chips = _host_chips()
+        self._chips: Optional[List[int]] = None if chips is None else list(chips)
         try:
             self.scale_to(desired)
         except Exception:
@@ -134,35 +167,30 @@ class ReplicaSet:
             raise
 
     def scale_to(self, n: int) -> None:
+        n = int(n)
+        if self._chips is not None and n > len(self._chips):
+            raise ValueError(
+                f"{n} subprocess replicas requested but only {len(self._chips)} "
+                "TPU chip(s) are free for this set: a chip belongs to one "
+                "process, so each replica needs its own (serve several "
+                "models per chip in-process instead)")
         with self._lock:
-            self.desired = int(n)
+            self.desired = n
             self.reconcile()
-
-    def retain(self, keep: List["SubprocessReplica"]) -> None:
-        """Shrink to exactly `keep`, stopping every other replica.
-
-        scale_to() trims BY LIST POSITION (newest first), which is wrong
-        when the caller has readiness information — degrading a bench to
-        "the replicas that are ready" must not stop a ready replica while
-        keeping one that is still compiling."""
-        with self._lock:
-            keep_ids = {r.id for r in keep}
-            for r in self.replicas:
-                if r.id not in keep_ids:
-                    r.stop()
-                    log.info("replica set: retained-out %s", r.id)
-            self.replicas = [r for r in self.replicas if r.id in keep_ids]
-            self.desired = len(self.replicas)
 
     def reconcile(self) -> None:
         """Converge actual replicas to the desired count, replacing dead ones."""
         with self._lock:
             self.replicas = [r for r in self.replicas if self._evict_if_dead(r)]
             while len(self.replicas) < self.desired:
+                chip = None
+                if self._chips is not None:
+                    taken = {r.chip for r in self.replicas}
+                    chip = next(c for c in self._chips if c not in taken)
                 self.replicas.append(
                     SubprocessReplica(self.predictor_spec, model_path=self.model_path,
                                       startup_timeout_s=self.startup_timeout_s,
-                                      role=self.role)
+                                      role=self.role, chip=chip)
                 )
                 log.info("replica set: started %s on %s", self.replicas[-1].id, self.replicas[-1].url)
             while len(self.replicas) > self.desired:
@@ -432,12 +460,19 @@ class DisaggregatedReplicaSet:
                  startup_timeout_s: float = 60.0,
                  max_consecutive_failures: int = 3):
         self.pools: Dict[str, ReplicaSet] = {}
+        # the two pools share one host: give them disjoint chips (prefill
+        # the first `prefill`, decode the rest) or they would both pin chip 0
+        host = _host_chips()
+        chips: Dict[str, Optional[Sequence[int]]] = {"prefill": None, "decode": None}
+        if host is not None:
+            chips = {"prefill": host[:prefill], "decode": host[prefill:]}
         try:
             for role, n in (("prefill", prefill), ("decode", decode)):
                 self.pools[role] = ReplicaSet(
                     predictor_spec, n, model_path=model_path, role=role,
                     startup_timeout_s=startup_timeout_s,
-                    max_consecutive_failures=max_consecutive_failures)
+                    max_consecutive_failures=max_consecutive_failures,
+                    chips=chips[role])
         except Exception:
             self.shutdown()  # don't orphan the pool that did come up
             raise

@@ -226,8 +226,17 @@ class FedAvgAPI:
             round_span_attrs={"optimizer": self.fed_opt},
             metrics_history=self.metrics_history,
         )
+        w_global = self.model_trainer.get_model_params()
+        params_view = getattr(self._fedopt_server, "params_view", None)
+        if params_view is not None:
+            # mesh-sharded server: start from its sharded view of the same
+            # params, so round 0 runs on the layout every later round gets
+            # back (else local_train compiles twice and round 0 sits on one
+            # device)
+            w_global = params_view()
+            self._install_global(w_global)
         try:
-            engine.run(self.model_trainer.get_model_params())
+            engine.run(w_global)
         finally:
             if self._mw_ledger is not None:
                 from ...core.telemetry import modelwatch

@@ -28,6 +28,7 @@ from ...parallel.fsdp import make_fsdp_train_step, param_shardings
 from ...parallel.mesh import create_mesh
 from ...parallel.ring_attention import active_mesh
 from ...utils.checkpoint import CheckpointManager
+from ...utils.compile_cache import enable_compile_cache
 from .configurations import DatasetArguments, ExperimentArguments, ModelArguments
 
 log = logging.getLogger(__name__)
@@ -70,6 +71,7 @@ class LLMTrainer:
         exp_args: ExperimentArguments,
         devices=None,
     ):
+        enable_compile_cache()
         self.model_args = model_args = model_args.resolve_pretrained()
         self.data_args = data_args
         self.exp_args = exp_args
@@ -124,8 +126,23 @@ class LLMTrainer:
     # --- setup -----------------------------------------------------------
     def init_params(self, seed: Optional[int] = None):
         key = jax.random.PRNGKey(seed if seed is not None else self.exp_args.seed)
-        dummy = jnp.zeros((1, 8), jnp.int32)
-        params = self.model.init(key, dummy)["params"]
+        # jitted: ONE program (XLA drops the dummy forward, keeps the RNG)
+        # instead of an eager op-by-op forward that compiles ~100 tiny
+        # executables; traced at the training seq_len so a pallas attention
+        # lowers at the shape the step will use
+        dummy = jnp.zeros((1, self.model_args.seq_len), jnp.int32)
+
+        def init(k):
+            return self.model.init(k, dummy)["params"]
+
+        # born sharded: without out_shardings the whole tree materializes on
+        # device 0 before _build reshards it (seen on 4 chips: chip 0 peaked
+        # at the FULL f32 state). The pp layout restacks leaves, so it
+        # shards after the split instead.
+        out_shardings = None
+        if self.exp_args.pp == 1:
+            out_shardings = param_shardings(jax.eval_shape(init, key), self.mesh)
+        params = jax.jit(init, out_shardings=out_shardings)(key)
         if self.model_args.model_name_or_path:
             # overlay pretrained base weights; freshly-initialized LoRA
             # adapter leaves (and anything the checkpoint lacks) survive
@@ -295,6 +312,7 @@ class LLMTrainer:
             getattr(self, "_devperf_label", "llm_train"), dt,
             steps=step + 1, tokens=tokens_seen)
         metrics = {
+            "first_loss": float(jax.device_get(losses[0])) if losses else float("nan"),
             "final_loss": final_loss,
             "steps": step + 1,
             "tokens_per_sec": tokens_per_sec,

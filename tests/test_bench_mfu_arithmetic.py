@@ -196,6 +196,7 @@ def test_no_remat_oom_stamp_gated_on_flagship_geometry_and_device(monkeypatch):
         return dict(calls["out"])
 
     monkeypatch.setattr(bench, "_bench_llm_tpu", fake_bench)
+    monkeypatch.setattr(bench, "_require_chip", lambda name: None)  # body is faked
     printed = []
     monkeypatch.setattr(
         "builtins.print", lambda *a, **k: printed.append(a[0] if a else ""))
@@ -213,6 +214,19 @@ def test_no_remat_oom_stamp_gated_on_flagship_geometry_and_device(monkeypatch):
     assert "no_remat_oom" in run(flagship, "TPU v5 lite")
     assert "no_remat_oom" not in run(tiny, "cpu")
     assert "no_remat_oom" not in run(flagship, "TPU v4")
+
+
+def test_chip_stage_without_tpu_exits_nonzero(monkeypatch):
+    """A measurement stage that finds no TPU exits non-zero instead of
+    publishing CPU numbers under device-metric names; FEDML_BENCH_TINY=1 is
+    the one explicit CPU dry-run."""
+    monkeypatch.delenv("FEDML_BENCH_TINY", raising=False)
+    with pytest.raises(SystemExit) as exc:
+        bench._require_chip("llm_pallas")
+    assert exc.value.code not in (0, None)
+    assert "needs a TPU" in str(exc.value.code) and "'cpu'" in str(exc.value.code)
+    monkeypatch.setenv("FEDML_BENCH_TINY", "1")
+    bench._require_chip("llm_pallas")  # the dry-run passes
 
 
 @pytest.mark.slow

@@ -19,7 +19,6 @@ sys.path.insert(0, os.path.join(
 
 import bench_regress  # noqa: E402
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _write(tmp_path, name, doc):
@@ -116,16 +115,6 @@ class TestCompare:
         report = bench_regress.compare(str(tmp_path), 0.10)
         assert report["newest"] is None
         assert bench_regress.main(["--repo", str(tmp_path)]) == 0
-
-
-class TestRealTrajectory:
-    @pytest.mark.skipif(
-        not any(f.startswith("BENCH_MEASURED_") for f in os.listdir(REPO)),
-        reason="no measured artifacts banked")
-    def test_repo_history_is_green(self, capsys):
-        assert bench_regress.main(["--repo", REPO]) == 0
-        out = capsys.readouterr().out
-        assert "bench_regress:" in out
 
 
 class TestRenderTable:

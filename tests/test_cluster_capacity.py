@@ -145,10 +145,22 @@ def test_acquire_detects_concurrent_claim(tmp_path):
 
 
 def test_detect_local_capacity_reports_host_without_touching_jax(monkeypatch):
-    monkeypatch.delenv("FEDML_DETECT_ACCEL", raising=False)
+    """Chips are counted from device nodes: an agent that initialised JAX
+    would hold the chip its own job is about to need."""
+    import jax
+
+    from fedml_tpu.computing.scheduler import cluster
+
+    def no_jax(*a, **k):
+        raise AssertionError("capacity detection must not initialise JAX")
+
+    monkeypatch.setattr(jax, "devices", no_jax)
     cap = detect_local_capacity(3)
     assert cap.edge_id == 3 and cap.cores >= 1 and cap.memory_mb > 0
-    assert cap.slots_total == 0  # no opt-in probe -> no accelerator claim
+    assert cap.slots_total == 0 and cap.accelerator_kind == ""  # this host has no chip
+    monkeypatch.setattr(cluster, "local_chip_count", lambda: 4)
+    cap = detect_local_capacity(3)
+    assert cap.slots_total == cap.slots_available == 4 and cap.accelerator_kind == "tpu"
 
 
 # --- launch integration ----------------------------------------------------

@@ -54,10 +54,38 @@ class TestInstrument:
         assert rec["bytes_accessed"] and rec["bytes_accessed"] > 0
         assert rec["op_intensity"] == pytest.approx(
             rec["flops_xla"] / rec["bytes_accessed"])
+        # the CPU is not in the peak table: no invented peak, so no MFU
+        # denominator and no roofline verdict
+        assert rec["peak_flops_per_sec"] is None
+        assert rec["roofline_verdict"] is None
+        assert rec["flops_source"] == devperf.FLOPS_SOURCE_XLA
+
+    def test_known_device_kind_gets_peak_and_verdict(self, monkeypatch):
+        monkeypatch.setattr(devperf, "_device_kind", lambda: "TPU v5 lite")
+        fn, x = _instrumented_matmul("t_known")
+        float(fn(x))
+        rec = devperf.get_registry().snapshot()["programs"]["t_known"]
+        assert rec["peak_flops_per_sec"] == pytest.approx(197e12)
         assert rec["roofline_verdict"] in (devperf.VERDICT_COMPUTE,
                                            devperf.VERDICT_BANDWIDTH)
-        assert rec["peak_flops_per_sec"] and rec["peak_flops_per_sec"] > 0
-        assert rec["flops_source"] == devperf.FLOPS_SOURCE_XLA
+
+    def test_signature_drift_raises_instead_of_rejitting(self):
+        """One wrapper serves one signature: a call the captured executable
+        cannot take surfaces, it is not re-dispatched through jit (a second,
+        unrecorded compile that would also mask a sharding mismatch)."""
+        fn, x = _instrumented_matmul("t_drift")
+        float(fn(x))
+        with pytest.raises((TypeError, ValueError)):
+            fn(jnp.ones((3, 5), x.dtype))
+        assert tel.compile_count("t_drift") <= 1
+
+    def test_compile_error_surfaces(self):
+        def bad(x):
+            raise RuntimeError("boom at trace time")
+
+        fn = devperf.instrument(jax.jit(bad), "t_bad")
+        with pytest.raises(RuntimeError, match="boom at trace time"):
+            fn(jnp.ones((2,)))
 
     def test_disabled_returns_fn_unchanged(self, monkeypatch):
         monkeypatch.setenv("FEDML_DEVPERF", "0")
@@ -66,7 +94,8 @@ class TestInstrument:
         assert devperf.observe_step("t_disabled", 1.0) is None
         assert devperf.start_hbm_sampler() is None
 
-    def test_caller_hint_beats_cost_analysis(self):
+    def test_caller_hint_beats_cost_analysis(self, monkeypatch):
+        monkeypatch.setattr(devperf, "_device_kind", lambda: "TPU v5 lite")
         fn, x = _instrumented_matmul("t_hint", flops_hint=123.0)
         float(fn(x))
         rec = devperf.get_registry().snapshot()["programs"]["t_hint"]
@@ -88,12 +117,12 @@ class TestMfuParity:
         flops_per_token, tokens_per_step, steps, wall = 250.0, 512, 8, 0.4
         reg = devperf.get_registry()
         reg.register("t_parity", flops_per_token_hint=flops_per_token)
-        reg.note_capture("t_parity", device_kind="unknown-chip",
+        reg.note_capture("t_parity", device_kind="TPU v5 lite",
                          flops_xla=None, bytes_accessed=None, memory=None,
                          aot=False)
         mfu = devperf.observe_step("t_parity", wall, steps=steps,
                                    tokens=steps * tokens_per_step)
-        peak = device_specs.peak_flops_per_sec("unknown-chip")
+        peak = device_specs.peak_flops_per_sec("TPU v5 lite")
         expected = bench._mfu_from_rate(
             tokens_per_sec=steps * tokens_per_step / wall,
             step_flops=flops_per_token * tokens_per_step,
@@ -111,9 +140,12 @@ class TestMfuParity:
         assert bench._chip_peak_tflops(_Dev(), 16) == pytest.approx(
             device_specs.peak_tflops("TPU v4", 16))
         assert device_specs.peak_tflops("v5p", 16) == pytest.approx(459.0)
-        # unknown chips fall back to the modest CPU-CI peak, never 0
-        assert device_specs.peak_tflops("cpu", 16) == pytest.approx(
-            device_specs.UNKNOWN_PEAK_TFLOPS)
+        # a device outside the table has NO peak (callers then report no
+        # MFU); an unlisted TPU is an error, not a default
+        assert device_specs.peak_tflops("cpu", 16) is None
+        assert device_specs.roofline_ridge_flops_per_byte("cpu") is None
+        with pytest.raises(ValueError, match="TPU v9"):
+            device_specs.peak_tflops("TPU v9", 16)
         assert bench._device_hbm_fallback("v5 lite") == 16 * 1024**3
 
 
@@ -161,7 +193,7 @@ class TestHbmSampler:
     def test_prom_gauges_expose_hbm_and_programs(self):
         reg = devperf.get_registry()
         reg.register("t_prom", flops_hint=100.0)
-        reg.note_capture("t_prom", device_kind="", flops_xla=None,
+        reg.note_capture("t_prom", device_kind="TPU v5 lite", flops_xla=None,
                          bytes_accessed=None, memory=None, aot=False)
         devperf.observe_step("t_prom", 0.5)
         reg.note_hbm("dev:0", {"bytes_in_use": 7.0, "peak_bytes_in_use": 9.0,
@@ -213,7 +245,7 @@ class TestAttribution:
         assert spans == {"fedavg.round": 20.0, "client.train": 14.0}
         reg = devperf.get_registry()
         reg.register("llm_train", flops_hint=1e9)
-        reg.note_capture("llm_train", device_kind="", flops_xla=None,
+        reg.note_capture("llm_train", device_kind="TPU v5 lite", flops_xla=None,
                          bytes_accessed=None, memory=None, aot=False)
         devperf.observe_step("llm_train", 14.0)
         report = perf_report.attribute(
@@ -241,9 +273,10 @@ class TestMfuCollapseAlert:
                        if r["name"] == "mfu_collapse")
             eng = slo.SLOEngine([slo.SLOSpec(**row)], store=store,
                                 front="test")
-            # a ~1e4-FLOP program against a >=50ms throttled wall sits at
-            # ~1e-7 MFU even vs the modest unknown-chip peak: two orders of
-            # magnitude under the pack's 1e-5 collapse floor
+            # a ~1e4-FLOP program against a >=50ms throttled wall sits far
+            # under the pack's 1e-5 collapse floor vs a v5e's peak (the CPU
+            # itself has no peak, hence no MFU to collapse)
+            monkeypatch.setattr(devperf, "_device_kind", lambda: "TPU v5 lite")
             fn, x = _instrumented_matmul("t_chaos", size=16)
             with flight_recorder.installed(role="test"):
                 for _ in range(4):

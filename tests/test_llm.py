@@ -107,78 +107,50 @@ class TestAttentionImpls:
         for name, a, b in zip("dq dk dv".split(), got, want):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name)
 
-    def test_flash_wide_stats_mode_matches_xla(self, monkeypatch):
-        """FEDML_FLASH_WIDE_STATS=1: lse/delta broadcast over 128 lanes (the
-        official jax kernel's layout; the Mosaic-acceptance hedge for the
-        default (block_q, 1) layout) — fwd + all three grads must match the
-        einsum path exactly like narrow mode does."""
+    def test_flash_nondefault_blocks_match_xla(self):
+        """Explicit block sizes (the bench's attn_micro sweep passes them)
+        stay numerically exact at a non-default, uneven config."""
+        from fedml_tpu.models.transformer import repeat_kv
         from fedml_tpu.ops.flash_attention import flash_attention
 
-        monkeypatch.setenv("FEDML_FLASH_WIDE_STATS", "1")
-        B, T, Hq, Hkv, D = 1, 256, 4, 2, 16
-        ks = jax.random.split(jax.random.PRNGKey(11), 3)
-        q = jax.random.normal(ks[0], (B, T, Hq, D), jnp.float32)
-        k = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
-        v = jax.random.normal(ks[2], (B, T, Hkv, D), jnp.float32)
-        g = jax.random.normal(jax.random.PRNGKey(12), (B, T, Hq, D), jnp.float32)
-        from fedml_tpu.models.transformer import repeat_kv
-
-        kr, vr = repeat_kv(k, v, Hq)
-        ref = xla_attention(q, kr, vr, causal=True)
-        out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-        def f_flash(q, k, v):
-            return (flash_attention(q, k, v, causal=True,
-                                    block_q=128, block_k=128) * g).sum()
-
-        def f_xla(q, k, v):
-            kr, vr = repeat_kv(k, v, Hq)
-            return (xla_attention(q, kr, vr, causal=True) * g).sum()
-
-        got = jax.grad(f_flash, (0, 1, 2))(q, k, v)
-        want = jax.grad(f_xla, (0, 1, 2))(q, k, v)
-        for name, a, b in zip("dq dk dv".split(), got, want):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-5, err_msg=name)
-        # small-block shapes can't host 128 lanes: under a wide verdict
-        # (narrow is Mosaic-rejected) they take the einsum fallback — never
-        # the rejected narrow layout — and stay numerically correct
-        out_small = flash_attention(q[:, :32], k[:, :32], v[:, :32],
-                                    causal=True, block_q=16, block_k=16)
-        kr_s, vr_s = repeat_kv(k[:, :32], v[:, :32], Hq)
-        np.testing.assert_allclose(
-            np.asarray(out_small),
-            np.asarray(xla_attention(q[:, :32], kr_s, vr_s, causal=True)),
-            atol=2e-5)
-
-    def test_flash_block_env_override_matches_xla(self, monkeypatch):
-        """FEDML_FLASH_BLOCK_Q/K (the attn_micro sweep's tuned-config
-        channel) resolve the default block sizes; the kernel must stay
-        numerically exact at a non-default config, and an invalid value
-        must fall back to the 128 default instead of crashing."""
-        from fedml_tpu.ops import flash_attention as fa
-
-        monkeypatch.setenv("FEDML_FLASH_BLOCK_Q", "64")
-        monkeypatch.setenv("FEDML_FLASH_BLOCK_K", "256")
         B, T, Hq, Hkv, D = 1, 256, 4, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(21), 3)
         q = jax.random.normal(ks[0], (B, T, Hq, D), jnp.float32)
         k = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
         v = jax.random.normal(ks[2], (B, T, Hkv, D), jnp.float32)
-        from fedml_tpu.models.transformer import repeat_kv
-
         kr, vr = repeat_kv(k, v, Hq)
         ref = xla_attention(q, kr, vr, causal=True)
-        out = fa.flash_attention(q, k, v, causal=True)  # env-resolved blocks
+        out = flash_attention(q, k, v, causal=True, block_q=64, block_k=256)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-        # invalid: not a multiple of the lane granularity -> default, warn
-        monkeypatch.setenv("FEDML_FLASH_BLOCK_K", "100")
-        with pytest.warns(UserWarning, match="FEDML_FLASH_BLOCK_K"):
-            assert fa._env_block(fa._BLOCK_K_ENV, 128, 128) == 128
-        # explicit caller args always win over env
-        out2 = fa.flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+        out2 = flash_attention(q, k, v, causal=True)  # the 128x128 constants
         np.testing.assert_allclose(np.asarray(out2), np.asarray(ref), atol=2e-5)
+
+    def test_flash_untileable_shape_raises(self):
+        """A caller that asked for the pallas kernel never silently gets
+        einsum attention: blocks that do not tile the sequence raise."""
+        from fedml_tpu.ops.flash_attention import flash_attention, tiles
+
+        q, k, v = self._qkv(T=200)
+        assert not tiles(200) and tiles(256) and tiles(100)
+        with pytest.raises(ValueError, match="do not tile seq_len 200"):
+            flash_attention(q, k, v, causal=True)
+
+    def test_flash_bf16_under_ambient_highest_precision(self):
+        """bf16 operands pin DEFAULT matmul precision: under an ambient
+        ``highest`` context Mosaic rejects a bf16 contraction with fp32
+        contract precision ("Bad lhs type" on v5e, PR 21) — the lowered
+        kernel must not carry it."""
+        from fedml_tpu.ops.flash_attention import flash_attention
+
+        q, k, v = (x.astype(jnp.bfloat16) for x in self._qkv())
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+        with jax.default_matmul_precision("highest"):
+            jaxpr = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
+        assert "pallas_call" in jaxpr
+        assert "HIGHEST" not in jaxpr.split("pallas_call", 1)[1]
 
     def test_flash_grads_match_xla(self):
         # the Pallas backward kernels (dq + dkv) against einsum autodiff,

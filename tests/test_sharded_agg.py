@@ -89,9 +89,10 @@ class TestMeshSpec:
         assert topo["axis_sizes"] == [2, 4]
         assert topo["n_devices"] == 8
 
-    def test_oversized_spec_falls_back_to_none(self):
+    def test_oversized_spec_raises(self):
         dmesh.configure_server_mesh(spec="fsdp:64")
-        assert dmesh.server_mesh() is None
+        with pytest.raises(ValueError, match="needs 64 devices but only 8"):
+            dmesh.server_mesh()
 
     def test_unconfigured_is_none(self):
         assert dmesh.configured_spec() is None
@@ -117,11 +118,12 @@ class TestEngineRegistry:
         dmesh.configure_server_mesh(spec=None)
         assert get_engine(16) is plain
 
-    def test_configured_spec_on_oversized_mesh_stays_unsharded(self):
-        # a spec that cannot be satisfied resolves to the single-device
-        # engine (the sp CPU tier-1 behavior contract)
+    def test_configured_spec_on_oversized_mesh_raises(self):
+        # a spec that cannot be satisfied is an error, never a quiet
+        # single-device engine
         dmesh.configure_server_mesh(spec="fsdp:64")
-        assert type(get_engine(16)) is BucketedAggregator
+        with pytest.raises(ValueError, match="needs 64 devices"):
+            get_engine(16)
 
     def test_reset_engines_drops_cache(self):
         eng = get_engine(16)
@@ -249,6 +251,23 @@ class TestShardedFedOptServer:
                 b = np.asarray(host_s[name])
                 scale = np.max(np.abs(a)) + 1e-12
                 assert np.max(np.abs(a - b)) / scale < 1e-4, (opt, name)
+
+    def test_optimizer_state_sharded_before_first_round(self):
+        """Adam's moments must carry the layout's vec_sharding from
+        construction: on device 0 they would pin the whole optimizer state
+        to one chip AND cost a retrace when round 1's outputs come back
+        sharded (the seed's ``round_traces == 2``)."""
+        eng = ShardedBucketedAggregator(4, _mesh8())
+        params = {"w": jnp.ones((16, 3), jnp.float32), "b": jnp.ones((5,), jnp.float32)}
+        srv = ShardedFedOptServer(
+            types.SimpleNamespace(server_optimizer="adam", server_lr=0.1), params, eng)
+        moments = [l for l in jax.tree.leaves(srv._state) if l.ndim == 1]
+        assert moments, "adam state should hold mu/nu group vectors"
+        for leaf in moments:
+            assert leaf.sharding == srv.layout.vec_sharding
+        for leaf in jax.tree.leaves(srv._state):
+            if leaf.ndim == 0:
+                assert leaf.sharding == srv.layout.repl_sharding
 
     def test_one_round_trace_and_sharded_outputs(self):
         _g_u, g_s, srv_s, _eng = self._run_rounds()
