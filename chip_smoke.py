@@ -21,11 +21,15 @@ random from a seed):
             steps of __graft_entry__.multichip_steps, FedOpt with
             server_mesh fsdp:4
 
-Every phase must pass; nothing here catches a failure and carries on. The last
-stdout line is one JSON object ending in ``"claim": null``: the wall times it
-holds are smoke timings on the named device (compile included), never
-performance results. Exit code 0 only on a TPU with every phase passing; with
-no accelerator it exits non-zero before compiling anything.
+Every phase must pass; nothing here catches a failure and carries on. The
+second-to-last stdout line, ``chip_smoke: summary {...}``, is a JSON object
+ending in ``"claim": null``: the wall times it holds are smoke timings on the
+named device (compile included), never performance results. The last stdout
+line is exactly ``{"ok": true, "device": {"platform": ..., "kind": ...,
+"count": ...}}`` with the device as JAX reports it — the driver parses that
+line and accepts no other key. Exit code 0 only on a TPU with every phase
+passing; with no accelerator it exits non-zero before compiling anything and
+prints no result.
 """
 
 from __future__ import annotations
@@ -607,9 +611,8 @@ def main(argv=None) -> int:
     if count >= 4:
         run("multichip", phase_multichip, sz, clog, dry, platform)
 
+    device = {"platform": platform, "kind": kind, "count": count}
     summary = {
-        "ok": True,
-        "device": {"platform": platform, "kind": kind, "count": count},
         "dry_run_cpu": dry,
         "jax": jax.__version__,
         "timings": f"smoke wall times on {kind} x{count}, compile included — not performance results",
@@ -618,7 +621,9 @@ def main(argv=None) -> int:
         "phases": phases,
         "claim": None,
     }
-    print(json.dumps(summary), flush=True)
+    print(f"chip_smoke: summary {json.dumps(summary)}", flush=True)
+    # the driver's contract: the last stdout line is this object and nothing more
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

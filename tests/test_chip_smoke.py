@@ -46,9 +46,18 @@ def test_smoke_dry_run_cpu_passes():
     r = _run_smoke("--dry-run-cpu", timeout=1500)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "DRY RUN" in r.stdout
-    summary = json.loads(r.stdout.strip().splitlines()[-1])
-    assert summary["ok"] is True and summary["dry_run_cpu"] is True
-    assert summary["device"]["platform"] == "cpu"
+    *_, summary_line, last = r.stdout.strip().splitlines()
+    # the driver accepts exactly these keys on the last line, no others
+    result = json.loads(last)
+    assert list(result) == ["ok", "device"] and result["ok"] is True
+    assert list(result["device"]) == ["platform", "kind", "count"]
+    assert result["device"]["platform"] == "cpu"
+    assert result["device"]["kind"] == jax.devices()[0].device_kind
+    assert type(result["device"]["count"]) is int and result["device"]["count"] >= 4
+    prefix = "chip_smoke: summary "
+    assert summary_line.startswith(prefix)
+    summary = json.loads(summary_line[len(prefix):])
+    assert summary["dry_run_cpu"] is True
     assert set(summary["phases"]) >= {"kernel", "fedavg", "llm", "serving", "multichip"}
     assert list(summary)[-1] == "claim" and summary["claim"] is None
 
