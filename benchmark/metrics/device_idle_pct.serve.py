@@ -1,0 +1,6 @@
+"""1 - union of device-op intervals over the traced part of the window."""
+
+
+def read(run):
+    t = run.get("trace")
+    return None if not t else t["idle_pct"]
