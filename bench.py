@@ -3130,7 +3130,14 @@ def _serving_load_once(reqs: list, paged: bool):
         done_gate.set()
         samp.join(timeout=2)
         st = pred.engine.stats()
-        pct = pred.engine.latency_percentiles()
+
+        def timing_pcts(key: str) -> dict:
+            # the engine's own readings, one per reply (the reply's "timing")
+            xs = sorted(r["timing"][key] for r in ok if r["timing"][key] is not None)
+            return {f"p{int(q * 100)}": xs[min(len(xs) - 1, int(q * len(xs)))] if xs else None
+                    for q in (0.5, 0.99)}
+
+        pct = {"ttft_s": timing_pcts("ttft_s"), "tpot_s": timing_pcts("tpot_s")}
         if failures:
             # acceptance is "without request failures": any failure is a
             # stage failure, with the first few causes in the record

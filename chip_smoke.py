@@ -316,7 +316,7 @@ def _check_llm(trainer, metrics, events, interpreted):
           f"loss not finite: {metrics}")
     check(metrics["final_loss"] < metrics["first_loss"],
           f"loss did not fall: {metrics['first_loss']} -> {metrics['final_loss']}")
-    step_compiles = [e for e in events if e.fun == "jit(step)"]
+    step_compiles = [e for e in events if e.fun == "jit(train_step)"]
     check(len(step_compiles) == 1,
           f"train step compiled {len(step_compiles)}x: {step_compiles}")
     hlo = trainer._step_fn.compiled.as_text()

@@ -17,6 +17,7 @@ neither path is driven end-to-end).
 from __future__ import annotations
 
 import logging
+import threading
 from typing import Any, List, Optional, Tuple
 
 import jax
@@ -38,12 +39,19 @@ _GLOBAL_SOLUTIONS = (DP_SOLUTION_CDP, DP_SOLUTION_NBAFL, DP_SOLUTION_DP_CLIP)
 
 class FedMLDifferentialPrivacy:
     _instance: Optional["FedMLDifferentialPrivacy"] = None
+    _instance_lock = threading.Lock()
 
     @classmethod
     def get_instance(cls) -> "FedMLDifferentialPrivacy":
-        if cls._instance is None:
-            cls._instance = cls()
-        return cls._instance
+        # parties of one process (the in-memory cross-silo harness) call
+        # fedml.init from threads: one instance, built once. Unlocked, two
+        # threads each built one (a device PRNG key apiece), the loser was
+        # dropped while the other thread still configured it, and the process
+        # segfaulted about once in a hundred runs of tests/test_cross_silo.py
+        with cls._instance_lock:
+            if cls._instance is None:
+                cls._instance = cls()
+            return cls._instance
 
     def __init__(self) -> None:
         self.is_enabled = False

@@ -21,6 +21,8 @@ from .core import (
     export_chrome_trace,
     get_telemetry,
     histogram,
+    profiler_sink_overhead_ns,
+    record_span,
     reset,
     set_enabled,
     snapshot,
@@ -87,6 +89,7 @@ __all__ = [
     "get_telemetry",
     "span",
     "timed",
+    "record_span",
     "counter",
     "histogram",
     "snapshot",
@@ -95,6 +98,7 @@ __all__ = [
     "set_enabled",
     "reset",
     "disabled_span_overhead_ns",
+    "profiler_sink_overhead_ns",
     "track_compiles",
     "compile_count",
     "record_transfer",

@@ -45,9 +45,11 @@ __all__ = [
     "snapshot",
     "summary",
     "export_chrome_trace",
+    "record_span",
     "set_enabled",
     "reset",
     "disabled_span_overhead_ns",
+    "profiler_sink_overhead_ns",
 ]
 
 _ENV_DISABLE = "FEDML_TELEMETRY"  # set to "0" to disable the default registry
@@ -73,6 +75,16 @@ _span_event_hook: Optional[Callable[[bool, Any, Any], None]] = None
 # Called OUTSIDE the registry lock so the store's lock stays a leaf (no
 # telemetry->tsdb ordering edge); the no-hook path is a None-check.
 _metric_sample_hook: Optional[Callable[[str, str, float], None]] = None
+
+# Installed by jax_hooks on import (this module imports no jax). Signature:
+# factory(name) -> context manager that writes one host event into the
+# profiler's trace (``jax.profiler.TraceAnnotation``). Every enabled span
+# enters one named ``PROFILER_PREFIX + <span name>`` around its own clock
+# reads, so while a ``jax.profiler`` trace is being captured the program's
+# spans sit on the device ops' clock; outside a capture the annotation is a
+# flag check in native code. The disabled path never touches it.
+_profiler_annotation: Optional[Callable[[str], Any]] = None
+PROFILER_PREFIX = "fedml:"
 
 
 class _NullSpan:
@@ -101,13 +113,15 @@ class _Span:
     """Open-span handle. Created per ``with`` block on the enabled path (and
     always by ``timed()``); records itself into the registry on exit."""
 
-    __slots__ = ("_t", "name", "attrs", "seq", "depth", "parent_seq", "t0_ns", "dur_ns", "_record")
+    __slots__ = ("_t", "name", "attrs", "seq", "depth", "parent_seq", "t0_ns", "dur_ns", "_record",
+                 "_ann")
 
     def __init__(self, t: "Telemetry", name: str, attrs: Dict[str, Any], record: bool):
         self._t = t
         self.name = name
         self.attrs = attrs
         self._record = record
+        self._ann = None
         self.dur_ns: Optional[int] = None
 
     @property
@@ -130,12 +144,18 @@ class _Span:
         hook = _span_event_hook
         if hook is not None and t._enabled:
             hook(True, self, None)
+        ann = _profiler_annotation
+        if ann is not None and t._enabled:
+            self._ann = ann(PROFILER_PREFIX + self.name)
+            self._ann.__enter__()
         self.t0_ns = time.perf_counter_ns()  # last: exclude bookkeeping
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()  # first: exclude bookkeeping
         self.dur_ns = t1 - self.t0_ns
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         t = self._t
         stack = t._stack()
         if stack and stack[-1] is self:
@@ -310,19 +330,35 @@ class Telemetry:
                 h = self._histograms[name] = Histogram(name, self)
             return h
 
+    def record_span(self, name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+        """Record a span from two ``time.perf_counter_ns()`` readings: an
+        interval that starts on one thread and ends on another (a request's
+        wait in a queue), which no ``with`` block can bracket. Same record
+        and ``span_stats`` roll-up as ``span()``; it is a root span of the
+        recording thread's lane and touches no nesting state. A no-op on a
+        disabled registry."""
+        if self._enabled:
+            self._append_record(name, None, None, 0, int(t0_ns), int(t1_ns) - int(t0_ns), attrs, False)
+
     def _record_span(self, sp: _Span, errored: bool) -> None:
+        self._append_record(sp.name, sp.seq, sp.parent_seq, sp.depth, sp.t0_ns, sp.dur_ns,
+                            sp.attrs, errored)
+
+    def _append_record(self, name: str, seq: Optional[int], parent_seq: Optional[int], depth: int,
+                       t0_ns: int, dur_ns: int, attrs: Dict[str, Any], errored: bool) -> None:
+        """``seq`` None (a span from two readings): numbered here, at its end."""
         tid = threading.get_ident()
         rec = {
-            "name": sp.name,
-            "seq": sp.seq,
-            "parent_seq": sp.parent_seq,
-            "depth": sp.depth,
-            "t0_ns": sp.t0_ns - self._epoch_ns,
-            "dur_ns": sp.dur_ns,
+            "name": name,
+            "seq": seq,
+            "parent_seq": parent_seq,
+            "depth": depth,
+            "t0_ns": t0_ns - self._epoch_ns,
+            "dur_ns": dur_ns,
             "tid": tid,
         }
-        if sp.attrs:
-            rec["attrs"] = sp.attrs
+        if attrs:
+            rec["attrs"] = attrs
         if errored:
             rec["error"] = True
         getter = _trace_ctx_getter
@@ -335,14 +371,17 @@ class Telemetry:
                 if ctx.round_idx is not None:
                     rec["trace_round"] = ctx.round_idx
         with self._lock:
+            if seq is None:
+                self._seq += 1
+                rec["seq"] = self._seq
             self._thread_names.setdefault(tid, threading.current_thread().name)
-            st = self._span_stats.get(sp.name)
+            st = self._span_stats.get(name)
             if st is None:
-                st = self._span_stats[sp.name] = [0, 0.0, 0.0]
+                st = self._span_stats[name] = [0, 0.0, 0.0]
             st[0] += 1
-            st[1] += sp.dur_ns
-            if sp.dur_ns > st[2]:
-                st[2] = sp.dur_ns
+            st[1] += dur_ns
+            if dur_ns > st[2]:
+                st[2] = dur_ns
             if len(self._spans) < self.max_span_records:
                 self._spans.append(rec)
             else:
@@ -399,10 +438,14 @@ class Telemetry:
 
     def snapshot(self) -> Dict[str, Any]:
         """Plain-dict view for programmatic assertion. Spans are in START
-        order (``seq`` is assigned at entry), with parentage + depth."""
+        order (``seq`` is assigned at entry), with parentage + depth.
+        ``epoch_perf_ns`` is the ``time.perf_counter_ns()`` reading every
+        span's ``t0_ns`` is relative to, so a reader can place spans against
+        ``time.perf_counter()`` readings taken elsewhere in the process."""
         with self._lock:
             spans = sorted(self._spans, key=lambda r: r["seq"])
             return {
+                "epoch_perf_ns": self._epoch_ns,
                 "spans": [dict(r) for r in spans],
                 "counters": {k: c.value for k, c in self._counters.items()},
                 "histograms": {k: h.as_dict() for k, h in self._histograms.items()},
@@ -510,6 +553,10 @@ def timed(name: str, **attrs) -> _Span:
     return _DEFAULT.timed(name, **attrs)
 
 
+def record_span(name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+    _DEFAULT.record_span(name, t0_ns, t1_ns, **attrs)
+
+
 def counter(name: str) -> Counter:
     return _DEFAULT.counter(name)
 
@@ -559,3 +606,33 @@ def disabled_span_overhead_ns(iters: int = 2000, batches: int = 5) -> float:
         return best
     finally:
         t.set_enabled(was)
+
+
+def profiler_sink_overhead_ns(iters: int = 2000, batches: int = 5) -> float:
+    """What the profiler sink adds to one ENABLED span while no trace is
+    being captured, in ns: spans of a scratch registry with the annotation
+    hook installed against the same with it taken out, minimum over batches
+    on each side (the contract is < 2µs; tests/test_telemetry.py pins it)."""
+    global _profiler_annotation
+    t = Telemetry(enabled=True, max_span_records=0)
+
+    def best() -> float:
+        out = float("inf")
+        for _ in range(batches):
+            t0 = time.perf_counter_ns()
+            for _ in range(iters):
+                with t.span("overhead.probe"):
+                    pass
+            out = min(out, (time.perf_counter_ns() - t0) / iters)
+        return out
+
+    with t.span("overhead.resolve"):  # let a lazy hook bind itself first
+        pass
+    hook = _profiler_annotation
+    try:
+        with_sink = best()
+        _profiler_annotation = None
+        without = best()
+    finally:
+        _profiler_annotation = hook
+    return max(0.0, with_sink - without)

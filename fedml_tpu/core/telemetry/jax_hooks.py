@@ -1,13 +1,18 @@
 """JAX-aware telemetry hooks.
 
-Nothing here imports jax — both hooks exploit properties of *call sites*:
+Importing this module imports no jax — the hooks exploit properties of *call
+sites*:
 
 - ``track_compiles`` wraps a function so a counter bumps when the body runs
   under tracing. Inside ``jax.jit`` the Python body executes only on (re)trace,
   so the counter advances per compile, not per call — the same trick
   ``BucketedAggregator.accum_traces`` uses (tests/test_bucketed_agg.py pins it).
+  The wrapper carries the label as its ``__name__``, so the jitted program
+  reaches the profiler's trace and the HLO as ``jit_<label>``.
 - ``record_transfer`` is called from the ``utils/pytree.py`` flat-vector comm
   boundary with the byte count of each host<->device hop.
+- the registry's second span sink: ``core._profiler_annotation`` is pointed at
+  ``jax.profiler.TraceAnnotation``, resolved on the first enabled span.
 """
 
 from __future__ import annotations
@@ -15,6 +20,7 @@ from __future__ import annotations
 import functools
 from typing import Any, Callable, Optional
 
+from . import core as _core
 from .core import Telemetry, get_telemetry
 
 COMPILE_COUNTER_PREFIX = "jax.compiles."
@@ -38,7 +44,21 @@ def track_compiles(fn: Callable, name: Optional[str] = None, telemetry: Optional
         (telemetry or get_telemetry()).counter(COMPILE_COUNTER_PREFIX + label).add(1)
         return fn(*args, **kwargs)
 
+    # jax.jit names the program after the function it is handed
+    wrapped.__name__ = wrapped.__qualname__ = label
     return wrapped
+
+
+def _profiler_annotation(name: str):
+    """First enabled span of the process: bind the profiler's annotation
+    class in place of this resolver, so later spans call it directly."""
+    from jax.profiler import TraceAnnotation
+
+    _core._profiler_annotation = TraceAnnotation
+    return TraceAnnotation(name)
+
+
+_core._profiler_annotation = _profiler_annotation
 
 
 def compile_count(name: str, telemetry: Optional[Telemetry] = None) -> int:

@@ -117,6 +117,20 @@ def new_trace_id() -> str:
     return os.urandom(16).hex()
 
 
+# HTTP request header the serving gateway writes and the replica parses
+# (the W3C name; the value is ``to_traceparent()``, no second wire format).
+TRACEPARENT_HEADER = "traceparent"
+
+
+def request_id() -> str:
+    """The id every span of the serving request in flight on this thread
+    carries: the active context's ``trace_id`` (the gateway or the HTTP
+    handler opened one per request), else a fresh one — the caller is then
+    the first program boundary the request crosses."""
+    ctx = current()
+    return ctx.trace_id if ctx is not None else new_trace_id()
+
+
 # --- thread-local active context ----------------------------------------
 _tls = threading.local()
 

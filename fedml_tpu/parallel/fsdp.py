@@ -115,7 +115,8 @@ def make_fsdp_train_step(
         logits, aux = out if isinstance(out, tuple) else (out, 0.0)
         return causal_lm_loss(logits, tokens, mask) + aux
 
-    def step(params, opt_state, tokens, mask):
+    # named for the trace: the jitted program is jit_train_step there
+    def train_step(params, opt_state, tokens, mask):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, mask)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
@@ -136,7 +137,7 @@ def make_fsdp_train_step(
         batch_spec = P(batch_axes, seq_axis) if seq_axis else P(batch_axes)
         data_shard = NamedSharding(mesh, batch_spec)
         return jax.jit(
-            step,
+            train_step,
             in_shardings=(p_shard, o_shard, data_shard, data_shard),
             out_shardings=(p_shard, o_shard, NamedSharding(mesh, P())),
             donate_argnums=(0, 1) if donate else (),

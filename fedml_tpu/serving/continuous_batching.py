@@ -29,7 +29,11 @@ admission (see ``Attention._decode_attention``'s cache_idx notes).
 
 Telemetry: TTFT/TPOT histograms, token/request counters, and a ``stats()``
 snapshot (slot occupancy, queue depth) that the inference runner exports
-as Prometheus gauges and ``/statusz`` fields.
+as Prometheus gauges and ``/statusz`` fields. Spans (docs/observability.md,
+"Serving request lifecycle"): every request leaves ``serving.request.queue``
+/ ``.admit`` / ``.decode`` records carrying its ``request_id``; the worker
+loop leaves ``serving.engine.iteration`` with its children and
+``serving.engine.idle``.
 """
 
 from __future__ import annotations
@@ -47,7 +51,7 @@ import numpy as np
 
 from ..core import telemetry as tel
 from ..core.pipeline.executor import PipelinedExecutor, PipelineError, StageSpec
-from ..core.telemetry import devperf, track_compiles, tsdb
+from ..core.telemetry import devperf, trace_context, track_compiles, tsdb
 from ..models.transformer import TransformerConfig
 from ..train.llm.generation import (
     _lru_get,
@@ -146,12 +150,15 @@ class RequestHandle:
     """Future for one submitted request. ``result()`` blocks for the full
     token list; ``text`` is filled when the engine has a tokenizer."""
 
-    def __init__(self):
+    def __init__(self, request_id: Optional[str] = None):
         self._ev = threading.Event()
         self._tokens: Optional[List[int]] = None
         self._exc: Optional[BaseException] = None
         self.text: Optional[str] = None
-        self.ttft_s: Optional[float] = None
+        # the caller's, else the thread's active trace context's, else minted
+        self.request_id: str = request_id or trace_context.request_id()
+        self.queue_wait_s: Optional[float] = None  # enqueued -> popped for admission
+        self.ttft_s: Optional[float] = None  # enqueued -> first token on the host
         self.tpot_s: Optional[float] = None
 
     def done(self) -> bool:
@@ -182,7 +189,10 @@ class _Pending:
     seed: int
     eos_ids: Optional[Tuple[int, ...]]
     handle: RequestHandle
-    t_submit: float
+    request_id: str
+    t_submit_ns: int  # perf_counter_ns readings: the request's spans are
+    t_pop_ns: int = 0  # recorded from them (tel.record_span) by the worker
+    queue_depth: int = 0  # requests ahead of this one when it was enqueued
     tenant: str = "default"
     wfq_tag: float = 0.0  # weighted-fair-queueing virtual finish tag
 
@@ -192,7 +202,7 @@ class _Active:
     pending: _Pending
     budget: int  # max_new clamped to cache capacity at admit
     tokens: List[int] = dataclasses.field(default_factory=list)
-    t_first: float = 0.0
+    t_first_ns: int = 0
     generated: int = 0  # device tokens produced, kept OR discarded
 
 
@@ -243,10 +253,6 @@ class ContinuousBatchingEngine:
         self._stopping = False
         self._requests_done = 0
         self._tokens_out = 0  # KEPT tokens (post-EOS/budget truncation)
-        # bounded recent samples: exact TTFT/TPOT percentiles for the load
-        # bench + /statusz (histogram buckets are too coarse for p99)
-        self._recent_ttft: "collections.deque[float]" = collections.deque(maxlen=8192)
-        self._recent_tpot: "collections.deque[float]" = collections.deque(maxlen=8192)
         self._worker = threading.Thread(
             target=self._loop, name="cb-engine", daemon=True
         )
@@ -278,8 +284,9 @@ class ContinuousBatchingEngine:
         seed: int = 0,
         eos_id=None,
         tenant: str = "default",
+        request_id: Optional[str] = None,
     ) -> RequestHandle:
-        handle = RequestHandle()
+        handle = RequestHandle(request_id)
         prompt = [int(t) for t in prompt]
         eos_ids: Optional[Tuple[int, ...]] = None
         if eos_id is not None:
@@ -306,7 +313,8 @@ class ContinuousBatchingEngine:
             return handle
         item = _Pending(
             prompt, int(max_new_tokens), float(temperature), int(seed),
-            eos_ids, handle, time.perf_counter(), tenant=str(tenant),
+            eos_ids, handle, handle.request_id, time.perf_counter_ns(),
+            tenant=str(tenant),
         )
         with self._work:
             if self._stopping:
@@ -316,6 +324,7 @@ class ContinuousBatchingEngine:
                 self._reject_queue_full(item)
                 return handle
             self._on_enqueue(item)
+            item.queue_depth = len(self._queue)
             self._queue.append(item)
             tel.counter("serving.cb.requests").add(1)
             self._work.notify()
@@ -358,26 +367,6 @@ class ContinuousBatchingEngine:
                 "tokens_out": self._tokens_out,
             }
 
-    def latency_percentiles(self) -> dict:
-        """Exact percentiles over the recent-sample windows (seconds)."""
-
-        def pct(samples, qs):
-            if not samples:
-                return {f"p{int(q * 100)}": None for q in qs}
-            xs = sorted(samples)
-            return {
-                f"p{int(q * 100)}": xs[min(len(xs) - 1, int(q * len(xs)))]
-                for q in qs
-            }
-
-        with self._lock:
-            ttft = list(self._recent_ttft)
-            tpot = list(self._recent_tpot)
-        return {
-            "ttft_s": pct(ttft, (0.5, 0.99)),
-            "tpot_s": pct(tpot, (0.5, 0.99)),
-        }
-
     def shutdown(self, timeout: float = 10.0) -> None:
         """Stop the worker; queued and in-flight requests fail fast (the
         callers' futures unblock) rather than hang."""
@@ -388,15 +377,17 @@ class ContinuousBatchingEngine:
 
     # -- worker ------------------------------------------------------------
 
+    def _idle_locked(self) -> bool:
+        return (not self._stopping and not self._queue
+                and all(s is None for s in self._slots))
+
     def _loop(self) -> None:
         while True:
             with self._work:
-                while (
-                    not self._stopping
-                    and not self._queue
-                    and all(s is None for s in self._slots)
-                ):
-                    self._work.wait()
+                if self._idle_locked():
+                    with tel.span("serving.engine.idle"):
+                        while self._idle_locked():
+                            self._work.wait()
                 if self._stopping:
                     err = RuntimeError("engine is shutting down")
                     for item in self._queue:
@@ -408,10 +399,14 @@ class ContinuousBatchingEngine:
                             self._release_slot(i, s)
                             self._slots[i] = None
                     return
+                n_active = sum(1 for s in self._slots if s is not None)
+                n_queued = len(self._queue)
             try:
-                self._admit_all()  # fedlint: disable=interproc-host-sync admission copies prompts host->device once per request, not per token; the r05 per-token sync lived in _step_chunk's decode path and is gone
-                if any(s is not None for s in self._slots):
-                    self._step_chunk()  # fedlint: disable=interproc-host-sync one bounded sync per decode chunk is the engine's design: tokens must reach the host to stream to callers
+                with tel.span("serving.engine.iteration", slots=n_active,
+                              queue_depth=n_queued):
+                    self._admit_all()  # fedlint: disable=interproc-host-sync admission copies prompts host->device once per request, not per token; the r05 per-token sync lived in _step_chunk's decode path and is gone
+                    if any(s is not None for s in self._slots):
+                        self._step_chunk()  # fedlint: disable=interproc-host-sync one bounded sync per decode chunk is the engine's design: tokens must reach the host to stream to callers
             except Exception as e:  # noqa: BLE001 - engine thread boundary:
                 # fail every rider rather than die silently with their
                 # futures hanging; next iteration serves fresh requests
@@ -434,6 +429,7 @@ class ContinuousBatchingEngine:
                 if not self._queue:
                     return
                 item = self._queue.popleft()
+            item.t_pop_ns = time.perf_counter_ns()
             P = len(item.prompt)
             # clamp to capacity: decode writes land at P..P+budget-2 (the
             # first token is sampled from prefill logits, never written
@@ -441,7 +437,8 @@ class ContinuousBatchingEngine:
             # in-bounds; the step fn's idx clamp absorbs mid-chunk overrun
             budget = min(item.max_new, cfg.max_seq_len - P)
             try:
-                with tel.timed("serving.cb.prefill", prompt_len=P):
+                with tel.span("serving.cb.prefill", request_id=item.request_id,
+                              prompt_len=P):
                     P_b = min(-(-P // 16) * 16, cfg.max_seq_len)
                     ids = jnp.asarray([item.prompt], jnp.int32)
                     padded = (
@@ -465,22 +462,33 @@ class ContinuousBatchingEngine:
                 log.exception("continuous-batching admit failed")
                 item.handle._fail(e)
                 continue
-            now = time.perf_counter()
+            now_ns = time.perf_counter_ns()
             self._cache = cache
-            active = _Active(item, budget, [tok0], now, generated=1)
+            active = _Active(item, budget, [tok0], now_ns, generated=1)
             self._tok[free] = tok0
             self._lengths[free] = P
             self._temps[free] = item.temperature
             self._keys[free] = np.asarray(key2, np.uint32)  # fedlint: disable=host-sync PRNG row refresh once per admission; key already host-resident post-admit
-            ttft = now - item.t_submit
-            active.pending.handle.ttft_s = ttft
-            self._recent_ttft.append(ttft)
-            tel.histogram("serving.cb.ttft_seconds").observe(ttft)
-            tel.counter("serving.cb.admissions").add(1)
+            self._note_first_token(item, now_ns)
             with self._lock:
                 self._slots[free] = active
-            if self._finish_if_done(free, now):
-                continue
+            self._finish_if_done(free, now_ns)
+
+    def _note_first_token(self, item: _Pending, now_ns: int, shared: int = 0) -> float:
+        """The request's first token is on the host: its queue and admit
+        spans (``queue + admit == ttft_s`` by construction: three readings of
+        one clock), the handle's timings, the TTFT series. Returns TTFT."""
+        handle = item.handle
+        handle.queue_wait_s = (item.t_pop_ns - item.t_submit_ns) / 1e9
+        handle.ttft_s = ttft = (now_ns - item.t_submit_ns) / 1e9
+        tel.record_span("serving.request.queue", item.t_submit_ns, item.t_pop_ns,
+                        request_id=item.request_id, queue_depth=item.queue_depth)
+        tel.record_span("serving.request.admit", item.t_pop_ns, now_ns,
+                        request_id=item.request_id, prompt_len=len(item.prompt),
+                        shared=shared)
+        tel.histogram("serving.cb.ttft_seconds").observe(ttft)
+        tel.counter("serving.cb.admissions").add(1)
+        return ttft
 
     def _step_fn(self):
         return _cb_step_fn(self._cfg, self._B, self._C)
@@ -495,46 +503,49 @@ class ContinuousBatchingEngine:
             active_mask = np.asarray(
                 [s is not None for s in self._slots], bool
             )
-        fn = self._step_fn()
-        with tel.timed("serving.cb.chunk", slots=int(active_mask.sum())) as sp:
-            cache, tok, lengths, keys, toks = fn(
-                self._params,
-                self._cache,
-                *self._step_extra_args(),
-                jnp.asarray(self._tok),
-                jnp.asarray(self._lengths),
-                jnp.asarray(self._keys),
-                jnp.asarray(self._temps),
-                jnp.asarray(active_mask),
-            )
-            toks = np.asarray(toks)  # [B, C]; forces chunk completion
-        devperf.observe_step(self._devperf_label, sp.duration_s,
-                             tokens=int(active_mask.sum()) * self._C)
-        self._cache = cache
-        # np.array (not asarray): device arrays view as READ-ONLY numpy;
-        # these mirrors are mutated per-slot at admit time
-        self._tok = np.array(tok, np.int32)
-        self._lengths = np.array(lengths, np.int32)
-        self._keys = np.array(keys, np.uint32)
-        now = time.perf_counter()
         n_live = int(active_mask.sum())
-        tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
-        for b in range(self._B):
-            with self._lock:
-                s = self._slots[b]
-            if s is None:
-                continue
-            s.generated += self._C
-            for t in toks[b]:
-                t = int(t)
-                s.tokens.append(t)
-                if s.pending.eos_ids is not None and t in s.pending.eos_ids:
-                    break
-                if len(s.tokens) >= s.budget:
-                    break
-            self._finish_if_done(b, now)
+        with tel.span("serving.cb.chunk", slots=n_live):
+            with tel.timed("serving.cb.chunk.dispatch") as dispatch:
+                cache, tok, lengths, keys, toks = self._step_fn()(
+                    self._params,
+                    self._cache,
+                    *self._step_extra_args(),
+                    jnp.asarray(self._tok),
+                    jnp.asarray(self._lengths),
+                    jnp.asarray(self._keys),
+                    jnp.asarray(self._temps),
+                    jnp.asarray(active_mask),
+                )
+            with tel.timed("serving.cb.chunk.sync") as sync:
+                toks = np.asarray(toks)  # [B, C]; forces chunk completion
+            with tel.span("serving.cb.chunk.post"):
+                devperf.observe_step(self._devperf_label,
+                                     dispatch.duration_s + sync.duration_s,
+                                     tokens=n_live * self._C)
+                self._cache = cache
+                # np.array (not asarray): device arrays view as READ-ONLY
+                # numpy; these mirrors are mutated per-slot at admit time
+                self._tok = np.array(tok, np.int32)
+                self._lengths = np.array(lengths, np.int32)
+                self._keys = np.array(keys, np.uint32)
+                now_ns = time.perf_counter_ns()
+                tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
+                for b in range(self._B):
+                    with self._lock:
+                        s = self._slots[b]
+                    if s is None:
+                        continue
+                    s.generated += self._C
+                    for t in toks[b]:
+                        t = int(t)
+                        s.tokens.append(t)
+                        if s.pending.eos_ids is not None and t in s.pending.eos_ids:
+                            break
+                        if len(s.tokens) >= s.budget:
+                            break
+                    self._finish_if_done(b, now_ns)
 
-    def _finish_if_done(self, b: int, now: float) -> bool:
+    def _finish_if_done(self, b: int, now_ns: int) -> bool:
         """Free slot ``b`` if its request hit EOS or its token budget; the
         slot's cache leftovers are overwritten wholesale on re-admission."""
         with self._lock:
@@ -551,9 +562,8 @@ class ContinuousBatchingEngine:
         else:
             s.tokens = s.tokens[: s.budget]
         if len(s.tokens) > 1:
-            tpot = (now - s.t_first) / (len(s.tokens) - 1)
+            tpot = (now_ns - s.t_first_ns) / 1e9 / (len(s.tokens) - 1)
             s.pending.handle.tpot_s = tpot
-            self._recent_tpot.append(tpot)
             tel.histogram("serving.cb.tpot_seconds").observe(tpot)
         # EOS/budget mid-chunk waste, measured instead of silent: the slot
         # kept burning decode FLOPs until the chunk boundary; the paged
@@ -561,6 +571,9 @@ class ContinuousBatchingEngine:
         wasted = s.generated - len(s.tokens)
         if wasted > 0:
             tel.counter("serving.wasted_tokens").add(wasted)
+        tel.record_span("serving.request.decode", s.t_first_ns, now_ns,
+                        request_id=s.pending.request_id, tokens=len(s.tokens),
+                        wasted=wasted)
         self._release_slot(b, s)
         with self._lock:
             self._slots[b] = None
@@ -677,18 +690,19 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         seed: int = 0,
         eos_id=None,
         tenant: str = DEFAULT_TENANT,
+        request_id: Optional[str] = None,
     ) -> RequestHandle:
         if self._admission is not None:
             prompt = [int(t) for t in prompt]
             reason = self._admission.check(
                 tenant, len(prompt) + int(max_new_tokens))
             if reason is not None:
-                handle = RequestHandle()
+                handle = RequestHandle(request_id)
                 handle._fail(AdmissionError(tenant, reason))
                 return handle
         return super().submit(
             prompt, max_new_tokens, temperature=temperature, seed=seed,
-            eos_id=eos_id, tenant=tenant)
+            eos_id=eos_id, tenant=tenant, request_id=request_id)
 
     def _on_enqueue(self, item: _Pending) -> None:
         if self._admission is not None:
@@ -714,7 +728,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     # worker loop hot while backpressure holds
                     time.sleep(0.005)  # fedlint: disable=bare-sleep backpressure idle, not a retry
                 return
-            with tel.timed("serving.paged.admit_wave", n=len(wave)):
+            with tel.span("serving.paged.admit_wave", n=len(wave)):
                 self._run_wave(wave)
 
     def _pick_locked(self) -> Optional[_Pending]:
@@ -752,6 +766,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 item = self._pick_locked()
             if item is None:  # every queued tenant is deferred right now
                 return wave
+            item.t_pop_ns = time.perf_counter_ns()  # a deferred item is popped again
             P = len(item.prompt)
             budget = min(item.max_new, cfg.max_seq_len - P)
             n_req = -(-(P + budget) // self._ps)
@@ -810,7 +825,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         item = w.item
         P = len(item.prompt)
         prefix_len = w.n_shared * self._ps
-        with tel.timed("serving.cb.prefill", prompt_len=P, shared=prefix_len):
+        with tel.span("serving.cb.prefill", request_id=item.request_id,
+                      prompt_len=P, shared=prefix_len):
             if w.n_shared == 0:
                 P_b = min(-(-P // 16) * 16, cfg.max_seq_len)
                 ids = jnp.asarray([item.prompt], jnp.int32)
@@ -846,16 +862,17 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         decode pool."""
         item = w.item
         P = len(item.prompt)
-        write_ids = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
-        first_blk = w.n_shared
-        last_blk = -(-P // self._ps)  # exclusive: block of the last token
-        write_ids[first_blk:last_blk] = w.private_pages[:last_blk - first_blk]
-        pool, tok0, key2 = _paged_admit_fn(self._paged_cfg)(
-            self._cache, w.row_cache, jnp.asarray(write_ids), w.first_vec,
-            jax.random.PRNGKey(item.seed), jnp.float32(item.temperature))
-        self._cache = pool
-        w.tok0 = int(np.asarray(tok0))  # fedlint: disable=host-sync forces transfer completion: one sync per admission, not per decode step
-        w.key2 = np.asarray(key2, np.uint32)
+        with tel.span("serving.paged.transfer", request_id=item.request_id):
+            write_ids = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
+            first_blk = w.n_shared
+            last_blk = -(-P // self._ps)  # exclusive: block of the last token
+            write_ids[first_blk:last_blk] = w.private_pages[:last_blk - first_blk]
+            pool, tok0, key2 = _paged_admit_fn(self._paged_cfg)(
+                self._cache, w.row_cache, jnp.asarray(write_ids), w.first_vec,
+                jax.random.PRNGKey(item.seed), jnp.float32(item.temperature))
+            self._cache = pool
+            w.tok0 = int(np.asarray(tok0))  # fedlint: disable=host-sync forces transfer completion: one sync per admission, not per decode step
+            w.key2 = np.asarray(key2, np.uint32)
         return w
 
     def _stage_admit(self, w: _AdmitWork) -> _AdmitWork:
@@ -864,30 +881,27 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         cache so the NEXT request with this system prompt shares pages."""
         item = w.item
         b = w.slot
-        now = time.perf_counter()
-        table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
-        n_own = w.n_shared + len(w.private_pages)
-        table[:w.n_shared] = w.shared_pages
-        table[w.n_shared:n_own] = w.private_pages
-        self._tok[b] = w.tok0
-        self._lengths[b] = len(item.prompt)
-        self._temps[b] = item.temperature
-        self._keys[b] = w.key2
-        self._tables[b] = table
-        ttft = now - item.t_submit
-        item.handle.ttft_s = ttft
-        self._recent_ttft.append(ttft)
-        tel.histogram("serving.cb.ttft_seconds").observe(ttft)
-        tel.counter("serving.cb.admissions").add(1)
-        self._observe_tenant_ttft(item.tenant, ttft)
-        n_prompt_blocks = len(item.prompt) // self._ps  # FULL chunks only
-        self._alloc.register_prefix(
-            item.prompt, [int(p) for p in table[:n_prompt_blocks]])
-        with self._lock:
-            self._slots[b] = _Active(item, w.budget, [w.tok0], now,
-                                     generated=1)
-        w.admitted = True
-        self._finish_if_done(b, now)
+        with tel.span("serving.paged.admit", request_id=item.request_id):
+            now_ns = time.perf_counter_ns()
+            table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
+            n_own = w.n_shared + len(w.private_pages)
+            table[:w.n_shared] = w.shared_pages
+            table[w.n_shared:n_own] = w.private_pages
+            self._tok[b] = w.tok0
+            self._lengths[b] = len(item.prompt)
+            self._temps[b] = item.temperature
+            self._keys[b] = w.key2
+            self._tables[b] = table
+            ttft = self._note_first_token(item, now_ns, shared=w.n_shared * self._ps)
+            self._observe_tenant_ttft(item.tenant, ttft)
+            n_prompt_blocks = len(item.prompt) // self._ps  # FULL chunks only
+            self._alloc.register_prefix(
+                item.prompt, [int(p) for p in table[:n_prompt_blocks]])
+            with self._lock:
+                self._slots[b] = _Active(item, w.budget, [w.tok0], now_ns,
+                                         generated=1)
+            w.admitted = True
+            self._finish_if_done(b, now_ns)
         return w
 
     # -- page reclamation ---------------------------------------------------

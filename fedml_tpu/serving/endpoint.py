@@ -20,6 +20,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from ..core import telemetry as tel
+from ..core.telemetry import trace_context
 from .fedml_inference_runner import FedMLInferenceRunner
 from .fedml_predictor import FedMLPredictor
 
@@ -77,7 +79,8 @@ class _ReplicaClient:
         self._pool: List[http.client.HTTPConnection] = []
         self._lock = threading.Lock()
 
-    def request(self, path: str, payload: Dict[str, Any], timeout_s: float) -> Dict[str, Any]:
+    def request(self, path: str, payload: Dict[str, Any], timeout_s: float,
+                headers: Optional[Dict[str, str]] = None) -> Dict[str, Any]:
         with self._lock:
             conn = self._pool.pop() if self._pool else None
         if conn is None:
@@ -86,7 +89,7 @@ class _ReplicaClient:
             conn.sock.settimeout(timeout_s)  # pooled conns: per-call timeout
         try:
             conn.request("POST", path, json.dumps(payload).encode(),
-                         {"Content-Type": "application/json"})
+                         {"Content-Type": "application/json", **(headers or {})})
             resp = conn.getresponse()
             data = resp.read()
             if resp.status != 200:
@@ -205,8 +208,17 @@ class Endpoint:
             candidates = [c for c in pool if c.in_flight == low]
             client = candidates[next(self._rr) % len(candidates)]
             client.in_flight += 1
+        # the request's id is the caller's active context (a gateway above
+        # that already opened one for this request), else minted here, the
+        # first program boundary it crosses; it rides to the replica as a
+        # traceparent header
+        ctx = trace_context.current() or trace_context.TraceContext(trace_context.new_trace_id())
         try:
-            return client.request("/predict", payload, timeout_s)
+            with tel.span("serving.endpoint.predict", request_id=ctx.trace_id,
+                          replica=client.port):
+                return client.request(
+                    "/predict", payload, timeout_s,
+                    headers={trace_context.TRACEPARENT_HEADER: ctx.to_traceparent()})
         finally:
             with self._lock:
                 client.in_flight -= 1

@@ -19,7 +19,8 @@ import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Optional
 
-from ..core.telemetry import prom, slo, statusz
+from ..core import telemetry as tel
+from ..core.telemetry import prom, slo, statusz, trace_context
 from .admission import AdmissionError
 from .fedml_predictor import FedMLPredictor
 
@@ -221,6 +222,17 @@ class FedMLInferenceRunner:
                 if self.path != "/predict":
                     self._send_json({"error": "not found"}, code=404)
                     return
+                # the request's id: the gateway's (Endpoint.predict sends it
+                # as a traceparent header), else minted here; active on this
+                # thread so the predictor and engine.submit stamp their spans
+                ctx = (trace_context.TraceContext.from_traceparent(
+                           self.headers.get(trace_context.TRACEPARENT_HEADER))
+                       or trace_context.TraceContext(trace_context.new_trace_id()))
+                with trace_context.activated(ctx), \
+                        tel.span("serving.http.request", request_id=ctx.trace_id):
+                    self._predict()
+
+            def _predict(self):
                 try:
                     length = int(self.headers.get("Content-Length", 0))
                     input_json = json.loads(self.rfile.read(length) or b"{}")

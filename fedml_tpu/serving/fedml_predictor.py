@@ -12,6 +12,9 @@ from __future__ import annotations
 import abc
 from typing import Any, Callable, Optional
 
+from ..core import telemetry as tel
+from ..core.telemetry import trace_context
+
 
 class FedMLPredictor(abc.ABC):
     def __init__(self):
@@ -67,7 +70,8 @@ class LLMPredictor(FedMLPredictor):
     """LLM text-generation endpoint (BASELINE config 5 shape): KV-cache
     decode via train/llm/generation.py. Request: {"prompt": str,
     "max_new_tokens": int?, "temperature": float?} -> {"text": str} (engine
-    modes add "token_ids": [int]).
+    modes add "token_ids": [int] and "timing": {request_id, queue_wait_s,
+    ttft_s, tpot_s}, the engine's own readings for this request).
 
     Build from a checkpoint dir (HF llama safetensors + tokenizer.json) or
     pass (params, cfg, tokenizer) directly."""
@@ -182,7 +186,10 @@ class LLMPredictor(FedMLPredictor):
         from ..train.llm.generation import generate_text
 
         if self.engine is not None:
-            prompt_ids = self._tok.encode(str(request["prompt"]))
+            rid = trace_context.request_id()
+            prompt = str(request["prompt"])
+            with tel.span("serving.predict.encode", request_id=rid, chars=len(prompt)):
+                prompt_ids = self._tok.encode(prompt)
             tenant = str(request.get("tenant", "default"))
             if request.get("prefill_only"):
                 # cache warming (prefill-pool traffic): one decoded token
@@ -195,18 +202,30 @@ class LLMPredictor(FedMLPredictor):
             # engine's worker interleaves every in-flight request through
             # one always-running decode step (ThreadingHTTPServer gives a
             # thread per connection, so concurrency comes for free)
-            toks = self.engine.generate(
+            handle = self.engine.submit(
                 prompt_ids,
                 int(request.get("max_new_tokens", self._max_new)),
                 temperature=float(request.get("temperature", 0.0)),
                 seed=int(request.get("seed", 0)),
                 eos_id=self._eos_id,
                 tenant=tenant,
+                request_id=rid,
             )
+            with tel.span("serving.predict.wait", request_id=rid,
+                          prompt_tokens=len(prompt_ids)):
+                toks = handle.result(timeout=600.0)
             ids = [int(t) for t in toks]
+            with tel.span("serving.predict.decode_text", request_id=rid, tokens=len(ids)):
+                text = self._tok.decode(ids)
             # token_ids ride along: the text alone cannot say how many tokens
-            # were generated (decode drops ids outside the tokenizer's vocab)
-            return {"text": self._tok.decode(ids), "token_ids": ids}
+            # were generated (decode drops ids outside the tokenizer's vocab);
+            # timing is the engine's own (RequestHandle), so a client needs no
+            # hook inside the replica to split its latency
+            return {"text": text, "token_ids": ids,
+                    "timing": {"request_id": rid,
+                               "queue_wait_s": handle.queue_wait_s,
+                               "ttft_s": handle.ttft_s,
+                               "tpot_s": handle.tpot_s}}
         text = generate_text(
             self._params,
             self._cfg,

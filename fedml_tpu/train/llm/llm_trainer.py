@@ -187,13 +187,22 @@ class LLMTrainer:
             flops_per_token_hint=self._flops_per_token_hint(self.params))
 
     def _flops_per_token_hint(self, params) -> float:
-        """Analytic model FLOPs/token (6*N matmul + causal attention term,
-        bench.py's convention): the registry's MFU numerator, so live MFU
-        and bench's analytic MFU agree on the same run."""
-        n_params = sum(int(x.size) for x in jax.tree.leaves(params))
-        n_matmul = n_params - self.cfg.vocab_size * self.cfg.d_model
-        return (6.0 * n_matmul
-                + 6.0 * self.cfg.n_layers * self.cfg.d_model * self.cfg.max_seq_len)
+        """FLOPs one trained token REQUIRES (the registry's MFU numerator),
+        counted as ``benchmark/flops.py`` counts a step: forward 2 and input
+        gradients 2 per matmul weight (the embedding is a row lookup), a
+        weight gradient (2 more) only for the leaves that train — every
+        matmul weight in full fine-tuning, the adapters alone under LoRA —
+        and causal attention over the length the trainer's batches have
+        (``model_args.seq_len``, not the model's ``max_seq_len``): forward 2
+        and backward 4 matmuls of T/2 x head_dim per head and layer."""
+        leaves = jax.tree.leaves(params)
+        n_matmul = sum(int(x.size) for x in leaves) - self.cfg.vocab_size * self.cfg.d_model
+        n_train = n_matmul
+        if self.cfg.lora_rank > 0:
+            n_train = sum(int(x.size) for x, m in zip(leaves, jax.tree.leaves(lora_mask(params))) if m)
+        attn = (6.0 * self.cfg.n_layers * self.cfg.n_heads * self.cfg.head_dim
+                * self.model_args.seq_len)
+        return 4.0 * (n_matmul - n_train) + 6.0 * n_train + attn
 
     def _build_pp(self, params):
         """GPipe pipeline mode (ExperimentArguments.pp > 1): params live in
@@ -223,7 +232,7 @@ class LLMTrainer:
         # donate=True): the train loop overwrites both with the outputs, and
         # without donation XLA double-buffers the full fp32 state
         @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def step(params3, opt_state, tokens, mask):
+        def pp_train_step(params3, opt_state, tokens, mask):
             # mask is accepted for step-signature parity; the pipelined loss
             # packs full microbatches so no padding mask is needed
             loss, grads = jax.value_and_grad(loss_fn)(params3, tokens, tokens)
@@ -234,7 +243,7 @@ class LLMTrainer:
         self.opt_state = opt_state
         self._devperf_label = "llm_train_pp"
         self._step_fn = devperf.instrument(
-            step, self._devperf_label, n_devices=self.mesh.devices.size,
+            pp_train_step, self._devperf_label, n_devices=self.mesh.devices.size,
             flops_per_token_hint=self._flops_per_token_hint(p3))
         self._pp_mode = True
 
@@ -276,19 +285,26 @@ class LLMTrainer:
         losses, tokens_seen = [], 0
         step = 0
         # tel.timed: tokens/sec consumes the window duration; the span itself
-        # shows the whole local-training window in round traces
+        # shows the whole local-training window in round traces. Inside it:
+        # llm.train.step is batch to device + dispatch: the host's own work
+        # while the runtime still takes steps in flight (the head of a call),
+        # one device step once its bound is reached and the dispatch waits;
+        # llm.train.sync is the wait for the device at the window's end,
+        # llm.train.save each checkpoint call
         with tel.timed("llm.train", max_steps=exp.max_steps) as sp:
             for step, (toks, mask) in enumerate(batches):
-                self.params, self.opt_state, loss = self._step_fn(
-                    self.params, self.opt_state, jnp.asarray(toks), jnp.asarray(mask)
-                )
+                with tel.span("llm.train.step", step=step):
+                    self.params, self.opt_state, loss = self._step_fn(
+                        self.params, self.opt_state, jnp.asarray(toks), jnp.asarray(mask)
+                    )
                 losses.append(loss)
                 tokens_seen += toks.size
                 if exp.save_steps and (step + 1) % exp.save_steps == 0:
                     # async enqueue: the orbax writer runs behind the next
                     # train steps; the watermark commits on completion, so a
                     # crash mid-write resumes from the previous complete step
-                    self.save(step + 1, wait=False)  # fedlint: disable=interproc-host-sync amortized: fires every save_steps, and the device_get feeds the async orbax writer that runs behind the next train steps
+                    with tel.span("llm.train.save", step=step + 1):
+                        self.save(step + 1, wait=False)  # fedlint: disable=interproc-host-sync amortized: fires every save_steps, and the device_get feeds the async orbax writer that runs behind the next train steps
                 if step + 1 >= exp.max_steps:
                     break
             # modelwatch NaN guard + param norm: one jitted pass whose fetch
@@ -301,7 +317,8 @@ class LLMTrainer:
                     guard = modelwatch.train_guard(self.params)
             except Exception:  # noqa: BLE001 - the guard must never break training
                 guard = None
-            jax.block_until_ready(self.params)
+            with tel.span("llm.train.sync"):
+                jax.block_until_ready(self.params)
         dt = sp.duration_s
         final_loss = float(jax.device_get(losses[-1])) if losses else float("nan")
         tokens_per_sec = tokens_seen / dt if dt > 0 else 0.0
@@ -329,7 +346,8 @@ class LLMTrainer:
                 flight_recorder.mark("modelwatch_train_guard", nan=int(g[1]),
                                      inf=int(g[2]), final_loss=float(final_loss))
         log.info("LLM train done: %s", metrics)
-        self.save(step + 1)
+        with tel.span("llm.train.save", step=step + 1):
+            self.save(step + 1)
         # drain any async mid-training save still in flight before returning:
         # callers treat a returned train() as fully durable
         self.ckpt.wait_until_finished()

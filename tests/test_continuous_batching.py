@@ -191,11 +191,186 @@ def test_runner_serves_engine_and_exports_gauges(params):
         runner.stop()
 
 
-def test_latency_percentiles_populated_after_traffic(engine):
-    engine.generate(_prompt(4, 31), 6)
-    pct = engine.latency_percentiles()
-    assert pct["ttft_s"]["p50"] is not None and pct["ttft_s"]["p50"] > 0
-    assert pct["tpot_s"]["p50"] is not None and pct["tpot_s"]["p50"] > 0
+# -- the request record: one id, spans from the gateway to the last token -----
+
+
+class _CharTok:  # one token a character: the prompt's length is its encoding's
+    special_tokens = {}
+
+    def encode(self, s):
+        return [1 + (ord(c) % (CFG.vocab_size - 1)) for c in s] or [1]
+
+    def decode(self, ids):
+        return " ".join(str(i) for i in ids)
+
+
+REQUEST_SPANS = (
+    "serving.endpoint.predict", "serving.http.request", "serving.predict.encode",
+    "serving.predict.wait", "serving.predict.decode_text", "serving.request.queue",
+    "serving.request.admit", "serving.request.decode", "serving.cb.prefill",
+    "serving.paged.transfer", "serving.paged.admit")
+
+
+CALLER_ID = "cd" * 16
+
+
+@pytest.fixture(scope="module")
+def served_requests(params):
+    """Six requests (three share a 32-token prefix) through Endpoint.predict ->
+    HTTP replica -> LLMPredictor -> a tiny paged engine; the spans they left."""
+    from fedml_tpu.serving.endpoint import Endpoint
+    from fedml_tpu.serving.fedml_predictor import LLMPredictor
+
+    # chunks of 16 steps: the spans' own cost stays small beside the work they tile.
+    # Pages of 8: the compiled programs' cache keys are this file's own (test_paged_kv.py
+    # counts the traces of the page-16 programs of the same CFG in whatever process it shares)
+    pred = LLMPredictor(params, CFG, _CharTok(), default_max_new_tokens=4,
+                        paged=True, num_slots=2, decode_chunk=16, page_size=8)
+    ep = Endpoint("req_record", lambda: pred)
+    registry = tel.get_telemetry()
+    was = registry.enabled
+    registry.set_enabled(True)  # whatever an earlier file of this worker left it at
+    seq0 = registry.snapshot()["spans"][-1:]
+    seq0 = seq0[0]["seq"] if seq0 else 0
+    try:
+        system = "s" * 32
+        prompts = [system + "abc", "cold prompt one", system + "defgh", "x" * 20,
+                   system + "ij", "another cold prompt"]
+        replies = [None] * len(prompts)
+
+        def send(i):
+            replies[i] = ep.predict({"prompt": prompts[i], "max_new_tokens": 5 + i})
+
+        import threading
+        from fedml_tpu.core.telemetry import trace_context
+        with trace_context.activated(trace_context.TraceContext(CALLER_ID)):
+            send(0)  # registers the shared prefix pages; its caller already opened a context
+        threads = [threading.Thread(target=send, args=(i,)) for i in range(1, len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        ep.shutdown()
+        registry.set_enabled(was)
+    spans = [s for s in tel.snapshot()["spans"] if s["seq"] > seq0]
+    return replies, spans
+
+
+def test_every_span_of_a_request_carries_one_request_id(served_requests):
+    replies, spans = served_requests
+    ids = [r["timing"]["request_id"] for r in replies]
+    assert len(set(ids)) == len(ids) and all(len(i) == 32 for i in ids)
+    for rid in ids:
+        mine = [s for s in spans if (s.get("attrs") or {}).get("request_id") == rid]
+        names = {s["name"] for s in mine}
+        assert set(REQUEST_SPANS) - {"serving.paged.transfer", "serving.paged.admit",
+                                     "serving.cb.prefill"} <= names, names
+        assert {"serving.cb.prefill", "serving.paged.transfer", "serving.paged.admit"} <= names
+    # a span that belongs to a request names it: none of these families is anonymous
+    for s in spans:
+        if s["name"] in REQUEST_SPANS:
+            assert (s.get("attrs") or {}).get("request_id") in ids, s
+    # the spans of the handler thread carry the id as their trace context too
+    http = [s for s in spans if s["name"] == "serving.http.request"]
+    assert all(s["trace_id"] == s["attrs"]["request_id"] for s in http)
+
+
+def test_endpoint_keeps_the_id_of_a_caller_that_opened_a_context(served_requests):
+    replies, spans = served_requests
+    assert replies[0]["timing"]["request_id"] == CALLER_ID  # not a fresh one minted over it
+    mine = {s["name"] for s in spans if (s.get("attrs") or {}).get("request_id") == CALLER_ID}
+    assert {"serving.endpoint.predict", "serving.http.request", "serving.request.queue"} <= mine
+
+
+def test_queue_plus_admit_is_ttft_and_the_reply_says_the_same(served_requests):
+    replies, spans = served_requests
+    for reply in replies:
+        timing = reply["timing"]
+        mine = {s["name"]: s for s in spans
+                if (s.get("attrs") or {}).get("request_id") == timing["request_id"]}
+        queue, admit = mine["serving.request.queue"], mine["serving.request.admit"]
+        assert queue["t0_ns"] + queue["dur_ns"] == admit["t0_ns"]  # popped: one reading
+        assert abs((queue["dur_ns"] + admit["dur_ns"]) / 1e9 - timing["ttft_s"]) < 1e-3
+        assert abs(queue["dur_ns"] / 1e9 - timing["queue_wait_s"]) < 1e-3
+        decode = mine["serving.request.decode"]
+        assert decode["t0_ns"] == admit["t0_ns"] + admit["dur_ns"]  # first token: one reading
+        n = decode["attrs"]["tokens"]
+        assert n == len(reply["token_ids"]) and decode["attrs"]["wasted"] >= 0
+        assert abs(decode["dur_ns"] / 1e9 / (n - 1) - timing["tpot_s"]) < 1e-3
+        # the entry's spans nest: gateway > handler > wait > (queue + admit + decode)
+        assert mine["serving.endpoint.predict"]["dur_ns"] >= mine["serving.http.request"]["dur_ns"]
+        assert mine["serving.http.request"]["dur_ns"] >= mine["serving.predict.wait"]["dur_ns"]
+    shared = [s["attrs"]["shared"] for s in spans if s["name"] == "serving.request.admit"]
+    assert sorted(shared) == [0, 0, 0, 0, 32, 32]  # the first sharer fills the prefix cache
+
+
+def test_worker_loop_spans_tile_an_iteration(served_requests):
+    _, spans = served_requests
+    iters = [s for s in spans if s["name"] == "serving.engine.iteration"]
+    assert iters and all(set(s["attrs"]) == {"slots", "queue_depth"} for s in iters)
+    worker = {s["tid"] for s in iters}
+    assert len(worker) == 1
+    by_parent = {}
+    for s in spans:
+        by_parent.setdefault(s["parent_seq"], []).append(s)
+    covered = 0
+    for it in iters:
+        kids = by_parent.get(it["seq"], [])
+        assert {k["name"] for k in kids} <= {"serving.paged.admit_wave", "serving.cb.chunk"}
+        assert all(k["tid"] == it["tid"] for k in kids)
+        covered += sum(k["dur_ns"] for k in kids)
+    assert covered >= 0.95 * sum(s["dur_ns"] for s in iters)
+    chunks = [s for s in spans if s["name"] == "serving.cb.chunk"]
+    assert chunks and all(1 <= s["attrs"]["slots"] <= 2 for s in chunks)
+    for ch in chunks:
+        parts = by_parent.get(ch["seq"], [])
+        assert [p["name"] for p in parts] == ["serving.cb.chunk.dispatch", "serving.cb.chunk.sync",
+                                              "serving.cb.chunk.post"]
+    parts_ns = sum(p["dur_ns"] for ch in chunks for p in by_parent[ch["seq"]])
+    assert parts_ns >= 0.95 * sum(ch["dur_ns"] for ch in chunks)
+    # the stages of a wave run on the pipeline's threads, under the worker's wave
+    waves = [s for s in spans if s["name"] == "serving.paged.admit_wave"]
+    for name in ("serving.cb.prefill", "serving.paged.transfer", "serving.paged.admit"):
+        for st in (s for s in spans if s["name"] == name):
+            assert any(w["t0_ns"] <= st["t0_ns"] and
+                       st["t0_ns"] + st["dur_ns"] <= w["t0_ns"] + w["dur_ns"] for w in waves), st
+    assert any(s["name"] == "serving.engine.idle" and s["tid"] in worker for s in spans)
+
+
+@pytest.mark.parametrize("label", ["prefill", "cb_step", "cb_admit", "paged_step", "paged_admit",
+                                   "paged_gather", "paged_suffix_prefill"])
+def test_serving_programs_lower_under_their_labels_name(params, label):
+    from fedml_tpu.serving import continuous_batching as cb
+    from fedml_tpu.serving import paged_kv
+    from fedml_tpu.train.llm.generation import _prefill_fn
+
+    pcfg = paged_kv.paged_config(CFG, page_size=8, num_pages=5)
+    fn = {"prefill": lambda: _prefill_fn(CFG, 1, 16),
+          "cb_step": lambda: cb._cb_step_fn(CFG, 2, 4),
+          "cb_admit": lambda: cb._cb_admit_fn(CFG, 2),
+          "paged_step": lambda: paged_kv._paged_step_fn(pcfg, 2, 4),
+          "paged_admit": lambda: paged_kv._paged_admit_fn(pcfg),
+          "paged_gather": lambda: paged_kv._paged_gather_fn(pcfg),
+          "paged_suffix_prefill": lambda: paged_kv._suffix_prefill_fn(pcfg, 16)}[label]()
+    fn = getattr(fn, "_fn", fn)  # devperf.instrument wraps the decode steps
+    assert fn.__wrapped__.__name__ == label  # what jax.jit names the program after
+
+
+def test_submit_mints_an_id_when_no_boundary_did(engine):
+    from fedml_tpu.core.telemetry import trace_context
+
+    h = engine.submit(_prompt(4, 31), 6)
+    assert h.request_id and len(h.request_id) == 32
+    given = engine.submit(_prompt(4, 32), 6, request_id="caller-chosen")
+    assert given.request_id == "caller-chosen"
+    with trace_context.activated(trace_context.TraceContext("ab" * 16)):
+        active = engine.submit(_prompt(4, 33), 6)
+    assert active.request_id == "ab" * 16
+    for handle in (h, given, active):
+        assert len(handle.result(timeout=120)) == 6
+        assert handle.ttft_s >= handle.queue_wait_s >= 0 and handle.tpot_s > 0
 
 
 def test_check_serving_lint_clean_and_detects_regressions(tmp_path):

@@ -121,3 +121,36 @@ def test_facade_routes_to_frame():
     assert dp.frame.m == 3
     dp.account(sample_rate=0.5)
     assert math.isfinite(dp.get_epsilon(1e-5))
+
+
+def test_get_instance_builds_one_instance_under_racing_threads(monkeypatch):
+    """Parties of one process call fedml.init from threads: all of them get
+    the one instance, built once (unlocked, each racing thread built its own
+    and all but the last were dropped while another thread configured them)."""
+    import threading
+    import time
+
+    built = []
+    init = FedMLDifferentialPrivacy.__init__
+
+    def slow_init(self):
+        built.append(self)
+        time.sleep(0.05)  # hold the window open: every thread arrives while the first still builds
+        init(self)
+
+    monkeypatch.setattr(FedMLDifferentialPrivacy, "__init__", slow_init)
+    monkeypatch.setattr(FedMLDifferentialPrivacy, "_instance", None)
+    start = threading.Barrier(8)
+    got = []
+
+    def party():
+        start.wait()
+        got.append(FedMLDifferentialPrivacy.get_instance())
+
+    threads = [threading.Thread(target=party) for _ in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(built) == 1 and len(got) == 8
+    assert all(g is built[0] for g in got)

@@ -95,6 +95,7 @@ class TestPipelinedExecutor:
         assert report.overlap_frac == 0.0
 
     def test_emits_pipeline_series(self):
+        was = tel.get_telemetry().enabled
         tel.set_enabled(True)
         tel.reset()
         try:
@@ -109,7 +110,7 @@ class TestPipelinedExecutor:
                 assert any(series in k for k in hists), series
         finally:
             tel.reset()
-            tel.set_enabled(False)
+            tel.set_enabled(was)  # not off: the next file of this worker gets the registry as it found it
 
 
 # ---------------------------------------------------------------------------
@@ -256,6 +257,7 @@ class TestCollapsedPipelineAlert:
     def test_zero_overlap_fires_pipeline_overlap_frac(self):
         from fedml_tpu.core.telemetry import slo
 
+        was = tel.get_telemetry().enabled
         tel.set_enabled(True)
         tel.reset()
         slo.reset()
@@ -281,11 +283,12 @@ class TestCollapsedPipelineAlert:
             slo.deactivate(engine)
             slo.reset()
             tel.reset()
-            tel.set_enabled(False)
+            tel.set_enabled(was)  # not off: the next file of this worker gets the registry as it found it
 
     def test_healthy_overlap_does_not_alert(self):
         from fedml_tpu.core.telemetry import slo
 
+        was = tel.get_telemetry().enabled
         tel.set_enabled(True)
         tel.reset()
         slo.reset()
@@ -305,4 +308,4 @@ class TestCollapsedPipelineAlert:
             slo.deactivate(engine)
             slo.reset()
             tel.reset()
-            tel.set_enabled(False)
+            tel.set_enabled(was)  # not off: the next file of this worker gets the registry as it found it

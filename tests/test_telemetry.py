@@ -3,10 +3,12 @@ counters, Chrome-trace schema, compile-counter agreement with the bucketed
 engine's trace counters, the < 1µs disabled-path contract, the full sp
 FedAvg round span lifecycle, and the repo-wide timing-idiom lint."""
 
+import glob
 import importlib.util
 import json
 import os
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -56,6 +58,113 @@ class TestSpanNesting:
             pass
         assert sp.duration_s is not None and sp.duration_s >= 0.0
         assert t.snapshot()["spans"] == []  # measured, not recorded
+
+
+class TestRecordSpan:
+    def test_interval_from_two_threads_is_one_record(self):
+        """A span whose start is read on one thread and whose end on another:
+        same record and roll-up as a ``with`` block, no nesting state touched."""
+        t = Telemetry(enabled=True)
+        box = {}
+        th = threading.Thread(target=lambda: box.update(t0=time.perf_counter_ns()))
+        th.start()
+        th.join(timeout=10)
+        with t.span("worker.pass"):
+            t1 = time.perf_counter_ns()
+            t.record_span("request.queue", box["t0"], t1, request_id="r1", queue_depth=3)
+            with t.span("child"):
+                pass
+        snap = t.snapshot()
+        by_name = {s["name"]: s for s in snap["spans"]}
+        rec = by_name["request.queue"]
+        assert rec["dur_ns"] == t1 - box["t0"]
+        assert rec["attrs"] == {"request_id": "r1", "queue_depth": 3}
+        assert rec["parent_seq"] is None and rec["depth"] == 0
+        assert rec["tid"] == threading.get_ident()  # the recording thread's lane
+        # the open span's nesting is untouched: child still hangs under it
+        assert by_name["child"]["parent_seq"] == by_name["worker.pass"]["seq"]
+        assert snap["span_stats"]["request.queue"]["count"] == 1
+        assert len({s["seq"] for s in snap["spans"]}) == 3
+
+    def test_disabled_registry_records_nothing(self):
+        t = Telemetry(enabled=False)
+        t.record_span("request.queue", 1, 2, request_id="r")
+        assert t.snapshot()["spans"] == [] and t.snapshot()["span_stats"] == {}
+
+    def test_epoch_places_spans_on_the_perf_counter_clock(self):
+        t = Telemetry(enabled=True)
+        before = time.perf_counter()
+        with t.span("placed"):
+            pass
+        after = time.perf_counter()
+        snap = t.snapshot()
+        start_s = (snap["epoch_perf_ns"] + snap["spans"][0]["t0_ns"]) / 1e9
+        assert before <= start_s <= after
+        a = time.perf_counter_ns()
+        b = a + 5_000
+        t.record_span("from_readings", a, b)
+        rec = t.snapshot()["spans"][-1]
+        assert snap["epoch_perf_ns"] + rec["t0_ns"] == a and rec["dur_ns"] == 5_000
+        t.reset()  # a new epoch, still the clock's own reading
+        assert abs(t.snapshot()["epoch_perf_ns"] - time.perf_counter_ns()) < 1e9
+
+
+class TestProfilerSink:
+    def test_spans_reach_the_profilers_host_plane(self, tmp_path):
+        """One span, two sinks: under a jax.profiler capture every span and
+        timed block is also a ``fedml:<name>`` event of the host plane, with
+        the registry's duration and (relative to a sibling) its start."""
+        import jax
+
+        t = Telemetry(enabled=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with t.span("sink.outer", round=1):
+                time.sleep(0.02)
+                with t.timed("sink.inner"):
+                    jnp.ones((64, 64)).sum().block_until_ready()
+                    time.sleep(0.01)
+        finally:
+            jax.profiler.stop_trace()
+        t.record_span("sink.recorded", 1, 2)  # an interval has no block to annotate
+        path = sorted(glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")))[-1]
+        data = jax.profiler.ProfileData.from_file(path)
+        events = {}
+        for plane in data.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("fedml:"):
+                        events[e.name] = (e.start_ns, e.duration_ns)
+        assert set(events) == {"fedml:sink.outer", "fedml:sink.inner"}
+        spans = {s["name"]: s for s in t.snapshot()["spans"]}
+        slack_ns = 2e6  # the annotation brackets the registry's two clock reads
+        for name in ("sink.outer", "sink.inner"):
+            dur = events["fedml:" + name][1]
+            assert 0 <= dur - spans[name]["dur_ns"] < slack_ns, name
+        offset_trace = events["fedml:sink.inner"][0] - events["fedml:sink.outer"][0]
+        offset_registry = spans["sink.inner"]["t0_ns"] - spans["sink.outer"]["t0_ns"]
+        assert abs(offset_trace - offset_registry) < slack_ns
+        assert offset_registry >= 0.02e9
+
+    def test_a_disabled_registry_writes_no_annotation(self, tmp_path):
+        import jax
+
+        t = Telemetry(enabled=False)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with t.span("sink.off"), t.timed("sink.off_timed"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        path = sorted(glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")))[-1]
+        data = jax.profiler.ProfileData.from_file(path)
+        names = [e.name for plane in data.planes for line in plane.lines for e in line.events]
+        assert not [n for n in names if n.startswith("fedml:")]
+
+    def test_sink_adds_under_2us_when_no_trace_is_captured(self):
+        assert tel.profiler_sink_overhead_ns() < 2000.0
 
 
 class TestCounterThreads:
@@ -131,6 +240,19 @@ class TestJaxHooks:
             eng.aggregate(pairs)
         assert tel.compile_count("agg_accum") - before == eng.accum_traces
         assert eng.accum_traces == 2  # first-bucket + steady-state, once
+
+    def test_tracked_function_is_named_after_its_label(self):
+        """jax.jit names a program after the function it is handed: a
+        tracked function lowers as jit_<label>, not as its own __name__."""
+        import jax
+
+        def run(x):
+            return x + 1
+
+        fn = jax.jit(tel.track_compiles(run, name="labelled_prog"))
+        assert "@jit_labelled_prog" in fn.lower(jnp.zeros((2,))).as_text()
+        unnamed = tel.track_compiles(run)
+        assert unnamed.__name__ == "run"  # no label: the function's own name stays
 
     def test_record_transfer_books_both_directions(self):
         from fedml_tpu.utils.pytree import tree_from_numpy, tree_to_numpy

@@ -3,8 +3,9 @@ tools/check_serving.py, PR 6).
 
 The serving hot paths — the continuous-batching engine's admit/step loop
 and the gateway's forward path — must time themselves through
-``tel.timed(``/``tel.span(`` (perf_counter-based): an uninstrumented hot
-loop is how the r05 endpoint collapse (14.5 tok/s against a 370k tok/s
+``tel.timed(``/``tel.span(``/``tel.record_span(`` (perf_counter-based;
+``record_span`` is the form for an interval that crosses threads): an
+uninstrumented hot loop is how the r05 endpoint collapse (14.5 tok/s against a 370k tok/s
 chip) stayed invisible until a full bench window. The registry below names
 the functions that MUST contain a span call; deleting the instrumentation
 — or renaming a registered function/file without updating the registry —
@@ -29,7 +30,7 @@ HOT_LOOPS: tuple = (
     ("replica_controller.py", "InferenceGateway.predict"),
 )
 
-_SPAN_ATTRS = ("timed", "span")
+_SPAN_ATTRS = ("timed", "span", "record_span")
 _SERVING_DIR = "fedml_tpu/serving"
 
 
