@@ -218,6 +218,30 @@ def _auto_attention_impl(platform: str, seq_len: int) -> str:
     return impl
 
 
+@functools.lru_cache(maxsize=None)
+def _paged_attention_impl(platform: str, head_dim: int, page_size: int,
+                          n_heads: int, n_kv_heads: int, dtype: str):
+    """Which formulation paged decode reads the pool with, logged once per
+    distinct case: ``ops.paged_attention.paged_attention`` — compiled on the
+    TPU wherever its blocks tile (``ops.paged_attention.tiles``), interpreted
+    on the CPU at any shape (tests, ``chip_smoke.py --dry-run-cpu``) — and the
+    gather + repeat + masked-einsum ``paged_attention_reference`` for a TPU
+    shape the kernel cannot tile. Decided here, from shapes, before anything
+    runs: a kernel that fails to compile raises, it is never retried plain."""
+    from ..ops import paged_attention as pa
+
+    shape = (f"head_dim={head_dim} page_size={page_size} n_heads={n_heads} "
+             f"n_kv_heads={n_kv_heads} dtype={dtype}")
+    if platform == "tpu" and not pa.tiles(head_dim, page_size, n_heads,
+                                          n_kv_heads, dtype):
+        log.warning("paged decode attention -> reference formulation (full-"
+                    "context gather + repeat_kv): the kernel cannot tile %s", shape)
+        return pa.paged_attention_reference
+    log.info("paged decode attention -> pallas kernel (platform=%s, %s)",
+             platform, shape)
+    return pa.paged_attention
+
+
 def _sharded_flash_attention(q, k, v):
     """The pallas kernel under whatever mesh the train step is sharded over.
 
@@ -359,11 +383,13 @@ class Attention(nn.Module):
         prefix get fresh private pages from the first non-shared chunk on),
         so copy-on-write never needs an actual copy.
 
-        Read: gather each row's pages back into logical order
-        (pool[bt[b]] → [max_blocks·page]) and mask positions > cache_idx[b].
-        Unallocated block-table entries point at the reserved trash page 0;
-        their positions are always beyond the row's index, so the mask makes
-        their garbage invisible by the same argument as ``_rewind_cache``."""
+        Read: ``ops/paged_attention.py`` walks each row's live pages in the
+        pool itself (``_paged_attention_impl`` says which form of it runs); a
+        row attends to positions ``<= cache_idx[b]`` — its own written prefix
+        plus the token just scattered. Unallocated block-table entries point
+        at the reserved trash page 0 and lie beyond every row's index, so
+        their garbage is never read. ``paged_step`` hands a freed slot
+        ``cache_idx = -1``: length 0, no page read, output ignored."""
         cfg = self.cfg
         hd = cfg.head_dim
         ps = cfg.kv_page_size
@@ -379,19 +405,18 @@ class Attention(nn.Module):
         # the contiguous modes' shared scalar write index, kept so the two
         # cache pytrees stay congruent for gather/scatter; unused here
         self.variable("cache", "idx", lambda: jnp.zeros((), jnp.int32))
+        # a freed slot's token goes to the trash page whatever its table holds
+        w_idx = jnp.maximum(cache_idx, 0)
         page = jnp.take_along_axis(
-            block_tables, (cache_idx // ps)[:, None], axis=1)[:, 0]  # [B]
-        off = cache_idx % ps
+            block_tables, (w_idx // ps)[:, None], axis=1)[:, 0]  # [B]
+        page = jnp.where(cache_idx < 0, 0, page)
+        off = w_idx % ps
         if self.is_mutable_collection("cache"):
             ck.value = ck.value.at[page, off].set(k[:, 0].astype(ck.value.dtype))
             cv.value = cv.value.at[page, off].set(v[:, 0].astype(cv.value.dtype))
-        S_l = block_tables.shape[1] * ps  # logical context length
-        k_rows = ck.value[block_tables].reshape(B, S_l, cfg.n_kv_heads, hd)
-        v_rows = cv.value[block_tables].reshape(B, S_l, cfg.n_kv_heads, hd)
-        k_all, v_all = repeat_kv(k_rows, v_rows, cfg.n_heads)
-        # [B, 1, 1, S_l]: row b sees exactly its own written prefix
-        valid = (jnp.arange(S_l)[None, :] <= cache_idx[:, None])[:, None, None]
-        out = xla_attention(q, k_all, v_all, mask=valid)
+        attend = _paged_attention_impl(jax.default_backend(), hd, ps, cfg.n_heads,
+                                       cfg.n_kv_heads, jnp.dtype(q.dtype).name)
+        out = attend(q[:, 0], ck.value, cv.value, block_tables, cache_idx + 1)
         out = out.reshape(B, T, cfg.n_heads * hd)
         return LoRALinear(cfg.d_model, cfg, name="o_proj")(out)
 

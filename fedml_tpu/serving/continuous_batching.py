@@ -498,13 +498,18 @@ class ContinuousBatchingEngine:
         paged engine slips its block tables in here)."""
         return ()
 
+    def _chunk_attrs(self, active_mask: np.ndarray) -> dict:
+        """Hook: further attributes of the ``serving.cb.chunk`` span."""
+        return {}
+
     def _step_chunk(self) -> None:
         with self._lock:
             active_mask = np.asarray(
                 [s is not None for s in self._slots], bool
             )
         n_live = int(active_mask.sum())
-        with tel.span("serving.cb.chunk", slots=n_live):
+        with tel.span("serving.cb.chunk", slots=n_live,
+                      **self._chunk_attrs(active_mask)):
             with tel.timed("serving.cb.chunk.dispatch") as dispatch:
                 cache, tok, lengths, keys, toks = self._step_fn()(
                     self._params,
@@ -678,6 +683,13 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     def _step_extra_args(self) -> tuple:
         return (jnp.asarray(self._tables),)
+
+    def _chunk_attrs(self, active_mask: np.ndarray) -> dict:
+        # pages the chunk's first token-step reads: each active row's written
+        # prefix plus the token it writes; beside B x n_blocks, the share of
+        # a whole-table read that paged attention still makes
+        lens = self._lengths[active_mask].astype(np.int64) + 1
+        return {"pages": int((-(-lens // self._ps)).sum())}
 
     # -- admission-gated submit ---------------------------------------------
 

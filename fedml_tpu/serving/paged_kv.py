@@ -81,18 +81,23 @@ def _num_blocks(cfg: TransformerConfig) -> int:
 
 
 def paged_pool_init(params, cfg: TransformerConfig, B: int):
-    """Materialize the empty page-pool cache pytree (one eager apply, the
-    same trick the slot engine uses for its row pool)."""
+    """The empty page-pool cache pytree: zeros in the shapes one decode
+    step's apply gives its cache. The apply is only traced for its shapes:
+    run eagerly it was a hundred one-op programs in every set-up."""
     model = decode_model(cfg)
-    _, state = model.apply(
-        {"params": params},
-        jnp.zeros((B, 1), jnp.int32),
-        positions=jnp.zeros((B, 1), jnp.int32),
-        cache_idx=jnp.zeros((B,), jnp.int32),
-        block_tables=jnp.zeros((B, _num_blocks(cfg)), jnp.int32),
-        mutable=["cache"],
-    )
-    return state["cache"]
+
+    def cache_of(p):
+        return model.apply(
+            {"params": p},
+            jnp.zeros((B, 1), jnp.int32),
+            positions=jnp.zeros((B, 1), jnp.int32),
+            cache_idx=jnp.zeros((B,), jnp.int32),
+            block_tables=jnp.zeros((B, _num_blocks(cfg)), jnp.int32),
+            mutable=["cache"],
+        )[1]["cache"]
+
+    return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                  jax.eval_shape(cache_of, params))
 
 
 def _paged_admit_fn(cfg: TransformerConfig):
@@ -196,7 +201,9 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
                     {"params": params, "cache": pool},
                     tok[:, None],
                     positions=idx[:, None],
-                    cache_idx=idx,
+                    # -1: a freed slot (stale length, all-trash table) writes
+                    # the trash page and reads no page at all
+                    cache_idx=jnp.where(active, idx, -1),
                     block_tables=block_tables,
                     mutable=["cache"],
                 )

@@ -125,6 +125,13 @@ def test_send_survives_peer_restart():
         t1 = threading.Thread(target=m1.handle_receive_message, daemon=True)
         t1.start()
         m0.send_message(Message(1, 0, 1))
+        # the first message is IN before the peer goes: torn down with the
+        # connection still unaccepted (6 xdist workers), the cached socket is
+        # not dead yet and takes the second send without an error
+        deadline = time.time() + 30
+        while time.time() < deadline and 1 not in got:
+            time.sleep(0.05)
+        assert 1 in got
         # peer "restarts": old manager torn down, new one on the same port
         m1.stop_receive_message()
         t1.join(timeout=10)
