@@ -67,10 +67,31 @@ class TransformerConfig:
     # bound, so halving weight bytes is a direct tokens/sec lever; activations
     # and KV cache stay in ``dtype``.
     weight_quant: str = "none"   # none | int8
+    # The layers' kinds, as data: () = every layer is an attention block (what
+    # every config above builds); else one entry a layer, "attention" or
+    # "mamba" (a selective state-space mixer, models/mamba.py, in the
+    # attention's place; the feed-forward is the same). ``hybrid_pattern``
+    # builds the periodic patterns published configs describe.
+    layer_pattern: Tuple[str, ...] = ()
+    use_rope: bool = True         # False: attention without positions
+    tie_embeddings: bool = False  # True: logits = x E^T, no lm_head
+    norm_eps: float = 1e-5
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0        # 0 = ceil(d_model / 16)
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def layer_kind(self, i: int) -> str:
+        return self.layer_pattern[i] if self.layer_pattern else "attention"
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Some layer carries a per-request state that is not K/V pages."""
+        return "mamba" in self.layer_pattern
 
     @classmethod
     def from_args(cls, args: Any) -> "TransformerConfig":
@@ -98,6 +119,16 @@ class TransformerConfig:
         )
         base.update(over)
         return cls(**base)
+
+
+LAYER_KINDS = ("attention", "mamba")
+
+
+def hybrid_pattern(n_layers: int, attn_period: int, attn_offset: int) -> Tuple[str, ...]:
+    """Layer ``i`` is attention where ``i % attn_period == attn_offset``, a
+    Mamba mixer otherwise."""
+    return tuple("attention" if i % attn_period == attn_offset else "mamba"
+                 for i in range(n_layers))
 
 
 def rotary_embedding(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -280,8 +311,9 @@ class Attention(nn.Module):
         q = LoRALinear(cfg.n_heads * hd, cfg, name="q_proj")(x).reshape(B, T, cfg.n_heads, hd)
         k = LoRALinear(cfg.n_kv_heads * hd, cfg, name="k_proj")(x).reshape(B, T, cfg.n_kv_heads, hd)
         v = LoRALinear(cfg.n_kv_heads * hd, cfg, name="v_proj")(x).reshape(B, T, cfg.n_kv_heads, hd)
-        q = rotary_embedding(q, positions, cfg.rope_theta)
-        k = rotary_embedding(k, positions, cfg.rope_theta)
+        if cfg.use_rope:
+            q = rotary_embedding(q, positions, cfg.rope_theta)
+            k = rotary_embedding(k, positions, cfg.rope_theta)
         if cfg.decode:
             if cfg.kv_page_size > 0:
                 return self._paged_decode_attention(q, k, v, B, T, cache_idx,
@@ -434,15 +466,27 @@ class MLP(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    kind: str = "attention"  # the mixer before the feed-forward: LAYER_KINDS
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, positions: jnp.ndarray,
                  attn_start: Optional[jnp.ndarray] = None,
                  cache_idx: Optional[jnp.ndarray] = None,
-                 block_tables: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 block_tables: Optional[jnp.ndarray] = None,
+                 seq_lens: Optional[jnp.ndarray] = None,
+                 snap_lens: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(RMSNorm(name="attn_norm")(x), positions, attn_start, cache_idx, block_tables)
-        h = RMSNorm(name="mlp_norm")(x)
+        if self.kind == "mamba":
+            from .mamba import MambaMixer
+
+            if attn_start is not None:
+                raise ValueError("a left-padded batch (attn_start) cannot carry a recurrent "
+                                 "state: pad tokens would advance it; serve through an engine")
+            x = x + MambaMixer(cfg, name="mamba")(
+                RMSNorm(cfg.norm_eps, name="mamba_norm")(x), seq_lens, snap_lens, cache_idx)
+        else:
+            x = x + Attention(cfg, name="attn")(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, attn_start, cache_idx, block_tables)
+        h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
         if cfg.moe_experts > 0:
             from .moe import MoEConfig, MoEMLP
 
@@ -472,9 +516,21 @@ class TransformerLM(nn.Module):
                  positions: Optional[jnp.ndarray] = None,
                  attn_start: Optional[jnp.ndarray] = None,
                  cache_idx: Optional[jnp.ndarray] = None,
-                 block_tables: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 block_tables: Optional[jnp.ndarray] = None,
+                 seq_lens: Optional[jnp.ndarray] = None,
+                 snap_lens: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+        """``seq_lens`` [B]: how many of this pass's T tokens are real (the
+        rest is right padding; default all). ``snap_lens`` [B]: after how many
+        of them a recurrent layer also keeps its state for the prefix cache.
+        Attention layers ignore both (padded K/V are overwritten before they
+        can be read: ``generation._rewind_cache``)."""
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, name="embed")(tokens).astype(cfg.dtype)
+        if cfg.layer_pattern and (len(cfg.layer_pattern) != cfg.n_layers
+                                  or set(cfg.layer_pattern) - set(LAYER_KINDS)):
+            raise ValueError(f"layer_pattern must name one of {LAYER_KINDS} for each of "
+                             f"{cfg.n_layers} layers, got {cfg.layer_pattern!r}")
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="embed")
+        x = embed(tokens).astype(cfg.dtype)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
         block = Block
@@ -488,8 +544,11 @@ class TransformerLM(nn.Module):
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             block = nn.remat(Block, static_argnums=(), policy=policy)
         for i in range(cfg.n_layers):
-            x = block(cfg, name=f"layer_{i}")(x, positions, attn_start, cache_idx, block_tables)
-        x = RMSNorm(name="final_norm")(x)
-        # tied-untied head: separate projection (llama style)
-        logits = LoRALinear(cfg.vocab_size, cfg, name="lm_head")(x)
+            x = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(
+                x, positions, attn_start, cache_idx, block_tables, seq_lens, snap_lens)
+        x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+        if cfg.tie_embeddings:
+            logits = embed.attend(x)
+        else:
+            logits = LoRALinear(cfg.vocab_size, cfg, name="lm_head")(x)
         return logits.astype(jnp.float32)

@@ -52,8 +52,10 @@ import numpy as np
 from ..core import telemetry as tel
 from ..core.pipeline.executor import PipelinedExecutor, PipelineError, StageSpec
 from ..core.telemetry import devperf, trace_context, track_compiles, tsdb
+from ..models.mamba import state_bytes as mamba_state_bytes, unpack_state
 from ..models.transformer import TransformerConfig
 from ..train.llm.generation import (
+    _leaf_at,
     _lru_get,
     _prefill_fn,
     _sample,
@@ -71,6 +73,7 @@ from .paged_kv import (
     paged_config,
     paged_pool_init,
     row_config,
+    snapshot_of,
 )
 
 log = logging.getLogger(__name__)
@@ -84,13 +87,16 @@ def _cb_admit_fn(cfg: TransformerConfig, B: int):
 
     def build():
         def run(cache, row_cache, slot, first_logits, key, temp):
-            def insert(dst, src):
+            row_cache = unpack_state(cfg, row_cache)
+
+            def insert(path, dst):
                 if dst.ndim == 0:
                     return dst
+                src = _leaf_at(row_cache, path)  # by path: a row may hold more (its snapshot)
                 start = (slot,) + (0,) * (dst.ndim - 1)
                 return jax.lax.dynamic_update_slice(dst, src.astype(dst.dtype), start)
 
-            new_cache = jax.tree_util.tree_map(insert, cache, row_cache)
+            new_cache = jax.tree_util.tree_map_with_path(insert, cache)
             key2, sub = jax.random.split(key)
             tok0 = _sample(first_logits, sub, temp)
             return new_cache, tok0, key2
@@ -609,6 +615,8 @@ class _AdmitWork:
     n_shared: int             # leading blocks served from the prefix cache
     shared_pages: List[int]   # one reference held per page
     private_pages: List[int]  # one reference held per page
+    state: object = None      # recurrent layers start from this snapshot (None: from zero)
+    snap_blocks: int = 0      # block boundary whose trie node wants this prefill's state
     row_cache: object = None
     first_vec: object = None  # [vocab] logits for the first sampled token
     tok0: int = 0
@@ -637,7 +645,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
       the discarded mid-chunk tail);
     - an optional :class:`AdmissionController` gates the front door:
       submit-time token budgets + shed, dequeue-time weighted fair
-      queueing + SLO-pressure deferral (serving/admission.py).
+      queueing + SLO-pressure deferral (serving/admission.py);
+    - a model with recurrent layers (``cfg.has_recurrent_state``) keeps their
+      per-slot state in the same cache pytree as the page pool, and its
+      prefix hits start from state snapshots the trie holds, at most
+      ``state_snapshots`` of them (see serving/paged_kv.py's header).
     """
 
     def __init__(
@@ -652,6 +664,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         watermark_frac: float = 0.05,
         max_queue: int = 4096,
         admission: Optional[AdmissionController] = None,
+        state_snapshots: int = 8,
     ):
         base = row_config(cfg)
         if num_pages is None:
@@ -662,8 +675,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             base, page_size=page_size, num_pages=num_pages)
         self._ps = int(page_size)
         self._n_blocks = base.max_seq_len // self._ps
+        self._stateful = base.has_recurrent_state
+        self._state_bytes = mamba_state_bytes(base) if self._stateful else 0
         self._alloc = PagedKVAllocator(
-            num_pages, page_size, watermark_frac=watermark_frac)
+            num_pages, page_size, watermark_frac=watermark_frac,
+            state_budget_bytes=int(state_snapshots) * self._state_bytes)
         self._admission = admission
         self._tables = np.full((num_slots, self._n_blocks), TRASH_PAGE,
                                np.int32)
@@ -689,7 +705,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # prefix plus the token it writes; beside B x n_blocks, the share of
         # a whole-table read that paged attention still makes
         lens = self._lengths[active_mask].astype(np.int64) + 1
-        return {"pages": int((-(-lens // self._ps)).sum())}
+        attrs = {"pages": int((-(-lens // self._ps)).sum())}
+        if self._stateful:  # live slots whose recurrent state the step updates
+            attrs["state_slots"] = int(active_mask.sum())
+        return attrs
 
     # -- admission-gated submit ---------------------------------------------
 
@@ -782,15 +801,15 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             P = len(item.prompt)
             budget = min(item.max_new, cfg.max_seq_len - P)
             n_req = -(-(P + budget) // self._ps)
-            shared = self._alloc.match_prefix(item.prompt)
             # never map the block holding the prompt's LAST token from the
             # prefix cache: the suffix pass needs >= 1 real token for the
             # first-logits read, and when P is page-aligned decode writes
-            # begin in exactly that block (shared pages are never written)
-            n_shared_max = (P - 1) // self._ps
-            if len(shared) > n_shared_max:
-                self._alloc.free(shared[n_shared_max:])
-                shared = shared[:n_shared_max]
+            # begin in exactly that block (shared pages are never written).
+            # With recurrent layers the match is also cut to its deepest
+            # snapshot: pages past it would be recomputed anyway.
+            match = self._alloc.match(item.prompt, max_blocks=(P - 1) // self._ps,
+                                      need_state=self._stateful)
+            shared = match.pages
             private = self._alloc.alloc(n_req - len(shared))
             if private is None:
                 self._alloc.free(shared)
@@ -808,7 +827,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     "enough (raise num_pages or lower max_new_tokens)"))
                 continue
             wave.append(_AdmitWork(item, free, budget, len(shared),
-                                   shared, private))
+                                   shared, private, match.state, match.snap_blocks))
             taken.add(free)
 
     def _run_wave(self, wave: List[_AdmitWork]) -> None:
@@ -837,15 +856,19 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         item = w.item
         P = len(item.prompt)
         prefix_len = w.n_shared * self._ps
+        # where a recurrent layer also keeps its state for the prefix cache; a
+        # dense model's programs take neither it nor the snapshot below
+        snap = jnp.int32(w.snap_blocks * self._ps) if self._stateful else None
+        attrs = {"state_hit": w.state is not None} if self._stateful else {}
         with tel.span("serving.cb.prefill", request_id=item.request_id,
-                      prompt_len=P, shared=prefix_len):
+                      prompt_len=P, shared=prefix_len, **attrs):
             if w.n_shared == 0:
                 P_b = min(-(-P // 16) * 16, cfg.max_seq_len)
                 ids = jnp.asarray([item.prompt], jnp.int32)
                 padded = (jnp.pad(ids, ((0, 0), (0, P_b - P)))
                           if P_b != P else ids)
                 row_cache, first = _prefill_fn(cfg, 1, P_b)(
-                    self._params, padded, jnp.int32(P))
+                    self._params, padded, jnp.int32(P), snap)
                 w.first_vec = first[0]
             else:
                 table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
@@ -854,7 +877,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 # stage; shared pages are never rewritten, so reading a
                 # one-wave-stale pool binding here is still exact
                 row_cache = _paged_gather_fn(self._paged_cfg)(
-                    self._cache, jnp.asarray(table), jnp.int32(prefix_len))
+                    self._cache, jnp.asarray(table), jnp.int32(prefix_len),
+                    w.state)
                 suffix = item.prompt[prefix_len:]
                 T_suf = len(suffix)
                 T_b = min(-(-T_suf // 16) * 16, cfg.max_seq_len - prefix_len)
@@ -862,7 +886,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 row_cache, w.first_vec = _suffix_prefill_fn(
                     self._paged_cfg, T_b)(
                     self._params, row_cache, ids, jnp.int32(prefix_len),
-                    jnp.int32(P))
+                    jnp.int32(P), snap)
         w.row_cache = row_cache
         return w
 
@@ -880,7 +904,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             last_blk = -(-P // self._ps)  # exclusive: block of the last token
             write_ids[first_blk:last_blk] = w.private_pages[:last_blk - first_blk]
             pool, tok0, key2 = _paged_admit_fn(self._paged_cfg)(
-                self._cache, w.row_cache, jnp.asarray(write_ids), w.first_vec,
+                self._cache, w.row_cache, jnp.asarray(write_ids),
+                jnp.int32(w.slot), w.first_vec,
                 jax.random.PRNGKey(item.seed), jnp.float32(item.temperature))
             self._cache = pool
             w.tok0 = int(np.asarray(tok0))  # fedlint: disable=host-sync forces transfer completion: one sync per admission, not per decode step
@@ -909,6 +934,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             n_prompt_blocks = len(item.prompt) // self._ps  # FULL chunks only
             self._alloc.register_prefix(
                 item.prompt, [int(p) for p in table[:n_prompt_blocks]])
+            if w.snap_blocks:
+                # the trie node this prompt diverged at had pages and no
+                # snapshot: it keeps the state this prefill left there
+                with tel.span("serving.state.snapshot", request_id=item.request_id,
+                              position=w.snap_blocks * self._ps,
+                              bytes=self._state_bytes):
+                    self._alloc.attach_state(
+                        item.prompt, w.snap_blocks, snapshot_of(w.row_cache),
+                        self._state_bytes)
+            w.row_cache = None
             with self._lock:
                 self._slots[b] = _Active(item, w.budget, [w.tok0], now_ns,
                                          generated=1)
@@ -976,6 +1011,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     float(st["kv_watermark_pages"])))
         out.append(("serving_kv_prefix_nodes", None,
                     float(st["kv_prefix_nodes"])))
+        if self._stateful:
+            out.append(("serving_state_snapshot_bytes", None,
+                        float(st["state_snapshot_bytes"])))
         with self._lock:
             tenants = [(t, sorted(dq)) for t, dq in self._tenant_ttft.items()
                        if dq]

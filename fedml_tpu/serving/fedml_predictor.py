@@ -84,7 +84,8 @@ class LLMPredictor(FedMLPredictor):
                  paged: Optional[bool] = None,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
-                 admission=None):
+                 admission=None,
+                 state_snapshots: int = 8):
         import os
 
         self._params = params
@@ -127,7 +128,8 @@ class LLMPredictor(FedMLPredictor):
                 self.engine = PagedContinuousBatchingEngine(
                     params, cfg, num_slots=slots, chunk=chunk,
                     page_size=ps, num_pages=pages, max_queue=max_queue,
-                    admission=admission)
+                    admission=admission,
+                    state_snapshots=int(state_snapshots))
             else:
                 from .continuous_batching import ContinuousBatchingEngine
 

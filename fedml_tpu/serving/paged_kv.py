@@ -1,4 +1,5 @@
-"""Paged KV cache: a block-pool allocator + the paged decode executables.
+"""Paged KV cache: a block-pool allocator + the paged decode executables,
+and beside the pages the per-slot state of recurrent layers.
 
 PR 6's engine provisions every slot a full [max_seq_len] KV row, so HBM is
 sized for the worst-case sequence times ``num_slots`` and common system
@@ -25,6 +26,21 @@ with a vLLM-style page pool:
   request stays queued) rather than corrupt in-flight decode. Occupancy,
   watermark, and hit/eviction counts are exported to telemetry.
 
+TWO KINDS OF STATE live in the one cache pytree the decode step carries
+(``TransformerConfig.layer_pattern``). An attention layer's leaves are the
+page pool above. A recurrent (Mamba) layer's leaves, named in
+``models/mamba.STATE_LEAVES``, are ``[num_slots, ...]`` arrays indexed by the
+request's SLOT: never paged, written whole at admission, carried and donated
+with the pool by ``_paged_step_fn``, left behind at release (the next
+admission overwrites the slot). Prefix sharing needs more than pages for
+them: the shared tokens can be skipped only from the exact state at the
+shared boundary, so a trie node may hold a SNAPSHOT of that state
+(``PagedKVAllocator.match`` / ``attach_state``), counted in bytes against a
+budget and evicted LRU; a match is usable as deep as its deepest node that
+holds one. Snapshots arise without priming: a prefill that diverges from the
+trie at a node without one emits the state at that boundary
+(``snap_lens``), and the node keeps it.
+
 Prefill reuses the contiguous executables (`generation._prefill_fn`) at
 B=1 and scatters the finished row into pages (``_paged_admit_fn``). A
 prefix HIT skips recomputing the shared prompt: gather the shared pages
@@ -45,9 +61,17 @@ import jax
 import jax.numpy as jnp
 
 from ..core import telemetry as tel
-from ..core.telemetry import devperf, track_compiles
+from ..core.telemetry import devperf, track_compiles, tsdb
+from ..models.mamba import PACKED, SNAPSHOT_LEAVES, STATE_LEAVES, mamba_layers, pack_state, unpack_state
 from ..models.transformer import TransformerConfig
-from ..train.llm.generation import _lru_get, _rewind_cache, _sample, decode_model
+from ..train.llm.generation import (
+    _leaf_at,
+    _leaf_name,
+    _lru_get,
+    _rewind_cache,
+    _sample,
+    decode_model,
+)
 
 #: reserved trash page: scatter target for every unowned block-table entry
 TRASH_PAGE = 0
@@ -100,24 +124,40 @@ def paged_pool_init(params, cfg: TransformerConfig, B: int):
                                   jax.eval_shape(cache_of, params))
 
 
+def snapshot_of(row_cache) -> dict:
+    """The prefix-cache snapshot a prefill left in its (packed) row cache:
+    ``{conv, ssm}`` stacked over the Mamba layers from the ``snap_*`` leaves,
+    the shape ``_paged_gather_fn`` takes it back in."""
+    return {dst: row_cache[PACKED][src] for src, dst in SNAPSHOT_LEAVES.items()}
+
+
 def _paged_admit_fn(cfg: TransformerConfig):
-    """Scatter one finished contiguous row cache into the pool at runtime
-    page ids and sample the request's first token. ``write_ids`` has one
-    entry per logical block; blocks the request does NOT own (shared
-    prefix pages, unallocated tail) carry TRASH_PAGE, so duplicate scatter
-    indices only ever clobber the trash page."""
+    """Write one finished contiguous row cache into the cache pytree and
+    sample the request's first token. K/V leaves scatter into the pool at
+    runtime page ids: ``write_ids`` has one entry per logical block; blocks the
+    request does NOT own (shared prefix pages, unallocated tail) carry
+    TRASH_PAGE, so duplicate scatter indices only ever clobber the trash page.
+    Recurrent-state leaves (``STATE_LEAVES``) are written whole at the
+    request's ``slot``. Leaves only the row has (its snapshot) stay behind.
+    The row arrives packed (``models/mamba.pack_state``)."""
     n_blocks = _num_blocks(cfg)
     ps = cfg.kv_page_size
 
     def build():
-        def run(pool, row_cache, write_ids, first_logits, key, temp):
-            def insert(dst, src):
+        def run(pool, row_cache, write_ids, slot, first_logits, key, temp):
+            row_cache = unpack_state(cfg, row_cache)
+
+            def insert(path, dst):
                 if dst.ndim == 0:
                     return dst  # scalar write index: meaningless for pools
+                src = _leaf_at(row_cache, path)
+                if _leaf_name(path) in STATE_LEAVES:
+                    return jax.lax.dynamic_update_slice(
+                        dst, src.astype(dst.dtype), (slot,) + (0,) * (dst.ndim - 1))
                 pages = src[0].reshape((n_blocks, ps) + src.shape[2:])
                 return dst.at[write_ids].set(pages.astype(dst.dtype))
 
-            new_pool = jax.tree_util.tree_map(insert, pool, row_cache)
+            new_pool = jax.tree_util.tree_map_with_path(insert, pool)
             key2, sub = jax.random.split(key)
             tok0 = _sample(first_logits, sub, temp)
             return new_pool, tok0, key2
@@ -128,23 +168,30 @@ def _paged_admit_fn(cfg: TransformerConfig):
 
 
 def _paged_gather_fn(cfg: TransformerConfig):
-    """Gather one request's pages back into a contiguous [1, S, kv, hd] row
-    (the suffix-prefill staging buffer), write index rewound to the shared
-    prefix length. Blocks beyond the prefix point at the trash page; their
-    garbage is overwritten by the suffix pass before any query can attend
-    to it (the ``_rewind_cache`` argument)."""
+    """Stage the row cache a suffix prefill starts from. K/V leaves: gather
+    one request's pages back into a contiguous [1, S, kv, hd] row, write index
+    rewound to the shared prefix length; blocks beyond the prefix point at the
+    trash page, their garbage is overwritten by the suffix pass before any
+    query can attend to it (the ``_rewind_cache`` argument). Recurrent-state
+    leaves: the prefix cache's snapshot ``state`` (``snapshot_of``'s shape),
+    never the pool's slots, which hold other requests. The row is packed."""
     ps = cfg.kv_page_size
+    recurrent = mamba_layers(cfg)
 
     def build():
-        def run(pool, block_table, prefix_len):
+        def run(pool, block_table, prefix_len, state=None):
             def gather(leaf):
                 if leaf.ndim == 0:
                     return leaf
                 pages = leaf[block_table]  # [n_blocks, ps, kv, hd]
                 return pages.reshape((1, pages.shape[0] * ps) + leaf.shape[2:])
 
-            row = jax.tree_util.tree_map(gather, pool)
-            return _rewind_cache(row, prefix_len)
+            row = jax.tree_util.tree_map(
+                gather, {k: v for k, v in pool.items() if k not in recurrent})
+            row = _rewind_cache(row, prefix_len)
+            if state is not None:
+                row[PACKED] = state
+            return row
 
         return jax.jit(track_compiles(run, name="paged_gather"))
 
@@ -156,21 +203,27 @@ def _suffix_prefill_fn(cfg: TransformerConfig, T_b: int):
     whose prefix pages were served from the prefix cache — the compute
     skip that makes prefix sharing a TTFT win, not only an HBM win.
     Compiled per 16-token suffix bucket; the start position (shared
-    prefix length) is a runtime value via the cache's rewound index."""
+    prefix length) is a runtime value via the cache's rewound index. A
+    recurrent layer starts from the snapshot the row cache holds, stops at
+    ``true_total`` and keeps its state at ``snap_total`` (absolute positions)
+    for the prefix cache. Row caches cross the boundary packed, both ways."""
 
     def build():
         model = decode_model(row_config(cfg))
 
-        def run(params, row_cache, suffix_padded, prefix_len, true_total):
+        def run(params, row_cache, suffix_padded, prefix_len, true_total, snap_total=None):
             positions = prefix_len + jnp.arange(T_b)[None, :]
             logits, state = model.apply(
-                {"params": params, "cache": row_cache},
+                {"params": params, "cache": unpack_state(cfg, row_cache)},
                 suffix_padded,
                 positions=positions,
                 mutable=["cache"],
+                seq_lens=jnp.reshape(true_total - prefix_len, (1,)),
+                snap_lens=(None if snap_total is None
+                           else jnp.reshape(jnp.maximum(snap_total - prefix_len, 0), (1,))),
             )
             first = logits[0, true_total - prefix_len - 1]
-            return _rewind_cache(state["cache"], true_total), first
+            return pack_state(cfg, _rewind_cache(state["cache"], true_total)), first
 
         return jax.jit(track_compiles(run, name="paged_suffix_prefill"))
 
@@ -236,7 +289,8 @@ class _PrefixNode:
     node keeps one RETENTION reference on its page; live requests mapping
     the page add their own."""
 
-    __slots__ = ("chunk", "page", "parent", "children", "tick")
+    __slots__ = ("chunk", "page", "parent", "children", "tick", "state",
+                 "state_bytes")
 
     def __init__(self, chunk: Tuple[int, ...], page: int,
                  parent: Optional["_PrefixNode"]):
@@ -245,6 +299,24 @@ class _PrefixNode:
         self.parent = parent
         self.children: Dict[Tuple[int, ...], "_PrefixNode"] = {}
         self.tick = 0
+        # recurrent layers' state after this node's last token (a snapshot,
+        # opaque to the allocator), and its bytes against the budget
+        self.state = None
+        self.state_bytes = 0
+
+
+@dataclasses.dataclass
+class PrefixMatch:
+    """What the prefix cache gives one admission. ``pages``: the shared
+    leading pages, one reference held for the caller on each. ``state``: the
+    recurrent state at ``len(pages) * page_size`` (None: the request needs
+    none, or starts from zero). ``snap_blocks``: the block boundary, deeper
+    than ``pages``, whose node matched but holds no snapshot: the prefill is
+    to emit the state there (0: none wanted)."""
+
+    pages: List[int]
+    state: object = None
+    snap_blocks: int = 0
 
 
 class PagedKVAllocator:
@@ -257,7 +329,7 @@ class PagedKVAllocator:
     """
 
     def __init__(self, num_pages: int, page_size: int, *,
-                 watermark_frac: float = 0.05):
+                 watermark_frac: float = 0.05, state_budget_bytes: int = 0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is trash)")
         self.num_pages = int(num_pages)
@@ -277,6 +349,12 @@ class PagedKVAllocator:
         self._prefix_misses = 0
         self._evictions = 0
         self._alloc_fail = 0
+        # snapshots of recurrent state held by trie nodes
+        self.state_budget_bytes = int(state_budget_bytes)
+        self._state_bytes = 0
+        self._state_hits = 0
+        self._state_misses = 0
+        self._state_evictions = 0
 
     # -- page lifecycle ----------------------------------------------------
 
@@ -332,25 +410,95 @@ class PagedKVAllocator:
         """Longest hash-consed prefix of ``tokens`` (full pages only).
         Returns the shared page ids with one reference taken per page for
         the caller (release via ``free`` with the rest of its table)."""
+        return self.match(tokens).pages
+
+    def match(self, tokens: Sequence[int], *, max_blocks: Optional[int] = None,
+              need_state: bool = False) -> PrefixMatch:
+        """``match_prefix`` for an admission: at most ``max_blocks`` pages,
+        and with ``need_state`` (the model has recurrent layers) only as deep
+        as the deepest matched node that holds a snapshot, because those
+        layers can skip the shared tokens from nowhere else. Matched nodes
+        past it take no reference; the deepest of them is where the caller's
+        prefill should leave a snapshot (``attach_state``). A state hit is an
+        admission that starts from a snapshot, a miss one that starts from
+        zero whether or not pages matched."""
         with self._lock:
-            pages: List[int] = []
+            nodes: List[_PrefixNode] = []
             level = self._root
             for chunk in self._chunks(tokens):
                 node = level.get(chunk)
                 if node is None:
                     break
-                self._tick += 1
-                node.tick = self._tick
-                self._ref[node.page] += 1
-                pages.append(node.page)
+                nodes.append(node)
                 level = node.children
-            if pages:
+            if nodes:
                 self._prefix_hits += 1
                 tel.counter("serving.kv.prefix_hits").add(1)
             else:
                 self._prefix_misses += 1
                 tel.counter("serving.kv.prefix_misses").add(1)
-            return pages
+            if max_blocks is not None:
+                nodes = nodes[:max_blocks]
+            for node in nodes:
+                self._tick += 1
+                node.tick = self._tick
+            out = PrefixMatch([])
+            if need_state:
+                keep = max((i + 1 for i, n in enumerate(nodes)
+                            if n.state is not None), default=0)
+                if keep < len(nodes):
+                    out.snap_blocks = len(nodes)
+                nodes = nodes[:keep]
+                if keep:
+                    out.state = nodes[-1].state
+                    self._state_hits += 1
+                    tel.counter("serving.state.prefix_hits").add(1)
+                else:
+                    self._state_misses += 1
+                    tel.counter("serving.state.prefix_misses").add(1)
+            for node in nodes:
+                self._ref[node.page] += 1
+                out.pages.append(node.page)
+            return out
+
+    def attach_state(self, tokens: Sequence[int], n_blocks: int, state,
+                     nbytes: int) -> bool:
+        """Give the node ``n_blocks`` chunks down ``tokens`` the recurrent
+        state at its boundary. Refused (False) when the node is gone or has
+        one already, or when one snapshot exceeds the whole budget; else the
+        LRU snapshots of other nodes make room."""
+        with self._lock:
+            node, level = None, self._root
+            for chunk in self._chunks(tokens)[:n_blocks]:
+                node = level.get(chunk)
+                if node is None:
+                    return False
+                level = node.children
+            if node is None or node.state is not None or nbytes > self.state_budget_bytes:
+                return False
+            while self._state_bytes + nbytes > self.state_budget_bytes:
+                victim = min((n for n in self._nodes if n.state is not None),
+                             key=lambda n: n.tick)
+                self._drop_state_locked(victim)
+            node.state, node.state_bytes = state, int(nbytes)
+            self._tick += 1
+            node.tick = self._tick  # a snapshot just written is the most recently used
+            self._state_bytes += node.state_bytes
+            tel.counter("serving.state.snapshots").add(1)
+            self._gauge_state_bytes_locked()
+            return True
+
+    def _drop_state_locked(self, node: _PrefixNode) -> None:
+        self._state_bytes -= node.state_bytes
+        node.state, node.state_bytes = None, 0
+        self._state_evictions += 1
+        tel.counter("serving.state.snapshot_evictions").add(1)
+        self._gauge_state_bytes_locked()
+
+    def _gauge_state_bytes_locked(self) -> None:
+        store = tsdb.active()
+        if store is not None:
+            store.record_gauge("serving.state.snapshot_bytes", float(self._state_bytes))
 
     def register_prefix(self, tokens: Sequence[int],
                         block_ids: Sequence[int]) -> None:
@@ -394,6 +542,8 @@ class PagedKVAllocator:
             if victim is None:
                 return
             self._nodes.remove(victim)
+            if victim.state is not None:  # a snapshot goes with its node
+                self._drop_state_locked(victim)
             level = victim.parent.children if victim.parent else self._root
             level.pop(victim.chunk, None)
             self._ref[victim.page] -= 1
@@ -418,12 +568,29 @@ class PagedKVAllocator:
                 "kv_prefix_misses": self._prefix_misses,
                 "kv_prefix_evictions": self._evictions,
                 "kv_alloc_deferred": self._alloc_fail,
+                "state_snapshots": sum(1 for n in self._nodes if n.state is not None),
+                "state_snapshot_bytes": self._state_bytes,
+                "state_budget_bytes": self.state_budget_bytes,
+                "state_prefix_hits": self._state_hits,
+                "state_prefix_misses": self._state_misses,
+                "state_snapshot_evictions": self._state_evictions,
             }
 
     def check_leaks(self) -> dict:
         """Test hook: with no live requests, every non-free page must be
-        either trash or a retained prefix page (refcount exactly 1)."""
+        either trash or a retained prefix page (refcount exactly 1), and the
+        snapshots the trie's nodes hold must be the bytes counted, inside the
+        budget (``state_leaked`` lists what is not)."""
         with self._lock:
+            held = sum(n.state_bytes for n in self._nodes)
+            state_leaked = []
+            if held != self._state_bytes:
+                state_leaked.append(f"nodes hold {held} bytes, {self._state_bytes} counted")
+            if self._state_bytes > self.state_budget_bytes:
+                state_leaked.append(f"{self._state_bytes} bytes over the budget "
+                                    f"{self.state_budget_bytes}")
+            state_leaked += [f"node of page {n.page}: state and bytes disagree"
+                             for n in self._nodes if (n.state is None) != (n.state_bytes == 0)]
             retained = {n.page for n in self._nodes}
             leaked = [
                 p for p in range(1, self.num_pages)
@@ -432,5 +599,7 @@ class PagedKVAllocator:
             free_set = set(self._free)
             double = [p for p in free_set if self._ref[p] != 0]
             return {"leaked": leaked, "bad_free": double,
+                    "state_leaked": state_leaked,
                     "accounted": len(free_set) + len(retained) + 1
-                    == self.num_pages and not (free_set & retained)}
+                    == self.num_pages and not (free_set & retained)
+                    and not state_leaked}

@@ -32,7 +32,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from ...models.transformer import TransformerConfig
+from ...models.transformer import TransformerConfig, hybrid_pattern
 from .safetensors_io import load_checkpoint_tensors, save_safetensors
 
 
@@ -51,9 +51,19 @@ def _rope_perm(n_heads: int, head_dim: int, inverse: bool = False) -> np.ndarray
 
 
 def config_from_hf(model_dir: str, **overrides: Any) -> TransformerConfig:
-    """Build a TransformerConfig from an HF config.json (llama family)."""
+    """Build a TransformerConfig from an HF config.json."""
     with open(os.path.join(model_dir, "config.json")) as f:
-        hf = json.load(f)
+        return config_from_hf_keys(json.load(f), **overrides)
+
+
+def config_from_hf_keys(hf: Dict[str, Any], **overrides: Any) -> TransformerConfig:
+    """The same from the published keys themselves. Llama-family keys map as
+    they always did. A file with the Jamba family's keys (``attn_layer_period``
+    / ``attn_layer_offset`` and ``mamba_*``) also gives the layer pattern
+    (attention where ``i % period == offset``, a Mamba mixer elsewhere), the
+    mixer's sizes, no rotary positions, the norm's epsilon and a tied head.
+    Its sparse variants are refused by name: ``num_experts > 1`` needs top-k
+    routing this block does not have."""
     base = dict(
         vocab_size=hf["vocab_size"],
         d_model=hf["hidden_size"],
@@ -64,6 +74,20 @@ def config_from_hf(model_dir: str, **overrides: Any) -> TransformerConfig:
         max_seq_len=hf.get("max_position_embeddings", 2048),
         rope_theta=float(hf.get("rope_theta", 10000.0)),
     )
+    if "attn_layer_period" in hf:
+        if int(hf.get("num_experts", 1)) > 1:
+            raise ValueError(
+                f"num_experts={hf['num_experts']} (top-{hf.get('num_experts_per_tok')}): the "
+                "block's feed-forward is dense; top-k expert routing is not implemented")
+        base.update(
+            layer_pattern=hybrid_pattern(hf["num_hidden_layers"], hf["attn_layer_period"],
+                                         hf["attn_layer_offset"]),
+            use_rope=False,
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            mamba_d_state=hf["mamba_d_state"], mamba_d_conv=hf["mamba_d_conv"],
+            mamba_expand=hf["mamba_expand"], mamba_dt_rank=hf["mamba_dt_rank"],
+        )
     base.update(overrides)
     return TransformerConfig(**base)
 

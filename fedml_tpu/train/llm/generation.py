@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.telemetry import track_compiles
+from ...models.mamba import pack_state, unpack_state
 from ...models.transformer import TransformerConfig, TransformerLM
 
 
@@ -85,22 +86,38 @@ def _rewind_cache(cache, true_len):
     return jax.tree_util.tree_map_with_path(fix, cache)
 
 
+def _leaf_name(path) -> str:
+    return str(getattr(path[-1], "key", path[-1]))
+
+
+def _leaf_at(tree, path):
+    """The leaf of ``tree`` at ``path`` (a ``tree_map_with_path`` key path)."""
+    for p in path:
+        tree = tree[getattr(p, "key", p)]
+    return tree
+
+
 def _prefill_fn(cfg: TransformerConfig, B: int, P_bucket: int):
     """Compiled per PROMPT-LENGTH BUCKET (multiples of 16), not per exact
     length: serving traffic with varied prompt lengths shares executables
     (a fresh compile per length was the old behavior's latency cliff).
-    ``true_len`` is a runtime scalar."""
+    ``true_len`` is a runtime scalar; so is ``snap_len``, the position at
+    which a recurrent layer also keeps its state for the prefix cache
+    (``models/mamba.py``; attention layers read neither). The cache handed on
+    is PACKED (``models/mamba.pack_state``: a dense model's is unchanged)."""
 
     def build():
         model = decode_model(cfg)
 
-        def run(params, prompt_padded, true_len):
+        def run(params, prompt_padded, true_len, snap_len=None):
             positions = jnp.broadcast_to(jnp.arange(P_bucket), (B, P_bucket))
             logits, state = model.apply(
-                {"params": params}, prompt_padded, positions=positions, mutable=["cache"]
+                {"params": params}, prompt_padded, positions=positions, mutable=["cache"],
+                seq_lens=jnp.broadcast_to(true_len, (B,)),
+                snap_lens=None if snap_len is None else jnp.broadcast_to(snap_len, (B,)),
             )
             first = logits[jnp.arange(B), true_len - 1]
-            return _rewind_cache(state["cache"], true_len), first
+            return pack_state(cfg, _rewind_cache(state["cache"], true_len)), first
 
         # compile observability: counter("jax.compiles.prefill") advances per
         # TRACE, not per call — the serving compile-count guards read it
@@ -118,6 +135,7 @@ def _decode_fn(cfg: TransformerConfig, B: int, max_new: int, sampled: bool,
             return jnp.isin(tok, jnp.asarray(eos_ids))
 
         def run(params, cache, first_logits, pos0, key, temperature):
+            cache = unpack_state(cfg, cache)  # as _prefill_fn hands it on
             key, sub = jax.random.split(key)
             temp = temperature if sampled else jnp.float32(0.0)
             first = _sample(first_logits, sub, temp)

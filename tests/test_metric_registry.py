@@ -136,6 +136,12 @@ EXPORTED = {
     "fedml_serving_kv_prefix_misses_total": "counter",
     "fedml_serving_kv_prefix_evictions_total": "counter",
     "fedml_serving_kv_alloc_deferred_total": "counter",
+    # recurrent-state snapshots of the prefix trie (models with Mamba layers)
+    "fedml_serving_state_prefix_hits_total": "counter",
+    "fedml_serving_state_prefix_misses_total": "counter",
+    "fedml_serving_state_snapshots_total": "counter",
+    "fedml_serving_state_snapshot_evictions_total": "counter",
+    "fedml_serving_state_snapshot_bytes": "gauge",
     # multi-tenant admission (serving/admission.py; {tenant}/{tenant,reason})
     "fedml_serving_admission_rejected_total": "counter",
     "fedml_serving_admission_deferrals_total": "counter",
