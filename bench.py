@@ -106,9 +106,11 @@ def _cost_analysis_flops(lowered_compiled) -> float | None:
 
 
 def _flash_blocks(seq: int) -> str:
-    from fedml_tpu.ops.flash_attention import BLOCK_K, BLOCK_Q
+    from fedml_tpu.ops.flash_attention import block_sizes
 
-    return f"{min(BLOCK_Q, seq)}x{min(BLOCK_K, seq)}"
+    s = _llm_shape()
+    bq, bk = block_sizes(seq, s["d_model"] // s["n_heads"], "fwd")
+    return f"{bq}x{bk}"
 
 
 def _timed_chain(step_once, reps_small: int = 2, reps_large: int = 12) -> float:
@@ -640,8 +642,8 @@ def _bench_attn_micro(reps: int = 6):
     MFU 0.261 — ~0.35 RAW hardware efficiency once remat's ~4/3 recompute
     is counted — against the flash headline's 0.299, implicating the
     kernel itself (not the surrounding step) as the MFU lever. This stage
-    isolates it and REPORTS the fastest config; the kernel's block sizes are
-    constants in ops/flash_attention.py, changed only by a perf PR that
+    isolates it and REPORTS the fastest config; the kernel's block ladder is
+    a constant of ops/flash_attention.py, changed only by a perf PR that
     cites the ledger — never steered by a file this stage writes."""
     import jax
     import jax.numpy as jnp
@@ -703,7 +705,7 @@ def _bench_attn_micro(reps: int = 6):
             raise
         except Exception as e:  # noqa: BLE001 - a Mosaic rejection (or OOM)
             # of ONE swept block config must not void the sweep: only the
-            # 128x128 constants are verified by chip_smoke.py, every other
+            # default choice is verified by chip_smoke.py, every other
             # config meets the compiler here — recorded under
             # rejected_configs, never substituted
             if (bq, bk) == (128, 128):

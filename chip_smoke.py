@@ -9,7 +9,8 @@ paths once through the entry points a user would call, at the full width of
 the models the repo supports (depth cut to what a 16 GB chip holds, weights
 random from a seed):
 
-  kernel    flash_attention fwd+bwd vs xla_attention at head_dim 128 (MHA + GQA)
+  kernel    flash_attention fwd+bwd vs xla_attention at head_dim 128 (MHA + GQA at
+            T 1,024, and the training cell's 32-on-8 heads at T 2,048)
   fedavg    fedml_tpu.run_simulation's body (FedMLRunner, sp backend): ResNet-56,
             CIFAR-10 shapes, batch 128, 4 clients/round, 3 rounds
   llm       LLMTrainer(...).train() at Llama-2-7B widths, seq 1024, LoRA r=8,
@@ -53,7 +54,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 # vocab matrices must fit 16 GB with activations.
 LLM_LAYERS = 2
 REAL = dict(
-    kernel=dict(batch=1, seq=1024, head_dim=128, cases=((32, 32), (32, 4))),
+    # (Hq, Hkv, T): MHA and GQA at the llm phase's length, and the shape of
+    # the benchmark's training cell (mistral7b_lora_pack2k: 32 on 8, T 2,048)
+    kernel=dict(batch=1, head_dim=128, cases=((32, 32, 1024), (32, 4, 1024), (32, 8, 2048))),
     fedavg=dict(model="resnet56", dataset="cifar10", batch_size=128,
                 client_num_in_total=8, client_num_per_round=4, comm_round=3),
     llm=dict(vocab_size=32000, d_model=4096, n_layers=LLM_LAYERS, n_heads=32,
@@ -61,7 +64,7 @@ REAL = dict(
     serving=dict(max_seq_len=512, new_tokens=32, slots=4, prefix_words=96),
 )
 DRY = dict(
-    kernel=dict(batch=1, seq=128, head_dim=16, cases=((4, 4), (4, 2))),
+    kernel=dict(batch=1, head_dim=16, cases=((4, 4, 128), (4, 2, 128), (4, 1, 256))),
     fedavg=dict(model="lr", dataset="mnist", batch_size=32,
                 client_num_in_total=8, client_num_per_round=4, comm_round=3),
     llm=dict(vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
@@ -171,9 +174,9 @@ def phase_kernel(sz):
     from fedml_tpu.models.transformer import repeat_kv, xla_attention
     from fedml_tpu.ops.flash_attention import flash_attention
 
-    B, T, D = sz["batch"], sz["seq"], sz["head_dim"]
+    B, D = sz["batch"], sz["head_dim"]
     worst = {}
-    for hq, hkv in sz["cases"]:
+    for hq, hkv, T in sz["cases"]:
         ks = jax.random.split(jax.random.PRNGKey(hq * 131 + hkv), 4)
         q32 = jax.random.normal(ks[0], (B, T, hq, D), jnp.float32)
         k32 = jax.random.normal(ks[1], (B, T, hkv, D), jnp.float32)
@@ -204,11 +207,11 @@ def phase_kernel(sz):
                 a = jnp.asarray(a, jnp.float32)
                 check(bool(jnp.all(jnp.isfinite(a))), f"kernel {tag} not finite ({hq}/{hkv} {name})")
                 err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
-                worst[f"Hq{hq}_Hkv{hkv}_{name}_{tag}"] = err
+                worst[f"Hq{hq}_Hkv{hkv}_T{T}_{name}_{tag}"] = err
                 check(err < TOL[name],
                       f"flash_attention {tag} off by {err:.3e} (> {TOL[name]}) at "
                       f"Hq={hq} Hkv={hkv} D={D} T={T} {name}")
-    return {"shapes": [f"Hq{a}/Hkv{b}/D{D}/T{T}" for a, b in sz["cases"]],
+    return {"shapes": [f"Hq{a}/Hkv{b}/D{D}/T{t}" for a, b, t in sz["cases"]],
             "max_rel_err": {k: float(f"{v:.3e}") for k, v in worst.items()}}
 
 

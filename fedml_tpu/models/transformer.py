@@ -234,11 +234,13 @@ def xla_attention(q, k, v, causal: bool = True, mask: Optional[jnp.ndarray] = No
 @functools.lru_cache(maxsize=None)
 def _auto_attention_impl(platform: str, seq_len: int) -> str:
     """What ``attention_impl="auto"`` means, logged once per distinct case:
-    the pallas flash kernel where it runs COMPILED (platform ``tpu``) and its
-    128x128 blocks tile the sequence (``ops.flash_attention.tiles`` — on v5e
+    the pallas flash kernel where it runs COMPILED (platform ``tpu``) and a
+    rung of its block ladder tiles the sequence (``ops.flash_attention.tiles``:
+    any length below 128 or a multiple of it; each kernel then takes the
+    largest rung up to 512 that divides the length, ``block_sizes`` — on v5e
     the kernel compiled and matched XLA at every such shape tried, T 8..16384,
-    head_dim 16..256, PR 21); XLA einsum attention otherwise (sequences the
-    blocks do not tile, and the CPU, where interpret-mode flash would be pure
+    head_dim 16..256, PR 21); XLA einsum attention otherwise (sequences no
+    rung tiles, and the CPU, where interpret-mode flash would be pure
     overhead). An explicit ``"pallas"`` is never rerouted — it runs the
     kernel or raises."""
     from ..ops.flash_attention import tiles
