@@ -15,7 +15,6 @@ subprocess that prints one JSON line —
     python bench.py --stage decode / decode_int8   (fp vs weight-only int8)
     python bench.py --stage resnet         (+ measured FedAvg rounds/hr)
     python bench.py --stage cpu_llm / cpu_resnet   (host-only baselines)
-    python bench.py --stage serving        (runs LAST)
 so chip HBM is truly released between stages (the process exits) and one
 stage's OOM cannot void the others. The orchestrator itself NEVER imports
 jax: it only spawns stages, merges their JSON, and records failures into
@@ -2910,389 +2909,6 @@ def _bench_placement_search(probe_publishes: int = 4, reps: int = 2):
     }
 
 
-def _bench_llm_serving(n_replicas: int = 1, clients: int = 4, reqs_per_client: int = 3):
-    """Endpoint-level decode throughput (BASELINE config 5): tokens/s
-    measured THROUGH the gateway with subprocess replicas — the real
-    deployment topology (gateway retry/eviction + HTTP + per-replica
-    KV-cache decode), unlike the in-process decode bench.
-
-    The replicas serve the FLAGSHIP 268M llama proxy (VERDICT r3 missing #4
-    — the old bench served a ~30M toy). A chip belongs to one process, so a
-    subprocess replica needs a chip of its own: the stage asks for ONE, the
-    ReplicaSet refuses more replicas than the host has chips, and a replica
-    that cannot come up fails the stage (its stderr is in the replica log)
-    instead of the bench measuring "however many did".
-
-    The gateway round-robins whole requests to replicas (reference
-    device_model_inference.py does the same); each replica additionally
-    runs server-side DYNAMIC BATCHING (10ms window, max 4 — the
-    _MicroBatcher the reference lacks), so concurrency is absorbed by both
-    replica parallelism and in-replica batch decode. Distinct prompts per
-    request so the platform cannot dedupe executions."""
-    import threading
-
-    from fedml_tpu.serving.replica_controller import InferenceGateway, ReplicaSet
-
-    # the warm-up/measured prompts rely on single-digit fields tokenizing to
-    # the same length (and 'req 9' being reserved for warm-up)
-    if clients > 10 or reqs_per_client > 9:
-        raise ValueError("serving bench supports clients <= 10 and reqs_per_client <= 9")
-
-    # env mutation only after all validation: a raise must not leak batching
-    # settings into the process
-    saved_env = {k: os.environ.get(k) for k in
-                 ("FEDML_SERVE_MAX_BATCH", "FEDML_SERVE_BATCH_WINDOW_MS",
-                  "FEDML_BENCH_FLAGSHIP")}
-    os.environ["FEDML_SERVE_MAX_BATCH"] = "4"  # inherited by replica children
-    os.environ["FEDML_SERVE_BATCH_WINDOW_MS"] = "10"
-    tiny = os.environ.get("FEDML_BENCH_TINY") == "1"
-    if not tiny:
-        os.environ["FEDML_BENCH_FLAGSHIP"] = "1"  # 268M predictor geometry
-
-    # matches bench_predictors' default_max_new_tokens (tiny mode is the
-    # CPU test harness for this path)
-    new_tokens = 16 if tiny else 64
-    # startup budget capped (VERDICT r3 weak #2); flagship compile lands well
-    # under this. The orchestrator's serving stage budget must stay above the
-    # serial sum of these (see _STAGES).
-    startup_budget_s = 60.0 if tiny else 300.0
-    predict_timeout_s = 60.0 if tiny else 240.0
-    rs = None
-    try:
-        rs = ReplicaSet(
-            "fedml_tpu.serving.bench_predictors:llm_bench_predictor",
-            desired=n_replicas, startup_timeout_s=startup_budget_s,
-        )
-        deadline = time.time() + startup_budget_s  # fedlint: disable=wall-clock startup deadline shared with replica subprocesses
-        while time.time() < deadline:  # fedlint: disable=wall-clock startup deadline shared with replica subprocesses
-            if len([r for r in rs.healthy() if r.ready()]) >= n_replicas:
-                break
-            time.sleep(1.0)  # fedlint: disable=bare-sleep replica startup poll pacing, not a retry
-            rs.reconcile()  # replace replicas that died during startup
-        n_ready = len([r for r in rs.healthy() if r.ready()])
-        if n_ready < n_replicas:
-            raise RuntimeError(
-                f"serving bench: only {n_ready}/{n_replicas} replicas became ready "
-                f"in {startup_budget_s:.0f}s; replica logs: "
-                f"{[r.log_path for r in rs.replicas]}")
-        gw = InferenceGateway(rs)
-        # warm EVERY replica with the measured prompt SHAPE: generate()
-        # compiles per prompt token-length, so the warm prompts must
-        # tokenize to the same length as the measured ones ('measure
-        # endpoint run {c} req {r}') or the timed window absorbs a fresh
-        # prefill compile on each replica; round-robin spreads these
-        for w in range(n_ready):
-            # single-digit fields keep the token length identical to the
-            # measured prompts; 'req 9' never occurs in the measured set
-            gw.predict({"prompt": f"measure endpoint run {w % 10} req 9"},
-                       timeout_s=predict_timeout_s)
-
-        results: list = []
-        errors: list = []
-
-        def client(cid: int) -> None:
-            try:
-                for r in range(reqs_per_client):
-                    out = gw.predict({"prompt": f"measure endpoint run {cid} req {r}"},
-                                     timeout_s=predict_timeout_s)
-                    results.append(out)
-            except Exception as e:  # noqa: BLE001
-                errors.append(e)
-
-        t0 = time.perf_counter()
-        threads = [threading.Thread(target=client, args=(c,)) for c in range(clients)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        dt = time.perf_counter() - t0
-        if errors:
-            raise RuntimeError(f"serving bench request failed: {errors[0]!r}")
-        total_new = new_tokens * len(results)
-        return {
-            "endpoint_decode_tokens_per_sec": total_new / dt,
-            "endpoint_replicas": n_ready,
-            "endpoint_requests": len(results),
-            "endpoint_model": "tiny" if tiny else "llama-268M flagship proxy (bf16)",
-            "endpoint_batching": "dynamic (per-replica micro-batch, window 10ms, max 4)",
-            # int8 weight-only mode (serving/quant.py) is opt-in; the label
-            # keeps a quantized measurement from ever reading as fp
-            "endpoint_weight_quant": (
-                "int8" if os.environ.get("FEDML_BENCH_INT8") == "1" else "none"),
-        }
-    finally:
-        if rs is not None:
-            rs.shutdown()
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
-def _serving_load_prompts(streams: int, tiny: bool, seed: int = 0):
-    """The load mix: RAGGED prompt/output lengths, and 70% of streams share
-    one of 4 system prompts (the production shape paged prefix sharing
-    exists for: few long system prompts, many short user tails)."""
-    import random
-
-    rng = random.Random(seed)
-    base = "federated benchmark serving endpoint throughput measure "
-    # tiny cfg has max_seq_len 64: one base rep keeps prompt+max_new inside
-    # the context while the shared system prefix still spans 2+ full pages
-    sys_reps = 1 if tiny else 8
-    system = [f"system prompt {w}: " + base * sys_reps
-              for w in ("alpha", "beta", "gamma", "delta")]
-    reqs = []
-    for i in range(streams):
-        tail = f"user {i % 97} asks question {i % 7} about topic {i % 13}"
-        if rng.random() < 0.70:
-            prompt = system[i % 4] + tail
-        else:
-            prompt = f"cold prompt {i}: " + base * rng.randint(1, sys_reps) + tail
-        max_new = rng.randint(2, 8) if tiny else rng.randint(4, 32)
-        reqs.append({"prompt": prompt, "max_new_tokens": max_new})
-    return reqs
-
-
-def _serving_load_once(reqs: list, paged: bool):
-    """One load run: `len(reqs)` concurrent HTTP streams against a fresh
-    in-process runner + engine (paged or fixed-slot, selected via the env
-    seam the predictor reads). Returns the metrics of this run."""
-    import http.client
-    import threading
-
-    from fedml_tpu.serving.bench_predictors import llm_bench_predictor
-    from fedml_tpu.serving.fedml_inference_runner import FedMLInferenceRunner
-
-    streams = len(reqs)
-    runner = None
-    os.environ["FEDML_SERVE_PAGED"] = "1" if paged else "0"
-    os.environ["FEDML_SERVE_CONTINUOUS"] = "0" if paged else "1"
-    try:
-        pred = llm_bench_predictor()  # warmed (engine compiles in warmup)
-        assert pred.engine is not None, "continuous engine did not come up"
-        runner = FedMLInferenceRunner(pred, port=0)
-        port = runner.start()
-
-        ok: list = []
-        failures: list = []
-        start_gate = threading.Event()
-
-        def stream(i: int) -> None:
-            # keep-alive connection per stream; one long-lived decode each,
-            # so `streams` requests really are concurrently in flight. The
-            # ramp (200 connects per 50ms tranche) keeps 10k near-simultaneous
-            # TCP connects from overflowing the server's accept backlog —
-            # every stream is still concurrently IN FLIGHT, admission just
-            # sees an arrival wave instead of a SYN flood.
-            start_gate.wait()
-            time.sleep((i // 200) * 0.05)  # fedlint: disable=bare-sleep connect-ramp pacing, not a retry
-            try:
-                conn = http.client.HTTPConnection("127.0.0.1", port, timeout=900)
-                conn.request("POST", "/predict", json.dumps(reqs[i]),
-                             {"Content-Type": "application/json"})
-                resp = conn.getresponse()
-                data = resp.read()
-                conn.close()
-                if resp.status != 200:
-                    raise RuntimeError(f"status {resp.status}: {data[:200]!r}")
-                ok.append(json.loads(data))
-            except Exception as e:  # noqa: BLE001 - tallied, stage-fatal below
-                failures.append(repr(e))
-
-        base = pred.engine.stats()["tokens_out"]
-        threads = [threading.Thread(target=stream, args=(i,)) for i in range(streams)]
-        # sample slot occupancy / queue depth / KV pages DURING the load
-        # (stats() after join always reads 0 — the interesting number is
-        # mid-burst)
-        occ_samples: list = []
-        q_samples: list = []
-        ppt_samples: list = []  # kv pages per live token (paged only)
-        done_gate = threading.Event()
-
-        def sampler() -> None:
-            start_gate.wait()
-            while not done_gate.wait(0.05):
-                s = pred.engine.stats()
-                occ_samples.append(s["slot_occupancy"])
-                q_samples.append(s["queue_depth"])
-                if paged and s.get("kv_tokens_live", 0) > 0:
-                    ppt_samples.append(s["kv_pages_per_token"])
-
-        samp = threading.Thread(target=sampler, daemon=True)
-        samp.start()
-        for t in threads:
-            t.start()
-        t0 = time.perf_counter()
-        start_gate.set()
-        for t in threads:
-            t.join()
-        dt = time.perf_counter() - t0
-        done_gate.set()
-        samp.join(timeout=2)
-        st = pred.engine.stats()
-
-        def timing_pcts(key: str) -> dict:
-            # the engine's own readings, one per reply (the reply's "timing")
-            xs = sorted(r["timing"][key] for r in ok if r["timing"][key] is not None)
-            return {f"p{int(q * 100)}": xs[min(len(xs) - 1, int(q * len(xs)))] if xs else None
-                    for q in (0.5, 0.99)}
-
-        pct = {"ttft_s": timing_pcts("ttft_s"), "tpot_s": timing_pcts("tpot_s")}
-        if failures:
-            # acceptance is "without request failures": any failure is a
-            # stage failure, with the first few causes in the record
-            raise RuntimeError(
-                f"serving_load[{'paged' if paged else 'fixed'}]: "
-                f"{len(failures)}/{streams} streams failed: "
-                + "; ".join(failures[:3]))
-        tokens = st["tokens_out"] - base
-        cfg = pred._cfg
-        # KV bytes actually provisioned by this engine (k+v, all layers)
-        import numpy as _np
-
-        per_tok = (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
-                   * _np.dtype(cfg.dtype).itemsize)
-        kv_tokens = (st["kv_pages_total"] * st["kv_page_size"] if paged
-                     else st["slots_total"] * cfg.max_seq_len)
-        return {
-            "tokens_per_sec": round(tokens / dt, 2),
-            "tokens": tokens,
-            "wall_s": round(dt, 2),
-            "ttft_p50_s": pct["ttft_s"]["p50"],
-            "ttft_p99_s": pct["ttft_s"]["p99"],
-            "tpot_p50_s": pct["tpot_s"]["p50"],
-            "tpot_p99_s": pct["tpot_s"]["p99"],
-            "slots": st["slots_total"],
-            "chunk": st["chunk"],
-            "occ_peak": round(max(occ_samples), 3) if occ_samples else None,
-            "occ_mean": (round(sum(occ_samples) / len(occ_samples), 3)
-                         if occ_samples else None),
-            "queue_peak": max(q_samples) if q_samples else None,
-            "kv_tokens": kv_tokens,
-            "kv_bytes": kv_tokens * per_tok,
-            "kv_pages_per_token": (
-                round(sum(ppt_samples) / len(ppt_samples), 4)
-                if ppt_samples else None),
-            "prefix_hits": st.get("kv_prefix_hits"),
-            "prefix_misses": st.get("kv_prefix_misses"),
-            "alloc_deferred": st.get("kv_alloc_deferred"),
-        }
-    finally:
-        if runner is not None:
-            runner.stop()
-
-
-def _bench_llm_serving_load(streams: int | None = None):
-    """Load test: 10k CONCURRENT streams against ONE endpoint, run TWICE —
-    paged KV engine vs fixed-slot engine — on the identical ragged
-    shared-prefix workload (serving/continuous_batching.py, paged_kv.py).
-
-    Topology: one in-process FedMLInferenceRunner (stdlib threading HTTP
-    server) over an LLMPredictor. In-process (no subprocess replicas)
-    because the claim under test is the ENGINE's ability to interleave the
-    streams on one chip; the `serving` stage keeps covering the
-    multi-replica topology.
-
-    The paged engine is deliberately given HALF the fixed engine's KV
-    provisioning (num_pages * page_size = slots * max_seq_len / 2): the
-    claim is that prefix sharing + token-granular paging beat worst-case
-    row allocation on BOTH axes at once — p99 TTFT (queue wait dominates
-    at this concurrency, and 70% of streams skip their system prompt's
-    prefill) AND total KV HBM. Both claims are integrity-GUARDED
-    (BenchIntegrityError) on the full-scale run; the tiny CPU harness
-    records but does not guard TTFT (8 slots of timing noise)."""
-    tiny = os.environ.get("FEDML_BENCH_TINY") == "1"
-    if streams is None:
-        streams = int(os.environ.get("FEDML_SERVE_LOAD_STREAMS",
-                                     "64" if tiny else "10240"))
-    saved_env = {k: os.environ.get(k) for k in
-                 ("FEDML_SERVE_CONTINUOUS", "FEDML_SERVE_PAGED",
-                  "FEDML_SERVE_SLOTS", "FEDML_SERVE_CHUNK",
-                  "FEDML_SERVE_PAGE_SIZE", "FEDML_SERVE_KV_PAGES",
-                  "FEDML_SERVE_MAX_QUEUE", "FEDML_BENCH_FLAGSHIP")}
-    slots = int(os.environ.setdefault("FEDML_SERVE_SLOTS",
-                                      "8" if tiny else "64"))
-    os.environ.setdefault("FEDML_SERVE_CHUNK", "4" if tiny else "16")
-    os.environ["FEDML_SERVE_MAX_QUEUE"] = str(streams + 64)
-    if not tiny:
-        os.environ["FEDML_BENCH_FLAGSHIP"] = "1"  # 268M predictor geometry
-    page_size = 16
-    os.environ["FEDML_SERVE_PAGE_SIZE"] = str(page_size)
-    max_seq = 64 if tiny else 256
-    # HALF the fixed-slot KV budget (+1 for the reserved trash page)
-    os.environ["FEDML_SERVE_KV_PAGES"] = str(
-        slots * max_seq // page_size // 2 + 1)
-    try:
-        reqs = _serving_load_prompts(streams, tiny)
-        paged = _serving_load_once(reqs, paged=True)
-        fixed = _serving_load_once(reqs, paged=False)
-        if paged["kv_bytes"] >= fixed["kv_bytes"]:
-            raise BenchIntegrityError(
-                f"paged engine provisioned {paged['kv_bytes']} KV bytes vs "
-                f"fixed {fixed['kv_bytes']} — the HBM claim is void")
-        if (not tiny and paged["ttft_p99_s"] is not None
-                and fixed["ttft_p99_s"] is not None
-                and paged["ttft_p99_s"] >= fixed["ttft_p99_s"]):
-            raise BenchIntegrityError(
-                f"paged p99 TTFT {paged['ttft_p99_s']:.3f}s did not beat "
-                f"fixed-slot {fixed['ttft_p99_s']:.3f}s at {streams} streams "
-                "— the latency claim is void")
-        out = {
-            "serving_load_streams": streams,
-            "serving_load_tokens_per_sec": paged["tokens_per_sec"],
-            "serving_load_tokens": paged["tokens"],
-            "serving_load_wall_s": paged["wall_s"],
-            "serving_load_ttft_p50_s": paged["ttft_p50_s"],
-            # headline keys (bench_regress HEADLINES): paged-engine tails
-            "serving_load_p99_ttft_s": paged["ttft_p99_s"],
-            "serving_load_p99_tpot_s": paged["tpot_p99_s"],
-            "kv_pages_per_token": paged["kv_pages_per_token"],
-            "serving_load_slots": paged["slots"],
-            "serving_load_chunk": paged["chunk"],
-            "serving_load_slot_occupancy_peak": paged["occ_peak"],
-            "serving_load_slot_occupancy_mean": paged["occ_mean"],
-            "serving_load_queue_depth_peak": paged["queue_peak"],
-            "serving_load_kv_bytes_paged": paged["kv_bytes"],
-            "serving_load_kv_bytes_fixed": fixed["kv_bytes"],
-            "serving_load_kv_hbm_ratio": round(
-                paged["kv_bytes"] / fixed["kv_bytes"], 3),
-            "serving_load_prefix_hits": paged["prefix_hits"],
-            "serving_load_prefix_misses": paged["prefix_misses"],
-            "serving_load_alloc_deferred": paged["alloc_deferred"],
-            "serving_load_fixed_tokens_per_sec": fixed["tokens_per_sec"],
-            "serving_load_fixed_ttft_p99_s": fixed["ttft_p99_s"],
-            "serving_load_fixed_tpot_p99_s": fixed["tpot_p99_s"],
-            "serving_load_model": "tiny" if tiny else "llama-268M flagship proxy (bf16)",
-            "serving_load_engine": ("paged KV (prefix-shared, "
-                                    "admission-pipelined) vs fixed-slot"),
-        }
-        for k in ("serving_load_ttft_p50_s", "serving_load_p99_ttft_s",
-                  "serving_load_p99_tpot_s", "serving_load_fixed_ttft_p99_s",
-                  "serving_load_fixed_tpot_p99_s"):
-            if out[k] is not None:
-                out[k] = round(out[k], 4)
-        # legacy aliases (dashboards pre-paged): same values, old names
-        out["serving_load_ttft_p99_s"] = out["serving_load_p99_ttft_s"]
-        out["serving_load_tpot_p50_s"] = (
-            round(paged["tpot_p50_s"], 4) if paged["tpot_p50_s"] is not None
-            else None)
-        out["serving_load_tpot_p99_s"] = out["serving_load_p99_tpot_s"]
-        return out
-    finally:
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
 # --- workload A: ResNet-56 / CIFAR-10 local SGD ------------------------------
 
 def _resnet56_fwd_flops_per_image(width: int = 16) -> float:
@@ -3797,17 +3413,12 @@ def _stage_result(name: str) -> dict:
         out = {"cpu_llm_tokens_per_sec": _bench_llm_torch_cpu(_LLM_SHAPE)}
     elif name == "cpu_resnet":
         out = {"cpu_resnet_images_per_sec": _bench_resnet_torch_cpu()}
-    elif name == "serving":
-        out = _bench_llm_serving()
-    elif name == "serving_load":
-        out = _bench_llm_serving_load()
     else:
         raise SystemExit(f"unknown stage {name!r}")
     return out
 
 
-# (stage, per-stage wall budget seconds). Headline FIRST; serving LAST so its
-# replica children can never leave a chip half-full under a later stage.
+# (stage, per-stage wall budget seconds). Headline FIRST.
 _STAGES: list[tuple[str, int]] = [
     ("llm_pallas", 1500),
     ("llm_xla", 1200),
@@ -3882,14 +3493,6 @@ _STAGES: list[tuple[str, int]] = [
     ("memplan", 480),
     ("cpu_llm", 400),
     ("cpu_resnet", 200),
-    # must exceed the stage's own internal worst case: 2x300s serial replica
-    # startup + 300s ready-wait + 2x240s warm + measured requests
-    ("serving", 1800),
-    # 1k-stream continuous-batching load test: in-process engine, so the
-    # worst case is warmup compiles + 1024 B=1 prefill admissions + chunked
-    # decode of ~32k tokens; runs after `serving` for the same
-    # chip-occupancy reason
-    ("serving_load", 1200),
 ]
 
 
@@ -4075,7 +3678,6 @@ def main() -> None:
     llm_xla = stage_out.get("llm_xla")
     decode = stage_out.get("decode")
     resnet = stage_out.get("resnet")
-    serving = stage_out.get("serving") or {"endpoint_decode_tokens_per_sec": None}
     cpu_llm = (stage_out.get("cpu_llm") or {}).get("cpu_llm_tokens_per_sec")
     cpu_resnet = (stage_out.get("cpu_resnet") or {}).get("cpu_resnet_images_per_sec")
 
@@ -4168,18 +3770,6 @@ def main() -> None:
                 out["int8_decode_speedup_long"] = round(
                     decode_int8["decode_tokens_per_sec_long"]
                     / decode["decode_tokens_per_sec_long"], 2)
-    out.update({k: (round(v, 1) if isinstance(v, float) else v)
-                for k, v in serving.items()})
-    serving_load = stage_out.get("serving_load")
-    if serving_load is not None:
-        out.update(serving_load)
-        if decode is not None and serving_load.get("serving_load_tokens_per_sec"):
-            # ISSUE 6 acceptance: endpoint decode within 10x of raw
-            # single-chip decode — this is the ratio under test (>1 means
-            # the endpoint is SLOWER than raw decode by that factor)
-            out["serving_load_vs_decode"] = round(
-                decode["decode_tokens_per_sec"]
-                / serving_load["serving_load_tokens_per_sec"], 2)
     memplan = stage_out.get("memplan")
     if memplan is not None:
         # VERDICT r4 next #6: memory_plan_validated + the measured ceiling

@@ -226,7 +226,7 @@ def xla_attention(q, k, v, causal: bool = True, mask: Optional[jnp.ndarray] = No
         tq, tk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), jnp.bool_), tk - tq)
     if mask is not None:
-        logits = jnp.where(mask[None, None] if mask.ndim == 2 else mask, logits, -1e30)
+        logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
@@ -304,7 +304,6 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, positions: jnp.ndarray,
-                 attn_start: Optional[jnp.ndarray] = None,
                  cache_idx: Optional[jnp.ndarray] = None,
                  block_tables: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         cfg = self.cfg
@@ -320,7 +319,7 @@ class Attention(nn.Module):
             if cfg.kv_page_size > 0:
                 return self._paged_decode_attention(q, k, v, B, T, cache_idx,
                                                     block_tables)
-            return self._decode_attention(q, k, v, B, T, attn_start, cache_idx)
+            return self._decode_attention(q, k, v, B, T)
         impl = cfg.attention_impl
         if impl == "auto":
             impl = _auto_attention_impl(jax.default_backend(), T)
@@ -339,47 +338,19 @@ class Attention(nn.Module):
         out = out.reshape(B, T, cfg.n_heads * hd)
         return LoRALinear(cfg.d_model, cfg, name="o_proj")(out)
 
-    def _decode_attention(self, q, k, v, B: int, T: int,
-                          attn_start: Optional[jnp.ndarray] = None,
-                          cache_idx: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-        """KV-cache attention for autoregressive decode (flax 'cache'
-        collection). Supports prefill (T = prompt length) and single-token
-        steps (T = 1): new k/v are written at the running cache index and
+    def _decode_attention(self, q, k, v, B: int, T: int) -> jnp.ndarray:
+        """KV-cache attention over contiguous rows (flax 'cache' collection):
+        the engine's prefill rows and ``generation.generate``. Supports
+        prefill (T = prompt length) and single-token steps (T = 1): new k/v
+        are written at the running cache index, shared by every row, and
         queries attend to everything written so far. Static shapes: the
-        cache is [B, max_seq_len, kv, hd] with an index mask.
-
-        ``attn_start`` [B] (optional): first VALID cache slot per row —
-        batched serving LEFT-pads shorter prompts so all rows share the
-        write index, and each row masks out its pad prefix.
-
-        ``cache_idx`` [B] (optional, T must be 1): PER-ROW write index —
-        the continuous-batching slot engine's mode. Rows at different
-        sequence lengths share ONE decode executable: row b's k/v land at
-        ``cache_idx[b]`` via scatter and its query attends to positions
-        ``<= cache_idx[b]``. The shared scalar index is ignored (each slot
-        tracks its own length host-side); stale garbage beyond a row's
-        index is invisible by the same argument as ``_rewind_cache``, and
-        a freed slot's leftovers are fully overwritten when the slot is
-        re-admitted (serving/continuous_batching.py writes the whole row)."""
+        cache is [B, max_seq_len, kv, hd] with an index mask."""
         cfg = self.cfg
         hd = cfg.head_dim
         S = cfg.max_seq_len
         ck = self.variable("cache", "k", jnp.zeros, (B, S, cfg.n_kv_heads, hd), q.dtype)
         cv = self.variable("cache", "v", jnp.zeros, (B, S, cfg.n_kv_heads, hd), q.dtype)
         cidx = self.variable("cache", "idx", lambda: jnp.zeros((), jnp.int32))
-        if cache_idx is not None:
-            if T != 1:
-                raise ValueError(f"cache_idx decode requires T=1 steps, got T={T}")
-            rows = jnp.arange(B)
-            if self.is_mutable_collection("cache"):
-                ck.value = ck.value.at[rows, cache_idx].set(k[:, 0].astype(ck.value.dtype))
-                cv.value = cv.value.at[rows, cache_idx].set(v[:, 0].astype(cv.value.dtype))
-            k_all, v_all = repeat_kv(ck.value, cv.value, cfg.n_heads)
-            # [B, 1, 1, S]: row b sees exactly its own written prefix
-            valid = (jnp.arange(S)[None, :] <= cache_idx[:, None])[:, None, None]
-            out = xla_attention(q, k_all, v_all, mask=valid)
-            out = out.reshape(B, T, cfg.n_heads * hd)
-            return LoRALinear(cfg.d_model, cfg, name="o_proj")(out)
         idx = cidx.value
         if self.is_mutable_collection("cache"):
             ck.value = jax.lax.dynamic_update_slice(ck.value, k.astype(ck.value.dtype), (0, idx, 0, 0))
@@ -388,12 +359,6 @@ class Attention(nn.Module):
         k_all, v_all = repeat_kv(ck.value, cv.value, cfg.n_heads)  # [B, S, h, hd]
         q_pos = idx + jnp.arange(T)  # absolute position of each query
         valid = jnp.arange(S)[None, :] <= q_pos[:, None]  # [T, S] causal+written
-        if attn_start is not None:
-            # [B, 1, T, S]: rows additionally exclude their pad prefix
-            valid = jnp.logical_and(
-                valid[None],
-                jnp.arange(S)[None, None, :] >= attn_start[:, None, None],
-            )[:, None]
         out = xla_attention(q, k_all, v_all, mask=valid)
         out = out.reshape(B, T, cfg.n_heads * hd)
         return LoRALinear(cfg.d_model, cfg, name="o_proj")(out)
@@ -408,8 +373,7 @@ class Attention(nn.Module):
         page ``block_tables[b, l // page]``, slot ``l % page``. Both the
         block tables [B, max_blocks] and the per-row write index
         ``cache_idx`` [B] are RUNTIME data, so one executable per (cfg, B)
-        serves every admission mix, exactly like the ``cache_idx`` slot
-        mode.
+        serves every admission mix.
 
         Write: the new k/v token scatters to (bt[b, idx//page], idx%page).
         The allocator guarantees the page being written has refcount 1 (a
@@ -436,7 +400,7 @@ class Attention(nn.Module):
             raise ValueError("kv_num_pages must be >= 2 (page 0 is the trash page)")
         ck = self.variable("cache", "k", jnp.zeros, (n_pages, ps, cfg.n_kv_heads, hd), q.dtype)
         cv = self.variable("cache", "v", jnp.zeros, (n_pages, ps, cfg.n_kv_heads, hd), q.dtype)
-        # the contiguous modes' shared scalar write index, kept so the two
+        # the contiguous rows' shared scalar write index, kept so the two
         # cache pytrees stay congruent for gather/scatter; unused here
         self.variable("cache", "idx", lambda: jnp.zeros((), jnp.int32))
         # a freed slot's token goes to the trash page whatever its table holds
@@ -472,7 +436,6 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, positions: jnp.ndarray,
-                 attn_start: Optional[jnp.ndarray] = None,
                  cache_idx: Optional[jnp.ndarray] = None,
                  block_tables: Optional[jnp.ndarray] = None,
                  seq_lens: Optional[jnp.ndarray] = None,
@@ -481,13 +444,10 @@ class Block(nn.Module):
         if self.kind == "mamba":
             from .mamba import MambaMixer
 
-            if attn_start is not None:
-                raise ValueError("a left-padded batch (attn_start) cannot carry a recurrent "
-                                 "state: pad tokens would advance it; serve through an engine")
             x = x + MambaMixer(cfg, name="mamba")(
                 RMSNorm(cfg.norm_eps, name="mamba_norm")(x), seq_lens, snap_lens, cache_idx)
         else:
-            x = x + Attention(cfg, name="attn")(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, attn_start, cache_idx, block_tables)
+            x = x + Attention(cfg, name="attn")(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, cache_idx, block_tables)
         h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
         if cfg.moe_experts > 0:
             from .moe import MoEConfig, MoEMLP
@@ -516,7 +476,6 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens: jnp.ndarray, train: bool = False,
                  positions: Optional[jnp.ndarray] = None,
-                 attn_start: Optional[jnp.ndarray] = None,
                  cache_idx: Optional[jnp.ndarray] = None,
                  block_tables: Optional[jnp.ndarray] = None,
                  seq_lens: Optional[jnp.ndarray] = None,
@@ -547,7 +506,7 @@ class TransformerLM(nn.Module):
             block = nn.remat(Block, static_argnums=(), policy=policy)
         for i in range(cfg.n_layers):
             x = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(
-                x, positions, attn_start, cache_idx, block_tables, seq_lens, snap_lens)
+                x, positions, cache_idx, block_tables, seq_lens, snap_lens)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         if cfg.tie_embeddings:
             logits = embed.attend(x)

@@ -1,10 +1,7 @@
 """Model serving (reference: python/fedml/serving/ + model_scheduler/)."""
 
 from .admission import AdmissionController, AdmissionError, TenantPolicy
-from .continuous_batching import (
-    ContinuousBatchingEngine,
-    PagedContinuousBatchingEngine,
-)
+from .continuous_batching import PagedContinuousBatchingEngine
 from .endpoint import Endpoint, EndpointManager, ModelCard, ModelDB
 from .fedml_inference_runner import FedMLInferenceRunner
 from .fedml_predictor import FedMLPredictor, JaxPredictor
@@ -14,7 +11,6 @@ __all__ = [
     "AdmissionController",
     "AdmissionError",
     "TenantPolicy",
-    "ContinuousBatchingEngine",
     "PagedContinuousBatchingEngine",
     "Endpoint",
     "EndpointManager",

@@ -1,39 +1,50 @@
-"""Continuous batching: a slotted decode engine over the existing KV cache.
+"""Continuous batching: one decode engine over a paged KV cache.
 
-The r05 endpoint served 14.5 tok/s against a 370k tok/s chip because the
-micro-batcher barriers decode on request boundaries: every 10ms window
-tears down the whole decode batch, re-prefills, and re-pays dispatch for
-at most 4 co-arriving requests. This engine inverts that (the PiPar
-principle applied to serving — overlap admission with compute instead of
-barriering on it):
+``PagedContinuousBatchingEngine`` is the serving path (``LLMPredictor(paged=True)``
+builds it); ``train/llm/generation.generate`` is the plain reference the tests
+hold it to, token for token. What the engine does:
 
-- a fixed pool of ``num_slots`` KV-cache rows is the decode batch, and ONE
-  jitted chunked step (``_cb_step_fn``: ``chunk`` tokens per dispatch)
-  runs for as long as any slot is live — requests join and leave at token
+- a fixed pool of ``num_slots`` rows is the decode batch, and ONE jitted
+  chunked step (``paged_kv._paged_step_fn``: ``chunk`` tokens per dispatch)
+  runs for as long as any slot is live: requests join and leave at token
   boundaries without recompiling or restarting anyone else's decode;
+- K/V live in a page pool (serving/paged_kv.py): each request reserves
+  ``ceil((prompt + budget) / page_size)`` pages at admit, so HBM scales with
+  admitted tokens and decode never runs out of pages mid-flight, and requests
+  sharing a hash-consed prompt prefix map the same physical pages;
 - prefill is disaggregated: each request prefills alone at B=1 through the
-  existing 16-token-bucketed executables (`generation._prefill_fn`), then
-  a tiny jitted admit writes its cache row into a free slot — a long
-  prompt never stalls in-flight generation;
+  16-token-bucketed executables (``generation._prefill_fn``; on a prefix hit,
+  a gather of the shared pages plus one suffix pass), then a jitted admit
+  scatters its row into its private pages. Admission runs as a prefill ->
+  transfer -> admit :class:`PipelinedExecutor` wave, so request i+1's prefill
+  overlaps request i's pool scatter and a long prompt never stalls in-flight
+  generation;
 - per-row state stays RUNTIME data: slot lengths ride the transformer's
-  ``cache_idx`` decode mode (per-row scatter writes + per-row validity
-  masks), temperatures and PRNG keys are per-row arrays, and EOS is
-  checked host-side between chunks — so one executable per (cfg, B, C)
-  serves every mix of prompt lengths, sampling settings, and stop tokens.
+  ``cache_idx``, block tables, temperatures and PRNG keys are per-row arrays,
+  and EOS is checked host-side between chunks, so one executable per
+  (cfg, B, C) serves every mix of prompt lengths, sampling settings and stop
+  tokens;
+- a model with recurrent layers (``cfg.has_recurrent_state``) keeps their
+  per-slot state in the same cache pytree as the page pool, and its prefix
+  hits start from state snapshots the trie holds, at most ``state_snapshots``
+  of them (see serving/paged_kv.py's header);
+- an optional :class:`AdmissionController` gates the front door: submit-time
+  token budgets + shed, dequeue-time weighted fair queueing + SLO-pressure
+  deferral (serving/admission.py).
 
 Chunking amortizes dispatch and the per-chunk host sync: one device call
 yields ``chunk`` tokens for every live slot. A slot that stops mid-chunk
-(EOS or budget) generates garbage until the chunk ends; the host discards
-it and the freed slot's cache leftovers are fully overwritten on the next
-admission (see ``Attention._decode_attention``'s cache_idx notes).
+(EOS or budget) generates garbage until the chunk ends; the host discards it
+(``serving.wasted_tokens``), frees the request's pages at that chunk boundary
+and points the slot's table at the trash page.
 
 Telemetry: TTFT/TPOT histograms, token/request counters, and a ``stats()``
-snapshot (slot occupancy, queue depth) that the inference runner exports
-as Prometheus gauges and ``/statusz`` fields. Spans (docs/observability.md,
-"Serving request lifecycle"): every request leaves ``serving.request.queue``
-/ ``.admit`` / ``.decode`` records carrying its ``request_id``; the worker
-loop leaves ``serving.engine.iteration`` with its children and
-``serving.engine.idle``.
+snapshot (slot occupancy, queue depth, page occupancy) that the inference
+runner exports as Prometheus gauges and ``/statusz`` fields. Spans
+(docs/observability.md, "Serving request lifecycle"): every request leaves
+``serving.request.queue`` / ``.admit`` / ``.decode`` records carrying its
+``request_id``; the worker loop leaves ``serving.engine.iteration`` with its
+children and ``serving.engine.idle``.
 """
 
 from __future__ import annotations
@@ -51,16 +62,11 @@ import numpy as np
 
 from ..core import telemetry as tel
 from ..core.pipeline.executor import PipelinedExecutor, PipelineError, StageSpec
-from ..core.telemetry import devperf, trace_context, track_compiles, tsdb
-from ..models.mamba import state_bytes as mamba_state_bytes, unpack_state
+from ..core.telemetry import devperf, trace_context, tsdb
+from ..models.mamba import state_bytes as mamba_state_bytes
 from ..models.transformer import TransformerConfig
-from ..train.llm.generation import (
-    _leaf_at,
-    _lru_get,
-    _prefill_fn,
-    _sample,
-    decode_model,
-)
+from ..train.llm.generation import _prefill_fn
+from ..train.llm.generation import _sample  # noqa: F401 - tests/benchmark_suite plants a fault by patching it here too
 from .admission import DEFAULT_TENANT, AdmissionController, AdmissionError
 from .admission import REASON_QUEUE_FULL, count_reject
 from .paged_kv import (
@@ -77,80 +83,6 @@ from .paged_kv import (
 )
 
 log = logging.getLogger(__name__)
-
-
-def _cb_admit_fn(cfg: TransformerConfig, B: int):
-    """Write one prefilled B=1 cache row into slot ``slot`` (runtime scalar:
-    one executable serves every slot) and sample the request's first token
-    from its prefill logits. Scalar cache leaves (the shared write index —
-    meaningless in cache_idx mode) keep the pool's value."""
-
-    def build():
-        def run(cache, row_cache, slot, first_logits, key, temp):
-            row_cache = unpack_state(cfg, row_cache)
-
-            def insert(path, dst):
-                if dst.ndim == 0:
-                    return dst
-                src = _leaf_at(row_cache, path)  # by path: a row may hold more (its snapshot)
-                start = (slot,) + (0,) * (dst.ndim - 1)
-                return jax.lax.dynamic_update_slice(dst, src.astype(dst.dtype), start)
-
-            new_cache = jax.tree_util.tree_map_with_path(insert, cache)
-            key2, sub = jax.random.split(key)
-            tok0 = _sample(first_logits, sub, temp)
-            return new_cache, tok0, key2
-
-        return jax.jit(track_compiles(run, name="cb_admit"))
-
-    return _lru_get(("cb_admit", cfg, B), build)
-
-
-def _cb_step_fn(cfg: TransformerConfig, B: int, C: int):
-    """The engine's one hot executable: C single-token steps over all B
-    slots. Everything per-request is runtime data (lengths, temps, keys,
-    active mask), so this compiles ONCE per (cfg, B, C) and every admission
-    mix reuses it — the compile-count guard in bench.py watches
-    ``jax.compiles.cb_step`` for regressions."""
-
-    def build():
-        model = decode_model(cfg)
-        S = cfg.max_seq_len
-
-        def run(params, cache, tok, lengths, keys, temps, active):
-            def step(carry, _):
-                cache, tok, lengths, keys = carry
-                split = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
-                keys2, subs = split[:, 0], split[:, 1]
-                # clamp: a slot past its budget (mid-chunk EOS / inactive)
-                # rewrites the last cache slot with garbage the host never
-                # reads, instead of scattering out of bounds
-                idx = jnp.minimum(lengths, S - 1)
-                logits, state = model.apply(
-                    {"params": params, "cache": cache},
-                    tok[:, None],
-                    positions=idx[:, None],
-                    cache_idx=idx,
-                    mutable=["cache"],
-                )
-                nxt = jax.vmap(_sample)(logits[:, -1], subs, temps)
-                nxt = jnp.where(active, nxt, 0)
-                lengths = lengths + active.astype(jnp.int32)
-                return (state["cache"], nxt, lengths, keys2), nxt
-
-            (cache, tok, lengths, keys), toks = jax.lax.scan(
-                step, (cache, tok, lengths, keys), None, length=C
-            )
-            return cache, tok, lengths, keys, toks.swapaxes(0, 1)  # [B, C]
-
-        # donate the cache pool (arg 1): halves peak HBM for the biggest
-        # buffer in serving; CPU has no donation, so gate to avoid warnings
-        donate = (1,) if jax.default_backend() == "tpu" else ()
-        fn = jax.jit(track_compiles(run, name="cb_step"), donate_argnums=donate)
-        return devperf.instrument(fn, "cb_step")
-
-    return _lru_get(("cb_step", cfg, B, C), build)
-
 
 class RequestHandle:
     """Future for one submitted request. ``result()`` blocks for the full
@@ -206,22 +138,41 @@ class _Pending:
 @dataclasses.dataclass
 class _Active:
     pending: _Pending
-    budget: int  # max_new clamped to cache capacity at admit
+    budget: int  # max_new clamped to max_seq_len - prompt at admit
     tokens: List[int] = dataclasses.field(default_factory=list)
     t_first_ns: int = 0
     generated: int = 0  # device tokens produced, kept OR discarded
 
 
-class ContinuousBatchingEngine:
-    """Slotted continuous-batching decode engine (see module docstring).
+@dataclasses.dataclass
+class _AdmitWork:
+    """One request moving through the prefill -> transfer -> admit pipeline
+    (created by ``_collect_wave`` holding its slot + page reservations)."""
 
-    ``submit()`` is thread-safe and non-blocking (FIFO admission when a
-    slot frees); ``generate()`` is the blocking convenience. One engine
-    owns one cache pool and one worker thread; model params are shared,
-    read-only."""
+    item: _Pending
+    slot: int
+    budget: int
+    n_shared: int             # leading blocks served from the prefix cache
+    shared_pages: List[int]   # one reference held per page
+    private_pages: List[int]  # one reference held per page
+    state: object = None      # recurrent layers start from this snapshot (None: from zero)
+    snap_blocks: int = 0      # block boundary whose trie node wants this prefill's state
+    row_cache: object = None
+    first_vec: object = None  # [vocab] logits for the first sampled token
+    tok0: int = 0
+    key2: object = None
+    admitted: bool = False
 
-    #: devperf registry label for the decode executable this engine drives
-    _devperf_label = "cb_step"
+
+class PagedContinuousBatchingEngine:
+    """Continuous-batching decode engine over a paged KV cache (see the
+    module docstring and serving/paged_kv.py).
+
+    ``submit()`` is thread-safe and non-blocking (a request is admitted when
+    a slot and its pages are free: FIFO, or weighted-fair under an
+    :class:`AdmissionController`); ``generate()`` is the blocking
+    convenience. One engine owns one page pool and one worker thread; model
+    params are shared, read-only."""
 
     def __init__(
         self,
@@ -230,19 +181,40 @@ class ContinuousBatchingEngine:
         *,
         num_slots: int = 8,
         chunk: int = 8,
+        page_size: int = 16,
+        num_pages: Optional[int] = None,
+        watermark_frac: float = 0.05,
         max_queue: int = 4096,
+        admission: Optional[AdmissionController] = None,
+        state_snapshots: int = 8,
     ):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if chunk < 1:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
+        base = row_config(cfg)
+        if num_pages is None:
+            # default: room for every slot at max_seq_len (+trash);
+            # deployments shrink this to realize the HBM win
+            num_pages = num_slots * (base.max_seq_len // page_size) + 1
+        self._paged_cfg = paged_config(
+            base, page_size=page_size, num_pages=num_pages)
+        self._ps = int(page_size)
+        self._n_blocks = base.max_seq_len // self._ps
+        self._stateful = base.has_recurrent_state
+        self._state_bytes = mamba_state_bytes(base) if self._stateful else 0
+        self._alloc = PagedKVAllocator(
+            num_pages, page_size, watermark_frac=watermark_frac,
+            state_budget_bytes=int(state_snapshots) * self._state_bytes)
+        self._admission = admission
+        self._tenant_ttft: dict = {}
         self._params = params
-        self._cfg = cfg
+        self._cfg = base
         self._B = int(num_slots)
         self._C = int(chunk)
         self._max_queue = int(max_queue)
 
-        self._cache = self._build_cache()
+        self._cache = paged_pool_init(self._params, self._paged_cfg, self._B)
 
         # per-slot host mirrors (numpy: rebuilt into device arrays per chunk)
         self._slots: List[Optional[_Active]] = [None] * self._B
@@ -252,6 +224,7 @@ class ContinuousBatchingEngine:
         self._keys = np.tile(
             np.asarray(jax.random.PRNGKey(0), np.uint32), (self._B, 1)
         )
+        self._tables = np.full((self._B, self._n_blocks), TRASH_PAGE, np.int32)
 
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -264,21 +237,6 @@ class ContinuousBatchingEngine:
         )
         self._worker.start()
 
-    def _build_cache(self):
-        """Slot pool cache: one eager single-token apply yields the exact
-        pytree the decode step carries ([B, S, kv, hd] per layer + the
-        scalar index the cache_idx mode ignores). The paged engine
-        overrides this with the page-pool pytree."""
-        model = decode_model(self._cfg)
-        _, state = model.apply(
-            {"params": self._params},
-            jnp.zeros((self._B, 1), jnp.int32),
-            positions=jnp.zeros((self._B, 1), jnp.int32),
-            cache_idx=jnp.zeros((self._B,), jnp.int32),
-            mutable=["cache"],
-        )
-        return state["cache"]
-
     # -- public API --------------------------------------------------------
 
     def submit(
@@ -289,11 +247,17 @@ class ContinuousBatchingEngine:
         temperature: float = 0.0,
         seed: int = 0,
         eos_id=None,
-        tenant: str = "default",
+        tenant: str = DEFAULT_TENANT,
         request_id: Optional[str] = None,
     ) -> RequestHandle:
         handle = RequestHandle(request_id)
         prompt = [int(t) for t in prompt]
+        if self._admission is not None:
+            reason = self._admission.check(
+                tenant, len(prompt) + int(max_new_tokens))
+            if reason is not None:
+                handle._fail(AdmissionError(tenant, reason))
+                return handle
         eos_ids: Optional[Tuple[int, ...]] = None
         if eos_id is not None:
             eos_ids = (
@@ -327,21 +291,17 @@ class ContinuousBatchingEngine:
                 handle._fail(RuntimeError("engine is shutting down"))
                 return handle
             if len(self._queue) >= self._max_queue:
-                self._reject_queue_full(item)
+                count_reject(item.tenant, REASON_QUEUE_FULL)
+                handle._fail(AdmissionError(item.tenant, REASON_QUEUE_FULL))
                 return handle
-            self._on_enqueue(item)
+            if self._admission is not None:
+                item.wfq_tag = self._admission.stamp(
+                    item.tenant, len(item.prompt) + item.max_new)
             item.queue_depth = len(self._queue)
             self._queue.append(item)
             tel.counter("serving.cb.requests").add(1)
             self._work.notify()
         return handle
-
-    def _reject_queue_full(self, item: _Pending) -> None:
-        item.handle._fail(RuntimeError("admission queue full"))
-
-    def _on_enqueue(self, item: _Pending) -> None:
-        """Hook (called under the lock): the paged engine stamps the WFQ
-        virtual finish tag here."""
 
     def generate(
         self,
@@ -352,7 +312,7 @@ class ContinuousBatchingEngine:
         seed: int = 0,
         eos_id=None,
         timeout: Optional[float] = 600.0,
-        tenant: str = "default",
+        tenant: str = DEFAULT_TENANT,
     ) -> List[int]:
         return self.submit(
             prompt, max_new_tokens, temperature=temperature, seed=seed,
@@ -363,7 +323,10 @@ class ContinuousBatchingEngine:
         """Gauge snapshot for /metrics and /statusz (cheap; lock-guarded)."""
         with self._lock:
             active = sum(1 for s in self._slots if s is not None)
-            return {
+            live = int(sum(int(self._lengths[i])
+                           for i, s in enumerate(self._slots)
+                           if s is not None))
+            out = {
                 "slots_total": self._B,
                 "slots_active": active,
                 "slot_occupancy": active / self._B,
@@ -372,6 +335,45 @@ class ContinuousBatchingEngine:
                 "requests_done": self._requests_done,
                 "tokens_out": self._tokens_out,
             }
+        a = self._alloc.stats()
+        pages_used = a["kv_pages_total"] - a["kv_pages_free"]
+        out.update(a)
+        out.update({
+            "kv_page_size": self._ps,
+            "kv_pages_in_use": pages_used,
+            "kv_tokens_live": live,
+            # pages per live token (multiply by page bytes for bytes/token)
+            "kv_pages_per_token": pages_used / live if live else 0.0,
+        })
+        if self._admission is not None:
+            out["admission"] = self._admission.stats()
+        return out
+
+    def prom_gauges(self) -> list:
+        """(name, labels, value) ride-along triples for /metrics."""
+        out = []
+        st = self._alloc.stats()
+        out.append(("serving_kv_pages", {"state": "free"},
+                    float(st["kv_pages_free"])))
+        out.append(("serving_kv_pages", {"state": "used"},
+                    float(st["kv_pages_total"] - st["kv_pages_free"])))
+        out.append(("serving_kv_pages", {"state": "watermark"},
+                    float(st["kv_watermark_pages"])))
+        out.append(("serving_kv_prefix_nodes", None,
+                    float(st["kv_prefix_nodes"])))
+        if self._stateful:
+            out.append(("serving_state_snapshot_bytes", None,
+                        float(st["state_snapshot_bytes"])))
+        with self._lock:
+            tenants = [(t, sorted(dq)) for t, dq in self._tenant_ttft.items()
+                       if dq]
+        for t, xs in tenants:
+            p99 = xs[min(len(xs) - 1, int(0.99 * len(xs)))]
+            out.append(("serving_tenant_ttft_p99_seconds", {"tenant": t},  # fedlint: disable=label-cardinality tenant set is bounded by the configured admission table, not the client population
+                        float(p99)))
+        if self._admission is not None:
+            out.extend(self._admission.prom_gauges())
+        return out
 
     def shutdown(self, timeout: float = 10.0) -> None:
         """Stop the worker; queued and in-flight requests fail fast (the
@@ -402,7 +404,7 @@ class ContinuousBatchingEngine:
                     for i, s in enumerate(self._slots):
                         if s is not None:
                             s.pending.handle._fail(err)
-                            self._release_slot(i, s)
+                            self._release_slot(i)
                             self._slots[i] = None
                     return
                 n_active = sum(1 for s in self._slots if s is not None)
@@ -421,330 +423,8 @@ class ContinuousBatchingEngine:
                     for i, s in enumerate(self._slots):
                         if s is not None:
                             s.pending.handle._fail(e)
-                            self._release_slot(i, s)
+                            self._release_slot(i)
                             self._slots[i] = None
-
-    def _admit_all(self) -> None:
-        cfg = self._cfg
-        while True:
-            with self._lock:
-                try:
-                    free = self._slots.index(None)
-                except ValueError:
-                    return
-                if not self._queue:
-                    return
-                item = self._queue.popleft()
-            item.t_pop_ns = time.perf_counter_ns()
-            P = len(item.prompt)
-            # clamp to capacity: decode writes land at P..P+budget-2 (the
-            # first token is sampled from prefill logits, never written
-            # ahead), so budget = S - P keeps every KEPT token's write
-            # in-bounds; the step fn's idx clamp absorbs mid-chunk overrun
-            budget = min(item.max_new, cfg.max_seq_len - P)
-            try:
-                with tel.span("serving.cb.prefill", request_id=item.request_id,
-                              prompt_len=P):
-                    P_b = min(-(-P // 16) * 16, cfg.max_seq_len)
-                    ids = jnp.asarray([item.prompt], jnp.int32)
-                    padded = (
-                        jnp.pad(ids, ((0, 0), (0, P_b - P))) if P_b != P else ids
-                    )
-                    row_cache, first_logits = _prefill_fn(cfg, 1, P_b)(
-                        self._params, padded, jnp.int32(P)
-                    )
-                    cache, tok0, key2 = _cb_admit_fn(cfg, self._B)(
-                        self._cache,
-                        row_cache,
-                        jnp.int32(free),
-                        first_logits[0],
-                        jax.random.PRNGKey(item.seed),
-                        jnp.float32(item.temperature),
-                    )
-                    tok0 = int(np.asarray(tok0))  # fedlint: disable=host-sync forces admit completion: one sync per admission, not per decode step
-            except Exception as e:  # noqa: BLE001 - a bad prompt (or a
-                # prefill compile failure) fails ITS caller, not the pool;
-                # the popped item would otherwise hang its future forever
-                log.exception("continuous-batching admit failed")
-                item.handle._fail(e)
-                continue
-            now_ns = time.perf_counter_ns()
-            self._cache = cache
-            active = _Active(item, budget, [tok0], now_ns, generated=1)
-            self._tok[free] = tok0
-            self._lengths[free] = P
-            self._temps[free] = item.temperature
-            self._keys[free] = np.asarray(key2, np.uint32)  # fedlint: disable=host-sync PRNG row refresh once per admission; key already host-resident post-admit
-            self._note_first_token(item, now_ns)
-            with self._lock:
-                self._slots[free] = active
-            self._finish_if_done(free, now_ns)
-
-    def _note_first_token(self, item: _Pending, now_ns: int, shared: int = 0) -> float:
-        """The request's first token is on the host: its queue and admit
-        spans (``queue + admit == ttft_s`` by construction: three readings of
-        one clock), the handle's timings, the TTFT series. Returns TTFT."""
-        handle = item.handle
-        handle.queue_wait_s = (item.t_pop_ns - item.t_submit_ns) / 1e9
-        handle.ttft_s = ttft = (now_ns - item.t_submit_ns) / 1e9
-        tel.record_span("serving.request.queue", item.t_submit_ns, item.t_pop_ns,
-                        request_id=item.request_id, queue_depth=item.queue_depth)
-        tel.record_span("serving.request.admit", item.t_pop_ns, now_ns,
-                        request_id=item.request_id, prompt_len=len(item.prompt),
-                        shared=shared)
-        tel.histogram("serving.cb.ttft_seconds").observe(ttft)
-        tel.counter("serving.cb.admissions").add(1)
-        return ttft
-
-    def _step_fn(self):
-        return _cb_step_fn(self._cfg, self._B, self._C)
-
-    def _step_extra_args(self) -> tuple:
-        """Extra device args between the cache and the token mirrors (the
-        paged engine slips its block tables in here)."""
-        return ()
-
-    def _chunk_attrs(self, active_mask: np.ndarray) -> dict:
-        """Hook: further attributes of the ``serving.cb.chunk`` span."""
-        return {}
-
-    def _step_chunk(self) -> None:
-        with self._lock:
-            active_mask = np.asarray(
-                [s is not None for s in self._slots], bool
-            )
-        n_live = int(active_mask.sum())
-        with tel.span("serving.cb.chunk", slots=n_live,
-                      **self._chunk_attrs(active_mask)):
-            with tel.timed("serving.cb.chunk.dispatch") as dispatch:
-                cache, tok, lengths, keys, toks = self._step_fn()(
-                    self._params,
-                    self._cache,
-                    *self._step_extra_args(),
-                    jnp.asarray(self._tok),
-                    jnp.asarray(self._lengths),
-                    jnp.asarray(self._keys),
-                    jnp.asarray(self._temps),
-                    jnp.asarray(active_mask),
-                )
-            with tel.timed("serving.cb.chunk.sync") as sync:
-                toks = np.asarray(toks)  # [B, C]; forces chunk completion
-            with tel.span("serving.cb.chunk.post"):
-                devperf.observe_step(self._devperf_label,
-                                     dispatch.duration_s + sync.duration_s,
-                                     tokens=n_live * self._C)
-                self._cache = cache
-                # np.array (not asarray): device arrays view as READ-ONLY
-                # numpy; these mirrors are mutated per-slot at admit time
-                self._tok = np.array(tok, np.int32)
-                self._lengths = np.array(lengths, np.int32)
-                self._keys = np.array(keys, np.uint32)
-                now_ns = time.perf_counter_ns()
-                tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
-                for b in range(self._B):
-                    with self._lock:
-                        s = self._slots[b]
-                    if s is None:
-                        continue
-                    s.generated += self._C
-                    for t in toks[b]:
-                        t = int(t)
-                        s.tokens.append(t)
-                        if s.pending.eos_ids is not None and t in s.pending.eos_ids:
-                            break
-                        if len(s.tokens) >= s.budget:
-                            break
-                    self._finish_if_done(b, now_ns)
-
-    def _finish_if_done(self, b: int, now_ns: int) -> bool:
-        """Free slot ``b`` if its request hit EOS or its token budget; the
-        slot's cache leftovers are overwritten wholesale on re-admission."""
-        with self._lock:
-            s = self._slots[b]
-        if s is None:
-            return False
-        eos = s.pending.eos_ids
-        hit_eos = eos is not None and any(t in eos for t in s.tokens)
-        if not hit_eos and len(s.tokens) < s.budget:
-            return False
-        if hit_eos:
-            cut = next(i for i, t in enumerate(s.tokens) if t in eos)
-            s.tokens = s.tokens[: cut + 1]
-        else:
-            s.tokens = s.tokens[: s.budget]
-        if len(s.tokens) > 1:
-            tpot = (now_ns - s.t_first_ns) / 1e9 / (len(s.tokens) - 1)
-            s.pending.handle.tpot_s = tpot
-            tel.histogram("serving.cb.tpot_seconds").observe(tpot)
-        # EOS/budget mid-chunk waste, measured instead of silent: the slot
-        # kept burning decode FLOPs until the chunk boundary; the paged
-        # engine also reclaims the request's KV pages here (_release_slot)
-        wasted = s.generated - len(s.tokens)
-        if wasted > 0:
-            tel.counter("serving.wasted_tokens").add(wasted)
-        tel.record_span("serving.request.decode", s.t_first_ns, now_ns,
-                        request_id=s.pending.request_id, tokens=len(s.tokens),
-                        wasted=wasted)
-        self._release_slot(b, s)
-        with self._lock:
-            self._slots[b] = None
-            self._requests_done += 1
-            self._tokens_out += len(s.tokens)
-        s.pending.handle._finish(s.tokens)
-        return True
-
-    def _release_slot(self, b: int, s: _Active) -> None:
-        """Hook: free per-slot resources at the chunk boundary where the
-        host learns the request is done. The contiguous engine has nothing
-        to free (the row is overwritten wholesale on re-admission)."""
-
-
-# ---------------------------------------------------------------------------
-# paged engine: block-table KV over a shared page pool (serving/paged_kv.py)
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass
-class _AdmitWork:
-    """One request moving through the prefill -> transfer -> admit pipeline
-    (created by ``_collect_wave`` holding its slot + page reservations)."""
-
-    item: _Pending
-    slot: int
-    budget: int
-    n_shared: int             # leading blocks served from the prefix cache
-    shared_pages: List[int]   # one reference held per page
-    private_pages: List[int]  # one reference held per page
-    state: object = None      # recurrent layers start from this snapshot (None: from zero)
-    snap_blocks: int = 0      # block boundary whose trie node wants this prefill's state
-    row_cache: object = None
-    first_vec: object = None  # [vocab] logits for the first sampled token
-    tok0: int = 0
-    key2: object = None
-    admitted: bool = False
-
-
-class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
-    """Continuous batching over a PAGED KV cache (see serving/paged_kv.py).
-
-    Same public surface as :class:`ContinuousBatchingEngine` plus:
-
-    - HBM scales with admitted tokens, not ``num_slots * max_seq_len``:
-      each request reserves ``ceil((prompt + budget) / page_size)`` pages
-      at admit (reservation up front means decode never OOMs mid-flight),
-      and requests sharing a hash-consed prompt prefix map the same
-      physical pages;
-    - admission runs as a prefill -> transfer -> admit
-      :class:`PipelinedExecutor` wave, so request i+1's prefill overlaps
-      request i's pool scatter — the PiPar overlap principle applied to
-      the serving front door (a long prompt never serializes admissions,
-      and in the disaggregated topology the transfer stage is the
-      prefill-pool -> decode-pool page handoff);
-    - a finished request's pages are reclaimed at the chunk boundary
-      where the host learns about EOS (``serving.wasted_tokens`` counts
-      the discarded mid-chunk tail);
-    - an optional :class:`AdmissionController` gates the front door:
-      submit-time token budgets + shed, dequeue-time weighted fair
-      queueing + SLO-pressure deferral (serving/admission.py);
-    - a model with recurrent layers (``cfg.has_recurrent_state``) keeps their
-      per-slot state in the same cache pytree as the page pool, and its
-      prefix hits start from state snapshots the trie holds, at most
-      ``state_snapshots`` of them (see serving/paged_kv.py's header).
-    """
-
-    def __init__(
-        self,
-        params,
-        cfg: TransformerConfig,
-        *,
-        num_slots: int = 8,
-        chunk: int = 8,
-        page_size: int = 16,
-        num_pages: Optional[int] = None,
-        watermark_frac: float = 0.05,
-        max_queue: int = 4096,
-        admission: Optional[AdmissionController] = None,
-        state_snapshots: int = 8,
-    ):
-        base = row_config(cfg)
-        if num_pages is None:
-            # drop-in default: same KV capacity as the slot engine (+trash);
-            # deployments shrink this to realize the HBM win (bench does)
-            num_pages = num_slots * (base.max_seq_len // page_size) + 1
-        self._paged_cfg = paged_config(
-            base, page_size=page_size, num_pages=num_pages)
-        self._ps = int(page_size)
-        self._n_blocks = base.max_seq_len // self._ps
-        self._stateful = base.has_recurrent_state
-        self._state_bytes = mamba_state_bytes(base) if self._stateful else 0
-        self._alloc = PagedKVAllocator(
-            num_pages, page_size, watermark_frac=watermark_frac,
-            state_budget_bytes=int(state_snapshots) * self._state_bytes)
-        self._admission = admission
-        self._tables = np.full((num_slots, self._n_blocks), TRASH_PAGE,
-                               np.int32)
-        self._tenant_ttft: dict = {}
-        super().__init__(params, base, num_slots=num_slots, chunk=chunk,
-                         max_queue=max_queue)
-
-    # -- cache + step wiring ------------------------------------------------
-
-    _devperf_label = "paged_step"
-
-    def _build_cache(self):
-        return paged_pool_init(self._params, self._paged_cfg, self._B)
-
-    def _step_fn(self):
-        return _paged_step_fn(self._paged_cfg, self._B, self._C)
-
-    def _step_extra_args(self) -> tuple:
-        return (jnp.asarray(self._tables),)
-
-    def _chunk_attrs(self, active_mask: np.ndarray) -> dict:
-        # pages the chunk's first token-step reads: each active row's written
-        # prefix plus the token it writes; beside B x n_blocks, the share of
-        # a whole-table read that paged attention still makes
-        lens = self._lengths[active_mask].astype(np.int64) + 1
-        attrs = {"pages": int((-(-lens // self._ps)).sum())}
-        if self._stateful:  # live slots whose recurrent state the step updates
-            attrs["state_slots"] = int(active_mask.sum())
-        return attrs
-
-    # -- admission-gated submit ---------------------------------------------
-
-    def submit(
-        self,
-        prompt: Sequence[int],
-        max_new_tokens: int,
-        *,
-        temperature: float = 0.0,
-        seed: int = 0,
-        eos_id=None,
-        tenant: str = DEFAULT_TENANT,
-        request_id: Optional[str] = None,
-    ) -> RequestHandle:
-        if self._admission is not None:
-            prompt = [int(t) for t in prompt]
-            reason = self._admission.check(
-                tenant, len(prompt) + int(max_new_tokens))
-            if reason is not None:
-                handle = RequestHandle(request_id)
-                handle._fail(AdmissionError(tenant, reason))
-                return handle
-        return super().submit(
-            prompt, max_new_tokens, temperature=temperature, seed=seed,
-            eos_id=eos_id, tenant=tenant, request_id=request_id)
-
-    def _on_enqueue(self, item: _Pending) -> None:
-        if self._admission is not None:
-            item.wfq_tag = self._admission.stamp(
-                item.tenant, len(item.prompt) + item.max_new)
-
-    def _reject_queue_full(self, item: _Pending) -> None:
-        count_reject(item.tenant, REASON_QUEUE_FULL)
-        item.handle._fail(AdmissionError(item.tenant, REASON_QUEUE_FULL))
-
-    # -- pipelined admission ------------------------------------------------
 
     def _admit_all(self) -> None:
         while True:
@@ -799,6 +479,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 return wave
             item.t_pop_ns = time.perf_counter_ns()  # a deferred item is popped again
             P = len(item.prompt)
+            # clamp to capacity: decode writes land at P..P+budget-2 (the
+            # first token is sampled from prefill logits, never written
+            # ahead), so budget = S - P keeps every KEPT token's write inside
+            # the pages reserved below
             budget = min(item.max_new, cfg.max_seq_len - P)
             n_req = -(-(P + budget) // self._ps)
             # never map the block holding the prompt's LAST token from the
@@ -951,9 +635,114 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self._finish_if_done(b, now_ns)
         return w
 
-    # -- page reclamation ---------------------------------------------------
+    def _note_first_token(self, item: _Pending, now_ns: int, shared: int) -> float:
+        """The request's first token is on the host: its queue and admit
+        spans (``queue + admit == ttft_s`` by construction: three readings of
+        one clock), the handle's timings, the TTFT series. Returns TTFT."""
+        handle = item.handle
+        handle.queue_wait_s = (item.t_pop_ns - item.t_submit_ns) / 1e9
+        handle.ttft_s = ttft = (now_ns - item.t_submit_ns) / 1e9
+        tel.record_span("serving.request.queue", item.t_submit_ns, item.t_pop_ns,
+                        request_id=item.request_id, queue_depth=item.queue_depth)
+        tel.record_span("serving.request.admit", item.t_pop_ns, now_ns,
+                        request_id=item.request_id, prompt_len=len(item.prompt),
+                        shared=shared)
+        tel.histogram("serving.cb.ttft_seconds").observe(ttft)
+        tel.counter("serving.cb.admissions").add(1)
+        return ttft
 
-    def _release_slot(self, b: int, s: _Active) -> None:
+    def _step_chunk(self) -> None:
+        with self._lock:
+            active_mask = np.asarray(
+                [s is not None for s in self._slots], bool
+            )
+        n_live = int(active_mask.sum())
+        # pages the chunk's first token-step reads: each active row's written
+        # prefix plus the token it writes; beside B x n_blocks, the share of
+        # a whole-table read that paged attention still makes
+        lens = self._lengths[active_mask].astype(np.int64) + 1
+        attrs = {"pages": int((-(-lens // self._ps)).sum())}
+        if self._stateful:  # live slots whose recurrent state the step updates
+            attrs["state_slots"] = n_live
+        with tel.span("serving.cb.chunk", slots=n_live, **attrs):
+            with tel.timed("serving.cb.chunk.dispatch") as dispatch:
+                cache, tok, lengths, keys, toks = _paged_step_fn(
+                    self._paged_cfg, self._B, self._C)(
+                    self._params,
+                    self._cache,
+                    jnp.asarray(self._tables),
+                    jnp.asarray(self._tok),
+                    jnp.asarray(self._lengths),
+                    jnp.asarray(self._keys),
+                    jnp.asarray(self._temps),
+                    jnp.asarray(active_mask),
+                )
+            with tel.timed("serving.cb.chunk.sync") as sync:
+                toks = np.asarray(toks)  # [B, C]; forces chunk completion
+            with tel.span("serving.cb.chunk.post"):
+                devperf.observe_step("paged_step",
+                                     dispatch.duration_s + sync.duration_s,
+                                     tokens=n_live * self._C)
+                self._cache = cache
+                # np.array (not asarray): device arrays view as READ-ONLY
+                # numpy; these mirrors are mutated per-slot at admit time
+                self._tok = np.array(tok, np.int32)
+                self._lengths = np.array(lengths, np.int32)
+                self._keys = np.array(keys, np.uint32)
+                now_ns = time.perf_counter_ns()
+                tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
+                for b in range(self._B):
+                    with self._lock:
+                        s = self._slots[b]
+                    if s is None:
+                        continue
+                    s.generated += self._C
+                    for t in toks[b]:
+                        t = int(t)
+                        s.tokens.append(t)
+                        if s.pending.eos_ids is not None and t in s.pending.eos_ids:
+                            break
+                        if len(s.tokens) >= s.budget:
+                            break
+                    self._finish_if_done(b, now_ns)
+
+    def _finish_if_done(self, b: int, now_ns: int) -> bool:
+        """Free slot ``b`` and its pages if its request hit EOS or its token
+        budget."""
+        with self._lock:
+            s = self._slots[b]
+        if s is None:
+            return False
+        eos = s.pending.eos_ids
+        hit_eos = eos is not None and any(t in eos for t in s.tokens)
+        if not hit_eos and len(s.tokens) < s.budget:
+            return False
+        if hit_eos:
+            cut = next(i for i, t in enumerate(s.tokens) if t in eos)
+            s.tokens = s.tokens[: cut + 1]
+        else:
+            s.tokens = s.tokens[: s.budget]
+        if len(s.tokens) > 1:
+            tpot = (now_ns - s.t_first_ns) / 1e9 / (len(s.tokens) - 1)
+            s.pending.handle.tpot_s = tpot
+            tel.histogram("serving.cb.tpot_seconds").observe(tpot)
+        # EOS/budget mid-chunk waste, measured instead of silent: the slot
+        # kept burning decode FLOPs until the chunk boundary
+        wasted = s.generated - len(s.tokens)
+        if wasted > 0:
+            tel.counter("serving.wasted_tokens").add(wasted)
+        tel.record_span("serving.request.decode", s.t_first_ns, now_ns,
+                        request_id=s.pending.request_id, tokens=len(s.tokens),
+                        wasted=wasted)
+        self._release_slot(b)
+        with self._lock:
+            self._slots[b] = None
+            self._requests_done += 1
+            self._tokens_out += len(s.tokens)
+        s.pending.handle._finish(s.tokens)
+        return True
+
+    def _release_slot(self, b: int) -> None:
         """Chunk-boundary reclamation: drop the request's reference on
         every page its table maps and point the row at the trash page so
         the slot's remaining mid-chunk scatters can't touch reused pages."""
@@ -975,52 +764,3 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             store.record_observation(
                 "serving.tenant.ttft_seconds." + tenant, ttft)
 
-    # -- introspection ------------------------------------------------------
-
-    def stats(self) -> dict:
-        out = super().stats()
-        a = self._alloc.stats()
-        with self._lock:
-            live = int(sum(int(self._lengths[i])
-                           for i, s in enumerate(self._slots)
-                           if s is not None))
-        pages_used = a["kv_pages_total"] - a["kv_pages_free"]
-        out.update(a)
-        out.update({
-            "kv_page_size": self._ps,
-            "kv_pages_in_use": pages_used,
-            "kv_tokens_live": live,
-            # pages per live token: the bench's HBM-efficiency headline
-            # (multiply by page bytes for bytes/token; the slot engine's
-            # analogue is slots*max_seq_len/live, always >= paged's)
-            "kv_pages_per_token": pages_used / live if live else 0.0,
-        })
-        if self._admission is not None:
-            out["admission"] = self._admission.stats()
-        return out
-
-    def prom_gauges(self) -> list:
-        """(name, labels, value) ride-along triples for /metrics."""
-        out = []
-        st = self._alloc.stats()
-        out.append(("serving_kv_pages", {"state": "free"},
-                    float(st["kv_pages_free"])))
-        out.append(("serving_kv_pages", {"state": "used"},
-                    float(st["kv_pages_total"] - st["kv_pages_free"])))
-        out.append(("serving_kv_pages", {"state": "watermark"},
-                    float(st["kv_watermark_pages"])))
-        out.append(("serving_kv_prefix_nodes", None,
-                    float(st["kv_prefix_nodes"])))
-        if self._stateful:
-            out.append(("serving_state_snapshot_bytes", None,
-                        float(st["state_snapshot_bytes"])))
-        with self._lock:
-            tenants = [(t, sorted(dq)) for t, dq in self._tenant_ttft.items()
-                       if dq]
-        for t, xs in tenants:
-            p99 = xs[min(len(xs) - 1, int(0.99 * len(xs)))]
-            out.append(("serving_tenant_ttft_p99_seconds", {"tenant": t},  # fedlint: disable=label-cardinality tenant set is bounded by the configured admission table, not the client population
-                        float(p99)))
-        if self._admission is not None:
-            out.extend(self._admission.prom_gauges())
-        return out

@@ -68,20 +68,22 @@ class JaxPredictor(FedMLPredictor):
 
 class LLMPredictor(FedMLPredictor):
     """LLM text-generation endpoint (BASELINE config 5 shape): KV-cache
-    decode via train/llm/generation.py. Request: {"prompt": str,
-    "max_new_tokens": int?, "temperature": float?} -> {"text": str} (engine
-    modes add "token_ids": [int] and "timing": {request_id, queue_wait_s,
-    ttft_s, tpot_s}, the engine's own readings for this request).
+    decode. ``paged=True`` serves through the continuous-batching engine
+    (serving/continuous_batching.py); without it every request is one
+    ``generation.generate_text`` call, the plain reference. Request:
+    {"prompt": str, "max_new_tokens": int?, "temperature": float?} ->
+    {"text": str} (the engine adds "token_ids": [int] and "timing":
+    {request_id, queue_wait_s, ttft_s, tpot_s}, its own readings for this
+    request).
 
     Build from a checkpoint dir (HF llama safetensors + tokenizer.json) or
     pass (params, cfg, tokenizer) directly."""
 
     def __init__(self, params, cfg, tokenizer, default_max_new_tokens: int = 64,
                  eos_id: "int | tuple | None" = None,
-                 continuous: Optional[bool] = None,
                  num_slots: Optional[int] = None,
                  decode_chunk: Optional[int] = None,
-                 paged: Optional[bool] = None,
+                 paged: bool = False,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  admission=None,
@@ -102,40 +104,27 @@ class LLMPredictor(FedMLPredictor):
         # children get FEDML_SERVE_ROLE=prefill|decode): prefill replicas
         # exist to absorb cold long prompts + cache warming
         self.role = os.environ.get("FEDML_SERVE_ROLE", "mixed")
-        # continuous batching (serving/continuous_batching.py): requests
-        # stream through a slotted decode engine instead of the window
-        # micro-batcher. Explicit arg wins; env seam lets subprocess
-        # replicas opt in without code changes.
-        if continuous is None:
-            continuous = os.environ.get("FEDML_SERVE_CONTINUOUS", "0") not in ("0", "", "false")
-        if paged is None:
-            paged = os.environ.get("FEDML_SERVE_PAGED", "0") not in ("0", "", "false")
+        # deployment capacity: explicit args win; the env seam sizes
+        # subprocess replicas without code changes
         self.engine = None
-        if continuous or paged:
+        if paged:
+            from .continuous_batching import PagedContinuousBatchingEngine
+
             slots = int(num_slots if num_slots is not None
                         else os.environ.get("FEDML_SERVE_SLOTS", "8"))
             chunk = int(decode_chunk if decode_chunk is not None
                         else os.environ.get("FEDML_SERVE_CHUNK", "8"))
             max_queue = int(os.environ.get("FEDML_SERVE_MAX_QUEUE", "4096"))
-            if paged:
-                from .continuous_batching import PagedContinuousBatchingEngine
-
-                ps = int(page_size if page_size is not None
-                         else os.environ.get("FEDML_SERVE_PAGE_SIZE", "16"))
-                np_env = os.environ.get("FEDML_SERVE_KV_PAGES")
-                pages = (int(num_pages) if num_pages is not None
-                         else int(np_env) if np_env else None)
-                self.engine = PagedContinuousBatchingEngine(
-                    params, cfg, num_slots=slots, chunk=chunk,
-                    page_size=ps, num_pages=pages, max_queue=max_queue,
-                    admission=admission,
-                    state_snapshots=int(state_snapshots))
-            else:
-                from .continuous_batching import ContinuousBatchingEngine
-
-                self.engine = ContinuousBatchingEngine(
-                    params, cfg, num_slots=slots, chunk=chunk,
-                    max_queue=max_queue)
+            ps = int(page_size if page_size is not None
+                     else os.environ.get("FEDML_SERVE_PAGE_SIZE", "16"))
+            np_env = os.environ.get("FEDML_SERVE_KV_PAGES")
+            pages = (int(num_pages) if num_pages is not None
+                     else int(np_env) if np_env else None)
+            self.engine = PagedContinuousBatchingEngine(
+                params, cfg, num_slots=slots, chunk=chunk,
+                page_size=ps, num_pages=pages, max_queue=max_queue,
+                admission=admission,
+                state_snapshots=int(state_snapshots))
 
     @classmethod
     def from_checkpoint(cls, path: str, quantize: str = "none", **kw) -> "LLMPredictor":
@@ -195,12 +184,12 @@ class LLMPredictor(FedMLPredictor):
             tenant = str(request.get("tenant", "default"))
             if request.get("prefill_only"):
                 # cache warming (prefill-pool traffic): one decoded token
-                # forces the full prefill, and the paged engine registers
+                # forces the full prefill, and the engine registers
                 # the prompt's chunks in its prefix cache on admit — later
                 # requests sharing this prefix skip its compute + pages
                 self.engine.generate(prompt_ids, 1, tenant=tenant)
                 return {"warmed": True, "prompt_tokens": len(prompt_ids)}
-            # continuous mode: this thread just parks on its future; the
+            # this thread just parks on its future; the
             # engine's worker interleaves every in-flight request through
             # one always-running decode step (ThreadingHTTPServer gives a
             # thread per connection, so concurrency comes for free)
@@ -239,46 +228,3 @@ class LLMPredictor(FedMLPredictor):
             eos_id=self._eos_id,
         )
         return {"text": text}
-
-    def predict_many(self, requests: list) -> list:
-        """Dynamic-batching entry (FedMLInferenceRunner micro-batcher):
-        requests with identical generation settings decode as ONE batched
-        call (variable prompt lengths welcome — generation.generate_batch
-        left-pads); mixed settings fall into per-setting groups. Greedy
-        numerics equal per-request predict exactly."""
-        import jax
-
-        from ..train.llm.generation import generate_batch
-
-        out: list = [None] * len(requests)
-        groups: dict = {}
-        for i, r in enumerate(requests):
-            temp = float(r.get("temperature", 0.0))
-            if temp > 0.0:
-                # sampled requests are NOT co-batched: rows of one batch
-                # share a PRNG stream, so a fixed seed's output would depend
-                # on batch composition — reproducibility wins over batching
-                out[i] = self.predict(r)
-                continue
-            # greedy output is seed-independent: don't let client seeds
-            # split what could be one batch
-            k = int(r.get("max_new_tokens", self._max_new))
-            groups.setdefault(k, []).append(i)
-        for max_new, idxs in groups.items():
-            try:
-                prompts = [self._tok.encode(str(requests[i]["prompt"])) for i in idxs]
-                toks = generate_batch(
-                    self._params, self._cfg, prompts, max_new,
-                    temperature=0.0, key=jax.random.PRNGKey(0), eos_id=self._eos_id,
-                )
-                for i, t in zip(idxs, toks):
-                    out[i] = {"text": self._tok.decode([int(x) for x in t])}
-            except Exception:  # noqa: BLE001 - one bad group must not void
-                # the other groups' finished decodes: retry ITS members only,
-                # flagging individual failures for the micro-batcher to 500
-                for i in idxs:
-                    try:
-                        out[i] = self.predict(requests[i])
-                    except Exception as e:  # noqa: BLE001
-                        out[i] = {"__error__": repr(e)}
-        return out

@@ -1,10 +1,10 @@
 """Paged KV cache: a block-pool allocator + the paged decode executables,
 and beside the pages the per-slot state of recurrent layers.
 
-PR 6's engine provisions every slot a full [max_seq_len] KV row, so HBM is
-sized for the worst-case sequence times ``num_slots`` and common system
-prompts are stored once PER REQUEST. This module replaces the row pool
-with a vLLM-style page pool:
+A pool of one full [max_seq_len] KV row per slot sizes HBM for the worst-case
+sequence times ``num_slots`` and stores a common system prompt once PER
+REQUEST. The engine (serving/continuous_batching.py) keeps K/V in a
+vLLM-style page pool instead:
 
 - the physical cache is [kv_num_pages, kv_page_size, kv, hd] per layer —
   ONE pool shared by every in-flight request; page 0 is a reserved trash
@@ -231,11 +231,12 @@ def _suffix_prefill_fn(cfg: TransformerConfig, T_b: int):
 
 
 def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
-    """The paged engine's one hot executable: C single-token steps over all
-    B rows, addressing the shared page pool through runtime block tables.
-    Identical control structure to ``_cb_step_fn``; the cache argument is
-    the POOL (page-count-sized, not B-sized), so HBM scales with admitted
-    tokens instead of worst-case rows."""
+    """The engine's one hot executable: C single-token steps over all B
+    rows, addressing the shared page pool through runtime block tables.
+    Everything per-request is runtime data (lengths, tables, temps, keys,
+    active mask), so this compiles ONCE per (cfg, B, C) and every admission
+    mix reuses it. The cache argument is the POOL (page-count-sized, not
+    B-sized), so HBM scales with admitted tokens instead of worst-case rows."""
 
     def build():
         model = decode_model(cfg)
@@ -270,6 +271,8 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
             )
             return pool, tok, lengths, keys, toks.swapaxes(0, 1)  # [B, C]
 
+        # donate the pool (arg 1): halves peak HBM for the biggest buffer in
+        # serving; CPU has no donation, so gate to avoid warnings
         donate = (1,) if jax.default_backend() == "tpu" else ()
         fn = jax.jit(track_compiles(run, name="paged_step"),
                      donate_argnums=donate)

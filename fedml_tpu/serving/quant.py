@@ -6,8 +6,8 @@ bf16 (4x vs f32) at negligible quality cost for the model sizes served here;
 activations, norms, embeddings, LoRA adapters, and the KV cache stay in the
 model dtype. The reference's Deploy story serves fp checkpoints only
 (``model_scheduler/device_model_deployment.py:68``) — this is a beyond-parity
-serving feature, opt-in via ``TransformerConfig.weight_quant="int8"`` (or
-``FEDML_BENCH_INT8=1`` for the endpoint bench).
+serving feature, opt-in via ``TransformerConfig.weight_quant="int8"``
+(``LLMPredictor.from_checkpoint(path, quantize="int8")``).
 
 The transform rewrites a float param pytree into the layout
 ``LoRALinear`` consumes in int8 mode: each 2D ``kernel`` leaf becomes

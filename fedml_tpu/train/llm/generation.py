@@ -1,4 +1,6 @@
-"""Autoregressive generation with a KV cache (the serving decode path).
+"""Autoregressive generation with a KV cache: the plain reference of the
+serving engine (serving/continuous_batching.py, which also admits requests
+through ``_prefill_fn``) and ``LLMPredictor``'s engine-less mode.
 
 Reference analogue: BASELINE config 5 serves Llama-2 inference via
 docker/Triton (``device_model_deployment.py:68``); here decode is
@@ -216,144 +218,6 @@ def generate(
         jnp.float32(temperature),
     )
     return out[:, :max_new_tokens]
-
-
-def _prefill_batch_fn(cfg: TransformerConfig, B: int, P_bucket: int):
-    """Left-padded batched prefill: per-row pad prefix masked via
-    ``attn_start``; every row's last REAL token sits at the right edge."""
-
-    def build():
-        model = decode_model(cfg)
-
-        def run(params, prompt_padded, start):
-            positions = jnp.clip(
-                jnp.arange(P_bucket)[None, :] - start[:, None], 0, None
-            )
-            logits, state = model.apply(
-                {"params": params}, prompt_padded, positions=positions,
-                attn_start=start, mutable=["cache"],
-            )
-            return state["cache"], logits[:, -1]
-
-        return jax.jit(track_compiles(run, name="prefill_batch"))
-
-    return _lru_get(("prefill_b", cfg, B, P_bucket), build)
-
-
-def _decode_batch_fn(cfg: TransformerConfig, B: int, max_new: int, sampled: bool,
-                     eos_ids: Optional[Tuple[int, ...]]):
-    """Decode scan that carries the per-row ``attn_start`` mask (batched
-    serving); otherwise identical to _decode_fn."""
-
-    def build():
-        model = decode_model(cfg)
-
-        def is_eos(tok):
-            return jnp.isin(tok, jnp.asarray(eos_ids))
-
-        def run(params, cache, first_logits, pos0, start, key, temperature):
-            key, sub = jax.random.split(key)
-            temp = temperature if sampled else jnp.float32(0.0)
-            first = _sample(first_logits, sub, temp)
-
-            def step(carry, _):
-                cache, tok, pos, key, done = carry
-                key, sub = jax.random.split(key)
-                logits, state = model.apply(
-                    {"params": params, "cache": cache},
-                    tok[:, None],
-                    positions=pos[:, None],
-                    attn_start=start,
-                    mutable=["cache"],
-                )
-                nxt = _sample(logits[:, -1], sub, temp)
-                if eos_ids is not None:
-                    nxt = jnp.where(done, eos_ids[0], nxt)
-                    done = jnp.logical_or(done, is_eos(nxt))
-                return (state["cache"], nxt, pos + 1, key, done), tok
-
-            done0 = jnp.zeros((B,), bool) if eos_ids is None else is_eos(first)
-            (_, last, _, _, _), toks = jax.lax.scan(
-                step, (cache, first, pos0, key, done0), None, length=max_new - 1
-            )
-            return jnp.concatenate([toks.swapaxes(0, 1), last[:, None]], axis=1)
-
-        return jax.jit(track_compiles(run, name="decode_scan_batch"))
-
-    return _lru_get(("decode_b", cfg, B, max_new, sampled, eos_ids), build)
-
-
-def _batch_bucket(n: int) -> int:
-    b = 1
-    while b < n:
-        b *= 2
-    return b
-
-
-def generate_batch(
-    params,
-    cfg: TransformerConfig,
-    prompts,
-    max_new_tokens: int,
-    *,
-    temperature: float = 0.0,
-    key: Optional[jax.Array] = None,
-    eos_id: Optional[int] = None,
-) -> list:
-    """Batched generation over VARIABLE-length prompts (dynamic-batching
-    serving path): prompts are LEFT-padded to a shared 16-token length
-    bucket — all rows then share the cache write index while ``attn_start``
-    masks each row's pad prefix — and the batch dim is padded to a power of
-    two so executables are shared across batch sizes. Greedy numerics equal
-    per-prompt :func:`generate` exactly (tests/test_generation.py).
-
-    ``prompts``: sequence of token-id sequences. Returns a list of
-    [max_new_tokens] arrays."""
-    n = len(prompts)
-    if n == 0:
-        return []
-    lens = [len(p) for p in prompts]
-    if min(lens) < 1:
-        raise ValueError("every prompt must contain at least one token")
-    if max_new_tokens < 1:
-        raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
-    P_max = max(lens)
-    if P_max + max_new_tokens > cfg.max_seq_len:
-        raise ValueError(
-            f"longest prompt {P_max} + new {max_new_tokens} exceeds max_seq_len {cfg.max_seq_len}"
-        )
-    eos_ids: Optional[Tuple[int, ...]] = None
-    if eos_id is not None:
-        eos_ids = tuple(eos_id) if isinstance(eos_id, (list, tuple)) else (int(eos_id),)
-    key = key if key is not None else jax.random.PRNGKey(0)
-
-    # the batch path CANNOT rewind the shared write index (rows are
-    # left-padded to end at P_b), so decode writes land at P_b..P_b+new-1:
-    # P_b itself must leave room, else dynamic_update_slice would clamp and
-    # silently overwrite the last cache slot. At the boundary drop the
-    # bucket padding (exact-length compile) rather than corrupt the cache.
-    P_b = -(-P_max // 16) * 16
-    if P_b + max_new_tokens > cfg.max_seq_len:
-        P_b = P_max
-    B_b = _batch_bucket(n)
-    rows = []
-    starts = []
-    for i in range(B_b):
-        p = list(prompts[i]) if i < n else list(prompts[0])  # pad rows: replay row 0
-        pad = P_b - len(p)
-        rows.append([0] * pad + p)
-        starts.append(pad)
-    prompt_padded = jnp.asarray(rows, jnp.int32)
-    start = jnp.asarray(starts, jnp.int32)
-    true_len = P_b - start  # [B_b]
-
-    bucket = min(-(-max_new_tokens // 16) * 16, cfg.max_seq_len - P_b)
-    cache, first_logits = _prefill_batch_fn(cfg, B_b, P_b)(params, prompt_padded, start)
-    out = _decode_batch_fn(cfg, B_b, bucket, temperature > 0.0, eos_ids)(
-        params, cache, first_logits, true_len.astype(jnp.int32), start, key,
-        jnp.float32(temperature),
-    )
-    return [out[i, :max_new_tokens] for i in range(n)]
 
 
 def generate_text(

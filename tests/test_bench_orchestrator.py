@@ -168,19 +168,6 @@ def test_main_happy_path_merges_and_exits_zero(monkeypatch, tmp_path, capsys, _r
                      "memory_plan_validated": True}, None),
         "cpu_llm": ({"cpu_llm_tokens_per_sec": 100.0}, None),
         "cpu_resnet": ({"cpu_resnet_images_per_sec": 80.0}, None),
-        "serving": ({"endpoint_decode_tokens_per_sec": 700.0,
-                     "endpoint_replicas": 2, "endpoint_requests": 12,
-                     "endpoint_model": "llama-268M flagship proxy (bf16)",
-                     "endpoint_batching": "dynamic"}, None),
-        "serving_load": ({"serving_load_streams": 1024,
-                          "serving_load_tokens_per_sec": 300.0,
-                          "serving_load_ttft_p50_s": 0.8,
-                          "serving_load_ttft_p99_s": 2.5,
-                          "serving_load_tpot_p50_s": 0.004,
-                          "serving_load_tpot_p99_s": 0.02,
-                          "serving_load_slots": 64,
-                          "serving_load_slot_occupancy_peak": 1.0,
-                          "serving_load_slot_occupancy_mean": 0.9}, None),
         "agg": ({"agg_clients_per_sec": {"resnet56": {"8": 120.0, "64": 240.0},
                                          "llm268m": {"8": 3.0}},
                  "agg_hbm_gbps": {"resnet56": {"8": 1.5, "64": 2.8},
@@ -290,7 +277,6 @@ def test_main_happy_path_merges_and_exits_zero(monkeypatch, tmp_path, capsys, _r
     assert out["remat_xla_attention"] is False
     assert out["vs_baseline"] == 500.0  # 50000 / 100
     assert out["resnet56_vs_torch_cpu"] == 32.0  # 20*128 / 80
-    assert out["endpoint_replicas"] == 2
     assert out["attn_best_flash"] == "flash_256x256"
     assert out["attn_best_vs_einsum"] == 1.067
     assert out["agg_clients_per_sec"]["resnet56"]["64"] == 240.0
@@ -634,40 +620,6 @@ def test_llm_xla_non_oom_failure_does_not_respawn(monkeypatch, tmp_path,
         bench.main()
     assert len(calls) == 1  # the half-bs respawn is OOM-specific
     capsys.readouterr()
-
-
-def test_main_merges_serving_load_and_vs_decode(monkeypatch, tmp_path, capsys,
-                                                _restore_signals):
-    """The serving_load stage's keys (tokens/s, TTFT/TPOT tails, slot
-    occupancy) merge into the one-line JSON, and serving_load_vs_decode =
-    raw decode rate / endpoint rate (ISSUE 6 acceptance: within 10x)."""
-    _canned_stages(monkeypatch, tmp_path, {
-        "llm_pallas": _LLM_OK,
-        "decode": ({"decode_tokens_per_sec": 900.0, "bs": 4, "new": 128}, None),
-        "serving_load": ({"serving_load_streams": 1024,
-                          "serving_load_tokens_per_sec": 300.0,
-                          "serving_load_tokens": 32768,
-                          "serving_load_wall_s": 109.2,
-                          "serving_load_ttft_p50_s": 0.8,
-                          "serving_load_ttft_p99_s": 2.5,
-                          "serving_load_tpot_p50_s": 0.004,
-                          "serving_load_tpot_p99_s": 0.02,
-                          "serving_load_slots": 64,
-                          "serving_load_chunk": 16,
-                          "serving_load_slot_occupancy_peak": 1.0,
-                          "serving_load_slot_occupancy_mean": 0.9,
-                          "serving_load_queue_depth_peak": 960,
-                          "serving_load_model": "llama-268M flagship proxy (bf16)",
-                          "serving_load_engine": "continuous"}, None),
-    })
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code == 0
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["serving_load_tokens_per_sec"] == 300.0
-    assert out["serving_load_ttft_p99_s"] == 2.5
-    assert out["serving_load_slot_occupancy_peak"] == 1.0
-    assert out["serving_load_vs_decode"] == 3.0  # 900 / 300, within the 10x gate
 
 
 def test_memplan_device_kind_hbm_fallback_table():

@@ -56,12 +56,12 @@ class TestCompare:
 
     def test_lower_is_better_direction(self, tmp_path):
         _write(tmp_path, "BENCH_MEASURED_20260101T000000Z.json",
-               {"serving_load_ttft_p99_s": 0.5})
+               {"ckpt_enqueue_ms": 0.5})
         _write(tmp_path, "BENCH_MEASURED_20260102T000000Z.json",
-               {"serving_load_ttft_p99_s": 1.5})
+               {"ckpt_enqueue_ms": 1.5})
         report = bench_regress.compare(str(tmp_path), 0.10)
         assert [r["key"] for r in report["regressions"]] == \
-            ["serving_load_ttft_p99_s"]
+            ["ckpt_enqueue_ms"]
 
     def test_improvement_and_within_threshold_pass(self, tmp_path):
         _write(tmp_path, "BENCH_MEASURED_20260101T000000Z.json",

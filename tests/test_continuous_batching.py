@@ -1,7 +1,9 @@
-"""Continuous batching (serving/continuous_batching.py): slotted decode
-engine correctness against the reference ``generate()`` path, join/leave
-at token boundaries, EOS, single-compile across admission mixes, and the
-runner integration that replaces the window micro-batcher."""
+"""Continuous batching (serving/continuous_batching.py): the engine against
+the reference ``generate()`` path: join/leave at token boundaries, EOS,
+the budget clamp at ``max_seq_len``, fail-fast rejects, the HTTP runner in
+front of it, and the request's span record. (Greedy exactness on ragged
+lengths and compile-once are tests/test_paged_kv.py's, at this file's
+geometry too.)"""
 
 import json
 import urllib.request
@@ -13,7 +15,7 @@ import pytest
 
 from fedml_tpu.core import telemetry as tel
 from fedml_tpu.models.transformer import TransformerConfig, TransformerLM
-from fedml_tpu.serving.continuous_batching import ContinuousBatchingEngine
+from fedml_tpu.serving.continuous_batching import PagedContinuousBatchingEngine
 from fedml_tpu.train.llm.generation import generate
 
 CFG = TransformerConfig(
@@ -30,7 +32,8 @@ def params():
 
 @pytest.fixture()
 def engine(params):
-    eng = ContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4)
+    # pages of 8: the compiled programs' cache keys are this file's own (see served_requests)
+    eng = PagedContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4, page_size=8)
     yield eng
     eng.shutdown()
 
@@ -39,22 +42,10 @@ def _prompt(length, seed):
     return list(np.random.default_rng(seed).integers(1, CFG.vocab_size, length))
 
 
-def test_engine_greedy_matches_generate(engine, params):
-    """The keystone: for every prompt the slotted engine (per-row cache_idx
-    scatter decode, requests interleaved across 2 slots) emits exactly the
-    tokens the reference single-request ``generate()`` path emits."""
-    prompts = [_prompt(n, i) for i, n in enumerate((5, 9, 3, 17))]
-    handles = [engine.submit(p, 12) for p in prompts]
-    for p, h in zip(prompts, handles):
-        want = np.asarray(
-            generate(params, CFG, jnp.asarray([p], jnp.int32), 12)
-        )[0].tolist()
-        assert h.result(timeout=120) == want
-
-
 def test_engine_join_leave_more_requests_than_slots(engine):
     """6 requests through 2 slots: admission happens at token boundaries
-    (freed slots re-admit from the FIFO) and every future completes."""
+    (freed slots re-admit from the FIFO), every future completes and every
+    page comes back."""
     handles = [engine.submit(_prompt(4 + i, 100 + i), 6 + i) for i in range(6)]
     outs = [h.result(timeout=120) for h in handles]
     assert [len(o) for o in outs] == [6 + i for i in range(6)]
@@ -62,6 +53,7 @@ def test_engine_join_leave_more_requests_than_slots(engine):
     assert st["requests_done"] == 6
     assert st["slots_active"] == 0 and st["queue_depth"] == 0
     assert st["tokens_out"] == sum(len(o) for o in outs)
+    assert st["kv_tokens_live"] == 0 and engine._alloc.check_leaks()["accounted"]
 
 
 def test_engine_eos_truncates_like_generate(engine, params):
@@ -90,30 +82,6 @@ def test_engine_sampled_same_seed_deterministic(engine):
     assert len(c) == 10  # different seed still a full stream
 
 
-def test_cb_executables_compile_once_across_admission_mixes(params):
-    """The engine's whole point: one (cfg, B, C) step executable serves
-    every mix of prompt lengths, temperatures, and stop tokens — per-row
-    state is runtime data. A retrace here is the serving analogue of the
-    int8 decode regression bench.py guards with compile counters."""
-    eng = ContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4)
-    try:
-        eng.generate(_prompt(4, 0), 5)  # warm: compiles admit + step once
-        step0 = tel.compile_count("cb_step")
-        admit0 = tel.compile_count("cb_admit")
-        assert step0 >= 1 and admit0 >= 1
-        hs = [
-            eng.submit(_prompt(3, 1), 6),
-            eng.submit(_prompt(19, 2), 9, temperature=0.7, seed=5),
-            eng.submit(_prompt(8, 3), 4, eos_id=1),
-        ]
-        for h in hs:
-            h.result(timeout=120)
-        assert tel.compile_count("cb_step") == step0
-        assert tel.compile_count("cb_admit") == admit0
-    finally:
-        eng.shutdown()
-
-
 def test_engine_rejects_bad_requests_fast(engine):
     with pytest.raises(ValueError, match="at least one token"):
         engine.generate([], 4)
@@ -123,19 +91,22 @@ def test_engine_rejects_bad_requests_fast(engine):
         engine.generate(list(range(1, CFG.max_seq_len + 1)), 4)
 
 
-def test_engine_budget_clamped_to_cache_capacity(engine):
-    """A near-capacity prompt gets its stream clamped to the cache room
-    left instead of scattering out of bounds (or erroring)."""
+def test_engine_budget_clamped_to_cache_capacity(engine, params):
+    """A near-capacity prompt gets its stream clamped to the room
+    ``max_seq_len`` leaves instead of scattering out of bounds (or
+    erroring), and the clamped stream is still the reference's."""
     prompt = _prompt(CFG.max_seq_len - 3, 21)
     out = engine.generate(prompt, 50)
     assert len(out) == 3  # S - P
+    want = generate(params, CFG, jnp.asarray([prompt], jnp.int32), 3)
+    assert out == np.asarray(want)[0].tolist()
 
 
 def test_engine_queue_cap_and_shutdown_fail_fast(params):
-    eng = ContinuousBatchingEngine(params, CFG, num_slots=1, chunk=2,
-                                   max_queue=0)
+    eng = PagedContinuousBatchingEngine(params, CFG, num_slots=1, chunk=2,
+                                        page_size=8, max_queue=0)
     h = eng.submit([1, 2, 3], 4)
-    with pytest.raises(RuntimeError, match="admission queue full"):
+    with pytest.raises(RuntimeError, match="queue_full"):
         h.result(timeout=5)
     eng.shutdown()
     h2 = eng.submit([1, 2, 3], 4)
@@ -144,8 +115,7 @@ def test_engine_queue_cap_and_shutdown_fail_fast(params):
 
 
 def test_runner_serves_engine_and_exports_gauges(params):
-    """The HTTP runner routes through the engine (micro-batcher skipped),
-    /metrics exports the slot/queue gauges the autoscaler and load bench
+    """The HTTP runner routes through the engine, /metrics exports the slot/queue gauges the autoscaler and load bench
     read, and /statusz carries the stats() snapshot."""
     from fedml_tpu.serving.fedml_inference_runner import FedMLInferenceRunner
     from fedml_tpu.serving.fedml_predictor import LLMPredictor
@@ -160,10 +130,9 @@ def test_runner_serves_engine_and_exports_gauges(params):
             return " ".join(str(i) for i in ids)
 
     pred = LLMPredictor(params, CFG, _Tok(), default_max_new_tokens=4,
-                        continuous=True, num_slots=2, decode_chunk=2)
+                        paged=True, num_slots=2, decode_chunk=2, page_size=8)
     assert pred.engine is not None
     runner = FedMLInferenceRunner(pred, port=0)
-    assert runner.batcher is None  # engine replaces the window batcher
     port = runner.start()
     try:
         req = urllib.request.Request(
@@ -179,7 +148,7 @@ def test_runner_serves_engine_and_exports_gauges(params):
         ) as r:
             metrics = r.read().decode()
         for g in ("serving_cb_slots_total", "serving_cb_slot_occupancy",
-                  "serving_cb_queue_depth"):
+                  "serving_cb_queue_depth", "serving_kv_pages"):
             assert f"fedml_{g}" in metrics, g
         with urllib.request.urlopen(
             f"http://127.0.0.1:{port}/statusz", timeout=10
@@ -339,17 +308,14 @@ def test_worker_loop_spans_tile_an_iteration(served_requests):
     assert any(s["name"] == "serving.engine.idle" and s["tid"] in worker for s in spans)
 
 
-@pytest.mark.parametrize("label", ["prefill", "cb_step", "cb_admit", "paged_step", "paged_admit",
+@pytest.mark.parametrize("label", ["prefill", "paged_step", "paged_admit",
                                    "paged_gather", "paged_suffix_prefill"])
 def test_serving_programs_lower_under_their_labels_name(params, label):
-    from fedml_tpu.serving import continuous_batching as cb
     from fedml_tpu.serving import paged_kv
     from fedml_tpu.train.llm.generation import _prefill_fn
 
     pcfg = paged_kv.paged_config(CFG, page_size=8, num_pages=5)
     fn = {"prefill": lambda: _prefill_fn(CFG, 1, 16),
-          "cb_step": lambda: cb._cb_step_fn(CFG, 2, 4),
-          "cb_admit": lambda: cb._cb_admit_fn(CFG, 2),
           "paged_step": lambda: paged_kv._paged_step_fn(pcfg, 2, 4),
           "paged_admit": lambda: paged_kv._paged_admit_fn(pcfg),
           "paged_gather": lambda: paged_kv._paged_gather_fn(pcfg),
@@ -390,7 +356,7 @@ def test_check_serving_lint_clean_and_detects_regressions(tmp_path):
     # synthetic tree: _admit_all lost its span, _step_chunk is gone,
     # replica_controller.py does not exist
     (tmp_path / "continuous_batching.py").write_text(
-        "class ContinuousBatchingEngine:\n"
+        "class PagedContinuousBatchingEngine:\n"
         "    def _admit_all(self):\n"
         "        return 1\n"
     )

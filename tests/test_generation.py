@@ -177,56 +177,3 @@ def test_multi_eos_stops_on_any():
     out = np.asarray(generate(params, CFG, prompt, 12, eos_id=eos_ids))[0]
     assert out[0] == eos_ids[0]  # first token is an eos -> done immediately
     assert np.all(out[1:] == eos_ids[0])
-
-
-def test_generate_batch_matches_per_prompt():
-    """Dynamic-batching core: left-padded mixed-length batched generation is
-    bit-identical to per-prompt generate (greedy), including the batch-pad
-    rows bucketing adds."""
-    from fedml_tpu.train.llm.generation import generate_batch
-
-    params = _params()
-    rng = np.random.default_rng(11)
-    prompts = [
-        list(rng.integers(0, CFG.vocab_size, n)) for n in (3, 9, 5)
-    ]
-    outs = generate_batch(params, CFG, prompts, 6)
-    assert len(outs) == 3
-    for p, got in zip(prompts, outs):
-        want = generate(params, CFG, jnp.asarray([p], jnp.int32), 6)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want[0]),
-                                      err_msg=f"len={len(p)}")
-
-
-def test_generate_batch_eos_and_executable_sharing():
-    from fedml_tpu.train.llm import generation
-    from fedml_tpu.train.llm.generation import generate_batch
-
-    params = _params()
-    generation._COMPILED.clear()
-    outs = generate_batch(params, CFG, [[1, 2], [3, 4, 5]], 5, eos_id=0)
-    assert all(o.shape == (5,) for o in outs)
-    # batch of 3 shares the B-bucket-4 executables with a batch of 4
-    generate_batch(params, CFG, [[1], [2], [3]], 5, eos_id=0)
-    keys = [k for k in generation._COMPILED if k[0] in ("prefill_b", "decode_b")]
-    assert len(keys) == 4  # (prefill+decode) x (B2, B4) buckets... B2? 2->2, 3->4
-
-
-def test_generate_batch_boundary_no_cache_overflow():
-    """Bucket padding must never push decode writes past max_seq_len
-    (dynamic_update_slice would clamp and silently corrupt the last slot):
-    P=49 pads to 64 == max_seq_len with 15 new tokens requested — the
-    boundary drops bucket padding, and output equals per-prompt generate."""
-    from fedml_tpu.train.llm.generation import generate_batch
-
-    cfg = dataclasses.replace(CFG, max_seq_len=64)
-    model = TransformerLM(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
-    rng = np.random.default_rng(21)
-    prompts = [list(rng.integers(0, cfg.vocab_size, 49)),
-               list(rng.integers(0, cfg.vocab_size, 33))]
-    outs = generate_batch(params, cfg, prompts, 15)
-    for p, got in zip(prompts, outs):
-        want = generate(params, cfg, jnp.asarray([p], jnp.int32), 15)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want[0]),
-                                      err_msg=f"len={len(p)}")

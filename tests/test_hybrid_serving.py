@@ -15,7 +15,7 @@ import pytest
 
 from fedml_tpu.core import telemetry as tel
 from fedml_tpu.models.transformer import TransformerLM
-from fedml_tpu.serving.continuous_batching import ContinuousBatchingEngine, PagedContinuousBatchingEngine
+from fedml_tpu.serving.continuous_batching import PagedContinuousBatchingEngine
 from fedml_tpu.serving.paged_kv import PagedKVAllocator
 from fedml_tpu.train.llm.checkpoint_import import config_from_hf_keys
 
@@ -180,21 +180,14 @@ def test_zero_compiles_after_warm_up(params):
         eng.shutdown()
 
 
-def test_contiguous_engine_and_generate_carry_the_state_too(params):
-    """The slot engine without pages and the plain generate() loop run the
-    same mixer: per-row state, one token a step."""
-    from fedml_tpu.train.llm.generation import generate, generate_batch
+def test_generate_carries_the_state_too(params):
+    """The plain generate() loop, the engine's reference, runs the same
+    mixer: per-row state, one token a step."""
+    from fedml_tpu.train.llm.generation import generate
 
     prompt = _toks(21, 4)
     out = [int(t) for t in generate(params, CFG, jnp.asarray([prompt], jnp.int32), 7)[0]]
     assert _gap(params, prompt, out) < GAP_TOL
-    eng = ContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4)
-    try:
-        assert eng.generate(prompt, 7) == out
-    finally:
-        eng.shutdown()
-    with pytest.raises(ValueError, match="left-padded"):
-        generate_batch(params, CFG, [prompt, prompt[:5]], 4)
 
 
 # ---- the allocator's bookkeeping of snapshots, no device --------------------------------------------

@@ -138,18 +138,28 @@ def test_allocator_randomized_lifecycle_no_leaks():
 # --- engine ------------------------------------------------------------------
 
 
-def test_paged_engine_greedy_matches_generate_ragged(engine, params):
-    """Keystone: the paged engine (block-table scatter/gather decode) is
-    token-exact vs the contiguous reference path across ragged prompt
-    lengths spanning page boundaries."""
-    prompts = [_prompt(n, i) for i, n in enumerate((3, 15, 16, 17, 31, 40))]
-    handles = [engine.submit(p, 12) for p in prompts]
-    for p, h in zip(prompts, handles):
-        assert h.result(timeout=120) == _ref(params, p, 12)
-    # all pages returned (no retention yet for <1-page prompts; longer
-    # prompts retain their full chunks at refcount exactly 1)
-    leaks = engine._alloc.check_leaks()
-    assert leaks["leaked"] == [] and leaks["accounted"]
+# the fixture's geometry, and tests/test_continuous_batching.py's (its pages of 8)
+GEOMETRIES = [pytest.param(dict(page_size=16), id="page16"),
+              pytest.param(dict(page_size=8), id="page8")]
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_paged_engine_greedy_matches_generate_ragged(params, geometry):
+    """Keystone: the engine (block-table scatter/gather decode, requests
+    interleaved across 2 slots) is token-exact vs the contiguous reference
+    path across ragged prompt lengths spanning page boundaries."""
+    eng = PagedContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4, **geometry)
+    try:
+        prompts = [_prompt(n, i) for i, n in enumerate((3, 15, 16, 17, 31, 40))]
+        handles = [eng.submit(p, 12) for p in prompts]
+        for p, h in zip(prompts, handles):
+            assert h.result(timeout=120) == _ref(params, p, 12)
+        # all pages returned (no retention yet for <1-page prompts; longer
+        # prompts retain their full chunks at refcount exactly 1)
+        leaks = eng._alloc.check_leaks()
+        assert leaks["leaked"] == [] and leaks["accounted"]
+    finally:
+        eng.shutdown()
 
 
 def test_prefix_sharing_is_token_exact_and_skips_prefill(engine, params):
@@ -169,12 +179,13 @@ def test_prefix_sharing_is_token_exact_and_skips_prefill(engine, params):
     assert leaks["leaked"] == [] and leaks["accounted"]
 
 
-def test_paged_executables_compile_once_across_mixed_admissions(params):
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_paged_executables_compile_once_across_mixed_admissions(params, geometry):
     """Zero-recompile acceptance: one executable each for step / admit /
     gather / suffix-prefill serves every mix of prompt lengths, sampling
     settings, and prefix hit/miss — per-request state is runtime data
     (block tables ride the jitted step as arguments)."""
-    eng = PagedContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4)
+    eng = PagedContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4, **geometry)
     try:
         system = _prompt(16, 5)
         eng.generate(system + _prompt(3, 0), 5)   # warm: miss path

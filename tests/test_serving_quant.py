@@ -107,16 +107,28 @@ def test_int8_decode_end_to_end(fp_model):
     np.testing.assert_array_equal(toks, np.asarray(out2))
 
 
-def test_bench_predictor_int8_mode(monkeypatch):
-    monkeypatch.setenv("FEDML_BENCH_TINY", "1")
-    monkeypatch.setenv("FEDML_BENCH_INT8", "1")
-    monkeypatch.setenv("FEDML_REPLICA_PLATFORM", "cpu")
-    from fedml_tpu.serving.bench_predictors import llm_bench_predictor
+def test_int8_weights_through_the_engine_match_generate(fp_model):
+    """Weight-only int8 served through the continuous-batching engine: the
+    paged step reads the same quantised tree ``generate()`` does, token for
+    token, for requests of several lengths interleaved across two slots."""
+    from fedml_tpu.serving.continuous_batching import PagedContinuousBatchingEngine
+    from fedml_tpu.serving.quant import quantize_model_int8
+    from fedml_tpu.train.llm.generation import generate
 
-    predictor = llm_bench_predictor()
-    out = predictor.predict({"prompt": "federated", "max_new_tokens": 4})
-    assert isinstance(out.get("text"), str)
-    assert predictor._cfg.weight_quant == "int8"
+    cfg, _model, params = fp_model
+    qcfg, qparams = quantize_model_int8(cfg, params)
+    assert qcfg.weight_quant == "int8"
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, n).tolist() for n in (3, 8, 13, 5)]
+    eng = PagedContinuousBatchingEngine(qparams, qcfg, num_slots=2, chunk=4, page_size=8)
+    try:
+        handles = [eng.submit(p, 9) for p in prompts]
+        for p, h in zip(prompts, handles):
+            want = generate(qparams, qcfg, jnp.asarray([p], jnp.int32), 9)
+            assert h.result(timeout=120) == np.asarray(want)[0].tolist()
+        assert eng._alloc.check_leaks()["accounted"]
+    finally:
+        eng.shutdown()
 
 
 @pytest.mark.slow

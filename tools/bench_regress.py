@@ -44,18 +44,8 @@ HEADLINES: Dict[str, str] = {
     "decode_tokens_per_sec": "higher",
     "decode_tokens_per_sec_int8": "higher",
     "int8_decode_speedup": "higher",
-    "endpoint_decode_tokens_per_sec": "higher",
     "resnet56_steps_per_sec": "higher",
     "resnet56_mfu": "higher",
-    "serving_load_tokens_per_sec": "higher",
-    "serving_load_ttft_p50_s": "lower",
-    "serving_load_ttft_p99_s": "lower",
-    "serving_load_tpot_p50_s": "lower",
-    "serving_load_tpot_p99_s": "lower",
-    "serving_load_p99_ttft_s": "lower",      # ISSUE 16 paged-engine tails
-    "serving_load_p99_tpot_s": "lower",
-    "kv_pages_per_token": "lower",           # KV HBM efficiency under load
-    "serving_load_kv_hbm_ratio": "lower",    # paged/fixed provisioned bytes
     "async_rounds_per_hr.*": "higher",       # per-cohort dict
     "async_flatness_ratio": "higher",
     "agg_clients_per_sec.*": "higher",       # per-engine/K nested dict

@@ -23,9 +23,8 @@ from ._util import matches_file
 
 #: (serving-relative file, qualified function) -> must contain tel.timed/span
 HOT_LOOPS: tuple = (
-    ("continuous_batching.py", "ContinuousBatchingEngine._admit_all"),
-    ("continuous_batching.py", "ContinuousBatchingEngine._step_chunk"),
     ("continuous_batching.py", "PagedContinuousBatchingEngine._admit_all"),
+    ("continuous_batching.py", "PagedContinuousBatchingEngine._step_chunk"),
     ("continuous_batching.py", "PagedContinuousBatchingEngine._stage_prefill"),
     ("replica_controller.py", "InferenceGateway.predict"),
 )
