@@ -15,10 +15,14 @@ hold it to, token for token. What the engine does:
 - prefill is disaggregated: each request prefills alone at B=1 through the
   16-token-bucketed executables (``generation._prefill_fn``; on a prefix hit,
   a gather of the shared pages plus one suffix pass), then a jitted admit
-  scatters its row into its private pages. Admission runs as a prefill ->
-  transfer -> admit :class:`PipelinedExecutor` wave, so request i+1's prefill
-  overlaps request i's pool scatter and a long prompt never stalls in-flight
-  generation;
+  scatters its row into its private pages and samples its first token. An
+  admission wave runs on the worker's own thread (``_run_wave``): a rider's
+  programs are launched without waiting, the next rider's are launched behind
+  them, and only then is the first rider's token fetched, so the device sees
+  one chain ``prefill_0, admit_0, prefill_1, admit_1, ...`` and the host's
+  bookkeeping for rider i runs while the chip works on rider i+1. One thread
+  launches everything that touches the pool, so the admit program donates it
+  as the decode step does;
 - per-row state stays RUNTIME data: slot lengths ride the transformer's
   ``cache_idx``, block tables, temperatures and PRNG keys are per-row arrays,
   and EOS is checked host-side between chunks, so one executable per
@@ -61,7 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import telemetry as tel
-from ..core.pipeline.executor import PipelinedExecutor, PipelineError, StageSpec
 from ..core.telemetry import devperf, trace_context, tsdb
 from ..models.mamba import state_bytes as mamba_state_bytes
 from ..models.transformer import TransformerConfig
@@ -146,8 +149,8 @@ class _Active:
 
 @dataclasses.dataclass
 class _AdmitWork:
-    """One request moving through the prefill -> transfer -> admit pipeline
-    (created by ``_collect_wave`` holding its slot + page reservations)."""
+    """One request moving through an admission wave: prefill -> transfer ->
+    admit (created by ``_collect_wave`` holding its slot + page reservations)."""
 
     item: _Pending
     slot: int
@@ -158,9 +161,9 @@ class _AdmitWork:
     state: object = None      # recurrent layers start from this snapshot (None: from zero)
     snap_blocks: int = 0      # block boundary whose trie node wants this prefill's state
     row_cache: object = None
-    first_vec: object = None  # [vocab] logits for the first sampled token
-    tok0: int = 0
-    key2: object = None
+    first: object = None      # [1, vocab] logits the first token is sampled from
+    tok0: object = None       # on the device from the transfer until _stage_admit fetches it
+    key2: object = None       # likewise
     admitted: bool = False
 
 
@@ -515,71 +518,90 @@ class PagedContinuousBatchingEngine:
             taken.add(free)
 
     def _run_wave(self, wave: List[_AdmitWork]) -> None:
-        pipe = PipelinedExecutor(
-            [StageSpec("prefill", self._stage_prefill, maxsize=2),
-             StageSpec("transfer", self._stage_transfer, maxsize=2),
-             StageSpec("admit", self._stage_admit, maxsize=2)],
-            name="paged_admit")
-        try:
-            pipe.run(wave)
-        except PipelineError as e:
-            # fail the riders that never reached the admit stage and return
-            # their reservations; admitted riders keep decoding untouched
-            log.exception("paged admission wave failed")
-            for w in wave:
-                if w.admitted:
-                    continue
-                self._alloc.free(w.shared_pages + w.private_pages)
-                w.item.handle._fail(e)
+        """Admit the wave's riders on this (the worker's) thread. A rider's
+        programs are launched without waiting (``_stage_prefill``,
+        ``_stage_transfer``); the next rider's are launched behind them; only
+        then is the earlier rider's first token fetched and its bookkeeping
+        done (``_stage_admit``), while the chip works on the later rider. The
+        last rider's fetch is the one wait the wave cannot hide.
 
-    def _stage_prefill(self, w: _AdmitWork) -> _AdmitWork:
-        """Stage 1: produce a contiguous row cache + first-token logits —
-        a full bucketed prefill on a prefix MISS, or gather-shared-pages +
-        one suffix pass on a HIT (the prefix compute skip)."""
+        A rider whose launch, fetch or bookkeeping raises is failed alone: its
+        pages go back, riders already admitted keep decoding, riders behind
+        it are admitted. The exception is a call that raised AFTER it consumed
+        the donated pool: no rider can be served from a deleted pool, so the
+        wave's unadmitted riders are failed and the error goes on to
+        ``_loop``'s boundary, which fails every live rider."""
+        in_flight: Optional[_AdmitWork] = None  # launched, its first token still on the device
+        for w in wave:
+            if not self._try_stage(self._launch, w, wave):
+                continue
+            if in_flight is not None:
+                tel.counter("serving.paged.launches_overlapped").add(1)
+                self._try_stage(self._stage_admit, in_flight, wave)
+            in_flight = w
+        if in_flight is not None:
+            self._try_stage(self._stage_admit, in_flight, wave)
+
+    def _try_stage(self, stage, w: _AdmitWork, wave: List[_AdmitWork]) -> bool:
+        """Run one stage of rider ``w``; on an exception fail ``w`` (False), or
+        the wave's unadmitted riders and re-raise if the pool went with it."""
+        try:
+            stage(w)
+            return True
+        except Exception as e:  # noqa: BLE001 - one rider's failure stays that rider's
+            log.exception("paged admission of request %s failed", w.item.request_id)
+            pool_gone = any(x.is_deleted() for x in jax.tree_util.tree_leaves(self._cache))
+            for r in (wave if pool_gone else [w]):
+                if not r.admitted and not r.item.handle.done():
+                    self._tables[r.slot, :] = TRASH_PAGE  # whatever _stage_admit had published
+                    self._alloc.free(r.shared_pages + r.private_pages)
+                    r.item.handle._fail(e)
+            if pool_gone:
+                raise
+            return False
+
+    def _launch(self, w: _AdmitWork) -> None:
+        self._stage_prefill(w)
+        self._stage_transfer(w)
+
+    def _stage_prefill(self, w: _AdmitWork) -> None:
+        """Stage 1, launched and not waited for: produce a contiguous row
+        cache + first-token logits — a full bucketed prefill on a prefix MISS,
+        or gather-shared-pages + one suffix pass on a HIT (the prefix compute
+        skip). Operands cross as NumPy values: no one-op program is run to
+        build them."""
         cfg = self._cfg
         item = w.item
         P = len(item.prompt)
         prefix_len = w.n_shared * self._ps
         # where a recurrent layer also keeps its state for the prefix cache; a
         # dense model's programs take neither it nor the snapshot below
-        snap = jnp.int32(w.snap_blocks * self._ps) if self._stateful else None
+        snap = np.int32(w.snap_blocks * self._ps) if self._stateful else None
         attrs = {"state_hit": w.state is not None} if self._stateful else {}
         with tel.span("serving.cb.prefill", request_id=item.request_id,
                       prompt_len=P, shared=prefix_len, **attrs):
+            suffix = item.prompt[prefix_len:]
+            T_b = min(-(-len(suffix) // 16) * 16, cfg.max_seq_len - prefix_len)
+            ids = np.zeros((1, T_b), np.int32)
+            ids[0, :len(suffix)] = suffix
             if w.n_shared == 0:
-                P_b = min(-(-P // 16) * 16, cfg.max_seq_len)
-                ids = jnp.asarray([item.prompt], jnp.int32)
-                padded = (jnp.pad(ids, ((0, 0), (0, P_b - P)))
-                          if P_b != P else ids)
-                row_cache, first = _prefill_fn(cfg, 1, P_b)(
-                    self._params, padded, jnp.int32(P), snap)
-                w.first_vec = first[0]
+                w.row_cache, w.first = _prefill_fn(cfg, 1, T_b)(
+                    self._params, ids, np.int32(P), snap)
             else:
                 table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
                 table[:w.n_shared] = w.shared_pages
-                # the pool object is swapped functionally by the transfer
-                # stage; shared pages are never rewritten, so reading a
-                # one-wave-stale pool binding here is still exact
                 row_cache = _paged_gather_fn(self._paged_cfg)(
-                    self._cache, jnp.asarray(table), jnp.int32(prefix_len),
-                    w.state)
-                suffix = item.prompt[prefix_len:]
-                T_suf = len(suffix)
-                T_b = min(-(-T_suf // 16) * 16, cfg.max_seq_len - prefix_len)
-                ids = jnp.asarray([suffix + [0] * (T_b - T_suf)], jnp.int32)
-                row_cache, w.first_vec = _suffix_prefill_fn(
-                    self._paged_cfg, T_b)(
-                    self._params, row_cache, ids, jnp.int32(prefix_len),
-                    jnp.int32(P), snap)
-        w.row_cache = row_cache
-        return w
+                    self._cache, table, np.int32(prefix_len), w.state)
+                w.row_cache, w.first = _suffix_prefill_fn(self._paged_cfg, T_b)(
+                    self._params, row_cache, ids, np.int32(prefix_len),
+                    np.int32(P), snap)
 
-    def _stage_transfer(self, w: _AdmitWork) -> _AdmitWork:
-        """Stage 2: scatter the row's PROMPT blocks into the request's
-        private pages (shared blocks stay untouched behind TRASH write
-        ids) and sample the first token. This is the page handoff — in the
-        disaggregated topology it is the only stage that touches the
-        decode pool."""
+    def _stage_transfer(self, w: _AdmitWork) -> None:
+        """Stage 2, launched and not waited for: scatter the row's PROMPT
+        blocks into the request's private pages (shared blocks stay untouched
+        behind TRASH write ids), write its recurrent state at its slot and
+        sample the first token. This is the page handoff, the only stage that
+        writes the decode pool; the pool is donated to it."""
         item = w.item
         P = len(item.prompt)
         with tel.span("serving.paged.transfer", request_id=item.request_id):
@@ -587,31 +609,33 @@ class PagedContinuousBatchingEngine:
             first_blk = w.n_shared
             last_blk = -(-P // self._ps)  # exclusive: block of the last token
             write_ids[first_blk:last_blk] = w.private_pages[:last_blk - first_blk]
-            pool, tok0, key2 = _paged_admit_fn(self._paged_cfg)(
-                self._cache, w.row_cache, jnp.asarray(write_ids),
-                jnp.int32(w.slot), w.first_vec,
-                jax.random.PRNGKey(item.seed), jnp.float32(item.temperature))
-            self._cache = pool
-            w.tok0 = int(np.asarray(tok0))  # fedlint: disable=host-sync forces transfer completion: one sync per admission, not per decode step
-            w.key2 = np.asarray(key2, np.uint32)
-        return w
+            self._cache, w.tok0, w.key2 = _paged_admit_fn(self._paged_cfg)(
+                self._cache, w.row_cache, write_ids, np.int32(w.slot), w.first,
+                np.uint32(item.seed & 0xFFFFFFFF), np.float32(item.temperature))
+            w.first = None
+            w.tok0.copy_to_host_async()
+            w.key2.copy_to_host_async()
 
-    def _stage_admit(self, w: _AdmitWork) -> _AdmitWork:
-        """Stage 3: host bookkeeping — publish the block table, mirrors,
-        and the slot; register the prompt's full chunks in the prefix
-        cache so the NEXT request with this system prompt shares pages."""
+    def _stage_admit(self, w: _AdmitWork) -> None:
+        """Stage 3: fetch the first token (the wait for this rider's chain),
+        then host bookkeeping — publish the block table, mirrors, and the
+        slot; register the prompt's full chunks in the prefix cache so the
+        NEXT request with this system prompt shares pages."""
         item = w.item
         b = w.slot
+        with tel.span("serving.paged.first_token_wait", request_id=item.request_id):
+            tok0 = int(np.asarray(w.tok0))  # fedlint: disable=host-sync one sync per admission, not per decode step, behind the next rider's launches
+            key2 = np.asarray(w.key2, np.uint32)
         with tel.span("serving.paged.admit", request_id=item.request_id):
             now_ns = time.perf_counter_ns()
             table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
             n_own = w.n_shared + len(w.private_pages)
             table[:w.n_shared] = w.shared_pages
             table[w.n_shared:n_own] = w.private_pages
-            self._tok[b] = w.tok0
+            self._tok[b] = tok0
             self._lengths[b] = len(item.prompt)
             self._temps[b] = item.temperature
-            self._keys[b] = w.key2
+            self._keys[b] = key2
             self._tables[b] = table
             ttft = self._note_first_token(item, now_ns, shared=w.n_shared * self._ps)
             self._observe_tenant_ttft(item.tenant, ttft)
@@ -629,11 +653,10 @@ class PagedContinuousBatchingEngine:
                         self._state_bytes)
             w.row_cache = None
             with self._lock:
-                self._slots[b] = _Active(item, w.budget, [w.tok0], now_ns,
+                self._slots[b] = _Active(item, w.budget, [tok0], now_ns,
                                          generated=1)
             w.admitted = True
             self._finish_if_done(b, now_ns)
-        return w
 
     def _note_first_token(self, item: _Pending, now_ns: int, shared: int) -> float:
         """The request's first token is on the host: its queue and admit

@@ -104,6 +104,14 @@ def _num_blocks(cfg: TransformerConfig) -> int:
     return cfg.max_seq_len // cfg.kv_page_size
 
 
+def _donate(argnum: int) -> Tuple[int, ...]:
+    """``donate_argnums`` for a program that takes the pool and returns it:
+    donating halves peak HBM for the biggest buffer in serving and spares the
+    program a copy of it. Asked for on the TPU only: on the CPU the tests call
+    these programs with arrays they go on to read."""
+    return (argnum,) if jax.default_backend() == "tpu" else ()
+
+
 def paged_pool_init(params, cfg: TransformerConfig, B: int):
     """The empty page-pool cache pytree: zeros in the shapes one decode
     step's apply gives its cache. The apply is only traced for its shapes:
@@ -139,12 +147,19 @@ def _paged_admit_fn(cfg: TransformerConfig):
     TRASH_PAGE, so duplicate scatter indices only ever clobber the trash page.
     Recurrent-state leaves (``STATE_LEAVES``) are written whole at the
     request's ``slot``. Leaves only the row has (its snapshot) stay behind.
-    The row arrives packed (``models/mamba.pack_state``)."""
+    The row arrives packed (``models/mamba.pack_state``); ``first_logits`` is
+    the prefill's ``[1, vocab]``; ``seed`` is the request's seed as a uint32,
+    from which the program makes ``jax.random.PRNGKey(seed)`` itself.
+
+    The pool is DONATED where the backend donates: the caller's binding is
+    dead once the call is made, and it rebinds to the returned pool. That
+    holds in the engine because everything that reads or writes the pool is
+    launched from one thread, in program order."""
     n_blocks = _num_blocks(cfg)
     ps = cfg.kv_page_size
 
     def build():
-        def run(pool, row_cache, write_ids, slot, first_logits, key, temp):
+        def run(pool, row_cache, write_ids, slot, first_logits, seed, temp):
             row_cache = unpack_state(cfg, row_cache)
 
             def insert(path, dst):
@@ -158,11 +173,12 @@ def _paged_admit_fn(cfg: TransformerConfig):
                 return dst.at[write_ids].set(pages.astype(dst.dtype))
 
             new_pool = jax.tree_util.tree_map_with_path(insert, pool)
-            key2, sub = jax.random.split(key)
-            tok0 = _sample(first_logits, sub, temp)
+            key2, sub = jax.random.split(jax.random.PRNGKey(seed))
+            tok0 = _sample(first_logits[0], sub, temp)
             return new_pool, tok0, key2
 
-        return jax.jit(track_compiles(run, name="paged_admit"))
+        return jax.jit(track_compiles(run, name="paged_admit"),
+                       donate_argnums=_donate(0))
 
     return _lru_get(("paged_admit", cfg), build)
 
@@ -222,7 +238,7 @@ def _suffix_prefill_fn(cfg: TransformerConfig, T_b: int):
                 snap_lens=(None if snap_total is None
                            else jnp.reshape(jnp.maximum(snap_total - prefix_len, 0), (1,))),
             )
-            first = logits[0, true_total - prefix_len - 1]
+            first = logits[:, true_total - prefix_len - 1]  # [1, vocab], as _prefill_fn's
             return pack_state(cfg, _rewind_cache(state["cache"], true_total)), first
 
         return jax.jit(track_compiles(run, name="paged_suffix_prefill"))
@@ -271,11 +287,8 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
             )
             return pool, tok, lengths, keys, toks.swapaxes(0, 1)  # [B, C]
 
-        # donate the pool (arg 1): halves peak HBM for the biggest buffer in
-        # serving; CPU has no donation, so gate to avoid warnings
-        donate = (1,) if jax.default_backend() == "tpu" else ()
         fn = jax.jit(track_compiles(run, name="paged_step"),
-                     donate_argnums=donate)
+                     donate_argnums=_donate(1))
         return devperf.instrument(fn, "paged_step")
 
     return _lru_get(("paged_step", cfg, B, C), build)

@@ -6,6 +6,7 @@ lengths and compile-once are tests/test_paged_kv.py's, at this file's
 geometry too.)"""
 
 import json
+import threading
 import urllib.request
 
 import jax
@@ -177,7 +178,11 @@ REQUEST_SPANS = (
     "serving.endpoint.predict", "serving.http.request", "serving.predict.encode",
     "serving.predict.wait", "serving.predict.decode_text", "serving.request.queue",
     "serving.request.admit", "serving.request.decode", "serving.cb.prefill",
-    "serving.paged.transfer", "serving.paged.admit")
+    "serving.paged.transfer", "serving.paged.first_token_wait", "serving.paged.admit")
+
+#: the per-request stages of an admission wave, in the order a rider passes them
+WAVE_STAGES = ("serving.cb.prefill", "serving.paged.transfer", "serving.paged.first_token_wait",
+               "serving.paged.admit")
 
 
 CALLER_ID = "cd" * 16
@@ -234,9 +239,7 @@ def test_every_span_of_a_request_carries_one_request_id(served_requests):
     for rid in ids:
         mine = [s for s in spans if (s.get("attrs") or {}).get("request_id") == rid]
         names = {s["name"] for s in mine}
-        assert set(REQUEST_SPANS) - {"serving.paged.transfer", "serving.paged.admit",
-                                     "serving.cb.prefill"} <= names, names
-        assert {"serving.cb.prefill", "serving.paged.transfer", "serving.paged.admit"} <= names
+        assert set(REQUEST_SPANS) <= names, names
     # a span that belongs to a request names it: none of these families is anonymous
     for s in spans:
         if s["name"] in REQUEST_SPANS:
@@ -299,13 +302,215 @@ def test_worker_loop_spans_tile_an_iteration(served_requests):
                                               "serving.cb.chunk.post"]
     parts_ns = sum(p["dur_ns"] for ch in chunks for p in by_parent[ch["seq"]])
     assert parts_ns >= 0.95 * sum(ch["dur_ns"] for ch in chunks)
-    # the stages of a wave run on the pipeline's threads, under the worker's wave
-    waves = [s for s in spans if s["name"] == "serving.paged.admit_wave"]
-    for name in ("serving.cb.prefill", "serving.paged.transfer", "serving.paged.admit"):
-        for st in (s for s in spans if s["name"] == name):
-            assert any(w["t0_ns"] <= st["t0_ns"] and
-                       st["t0_ns"] + st["dur_ns"] <= w["t0_ns"] + w["dur_ns"] for w in waves), st
+    # the stages of a wave run on the worker's own thread, as children of its wave
+    waves = {s["seq"]: s for s in spans if s["name"] == "serving.paged.admit_wave"}
+    assert waves and all(w["tid"] in worker for w in waves.values())
+    for name in WAVE_STAGES:
+        stages = [s for s in spans if s["name"] == name]
+        assert len(stages) == 6, name  # one a request
+        for st in stages:
+            w = waves[st["parent_seq"]]
+            assert st["tid"] == w["tid"], st
+            assert w["t0_ns"] <= st["t0_ns"] and st["t0_ns"] + st["dur_ns"] <= w["t0_ns"] + w["dur_ns"], st
     assert any(s["name"] == "serving.engine.idle" and s["tid"] in worker for s in spans)
+
+
+# -- the admission wave's schedule: one thread, launches ahead of fetches ------
+
+
+class _Wave:
+    """An engine whose worker is held at the door of its next admission while
+    ``burst`` submits, so the burst rides ONE wave (slots and pages allowing);
+    ``spans()`` / ``count()`` give what the registry saw since construction."""
+
+    def __init__(self, params, **kw):
+        opts = dict(num_slots=3, chunk=4, page_size=8)
+        opts.update(kw)
+        self.registry = tel.get_telemetry()
+        self._was = self.registry.enabled
+        self.registry.set_enabled(True)  # whatever an earlier file of this worker left it at
+        last = self.registry.snapshot()["spans"][-1:]
+        self._seq0 = last[0]["seq"] if last else 0
+        self._counters0 = dict(self.registry.snapshot()["counters"])
+        self.eng = PagedContinuousBatchingEngine(params, CFG, **opts)
+
+    def burst(self, requests):
+        gate, inner = threading.Event(), self.eng._admit_all
+
+        def held():
+            assert gate.wait(timeout=60)
+            inner()
+
+        self.eng._admit_all = held
+        try:
+            return [self.eng.submit(p, n, **kw) for p, n, kw in requests]
+        finally:
+            self.eng._admit_all = inner
+            gate.set()
+
+    def spans(self, name=None):
+        return [s for s in self.registry.snapshot()["spans"]
+                if s["seq"] > self._seq0 and name in (None, s["name"])]
+
+    def count(self, counter):
+        return self.registry.snapshot()["counters"].get(counter, 0) - self._counters0.get(counter, 0)
+
+    def close(self):
+        self.eng.shutdown()
+        self.registry.set_enabled(self._was)
+
+
+@pytest.fixture()
+def wave(params):
+    w = _Wave(params)
+    yield w
+    w.close()
+
+
+def _ref(params, prompt, n):
+    return np.asarray(generate(params, CFG, jnp.asarray([prompt], jnp.int32), n))[0].tolist()
+
+
+def test_wave_launches_the_next_rider_before_it_waits_for_the_one_before(wave):
+    handles = wave.burst([(_prompt(5 + 3 * i, 200 + i), 6, {}) for i in range(3)])
+    for h in handles:
+        h.result(timeout=120)
+    (w,) = wave.spans("serving.paged.admit_wave")
+    assert w["attrs"]["n"] == 3
+    at = {(s["name"], s["attrs"]["request_id"]): s["t0_ns"] for s in wave.spans() if s["name"] in WAVE_STAGES}
+    ids = [h.request_id for h in handles]
+    for rid in ids:  # a rider passes its four stages in order
+        assert [at[(name, rid)] for name in WAVE_STAGES] == sorted(at[(name, rid)] for name in WAVE_STAGES)
+    for earlier, later in zip(ids, ids[1:]):
+        # both programs of the later rider are launched before the engine waits for the earlier one's token
+        assert at[("serving.paged.transfer", later)] < at[("serving.paged.first_token_wait", earlier)]
+        assert at[("serving.paged.first_token_wait", earlier)] < at[("serving.paged.first_token_wait", later)]
+    assert wave.count("serving.paged.launches_overlapped") == 2
+
+
+def test_every_span_of_a_wave_is_the_workers_and_no_thread_outlives_it(wave):
+    seen = []
+    inner = wave.eng._stage_admit
+
+    def spy(w):
+        seen.append({t.name for t in threading.enumerate()})
+        inner(w)
+
+    wave.eng._stage_admit = spy
+    for h in wave.burst([(_prompt(4 + i, 210 + i), 5, {}) for i in range(3)]):
+        h.result(timeout=120)
+    (w,) = wave.spans("serving.paged.admit_wave")
+    staged = [s for s in wave.spans() if s["name"] in WAVE_STAGES]
+    assert len(staged) == 3 * len(WAVE_STAGES)
+    assert all(s["tid"] == w["tid"] == wave.eng._worker.ident and s["parent_seq"] == w["seq"] for s in staged)
+    names = set().union(*seen) | {t.name for t in threading.enumerate()}
+    assert len(seen) == 3 and not [n for n in names if n.startswith("paged_admit")], names
+
+
+def test_continuous_batching_imports_nothing_of_the_round_pipeline():
+    import ast
+    import inspect
+
+    from fedml_tpu.serving import continuous_batching
+
+    tree = ast.parse(inspect.getsource(continuous_batching))
+    froms = [n.module or "" for n in ast.walk(tree) if isinstance(n, ast.ImportFrom)]
+    plain = [a.name for n in ast.walk(tree) if isinstance(n, ast.Import) for a in n.names]
+    assert not [m for m in froms + plain if "pipeline" in m]
+    assert not hasattr(continuous_batching, "PipelinedExecutor")
+
+
+def test_launches_overlapped_is_riders_less_waves_over_a_burst(wave):
+    """Seven requests through three slots: the burst's first wave takes three,
+    the rest are admitted as slots free, in waves of whatever was free."""
+    handles = wave.burst([(_prompt(3 + i, 220 + i), 3 + 2 * i, {}) for i in range(7)])
+    assert [len(h.result(timeout=120)) for h in handles] == [3 + 2 * i for i in range(7)]
+    waves = wave.spans("serving.paged.admit_wave")
+    assert sum(w["attrs"]["n"] for w in waves) == wave.count("serving.cb.admissions") == 7
+    assert waves[0]["attrs"]["n"] == 3 and len(waves) >= 3
+    assert wave.count("serving.paged.launches_overlapped") == 7 - len(waves)
+
+
+@pytest.fixture(scope="module")
+def one_wave_of_three_kinds(params):
+    """One wave whose riders are a sharer of a resident 16-token prefix, an
+    unshared prompt, and a sampled sharer; what each was served."""
+    w = _Wave(params)
+    try:
+        system = _prompt(16, 230)
+        w.eng.generate(system + _prompt(3, 231), 4)  # leaves the prefix pages resident
+        riders = {"shared": (system + _prompt(6, 232), 9, {}),
+                  "unshared": (_prompt(21, 233), 7, {}),
+                  "shared_sampled": (system + _prompt(11, 234), 8, {"temperature": 0.9, "seed": 5})}
+        handles = w.burst(list(riders.values()))
+        served = {k: h.result(timeout=120) for k, h in zip(riders, handles)}
+        waves = w.spans("serving.paged.admit_wave")
+        shared = {s["attrs"]["request_id"]: s["attrs"]["shared"] for s in w.spans("serving.cb.prefill")}
+        return riders, served, [x["attrs"]["n"] for x in waves], [shared[h.request_id] for h in handles]
+    finally:
+        w.close()
+
+
+@pytest.mark.parametrize("kind", ["shared", "unshared", "shared_sampled"])
+def test_riders_of_one_wave_are_served_generates_tokens(one_wave_of_three_kinds, params, kind):
+    riders, served, wave_sizes, shared = one_wave_of_three_kinds
+    assert wave_sizes == [1, 3] and shared == [16, 0, 16]
+    prompt, n, kw = riders[kind]
+    want = generate(params, CFG, jnp.asarray([prompt], jnp.int32), n, temperature=kw.get("temperature", 0.0),
+                    key=jax.random.PRNGKey(kw.get("seed", 0)))
+    assert served[kind] == np.asarray(want)[0].tolist()
+
+
+@pytest.mark.parametrize("stage", ["_stage_prefill", "_stage_transfer", "_stage_admit"])
+def test_a_failure_in_rider_two_of_three_is_rider_twos_alone(wave, params, stage):
+    system = _prompt(16, 240)
+    wave.eng.generate(system + _prompt(2, 241), 3)  # rider two holds shared pages when it fails
+    prompts = [_prompt(9, 242), system + _prompt(5, 243), _prompt(13, 244)]
+    inner = getattr(wave.eng, stage)
+
+    def planted(w):
+        if w.item.prompt == prompts[1]:
+            raise RuntimeError("planted in rider two")
+        inner(w)
+
+    setattr(wave.eng, stage, planted)
+    handles = wave.burst([(p, 6, {}) for p in prompts])
+    assert handles[0].result(timeout=120) == _ref(params, prompts[0], 6)
+    assert handles[2].result(timeout=120) == _ref(params, prompts[2], 6)
+    with pytest.raises(RuntimeError, match="planted in rider two"):
+        handles[1].result(timeout=120)
+    assert [w["attrs"]["n"] for w in wave.spans("serving.paged.admit_wave")] == [1, 3]
+    # rider three was launched behind rider one whichever stage of rider two failed
+    assert wave.count("serving.paged.launches_overlapped") == (2 if stage == "_stage_admit" else 1)
+    leaks = wave.eng._alloc.check_leaks()
+    assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["state_leaked"] == [] and leaks["accounted"]
+    assert np.all(wave.eng._tables == 0) and wave.eng.stats()["slots_active"] == 0
+    setattr(wave.eng, stage, inner)
+    assert wave.eng.generate(prompts[1], 6) == _ref(params, prompts[1], 6)  # the engine serves on
+
+
+def test_a_failure_that_consumed_the_pool_fails_the_wave_and_the_live_riders(wave):
+    """What donation makes possible: the admit program raised after it took
+    the pool. Nobody can be served from a deleted pool, so nobody hangs: the
+    wave fails its unadmitted riders (two and three), the loop's boundary the
+    one already decoding (one: admitted once rider two was launched)."""
+    prompts = [_prompt(5 + i, 250 + i) for i in range(3)]
+    inner = wave.eng._stage_transfer
+
+    def consumed(w):
+        if w.item.prompt != prompts[2]:
+            return inner(w)
+        for leaf in jax.tree_util.tree_leaves(wave.eng._cache):
+            leaf.delete()
+        raise RuntimeError("planted after the pool was donated")
+
+    wave.eng._stage_transfer = consumed
+    handles = wave.burst([(p, 30, {}) for p in prompts])
+    for h in handles:
+        with pytest.raises(RuntimeError, match="planted after the pool was donated"):
+            h.result(timeout=120)
+    assert wave.count("serving.cb.admissions") == 1
+    assert wave.eng.stats()["slots_active"] == 0 and wave.eng._alloc.check_leaks()["accounted"]
 
 
 @pytest.mark.parametrize("label", ["prefill", "paged_step", "paged_admit",

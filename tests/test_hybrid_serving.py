@@ -129,6 +129,50 @@ def test_three_requests_sharing_two_pages_unprimed_the_third_is_a_state_hit(para
         cold.shutdown()
 
 
+def test_one_wave_of_a_snapshot_hit_an_unshared_and_a_snapshot_leaving_rider(params):
+    """Three riders launched back to back on the worker's thread, the pool
+    rebound by each one's transfer before the next one's gather reads it: the
+    rider that starts from a snapshot, the one that shares nothing and the one
+    that finds pages without a snapshot (and leaves one) are each served
+    ``generate()``'s tokens, which are the reference's."""
+    import threading
+
+    from fedml_tpu.train.llm.generation import generate
+
+    sys_a, sys_b = _toks(2 * PS, 3), _toks(PS, 4)
+    eng = _engine(params)
+    try:
+        eng.generate(sys_a + _toks(7, 47), 4)   # registers sys_a's pages
+        eng.generate(sys_a + _toks(19, 59), 4)  # finds them without a snapshot, leaves one
+        eng.generate(sys_b + _toks(5, 61), 4)   # registers sys_b's page: no snapshot there yet
+        riders = [sys_a + _toks(12, 52), _toks(23, 7), sys_b + _toks(9, 63)]
+        tel.reset()
+        gate, inner = threading.Event(), eng._admit_all
+
+        def held():  # the worker waits at the door until all three are queued: one wave
+            assert gate.wait(timeout=60)
+            inner()
+
+        eng._admit_all = held
+        handles = [eng.submit(r, 8) for r in riders]
+        eng._admit_all = inner
+        gate.set()
+        served = [h.result(timeout=300) for h in handles]
+        snap = tel.snapshot()
+        assert [s["attrs"]["n"] for s in snap["spans"] if s["name"] == "serving.paged.admit_wave"] == [3]
+        prefills = [s["attrs"] for s in snap["spans"] if s["name"] == "serving.cb.prefill"]
+        assert [(a["state_hit"], a["shared"]) for a in prefills] == [(True, 2 * PS), (False, 0), (False, 0)]
+        assert snap["counters"]["serving.paged.launches_overlapped"] == 2
+        assert snap["counters"]["serving.state.snapshots"] == 1  # the third rider's, at sys_b's boundary
+        for r, out in zip(riders, served):
+            assert out == [int(t) for t in generate(params, CFG, jnp.asarray([r], jnp.int32), 8)[0]]
+            assert _gap(params, r, out) < GAP_TOL
+        leaks = eng._alloc.check_leaks()
+        assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["state_leaked"] == [] and leaks["accounted"]
+    finally:
+        eng.shutdown()
+
+
 def test_a_slot_reused_after_release_starts_clean(params):
     eng = _engine(params, num_slots=1)
     try:

@@ -262,3 +262,63 @@ def test_engine_stats_and_gauges_have_kv_series(engine):
         assert k in st, k
     names = {g[0] for g in engine.prom_gauges()}
     assert {"serving_kv_pages", "serving_kv_prefix_nodes"} <= names
+
+
+# --- the admit program alone: donation, and the key it makes from the seed ---------
+
+
+def _admit_operands(params, seed=3, temp=0.8, slot=0):
+    """A pool, a prefilled row and the rest of ``_paged_admit_fn``'s operands
+    for the fixture's geometry, as the engine's ``_stage_transfer`` passes them."""
+    from fedml_tpu.serving import paged_kv
+    from fedml_tpu.train.llm.generation import _prefill_fn
+
+    pcfg = paged_kv.paged_config(CFG, page_size=16, num_pages=9)
+    pool = paged_kv.paged_pool_init(params, pcfg, 2)
+    ids = np.zeros((1, 32), np.int32)
+    ids[0, :19] = _prompt(19, 5)
+    row, first = _prefill_fn(CFG, 1, 32)(params, ids, np.int32(19))
+    write_ids = np.array([3, 4, 0, 0], np.int32)
+    return pcfg, (pool, row, write_ids, np.int32(slot), first, np.uint32(seed), np.float32(temp))
+
+
+def test_paged_admit_donates_the_pool_where_the_backend_donates(params, monkeypatch):
+    from fedml_tpu.serving import paged_kv
+    from fedml_tpu.train.llm import generation
+
+    pcfg, args = _admit_operands(params)
+    plain = paged_kv._paged_admit_fn(pcfg)  # this backend's: the CPU's is not donated
+    assert not any(a.donated for a in jax.tree_util.tree_leaves(plain.lower(*args).args_info))
+    want_pool, want_tok, want_key = plain(*args)
+    assert not any(x.is_deleted() for x in jax.tree_util.tree_leaves(args[0]))
+
+    monkeypatch.setattr(generation, "_COMPILED", {})  # build again, as on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    donating = paged_kv._paged_admit_fn(pcfg)
+    assert donating is not plain
+    lowered = donating.lower(*args)
+    pool_info, *rest = lowered.args_info[0]
+    assert all(a.donated for a in jax.tree_util.tree_leaves(pool_info))
+    assert not any(a.donated for a in jax.tree_util.tree_leaves(rest))
+    n_pool = len(jax.tree_util.tree_leaves(args[0]))
+    header = lowered.compile().as_text().split("\n", 1)[0]
+    if "input_output_alias" in header:  # a backend that aliases says so: every pool leaf, and nothing else
+        assert header.count("-alias)") == n_pool, header
+    got_pool, got_tok, got_key = donating(*args)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, got_pool, want_pool)
+    assert int(got_tok) == int(want_tok) and np.array_equal(got_key, want_key)
+
+
+@pytest.mark.parametrize("seed", [0, 42, 2**31 + 5, 2**32 - 1, 2**32 + 7, -1])
+def test_admit_program_makes_the_requests_key_from_its_seed(params, seed):
+    """``jax.random.PRNGKey(seed)`` is built inside the program from a uint32
+    (the engine passes ``seed & 0xFFFFFFFF``): the key the slot decodes on and
+    the first sampled token are what the eager key gave."""
+    from fedml_tpu.serving import paged_kv
+    from fedml_tpu.train.llm.generation import _sample
+
+    pcfg, args = _admit_operands(params, seed=seed & 0xFFFFFFFF)
+    _, tok0, key2 = paged_kv._paged_admit_fn(pcfg)(*args)
+    want_key2, sub = jax.random.split(jax.random.PRNGKey(seed))
+    assert np.array_equal(key2, want_key2)
+    assert int(tok0) == int(_sample(args[4][0], sub, args[6]))
