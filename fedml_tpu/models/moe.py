@@ -15,11 +15,24 @@ the all-to-all between the token-sharded and expert-sharded layouts.
 Load balancing: the Switch aux loss E * sum_e(fraction_e * prob_e), scaled
 by ``aux_loss_weight`` and returned alongside the output; trainers add the
 sown values to the task loss directly.
+
+``RoutedMoE`` beside it is the DROPLESS top-k layer the serving path runs
+(``TransformerConfig.moe_routed_experts``): sigmoid scores over the published
+router width in float32, the ``moe_top_k`` largest, gates normalised over the
+picks and scaled, a shared expert every token passes, no capacity and no
+dropped token. It is told which experts it holds (``moe_held_experts`` of rank
+``moe_rank``) and computes its own experts' part of the sum, under plain
+``jit``: what the absent experts would add is left out, and on one device the
+layer runs without its exchange. The picked (token, held expert) pairs are
+sorted by expert into row tiles and go through ``ops/grouped_matmul.py``
+three times (gate, up, down): an expert nobody picked is not read.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import logging
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -27,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.lax import with_sharding_constraint as _wsc
 from jax.sharding import PartitionSpec as P
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,3 +170,164 @@ class MoEMLP(nn.Module):
         return out.reshape(orig_shape), (cfg.aux_loss_weight * aux).astype(jnp.float32)
 # sharding rules for these params live in parallel/fsdp.py DEFAULT_RULES
 # (moe_mlp/w_* entries) — single source of truth
+
+
+# ---------------------------------------------------------------------------
+# the dropless top-k layer with a share of the experts (the serving path's)
+# ---------------------------------------------------------------------------
+
+#: the collection a routed layer sows its facts of the routing into, for
+#: programs that ask for it (``mutable=[..., ROUTING_STATS]``): ``load``, the
+#: live (token, held expert) pairs of this call by held expert, ``[held]``
+ROUTING_STATS = "moe_stats"
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def live_tokens(x: jnp.ndarray, seq_lens: Optional[jnp.ndarray],
+                cache_idx: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """``[B, T]`` bool: the tokens of a pass that are somebody's. A paged
+    decode step marks a freed slot ``cache_idx < 0``; a padded prefill says how
+    many of its T tokens are real (``seq_lens``). The others are routed to no
+    expert: they read no weights and count in no statistic."""
+    B, T, _ = x.shape
+    if cache_idx is not None:
+        return jnp.broadcast_to((cache_idx >= 0)[:, None], (B, T))
+    if seq_lens is not None:
+        return jnp.arange(T)[None, :] < seq_lens[:, None]
+    return jnp.ones((B, T), jnp.bool_)
+
+
+def routing_stats(sown, n_live) -> jnp.ndarray:
+    """What a program hands the host of one pass's routing, packed in ONE small
+    int32 array: ``[tokens_routed, local_picks, experts_hit, load_0 ..
+    load_{held-1}]`` from the ``ROUTING_STATS`` collection of that pass
+    (``sown``: one ``load`` a routed layer) and its live tokens. ``tokens_routed``
+    = live tokens x routed layers; ``local_picks`` the (token, held expert)
+    pairs computed; ``experts_hit`` the (layer, held expert) with at least one."""
+    loads = jnp.stack(jax.tree_util.tree_leaves(sown))  # [routed layers, held]
+    head = jnp.stack([jnp.asarray(n_live, jnp.int32) * loads.shape[0], jnp.sum(loads),
+                      jnp.sum((loads > 0).astype(jnp.int32))])
+    return jnp.concatenate([head, jnp.sum(loads, axis=0)]).astype(jnp.int32)
+
+
+def route(router_logits: jnp.ndarray, top_k: int, scaling: float, norm_topk: bool):
+    """``(experts [N, k] int32, gates [N, k] f32)`` from logits ``[N, E]``:
+    sigmoid scores, the k largest, gates ``scaling * s_e / (sum of the picked
+    s + 1e-20)`` (``norm_topk``) or ``scaling * s_e``."""
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    top, experts = jax.lax.top_k(scores, top_k)
+    if norm_topk:
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), top * scaling
+
+
+def row_tile(n_tokens: int, top_k: int, n_routed: int) -> int:
+    """Rows of one tile of the sorted pairs, from shapes: twice what a held
+    expert expects (``n_tokens * top_k / n_routed``), a power of two within
+    16..128. 16 for a decode step (one or two tokens an expert: the call is
+    bound by the weights it reads), 128 for a long prefill (MXU rows)."""
+    want = 2.0 * n_tokens * top_k / max(n_routed, 1)
+    tm = 16
+    while tm < 128 and tm < want:
+        tm *= 2
+    return tm
+
+
+def sort_pairs(experts: jnp.ndarray, live: jnp.ndarray, first: int, held: int, tm: int):
+    """Lay the live (token, held expert) pairs out by expert in tiles of ``tm``
+    rows. ``experts [N, k]`` are ids over the router's width; this device
+    holds ``first .. first + held - 1``. Returns
+
+      row_token  [M]    the token whose activations go in each row (0 in padding)
+      pair_row   [N, k] the row of each pair (anything where ``mine`` is False)
+      mine       [N, k] the pair is live and its expert is held here
+      tile_group [M/tm] the held expert (0-based) of each live tile
+      n_live     [1]    tiles that hold rows
+      load       [held] pairs by held expert
+
+    ``M = N * min(k, held)`` rounded up to tiles plus one tile's padding a held
+    expert: every pair has a row whatever the imbalance (no capacity)."""
+    N, k = experts.shape
+    local = experts - first
+    mine = jnp.logical_and(jnp.logical_and(local >= 0, local < held), live[:, None])
+    flat = jnp.where(mine, local, held).reshape(-1)                      # ``held`` = not here
+    load = jnp.sum(jax.nn.one_hot(flat, held + 1, dtype=jnp.int32), axis=0)[:held]
+    tiles_of = -(-load // tm)
+    tile_end = jnp.cumsum(tiles_of)
+    row0 = (tile_end - tiles_of) * tm                                     # first row of each group
+    pair0 = jnp.cumsum(load) - load                                       # first sorted pair of each group
+    n_rows = -(-(N * min(k, held)) // tm) * tm + held * tm
+    order = jnp.argsort(flat, stable=True)                               # pairs by expert, absent last
+    e_sorted = flat[order]
+    here = e_sorted < held
+    g = jnp.minimum(e_sorted, held - 1)
+    dest = jnp.where(here, row0[g] + jnp.arange(N * k) - pair0[g], n_rows)  # n_rows: dropped
+    row_token = jnp.zeros((n_rows,), jnp.int32).at[dest].set((order // k).astype(jnp.int32), mode="drop")
+    pair_row = jnp.zeros((N * k,), jnp.int32).at[order].set(jnp.minimum(dest, n_rows - 1).astype(jnp.int32))
+    tile_group = jnp.minimum(jnp.searchsorted(tile_end, jnp.arange(n_rows // tm), side="right"), held - 1)
+    return (row_token, pair_row.reshape(N, k), mine, tile_group.astype(jnp.int32),
+            tile_end[-1:].astype(jnp.int32), load)
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_matmul_impl(platform: str, d_model: int, d_ff: int, tm: int, dtype: str):
+    """Which formulation the experts' matmuls run, logged once per distinct
+    case: ``ops.grouped_matmul.grouped_matmul`` (compiled on the TPU wherever
+    its blocks tile, interpreted on the CPU at any shape) and the gathered
+    einsum for a TPU shape the kernel cannot tile. Decided here, from shapes,
+    before anything runs."""
+    from ..ops import grouped_matmul as gm
+
+    shape = f"d_model={d_model} d_ff={d_ff} row_tile={tm} dtype={dtype}"
+    if platform == "tpu" and not gm.tiles(d_model, d_ff, tm, dtype):
+        log.warning("routed experts -> gathered-einsum formulation: the kernel cannot tile %s", shape)
+        return gm.grouped_matmul_reference
+    log.info("routed experts -> pallas grouped matmul (platform=%s, %s)", platform, shape)
+    return gm.grouped_matmul
+
+
+class RoutedMoE(nn.Module):
+    """The routed feed-forward of a ``TransformerConfig`` (see the module's
+    header): ``y = shared(x) + sum over the picks held here of g_e SwiGLU_e(x)``.
+    ``live [B, T]`` says which tokens are somebody's (``live_tokens``)."""
+
+    cfg: Any  # TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, live: jnp.ndarray) -> jnp.ndarray:
+        from .transformer import MLP
+
+        cfg = self.cfg
+        D, F, E, k = cfg.d_model, cfg.moe_d_ff, cfg.moe_routed_experts, cfg.moe_top_k
+        held = cfg.moe_held_experts or E
+        first = cfg.moe_rank * held
+        if first + held > E or k > E:
+            raise ValueError(f"rank {cfg.moe_rank} x {held} held experts and top-{k} do not fit {E} routed experts")
+        tokens = x.reshape(-1, D)
+        N = tokens.shape[0]
+        router = self.param("router", nn.initializers.lecun_normal(), (D, E), jnp.float32)
+        w_gate = self.param("w_gate", nn.initializers.lecun_normal(), (held, D, F), jnp.float32)
+        w_up = self.param("w_up", nn.initializers.lecun_normal(), (held, D, F), jnp.float32)
+        w_down = self.param("w_down", nn.initializers.lecun_normal(), (held, F, D), jnp.float32)
+
+        # the router keeps its published width and its picks, in float32
+        logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32), precision=HIGHEST)
+        experts, gates = route(logits, k, cfg.moe_routed_scaling, cfg.moe_norm_topk)
+        tm = row_tile(N, k, E)
+        row_token, pair_row, mine, tile_group, n_live, load = sort_pairs(
+            experts, live.reshape(-1), first, held, tm)
+        self.sow(ROUTING_STATS, "load", load)
+
+        matmul = _grouped_matmul_impl(jax.default_backend(), D, F, tm, jnp.dtype(cfg.dtype).name)
+        with jax.named_scope("moe_experts"):
+            rows = tokens[row_token].astype(cfg.dtype)                                    # [M, D]
+            gate = matmul(rows, w_gate.astype(cfg.dtype), tile_group, n_live, tm=tm)
+            up = matmul(rows, w_up.astype(cfg.dtype), tile_group, n_live, tm=tm)
+            out_rows = matmul(nn.silu(gate) * up, w_down.astype(cfg.dtype), tile_group, n_live, tm=tm)
+            # rows of tiles past the live ones are unspecified: read only what was laid out
+            picked = jnp.where(mine[..., None], out_rows[pair_row].astype(jnp.float32), 0.0)  # [N, k, D]
+            routed = jnp.sum(picked * gates[..., None], axis=1).astype(cfg.dtype)
+        y = routed.reshape(x.shape)
+        if cfg.moe_shared_experts > 0:
+            y = y + MLP(cfg, d_ff=F * cfg.moe_shared_experts, name="shared")(x)
+        return y

@@ -80,6 +80,37 @@ class TransformerConfig:
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0        # 0 = ceil(d_model / 16)
+    # Latent attention (a layer of kind "mla", models/mla.py): queries through
+    # a rank-``q_lora_rank`` bottleneck, keys and values expanded per head from
+    # ONE ``kv_lora_rank``-wide latent a token plus one rotary key of
+    # ``qk_rope_head_dim`` shared by all heads; what is cached is the two.
+    # Query/key width (nope + rope) and value width are their own values:
+    # ``head_dim`` below is the other kinds'.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The feed-forward's kind a layer: the first ``first_k_dense_replace``
+    # layers keep the dense SwiGLU of ``d_ff``; with ``moe_routed_experts > 0``
+    # the layers after them are dropless top-k routed layers (models/moe.py,
+    # ``RoutedMoE``): a router ``moe_routed_experts`` wide (the PUBLISHED
+    # count), ``moe_top_k`` picks a token, experts of width ``moe_d_ff``,
+    # ``moe_shared_experts`` of that width every token passes. This device
+    # holds ``moe_held_experts`` of them (0 = all), those of rank ``moe_rank``:
+    # experts ``rank * held .. rank * held + held - 1``.
+    first_k_dense_replace: int = 0
+    moe_routed_experts: int = 0
+    moe_held_experts: int = 0
+    moe_rank: int = 0
+    moe_top_k: int = 1
+    moe_d_ff: int = 0
+    moe_shared_experts: int = 0
+    moe_routed_scaling: float = 1.0
+    moe_norm_topk: bool = True
+    # an RMSNorm on each sublayer's OUTPUT before the residual add, beside the
+    # one on its input: x + N(mixer(N(x))), then h + N(ffn(N(h)))
+    sandwich_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -87,6 +118,32 @@ class TransformerConfig:
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else "attention"
+
+    def ffn_kind(self, i: int) -> str:
+        """"routed" or "dense" (the Switch layer of ``moe_experts`` is the
+        dense kind's training-only variant, chosen inside the block)."""
+        return "routed" if self.moe_routed_experts > 0 and i >= self.first_k_dense_replace else "dense"
+
+    @property
+    def routed_layers(self) -> int:
+        return sum(1 for i in range(self.n_layers) if self.ffn_kind(i) == "routed")
+
+    @property
+    def latent_layers(self) -> int:
+        return sum(1 for k in self.layer_pattern if k == "mla")
+
+    @property
+    def latent_width(self) -> int:
+        """Values one token leaves in one latent layer's cache."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row_width(self) -> int:
+        """Columns of a row of the latent leaf: ``latent_width`` filled up with
+        zeros to whole 128-lane tiles (576 -> 640). The TPU holds a 576-wide
+        row in 640 lanes anyway (XLA's tiled HBM layout), and a page can only
+        be DMA'd by whole tiles: the padding is in the shape, not beside it."""
+        return -(-self.latent_width // 128) * 128
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -121,7 +178,7 @@ class TransformerConfig:
         return cls(**base)
 
 
-LAYER_KINDS = ("attention", "mamba")
+LAYER_KINDS = ("attention", "mamba", "mla")
 
 
 def hybrid_pattern(n_layers: int, attn_period: int, attn_offset: int) -> Tuple[str, ...]:
@@ -421,18 +478,21 @@ class Attention(nn.Module):
 
 class MLP(nn.Module):
     cfg: TransformerConfig
+    d_ff: int = 0  # 0 = cfg.d_ff
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         cfg = self.cfg
-        gate = LoRALinear(cfg.d_ff, cfg, name="gate_proj")(x)
-        up = LoRALinear(cfg.d_ff, cfg, name="up_proj")(x)
+        d_ff = self.d_ff or cfg.d_ff
+        gate = LoRALinear(d_ff, cfg, name="gate_proj")(x)
+        up = LoRALinear(d_ff, cfg, name="up_proj")(x)
         return LoRALinear(cfg.d_model, cfg, name="down_proj")(nn.silu(gate) * up)
 
 
 class Block(nn.Module):
     cfg: TransformerConfig
     kind: str = "attention"  # the mixer before the feed-forward: LAYER_KINDS
+    ffn: str = "dense"       # the feed-forward: TransformerConfig.ffn_kind
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, positions: jnp.ndarray,
@@ -444,12 +504,27 @@ class Block(nn.Module):
         if self.kind == "mamba":
             from .mamba import MambaMixer
 
-            x = x + MambaMixer(cfg, name="mamba")(
+            mixed = MambaMixer(cfg, name="mamba")(
                 RMSNorm(cfg.norm_eps, name="mamba_norm")(x), seq_lens, snap_lens, cache_idx)
+        elif self.kind == "mla":
+            from .mla import LatentAttention
+
+            mixed = LatentAttention(cfg, name="attn")(
+                RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, cache_idx, block_tables)
         else:
-            x = x + Attention(cfg, name="attn")(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, cache_idx, block_tables)
+            mixed = Attention(cfg, name="attn")(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, cache_idx, block_tables)
+        if cfg.sandwich_norm:
+            mixed = RMSNorm(cfg.norm_eps, name="mixer_out_norm")(mixed)
+        x = x + mixed
         h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
-        if cfg.moe_experts > 0:
+        if self.ffn == "routed":
+            from .moe import RoutedMoE, live_tokens
+
+            y = RoutedMoE(cfg, name="moe")(h, live_tokens(h, seq_lens, cache_idx))
+            if cfg.sandwich_norm:
+                y = RMSNorm(cfg.norm_eps, name="mlp_out_norm")(y)
+            x = x + y
+        elif cfg.moe_experts > 0:
             from .moe import MoEConfig, MoEMLP
 
             moe_cfg = MoEConfig(
@@ -466,7 +541,10 @@ class Block(nn.Module):
             self.sow("losses", "moe_aux", aux)
             x = x + y
         else:
-            x = x + MLP(cfg, name="mlp")(h)
+            y = MLP(cfg, name="mlp")(h)
+            if cfg.sandwich_norm:
+                y = RMSNorm(cfg.norm_eps, name="mlp_out_norm")(y)
+            x = x + y
         return x
 
 
@@ -505,7 +583,7 @@ class TransformerLM(nn.Module):
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             block = nn.remat(Block, static_argnums=(), policy=policy)
         for i in range(cfg.n_layers):
-            x = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(
+            x = block(cfg, cfg.layer_kind(i), cfg.ffn_kind(i), name=f"layer_{i}")(
                 x, positions, cache_idx, block_tables, seq_lens, snap_lens)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         if cfg.tie_embeddings:

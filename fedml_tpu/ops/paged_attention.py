@@ -23,6 +23,13 @@ length a layer, whatever is live. This kernel reads each LIVE page once:
 * online softmax across blocks: bf16 (input dtype) operands, f32 scores,
   softmax statistics and accumulation, as in ``ops/flash_attention.py``.
 
+``paged_latent_attention`` is the same walk for a layer of latent attention
+(models/mla.py) in its absorbed form: ONE pool ``[n_pages, page_size, W]`` a
+layer (``W``: the latent and the rotary key, zero columns up to whole lanes),
+every query head against the same row, whose first ``r`` columns are also the
+value. A page is DMA'd once and used for both
+contractions; there is no kv-head mask because there is one "head".
+
 Rows of a VMEM block that no copy of this row has filled (the dead pages of
 a row's last block) hold what an earlier block left there, and at the very
 start zeros: their probabilities are exactly 0, so they add 0 as long as the
@@ -196,3 +203,130 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, lengths):
     logits = jnp.where(valid[:, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhk,bkhd->bhd", probs, v)
+
+
+# -- latent pages: one pool, the key's first ``rank`` columns are the value -----
+
+# tokens of one VMEM block of latents: 512 rows of 640 lanes at bf16 are 640 KB a slot
+LATENT_BLOCK_TOKENS = 512
+
+
+def latent_tiles(rank: int, width: int, page_size: int, dtype) -> bool:
+    """The compiled latent kernel's shape rule: the latent part (the value: a
+    slice of the key) and the whole row fill whole 128-lane vregs (576 = 512 +
+    64 does not: Mosaic cannot slice a page out of a 576-wide pool, which the
+    TPU holds 640 wide anyway, so the model fills the row up with zeros), and
+    a page is whole sublane tiles of its dtype. Interpret mode (the CPU) takes
+    any shape."""
+    sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return rank % 128 == 0 and width % 128 == 0 and page_size % sublanes == 0
+
+
+def _latent_kernel(len_ref, bt_ref, q_ref, c_hbm, o_ref, cbuf, sem, *,
+                   page_size: int, n_blocks: int, ppb: int, rank: int, scale: float):
+    b = pl.program_id(0)
+    H = q_ref.shape[1]
+    TB = ppb * page_size  # tokens of one block
+    length = len_ref[b]
+    n_pages = pl.cdiv(length, page_size)
+    n_blk = pl.cdiv(n_pages, ppb)
+
+    @pl.when(b == 0)
+    def _():
+        # stale rows are values too: they must be finite
+        cbuf[...] = jnp.zeros_like(cbuf)
+
+    def page_copy(blk, slot, i):
+        page = bt_ref[b * n_blocks + blk * ppb + i]
+        return pltpu.make_async_copy(c_hbm.at[page], cbuf.at[slot, pl.ds(i * page_size, page_size)],
+                                     sem.at[slot])
+
+    def for_live_pages(blk, slot, act):
+        for i in range(ppb):
+            @pl.when(blk * ppb + i < n_pages)
+            def _():
+                act(page_copy(blk, slot, i))
+
+    @pl.when(n_blk > 0)
+    def _():
+        for_live_pages(0, 0, lambda c: c.start())
+
+    q = q_ref[0]  # [H, W]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (H, TB), 1)
+
+    def body(blk, carry):
+        m, l, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blk)
+        def _():
+            for_live_pages(blk + 1, 1 - slot, lambda c: c.start())
+
+        for_live_pages(blk, slot, lambda c: c.wait())
+        kv = cbuf[slot]  # [TB, W]
+        s = _dot_nt(q, kv) * scale  # the filling columns are zeros on both sides
+        s = jnp.where(tok < length - blk * TB, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        acc_new = acc * corr + _dot_nn(p.astype(kv.dtype), kv[:, :rank])
+        return m_new, l_new, acc_new
+
+    m = jnp.full((H, 1), NEG_INF, jnp.float32)
+    l = jnp.zeros((H, 1), jnp.float32)
+    acc = jnp.zeros((H, rank), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m, l, acc))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+
+
+def paged_latent_attention(q, pool, block_tables, lengths, *, rank: int, scale: float):
+    """q ``[B, n_heads, W]`` (the absorbed query, the rotated rotary query
+    and zeros, of one token a row); pool ``[n_pages, page_size, W]``;
+    block_tables and lengths as ``paged_attention``'s. Row b's head i
+    scores ``q[b, i] . pool_row * scale`` over its positions ``< lengths[b]``
+    and returns the softmax-weighted sum of the rows' first ``rank`` columns:
+    ``[B, n_heads, rank]`` in q's dtype, zeros for a row of length 0."""
+    return _paged_latent_attention(q, pool, block_tables, lengths, rank=rank, scale=scale,
+                                   interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def _paged_latent_attention(q, pool, block_tables, lengths, *, rank: int, scale: float, interpret: bool):
+    B, H, W = q.shape
+    n_pages, ps, _ = pool.shape
+    n_blocks = block_tables.shape[1]
+    ppb = max(1, min(n_blocks, LATENT_BLOCK_TOKENS // ps))
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, n_blocks * ps)
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, page_size=ps, n_blocks=n_blocks, ppb=ppb, rank=rank, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, H, rank), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, ppb * ps, W), pool.dtype), pltpu.SemaphoreType.DMA((2,))],
+        ),
+        compiler_params=_grid("arbitrary"),  # rows in order: the buffer is zeroed by the first and kept
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(lengths, block_tables.astype(jnp.int32).reshape(-1), q, pool)
+
+
+def paged_latent_attention_reference(q, pool, block_tables, lengths, *, rank: int, scale: float):
+    """The plain formulation, same arguments and result: gather each row's
+    whole block table into logical order, mask the positions at or past the
+    row's length."""
+    B, H, W = q.shape
+    ps = pool.shape[1]
+    S = block_tables.shape[1] * ps
+    rows = pool[block_tables].reshape(B, S, W)
+    logits = jnp.einsum("bhw,bsw->bhs", q, rows).astype(jnp.float32) * scale
+    valid = jnp.arange(S)[None, :] < lengths[:, None]
+    logits = jnp.where(valid[:, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhs,bsr->bhr", probs, rows[..., :rank])

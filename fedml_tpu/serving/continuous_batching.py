@@ -32,6 +32,11 @@ hold it to, token for token. What the engine does:
   per-slot state in the same cache pytree as the page pool, and its prefix
   hits start from state snapshots the trie holds, at most ``state_snapshots``
   of them (see serving/paged_kv.py's header);
+- a model with latent-attention layers keeps ONE latent leaf a layer in the
+  pool (``models/mla.py``) where attention layers keep K and V; a model with
+  routed expert layers (``models/moe.RoutedMoE``) has its programs hand back
+  the routing of each pass packed in one small array, which becomes the
+  ``serving.moe.*`` counters and span attributes;
 - an optional :class:`AdmissionController` gates the front door: submit-time
   token budgets + shed, dequeue-time weighted fair queueing + SLO-pressure
   deferral (serving/admission.py).
@@ -86,6 +91,16 @@ from .paged_kv import (
 )
 
 log = logging.getLogger(__name__)
+
+#: decode chunks whose expert loads the ``serving.moe.load_imbalance`` gauge sums
+MOE_GAUGE_CHUNKS = 64
+
+
+def _gauge(name: str, value: float) -> None:
+    store = tsdb.active()
+    if store is not None:
+        store.record_gauge(name, value)
+
 
 class RequestHandle:
     """Future for one submitted request. ``result()`` blocks for the full
@@ -164,6 +179,8 @@ class _AdmitWork:
     first: object = None      # [1, vocab] logits the first token is sampled from
     tok0: object = None       # on the device from the transfer until _stage_admit fetches it
     key2: object = None       # likewise
+    routing: object = None    # likewise: the prefill's packed routing (models with routed layers)
+    prefill_span: object = None  # gets the routing's attributes once they are on the host
     admitted: bool = False
 
 
@@ -206,6 +223,15 @@ class PagedContinuousBatchingEngine:
         self._n_blocks = base.max_seq_len // self._ps
         self._stateful = base.has_recurrent_state
         self._state_bytes = mamba_state_bytes(base) if self._stateful else 0
+        # bytes one live token holds in the latent layers' pages
+        self._latent_token_bytes = (base.latent_layers * base.latent_width
+                                    * jnp.dtype(base.dtype).itemsize)
+        # routed layers: [tokens_routed, local_picks, experts_hit] and the pairs by
+        # held expert since the engine started; the last chunks' loads for the gauge
+        self._routed = bool(base.routed_layers)
+        self._moe_totals = np.zeros((3,), np.int64)
+        self._moe_load = np.zeros((base.moe_held_experts or base.moe_routed_experts,), np.int64)
+        self._moe_recent: "collections.deque" = collections.deque(maxlen=MOE_GAUGE_CHUNKS)
         self._alloc = PagedKVAllocator(
             num_pages, page_size, watermark_frac=watermark_frac,
             state_budget_bytes=int(state_snapshots) * self._state_bytes)
@@ -326,9 +352,7 @@ class PagedContinuousBatchingEngine:
         """Gauge snapshot for /metrics and /statusz (cheap; lock-guarded)."""
         with self._lock:
             active = sum(1 for s in self._slots if s is not None)
-            live = int(sum(int(self._lengths[i])
-                           for i, s in enumerate(self._slots)
-                           if s is not None))
+            live = self._live_tokens_locked()
             out = {
                 "slots_total": self._B,
                 "slots_active": active,
@@ -348,9 +372,21 @@ class PagedContinuousBatchingEngine:
             # pages per live token (multiply by page bytes for bytes/token)
             "kv_pages_per_token": pages_used / live if live else 0.0,
         })
+        if self._latent_token_bytes:
+            out["kv_latent_bytes_live"] = live * self._latent_token_bytes
+        if self._routed:
+            with self._lock:
+                out.update(moe_tokens_routed=int(self._moe_totals[0]),
+                           moe_local_picks=int(self._moe_totals[1]),
+                           moe_experts_hit=int(self._moe_totals[2]),
+                           moe_expert_load=[int(x) for x in self._moe_load])
         if self._admission is not None:
             out["admission"] = self._admission.stats()
         return out
+
+    def _live_tokens_locked(self) -> int:
+        """Tokens the live slots hold in the cache (caller holds the engine lock)."""
+        return int(sum(int(self._lengths[i]) for i, s in enumerate(self._slots) if s is not None))
 
     def prom_gauges(self) -> list:
         """(name, labels, value) ride-along triples for /metrics."""
@@ -367,6 +403,15 @@ class PagedContinuousBatchingEngine:
         if self._stateful:
             out.append(("serving_state_snapshot_bytes", None,
                         float(st["state_snapshot_bytes"])))
+        if self._latent_token_bytes:
+            with self._lock:
+                live = self._live_tokens_locked()
+            out.append(("serving_kv_latent_bytes_live", None, float(live * self._latent_token_bytes)))
+        if self._routed:
+            with self._lock:
+                recent = np.sum(self._moe_recent, axis=0) if self._moe_recent else np.zeros((1,))
+            if recent.sum() > 0:
+                out.append(("serving_moe_load_imbalance", None, float(recent.max() / recent.mean())))
         with self._lock:
             tenants = [(t, sorted(dq)) for t, dq in self._tenant_ttft.items()
                        if dq]
@@ -579,22 +624,25 @@ class PagedContinuousBatchingEngine:
         snap = np.int32(w.snap_blocks * self._ps) if self._stateful else None
         attrs = {"state_hit": w.state is not None} if self._stateful else {}
         with tel.span("serving.cb.prefill", request_id=item.request_id,
-                      prompt_len=P, shared=prefix_len, **attrs):
+                      prompt_len=P, shared=prefix_len, **attrs) as w.prefill_span:
             suffix = item.prompt[prefix_len:]
             T_b = min(-(-len(suffix) // 16) * 16, cfg.max_seq_len - prefix_len)
             ids = np.zeros((1, T_b), np.int32)
             ids[0, :len(suffix)] = suffix
             if w.n_shared == 0:
-                w.row_cache, w.first = _prefill_fn(cfg, 1, T_b)(
-                    self._params, ids, np.int32(P), snap)
+                out = _prefill_fn(cfg, 1, T_b)(self._params, ids, np.int32(P), snap)
             else:
                 table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
                 table[:w.n_shared] = w.shared_pages
                 row_cache = _paged_gather_fn(self._paged_cfg)(
                     self._cache, table, np.int32(prefix_len), w.state)
-                w.row_cache, w.first = _suffix_prefill_fn(self._paged_cfg, T_b)(
+                out = _suffix_prefill_fn(self._paged_cfg, T_b)(
                     self._params, row_cache, ids, np.int32(prefix_len),
                     np.int32(P), snap)
+            w.row_cache, w.first = out[:2]
+            if self._routed:
+                w.routing = out[2]
+                w.routing.copy_to_host_async()
 
     def _stage_transfer(self, w: _AdmitWork) -> None:
         """Stage 2, launched and not waited for: scatter the row's PROMPT
@@ -626,6 +674,10 @@ class PagedContinuousBatchingEngine:
         with tel.span("serving.paged.first_token_wait", request_id=item.request_id):
             tok0 = int(np.asarray(w.tok0))  # fedlint: disable=host-sync one sync per admission, not per decode step, behind the next rider's launches
             key2 = np.asarray(w.key2, np.uint32)
+            if w.routing is not None:  # the prefill ran before the admit program: already here
+                self._note_routing(np.asarray(w.routing), getattr(w.prefill_span, "attrs", None),
+                                   ("local_picks", "experts_hit"))
+                w.routing = w.prefill_span = None
         with tel.span("serving.paged.admit", request_id=item.request_id):
             now_ns = time.perf_counter_ns()
             table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
@@ -687,9 +739,11 @@ class PagedContinuousBatchingEngine:
         attrs = {"pages": int((-(-lens // self._ps)).sum())}
         if self._stateful:  # live slots whose recurrent state the step updates
             attrs["state_slots"] = n_live
-        with tel.span("serving.cb.chunk", slots=n_live, **attrs):
+        if self._latent_token_bytes:
+            _gauge("serving.kv.latent_bytes_live", float((lens - 1).sum() * self._latent_token_bytes))
+        with tel.span("serving.cb.chunk", slots=n_live, **attrs) as chunk_span:
             with tel.timed("serving.cb.chunk.dispatch") as dispatch:
-                cache, tok, lengths, keys, toks = _paged_step_fn(
+                cache, tok, lengths, keys, toks, *routing = _paged_step_fn(
                     self._paged_cfg, self._B, self._C)(
                     self._params,
                     self._cache,
@@ -714,6 +768,14 @@ class PagedContinuousBatchingEngine:
                 self._keys = np.array(keys, np.uint32)
                 now_ns = time.perf_counter_ns()
                 tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
+                if routing:
+                    load = self._note_routing(np.asarray(routing[0]), getattr(chunk_span, "attrs", None),
+                                              ("tokens_routed", "local_picks", "experts_hit"))
+                    with self._lock:
+                        self._moe_recent.append(load)
+                        recent = np.sum(self._moe_recent, axis=0)
+                    if recent.sum() > 0:
+                        _gauge("serving.moe.load_imbalance", float(recent.max() / recent.mean()))
                 for b in range(self._B):
                     with self._lock:
                         s = self._slots[b]
@@ -728,6 +790,22 @@ class PagedContinuousBatchingEngine:
                         if len(s.tokens) >= s.budget:
                             break
                     self._finish_if_done(b, now_ns)
+
+    def _note_routing(self, packed: np.ndarray, span_attrs: Optional[dict], names: Tuple[str, ...]) -> np.ndarray:
+        """One pass's packed routing (``models/moe.routing_stats``) into the
+        ``serving.moe.*`` counters, the engine's totals and, under ``names``,
+        the attributes of the span that covered the pass. Returns the pairs by
+        held expert."""
+        head = dict(zip(("tokens_routed", "local_picks", "experts_hit"), (int(x) for x in packed[:3])))
+        tel.counter("serving.moe.tokens_routed").add(head["tokens_routed"])
+        tel.counter("serving.moe.local_picks").add(head["local_picks"])
+        tel.counter("serving.moe.experts_hit").add(head["experts_hit"])
+        if span_attrs is not None:
+            span_attrs.update({k: head[k] for k in names})
+        with self._lock:
+            self._moe_totals += packed[:3]
+            self._moe_load += packed[3:]
+        return packed[3:].astype(np.int64)
 
     def _finish_if_done(self, b: int, now_ns: int) -> bool:
         """Free slot ``b`` and its pages if its request hit EOS or its token

@@ -68,7 +68,9 @@ from ..train.llm.generation import (
     _leaf_at,
     _leaf_name,
     _lru_get,
+    _mutable,
     _rewind_cache,
+    _routing,
     _sample,
     decode_model,
 )
@@ -233,13 +235,14 @@ def _suffix_prefill_fn(cfg: TransformerConfig, T_b: int):
                 {"params": params, "cache": unpack_state(cfg, row_cache)},
                 suffix_padded,
                 positions=positions,
-                mutable=["cache"],
+                mutable=_mutable(cfg),
                 seq_lens=jnp.reshape(true_total - prefix_len, (1,)),
                 snap_lens=(None if snap_total is None
                            else jnp.reshape(jnp.maximum(snap_total - prefix_len, 0), (1,))),
             )
             first = logits[:, true_total - prefix_len - 1]  # [1, vocab], as _prefill_fn's
-            return pack_state(cfg, _rewind_cache(state["cache"], true_total)), first
+            out = pack_state(cfg, _rewind_cache(state["cache"], true_total)), first
+            return out + _routing(cfg, state, true_total - prefix_len)
 
         return jax.jit(track_compiles(run, name="paged_suffix_prefill"))
 
@@ -252,13 +255,18 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
     Everything per-request is runtime data (lengths, tables, temps, keys,
     active mask), so this compiles ONCE per (cfg, B, C) and every admission
     mix reuses it. The cache argument is the POOL (page-count-sized, not
-    B-sized), so HBM scales with admitted tokens instead of worst-case rows."""
+    B-sized), so HBM scales with admitted tokens instead of worst-case rows.
+    A model with routed layers returns a sixth result: the chunk's routing,
+    summed over its C token-steps, packed (``models/moe.routing_stats``)."""
 
     def build():
         model = decode_model(cfg)
         S = cfg.max_seq_len
+        routed = bool(cfg.routed_layers)
 
         def run(params, pool, block_tables, tok, lengths, keys, temps, active):
+            n_active = jnp.sum(active.astype(jnp.int32)) if routed else None
+
             def step(carry, _):
                 pool, tok, lengths, keys = carry
                 split = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
@@ -275,17 +283,18 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
                     # the trash page and reads no page at all
                     cache_idx=jnp.where(active, idx, -1),
                     block_tables=block_tables,
-                    mutable=["cache"],
+                    mutable=_mutable(cfg),
                 )
                 nxt = jax.vmap(_sample)(logits[:, -1], subs, temps)
                 nxt = jnp.where(active, nxt, 0)
                 lengths = lengths + active.astype(jnp.int32)
-                return (state["cache"], nxt, lengths, keys2), nxt
+                return (state["cache"], nxt, lengths, keys2), (nxt,) + _routing(cfg, state, n_active)
 
-            (pool, tok, lengths, keys), toks = jax.lax.scan(
+            (pool, tok, lengths, keys), ys = jax.lax.scan(
                 step, (pool, tok, lengths, keys), None, length=C
             )
-            return pool, tok, lengths, keys, toks.swapaxes(0, 1)  # [B, C]
+            out = pool, tok, lengths, keys, ys[0].swapaxes(0, 1)  # [B, C]
+            return out + ((jnp.sum(ys[1], axis=0),) if routed else ())
 
         fn = jax.jit(track_compiles(run, name="paged_step"),
                      donate_argnums=_donate(1))

@@ -62,8 +62,19 @@ def config_from_hf_keys(hf: Dict[str, Any], **overrides: Any) -> TransformerConf
     / ``attn_layer_offset`` and ``mamba_*``) also gives the layer pattern
     (attention where ``i % period == offset``, a Mamba mixer elsewhere), the
     mixer's sizes, no rotary positions, the norm's epsilon and a tied head.
-    Its sparse variants are refused by name: ``num_experts > 1`` needs top-k
-    routing this block does not have."""
+    Its sparse variants are refused by name: the routed layer exists
+    (``models/moe.RoutedMoE``), expert layers inside a hybrid pattern are not
+    wired. A file with the latent-attention keys of the DeepSeek-V3 / openPangu
+    family (``kv_lora_rank``, ``q_lora_rank``, ``qk_*_head_dim``, ``v_head_dim``)
+    gives layers of kind ``"mla"`` with those sizes, ``first_k_dense_replace``
+    dense layers before routed ones (``n_routed_experts``,
+    ``num_experts_per_tok``, ``moe_intermediate_size``, ``n_shared_experts``,
+    ``routed_scaling_factor``, ``norm_topk_prob``), the sandwich norms and the
+    norm's epsilon. The SHARE of a deployment rides the same file:
+    ``n_routed_experts`` counts the experts HELD, ``router_width`` the
+    published count the router keeps (absent: all are held) and ``expert_rank``
+    which rank's experts these are. ``num_nextn_predict_layers`` must be 0: the
+    prediction module is not loaded."""
     base = dict(
         vocab_size=hf["vocab_size"],
         d_model=hf["hidden_size"],
@@ -78,7 +89,8 @@ def config_from_hf_keys(hf: Dict[str, Any], **overrides: Any) -> TransformerConf
         if int(hf.get("num_experts", 1)) > 1:
             raise ValueError(
                 f"num_experts={hf['num_experts']} (top-{hf.get('num_experts_per_tok')}): the "
-                "block's feed-forward is dense; top-k expert routing is not implemented")
+                "routed layer (models/moe.RoutedMoE) exists, but expert layers inside a hybrid "
+                "pattern are not wired")
         base.update(
             layer_pattern=hybrid_pattern(hf["num_hidden_layers"], hf["attn_layer_period"],
                                          hf["attn_layer_offset"]),
@@ -87,6 +99,29 @@ def config_from_hf_keys(hf: Dict[str, Any], **overrides: Any) -> TransformerConf
             norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
             mamba_d_state=hf["mamba_d_state"], mamba_d_conv=hf["mamba_d_conv"],
             mamba_expand=hf["mamba_expand"], mamba_dt_rank=hf["mamba_dt_rank"],
+        )
+    if "kv_lora_rank" in hf:
+        if int(hf.get("num_nextn_predict_layers", 0)) != 0:
+            raise ValueError(
+                f"num_nextn_predict_layers={hf['num_nextn_predict_layers']}: the multi-token-prediction "
+                "module is not loaded (a step yields one token a slot); set it to 0")
+        held = int(hf.get("n_routed_experts", 0))
+        base.update(
+            layer_pattern=("mla",) * hf["num_hidden_layers"],
+            q_lora_rank=hf["q_lora_rank"], kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=hf["qk_nope_head_dim"], qk_rope_head_dim=hf["qk_rope_head_dim"],
+            v_head_dim=hf["v_head_dim"],
+            norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            sandwich_norm=bool(hf.get("sandwich_norm", False)),
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            first_k_dense_replace=int(hf.get("first_k_dense_replace", 0)),
+            moe_routed_experts=int(hf.get("router_width", held)), moe_held_experts=held,
+            moe_rank=int(hf.get("expert_rank", 0)),
+            moe_top_k=int(hf.get("num_experts_per_tok", 1)),
+            moe_d_ff=int(hf.get("moe_intermediate_size", 0)),
+            moe_shared_experts=int(hf.get("n_shared_experts", 0)),
+            moe_routed_scaling=float(hf.get("routed_scaling_factor", 1.0)),
+            moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
         )
     base.update(overrides)
     return TransformerConfig(**base)

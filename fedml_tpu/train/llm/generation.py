@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from ...core.telemetry import track_compiles
 from ...models.mamba import pack_state, unpack_state
+from ...models.moe import ROUTING_STATS, routing_stats
 from ...models.transformer import TransformerConfig, TransformerLM
 
 
@@ -99,6 +100,16 @@ def _leaf_at(tree, path):
     return tree
 
 
+def _mutable(cfg: TransformerConfig) -> list:
+    """The collections a serving pass takes back from the model."""
+    return ["cache", ROUTING_STATS] if cfg.routed_layers else ["cache"]
+
+
+def _routing(cfg: TransformerConfig, state, n_live) -> tuple:
+    """``(routing_stats,)`` of a pass of a model with routed layers, else ``()``."""
+    return (routing_stats(state[ROUTING_STATS], n_live),) if cfg.routed_layers else ()
+
+
 def _prefill_fn(cfg: TransformerConfig, B: int, P_bucket: int):
     """Compiled per PROMPT-LENGTH BUCKET (multiples of 16), not per exact
     length: serving traffic with varied prompt lengths shares executables
@@ -106,7 +117,9 @@ def _prefill_fn(cfg: TransformerConfig, B: int, P_bucket: int):
     ``true_len`` is a runtime scalar; so is ``snap_len``, the position at
     which a recurrent layer also keeps its state for the prefix cache
     (``models/mamba.py``; attention layers read neither). The cache handed on
-    is PACKED (``models/mamba.pack_state``: a dense model's is unchanged)."""
+    is PACKED (``models/mamba.pack_state``: a dense model's is unchanged). A
+    model with routed layers also hands on the pass's routing, packed
+    (``models/moe.routing_stats``): a third result, for those models only."""
 
     def build():
         model = decode_model(cfg)
@@ -114,12 +127,13 @@ def _prefill_fn(cfg: TransformerConfig, B: int, P_bucket: int):
         def run(params, prompt_padded, true_len, snap_len=None):
             positions = jnp.broadcast_to(jnp.arange(P_bucket), (B, P_bucket))
             logits, state = model.apply(
-                {"params": params}, prompt_padded, positions=positions, mutable=["cache"],
+                {"params": params}, prompt_padded, positions=positions, mutable=_mutable(cfg),
                 seq_lens=jnp.broadcast_to(true_len, (B,)),
                 snap_lens=None if snap_len is None else jnp.broadcast_to(snap_len, (B,)),
             )
             first = logits[jnp.arange(B), true_len - 1]
-            return pack_state(cfg, _rewind_cache(state["cache"], true_len)), first
+            out = pack_state(cfg, _rewind_cache(state["cache"], true_len)), first
+            return out + _routing(cfg, state, B * true_len)
 
         # compile observability: counter("jax.compiles.prefill") advances per
         # TRACE, not per call — the serving compile-count guards read it
@@ -212,7 +226,7 @@ def generate(
     prompt_padded = jnp.pad(prompt, ((0, 0), (0, P_b - P))) if P_b != P else prompt
     cache, first_logits = _prefill_fn(cfg, B, P_b)(
         params, prompt_padded, jnp.int32(P)
-    )
+    )[:2]  # a model with routed layers hands on its routing too
     out = _decode_fn(cfg, B, bucket, temperature > 0.0, eos_ids)(
         params, cache, first_logits, jnp.full((B,), P, jnp.int32), key,
         jnp.float32(temperature),

@@ -143,6 +143,12 @@ EXPORTED = {
     "fedml_serving_state_snapshots_total": "counter",
     "fedml_serving_state_snapshot_evictions_total": "counter",
     "fedml_serving_state_snapshot_bytes": "gauge",
+    # latent pages and routed experts (models with MLA / RoutedMoE layers)
+    "fedml_serving_kv_latent_bytes_live": "gauge",
+    "fedml_serving_moe_tokens_routed_total": "counter",
+    "fedml_serving_moe_local_picks_total": "counter",
+    "fedml_serving_moe_experts_hit_total": "counter",
+    "fedml_serving_moe_load_imbalance": "gauge",
     # multi-tenant admission (serving/admission.py; {tenant}/{tenant,reason})
     "fedml_serving_admission_rejected_total": "counter",
     "fedml_serving_admission_deferrals_total": "counter",
