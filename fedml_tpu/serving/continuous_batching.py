@@ -15,19 +15,29 @@ hold it to, token for token. What the engine does:
 - prefill is disaggregated: each request prefills alone at B=1 through the
   16-token-bucketed executables (``generation._prefill_fn``; on a prefix hit,
   a gather of the shared pages plus one suffix pass), then a jitted admit
-  scatters its row into its private pages and samples its first token. An
-  admission wave runs on the worker's own thread (``_run_wave``): a rider's
-  programs are launched without waiting, the next rider's are launched behind
-  them, and only then is the first rider's token fetched, so the device sees
-  one chain ``prefill_0, admit_0, prefill_1, admit_1, ...`` and the host's
-  bookkeeping for rider i runs while the chip works on rider i+1. One thread
+  scatters its row into its private pages, samples its first token and
+  writes the request's row of the decode step's carry. An admission wave runs
+  on the worker's own thread (``_run_wave``): every rider's programs are
+  launched without waiting, so the device sees one chain ``prefill_0,
+  admit_0, prefill_1, admit_1, ...`` behind the chunk in flight. One thread
   launches everything that touches the pool, so the admit program donates it
   as the decode step does;
 - per-row state stays RUNTIME data: slot lengths ride the transformer's
   ``cache_idx``, block tables, temperatures and PRNG keys are per-row arrays,
-  and EOS is checked host-side between chunks, so one executable per
+  and EOS is checked host-side as a chunk's tokens land, so one executable per
   (cfg, B, C) serves every mix of prompt lengths, sampling settings and stop
   tokens;
+- THE LOOP RUNS ONE CHUNK AHEAD OF THE HOST (``_loop``): the worker never
+  blocks on the device with nothing queued behind the wait. What a chunk
+  carries to the next (each row's last token, length and PRNG key) stays on
+  the device: chunk n's results are chunk n+1's operands as they are, an
+  admission writes its slot's row there, and the host keeps only what it
+  owns (block tables, temperatures, who is live: uploaded when they changed)
+  and its own arithmetic copy of the lengths. An iteration launches the
+  wave's riders, launches chunk n+1 over the carry (riders included), and
+  only then, in the order the device finishes them, fetches chunk n's tokens
+  and the riders' first tokens and does their bookkeeping while chunk n+1
+  runs. The depth is one chunk: a constant of the design;
 - a model with recurrent layers (``cfg.has_recurrent_state``) keeps their
   per-slot state in the same cache pytree as the page pool, and its prefix
   hits start from state snapshots the trie holds, at most ``state_snapshots``
@@ -41,11 +51,22 @@ hold it to, token for token. What the engine does:
   token budgets + shed, dequeue-time weighted fair queueing + SLO-pressure
   deferral (serving/admission.py).
 
-Chunking amortizes dispatch and the per-chunk host sync: one device call
-yields ``chunk`` tokens for every live slot. A slot that stops mid-chunk
-(EOS or budget) generates garbage until the chunk ends; the host discards it
-(``serving.wasted_tokens``), frees the request's pages at that chunk boundary
-and points the slot's table at the trash page.
+Chunking amortizes dispatch and the per-chunk fetch: one device call yields
+``chunk`` tokens for every live slot. Who leaves when: a request that ends on
+its BUDGET ends at a step the host can count without seeing a token, so its
+row is masked out of the first chunk launched after the one that holds its
+last token, and it wastes the rest of that last chunk and no more. A request
+that ends on EOS is seen when its chunk lands, one chunk late: its reply is
+cut at the EOS and delivered then, and its row rides the chunk already
+queued, at most ``2 * chunk - 1`` tokens past the EOS. Either way the host
+discards the garbage (``serving.wasted_tokens``), frees the request's pages
+and points the slot's table at the trash page. Every launched chunk keeps,
+host-side, which request held which row at its launch (``_Chunk.rows``):
+tokens of a row whose request has ended, failed or been replaced by a new
+rider are dropped, never appended to another request. Pages and slots are
+handed on only to programs launched AFTER the last chunk that could write
+them: with one launching thread and one device queue, program order gives
+that (as it gives the donated pool, ``paged_kv._paged_admit_fn``).
 
 Telemetry: TTFT/TPOT histograms, token/request counters, and a ``stats()``
 snapshot (slot occupancy, queue depth, page occupancy) that the inference
@@ -157,15 +178,17 @@ class _Pending:
 class _Active:
     pending: _Pending
     budget: int  # max_new clamped to max_seq_len - prompt at admit
-    tokens: List[int] = dataclasses.field(default_factory=list)
+    tokens: List[int] = dataclasses.field(default_factory=list)  # empty until the first token lands
     t_first_ns: int = 0
-    generated: int = 0  # device tokens produced, kept OR discarded
+    generated: int = 0  # device tokens LAUNCHED for it (the first, + chunk a chunk), kept OR discarded
 
 
 @dataclasses.dataclass
 class _AdmitWork:
-    """One request moving through an admission wave: prefill -> transfer ->
-    admit (created by ``_collect_wave`` holding its slot + page reservations)."""
+    """One request moving through an admission: prefill -> transfer (both
+    launched by its wave) -> admit (its first token landed, behind the next
+    chunk's launch). Created by ``_collect_wave`` holding its slot + page
+    reservations; once ``launched`` its slot's table holds the pages."""
 
     item: _Pending
     slot: int
@@ -178,10 +201,20 @@ class _AdmitWork:
     row_cache: object = None
     first: object = None      # [1, vocab] logits the first token is sampled from
     tok0: object = None       # on the device from the transfer until _stage_admit fetches it
-    key2: object = None       # likewise
     routing: object = None    # likewise: the prefill's packed routing (models with routed layers)
     prefill_span: object = None  # gets the routing's attributes once they are on the host
-    admitted: bool = False
+    launched: bool = False    # its row is in the carry, its slot and table published
+
+
+@dataclasses.dataclass
+class _Chunk:
+    """One launched decode chunk until its tokens are on the host."""
+
+    rows: List[Optional[_Active]]  # who held which row at the launch (None: masked out)
+    toks: object                   # [B, C], on its way to the host
+    routing: object                # the chunk's packed routing (models with routed layers), likewise
+    span_attrs: Optional[dict]     # of the ``serving.cb.chunk`` span that launched it: gets the routing's
+    t_launch_ns: int
 
 
 class PagedContinuousBatchingEngine:
@@ -245,15 +278,24 @@ class PagedContinuousBatchingEngine:
 
         self._cache = paged_pool_init(self._params, self._paged_cfg, self._B)
 
-        # per-slot host mirrors (numpy: rebuilt into device arrays per chunk)
+        # what a chunk hands the next, (tok, lengths, keys): on the device from
+        # chunk to chunk, a row of it written by that slot's admission
+        self._carry = (jnp.zeros((self._B,), jnp.int32), jnp.zeros((self._B,), jnp.int32),
+                       jnp.tile(jnp.asarray(jax.random.PRNGKey(0), jnp.uint32), (self._B, 1)))
+        # per-slot state the HOST owns (changed at an admission or a release,
+        # uploaded when it changed: ``_device_copy``) and its own arithmetic
+        # copy of the carried lengths (stats and the chunk span's ``pages``)
         self._slots: List[Optional[_Active]] = [None] * self._B
-        self._tok = np.zeros((self._B,), np.int32)
         self._lengths = np.zeros((self._B,), np.int32)
         self._temps = np.zeros((self._B,), np.float32)
-        self._keys = np.tile(
-            np.asarray(jax.random.PRNGKey(0), np.uint32), (self._B, 1)
-        )
         self._tables = np.full((self._B, self._n_blocks), TRASH_PAGE, np.int32)
+        self._uploaded: dict = {}  # name -> (the host array as uploaded, its device copy)
+        # the worker's own: the chunk launched and not yet fetched, the riders
+        # launched whose first tokens are still on the device, when the last
+        # chunk landed
+        self._inflight: Optional[_Chunk] = None
+        self._riders: List[_AdmitWork] = []
+        self._t_landed_ns = 0
 
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -434,10 +476,29 @@ class PagedContinuousBatchingEngine:
     # -- worker ------------------------------------------------------------
 
     def _idle_locked(self) -> bool:
-        return (not self._stopping and not self._queue
+        return (not self._stopping and not self._queue and self._inflight is None
                 and all(s is None for s in self._slots))
 
+    def _fail_live_locked(self, err: BaseException) -> None:
+        """Fail every request that holds a slot (riders launched and not yet
+        landed hold theirs), free their pages and forget what is in flight
+        (caller holds the engine lock)."""
+        self._inflight = None
+        self._riders = []
+        for i, s in enumerate(self._slots):
+            if s is not None:
+                s.pending.handle._fail(err)
+                self._release_slot(i)
+                self._slots[i] = None
+
     def _loop(self) -> None:
+        """One iteration: launch the wave's riders (``_admit_all``), launch
+        the next chunk over the carry, riders included (``_step_chunk``), and
+        only then fetch what the device finishes first: the chunk that was in
+        flight, then the riders' first tokens (``_land_riders``), while the
+        chunk just launched runs. When nothing is in flight the iteration
+        launches and goes round; when nothing is left to launch the chunk still
+        in flight is landed on its own."""
         while True:
             with self._work:
                 if self._idle_locked():
@@ -449,37 +510,34 @@ class PagedContinuousBatchingEngine:
                     for item in self._queue:
                         item.handle._fail(err)
                     self._queue.clear()
-                    for i, s in enumerate(self._slots):
-                        if s is not None:
-                            s.pending.handle._fail(err)
-                            self._release_slot(i)
-                            self._slots[i] = None
+                    self._fail_live_locked(err)
                     return
                 n_active = sum(1 for s in self._slots if s is not None)
                 n_queued = len(self._queue)
             try:
                 with tel.span("serving.engine.iteration", slots=n_active,
                               queue_depth=n_queued):
-                    self._admit_all()  # fedlint: disable=interproc-host-sync admission copies prompts host->device once per request, not per token; the r05 per-token sync lived in _step_chunk's decode path and is gone
-                    if any(s is not None for s in self._slots):
-                        self._step_chunk()  # fedlint: disable=interproc-host-sync one bounded sync per decode chunk is the engine's design: tokens must reach the host to stream to callers
+                    self._admit_all()  # fedlint: disable=interproc-host-sync admission copies prompts host->device once per request, not per token, and waits for nothing
+                    if any(s is not None and s.generated < s.budget for s in self._slots):
+                        self._step_chunk()  # fedlint: disable=interproc-host-sync one bounded fetch per decode chunk, behind the next chunk's launch: tokens must reach the host to stream to callers
+                    elif self._inflight is not None:
+                        chunk, self._inflight = self._inflight, None
+                        self._land_chunk(chunk)  # fedlint: disable=interproc-host-sync the last chunk's fetch: nothing is left to launch ahead of it
+                    self._land_riders()  # fedlint: disable=interproc-host-sync one fetch per admission, behind the chunk that carries the rider
             except Exception as e:  # noqa: BLE001 - engine thread boundary:
-                # fail every rider rather than die silently with their
-                # futures hanging; next iteration serves fresh requests
+                # fail every rider (of the chunk at fault and of the one queued
+                # behind it) rather than die silently with their futures
+                # hanging; next iteration serves fresh requests
                 log.exception("continuous-batching worker step failed")
                 with self._lock:
-                    for i, s in enumerate(self._slots):
-                        if s is not None:
-                            s.pending.handle._fail(e)
-                            self._release_slot(i)
-                            self._slots[i] = None
+                    self._fail_live_locked(e)
 
     def _admit_all(self) -> None:
         while True:
             wave = self._collect_wave()
             if not wave:
                 with self._lock:
-                    starved = (bool(self._queue)
+                    starved = (bool(self._queue) and self._inflight is None
                                and all(s is None for s in self._slots))
                 if starved:
                     # every queued tenant is deferred (or the pool is
@@ -563,47 +621,62 @@ class PagedContinuousBatchingEngine:
             taken.add(free)
 
     def _run_wave(self, wave: List[_AdmitWork]) -> None:
-        """Admit the wave's riders on this (the worker's) thread. A rider's
-        programs are launched without waiting (``_stage_prefill``,
-        ``_stage_transfer``); the next rider's are launched behind them; only
-        then is the earlier rider's first token fetched and its bookkeeping
-        done (``_stage_admit``), while the chip works on the later rider. The
-        last rider's fetch is the one wait the wave cannot hide.
+        """Launch the wave's riders on this (the worker's) thread, waiting for
+        none: each rider's programs (``_stage_prefill``, ``_stage_transfer``)
+        queue behind the rider's before it. Their first tokens are fetched
+        once the chunk that carries them is launched (``_land_riders``).
 
-        A rider whose launch, fetch or bookkeeping raises is failed alone: its
-        pages go back, riders already admitted keep decoding, riders behind
-        it are admitted. The exception is a call that raised AFTER it consumed
-        the donated pool: no rider can be served from a deleted pool, so the
-        wave's unadmitted riders are failed and the error goes on to
-        ``_loop``'s boundary, which fails every live rider."""
-        in_flight: Optional[_AdmitWork] = None  # launched, its first token still on the device
+        A rider whose launch raises is failed alone: its pages go back, riders
+        already decoding keep decoding, riders behind it are launched. The
+        exception is a call that raised AFTER it consumed the donated pool: no
+        rider can be served from a deleted pool, so the wave's unlaunched
+        riders are failed and the error goes on to ``_loop``'s boundary, which
+        fails every rider that holds a slot."""
+        behind = False  # a rider of this wave is launched, its first token still on the device
         for w in wave:
             if not self._try_stage(self._launch, w, wave):
                 continue
-            if in_flight is not None:
+            if behind:
                 tel.counter("serving.paged.launches_overlapped").add(1)
-                self._try_stage(self._stage_admit, in_flight, wave)
-            in_flight = w
-        if in_flight is not None:
-            self._try_stage(self._stage_admit, in_flight, wave)
+            behind = True
+            self._riders.append(w)
+
+    def _land_riders(self) -> None:
+        """Fetch the launched riders' first tokens, in launch order, and do
+        their bookkeeping (``_stage_admit``): behind the chunk that carries
+        them, so the chip has that chunk to run while the host waits here. A
+        rider whose fetch or bookkeeping raises is failed alone; its row is out
+        of the next chunk and its tokens of the one in flight reach nobody."""
+        riders, self._riders = self._riders, []
+        for w in riders:
+            self._try_stage(self._stage_admit, w, riders)
 
     def _try_stage(self, stage, w: _AdmitWork, wave: List[_AdmitWork]) -> bool:
         """Run one stage of rider ``w``; on an exception fail ``w`` (False), or
-        the wave's unadmitted riders and re-raise if the pool went with it."""
+        the wave's unlaunched riders and re-raise if the pool went with it."""
         try:
             stage(w)
             return True
         except Exception as e:  # noqa: BLE001 - one rider's failure stays that rider's
             log.exception("paged admission of request %s failed", w.item.request_id)
-            pool_gone = any(x.is_deleted() for x in jax.tree_util.tree_leaves(self._cache))
-            for r in (wave if pool_gone else [w]):
-                if not r.admitted and not r.item.handle.done():
-                    self._tables[r.slot, :] = TRASH_PAGE  # whatever _stage_admit had published
-                    self._alloc.free(r.shared_pages + r.private_pages)
-                    r.item.handle._fail(e)
-            if pool_gone:
-                raise
-            return False
+            if not any(x.is_deleted() for x in jax.tree_util.tree_leaves(self._cache)):
+                self._fail_rider(w, e)
+                return False
+            for r in wave:
+                if not r.launched:  # a launched rider holds a slot: _loop's boundary fails it
+                    self._fail_rider(r, e)
+            raise
+
+    def _fail_rider(self, r: _AdmitWork, e: BaseException) -> None:
+        if r.item.handle.done():
+            return
+        if r.launched:  # its slot's table holds its pages
+            self._release_slot(r.slot)
+            with self._lock:
+                self._slots[r.slot] = None
+        else:
+            self._alloc.free(r.shared_pages + r.private_pages)
+        r.item.handle._fail(e)
 
     def _launch(self, w: _AdmitWork) -> None:
         self._stage_prefill(w)
@@ -647,53 +720,60 @@ class PagedContinuousBatchingEngine:
     def _stage_transfer(self, w: _AdmitWork) -> None:
         """Stage 2, launched and not waited for: scatter the row's PROMPT
         blocks into the request's private pages (shared blocks stay untouched
-        behind TRASH write ids), write its recurrent state at its slot and
-        sample the first token. This is the page handoff, the only stage that
-        writes the decode pool; the pool is donated to it."""
+        behind TRASH write ids), write its recurrent state at its slot, sample
+        the first token and write the slot's row of the carry. This is the
+        page handoff, the only stage that writes the decode pool; the pool is
+        donated to it. Once it is launched the rider's row can ride a chunk, so
+        the host's side of the row is published here: block table, temperature,
+        length, and the slot (its reply still empty)."""
         item = w.item
+        b = w.slot
         P = len(item.prompt)
         with tel.span("serving.paged.transfer", request_id=item.request_id):
             write_ids = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
             first_blk = w.n_shared
             last_blk = -(-P // self._ps)  # exclusive: block of the last token
             write_ids[first_blk:last_blk] = w.private_pages[:last_blk - first_blk]
-            self._cache, w.tok0, w.key2 = _paged_admit_fn(self._paged_cfg)(
-                self._cache, w.row_cache, write_ids, np.int32(w.slot), w.first,
-                np.uint32(item.seed & 0xFFFFFFFF), np.float32(item.temperature))
+            self._cache, w.tok0, self._carry = _paged_admit_fn(self._paged_cfg)(
+                self._cache, w.row_cache, write_ids, np.int32(b), w.first,
+                np.uint32(item.seed & 0xFFFFFFFF), np.float32(item.temperature),
+                self._carry, np.int32(P))
             w.first = None
             w.tok0.copy_to_host_async()
-            w.key2.copy_to_host_async()
+            n_own = w.n_shared + len(w.private_pages)
+            self._tables[b, :w.n_shared] = w.shared_pages
+            self._tables[b, w.n_shared:n_own] = w.private_pages
+            self._tables[b, n_own:] = TRASH_PAGE
+            self._temps[b] = item.temperature
+            with self._lock:
+                self._lengths[b] = P
+                self._slots[b] = _Active(item, w.budget, generated=1)
+            w.launched = True
 
     def _stage_admit(self, w: _AdmitWork) -> None:
-        """Stage 3: fetch the first token (the wait for this rider's chain),
-        then host bookkeeping — publish the block table, mirrors, and the
-        slot; register the prompt's full chunks in the prefix cache so the
-        NEXT request with this system prompt shares pages."""
+        """Stage 3, behind the launch of the chunk that carries the rider:
+        fetch the first token (the wait for this rider's chain), then host
+        bookkeeping — the first token into the reply, the request's timings,
+        the prompt's full chunks into the prefix cache so the NEXT request with
+        this system prompt shares pages."""
         item = w.item
         b = w.slot
         with tel.span("serving.paged.first_token_wait", request_id=item.request_id):
-            tok0 = int(np.asarray(w.tok0))  # fedlint: disable=host-sync one sync per admission, not per decode step, behind the next rider's launches
-            key2 = np.asarray(w.key2, np.uint32)
+            tok0 = int(np.asarray(w.tok0))  # fedlint: disable=host-sync one sync per admission, not per decode step, behind the launch of the chunk that carries the rider
             if w.routing is not None:  # the prefill ran before the admit program: already here
                 self._note_routing(np.asarray(w.routing), getattr(w.prefill_span, "attrs", None),
                                    ("local_picks", "experts_hit"))
                 w.routing = w.prefill_span = None
         with tel.span("serving.paged.admit", request_id=item.request_id):
             now_ns = time.perf_counter_ns()
-            table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
-            n_own = w.n_shared + len(w.private_pages)
-            table[:w.n_shared] = w.shared_pages
-            table[w.n_shared:n_own] = w.private_pages
-            self._tok[b] = tok0
-            self._lengths[b] = len(item.prompt)
-            self._temps[b] = item.temperature
-            self._keys[b] = key2
-            self._tables[b] = table
+            s = self._slots[b]
+            s.tokens.append(tok0)
+            s.t_first_ns = now_ns
             ttft = self._note_first_token(item, now_ns, shared=w.n_shared * self._ps)
             self._observe_tenant_ttft(item.tenant, ttft)
             n_prompt_blocks = len(item.prompt) // self._ps  # FULL chunks only
             self._alloc.register_prefix(
-                item.prompt, [int(p) for p in table[:n_prompt_blocks]])
+                item.prompt, [int(p) for p in self._tables[b, :n_prompt_blocks]])
             if w.snap_blocks:
                 # the trie node this prompt diverged at had pages and no
                 # snapshot: it keeps the state this prefill left there
@@ -704,10 +784,6 @@ class PagedContinuousBatchingEngine:
                         item.prompt, w.snap_blocks, snapshot_of(w.row_cache),
                         self._state_bytes)
             w.row_cache = None
-            with self._lock:
-                self._slots[b] = _Active(item, w.budget, [tok0], now_ns,
-                                         generated=1)
-            w.admitted = True
             self._finish_if_done(b, now_ns)
 
     def _note_first_token(self, item: _Pending, now_ns: int, shared: int) -> float:
@@ -726,70 +802,93 @@ class PagedContinuousBatchingEngine:
         tel.counter("serving.cb.admissions").add(1)
         return ttft
 
+    def _device_copy(self, name: str, host: np.ndarray):
+        """The device's copy of a per-slot array the host owns: uploaded again
+        only when the host's differs from what was uploaded last."""
+        held = self._uploaded.get(name)
+        if held is None or not np.array_equal(held[0], host):
+            held = self._uploaded[name] = (host.copy(), jax.device_put(host))
+        return held[1]
+
     def _step_chunk(self) -> None:
-        with self._lock:
-            active_mask = np.asarray(
-                [s is not None for s in self._slots], bool
-            )
-        n_live = int(active_mask.sum())
+        """Launch the next chunk over the carry as the device holds it, and
+        only then land the chunk that was in flight (``_land_chunk``): every
+        call launches exactly one ``jit_paged_step``. A row rides if its
+        request still has tokens to be launched (``generated < budget``): a
+        budget's end needs no token seen."""
+        rows = [s if s is not None and s.generated < s.budget else None for s in self._slots]
+        active = np.asarray([s is not None for s in rows], bool)
+        n_live = int(active.sum())
         # pages the chunk's first token-step reads: each active row's written
         # prefix plus the token it writes; beside B x n_blocks, the share of
         # a whole-table read that paged attention still makes
-        lens = self._lengths[active_mask].astype(np.int64) + 1
+        lens = self._lengths[active].astype(np.int64) + 1
         attrs = {"pages": int((-(-lens // self._ps)).sum())}
         if self._stateful:  # live slots whose recurrent state the step updates
             attrs["state_slots"] = n_live
         if self._latent_token_bytes:
             _gauge("serving.kv.latent_bytes_live", float((lens - 1).sum() * self._latent_token_bytes))
         with tel.span("serving.cb.chunk", slots=n_live, **attrs) as chunk_span:
-            with tel.timed("serving.cb.chunk.dispatch") as dispatch:
+            with tel.span("serving.cb.chunk.dispatch"):
                 cache, tok, lengths, keys, toks, *routing = _paged_step_fn(
                     self._paged_cfg, self._B, self._C)(
                     self._params,
                     self._cache,
-                    jnp.asarray(self._tables),
-                    jnp.asarray(self._tok),
-                    jnp.asarray(self._lengths),
-                    jnp.asarray(self._keys),
-                    jnp.asarray(self._temps),
-                    jnp.asarray(active_mask),
+                    self._device_copy("tables", self._tables),
+                    *self._carry,
+                    self._device_copy("temps", self._temps),
+                    self._device_copy("active", active),
                 )
-            with tel.timed("serving.cb.chunk.sync") as sync:
-                toks = np.asarray(toks)  # [B, C]; forces chunk completion
-            with tel.span("serving.cb.chunk.post"):
-                devperf.observe_step("paged_step",
-                                     dispatch.duration_s + sync.duration_s,
-                                     tokens=n_live * self._C)
-                self._cache = cache
-                # np.array (not asarray): device arrays view as READ-ONLY
-                # numpy; these mirrors are mutated per-slot at admit time
-                self._tok = np.array(tok, np.int32)
-                self._lengths = np.array(lengths, np.int32)
-                self._keys = np.array(keys, np.uint32)
-                now_ns = time.perf_counter_ns()
-                tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
-                if routing:
-                    load = self._note_routing(np.asarray(routing[0]), getattr(chunk_span, "attrs", None),
-                                              ("tokens_routed", "local_picks", "experts_hit"))
-                    with self._lock:
-                        self._moe_recent.append(load)
-                        recent = np.sum(self._moe_recent, axis=0)
-                    if recent.sum() > 0:
-                        _gauge("serving.moe.load_imbalance", float(recent.max() / recent.mean()))
-                for b in range(self._B):
-                    with self._lock:
-                        s = self._slots[b]
-                    if s is None:
-                        continue
-                    s.generated += self._C
-                    for t in toks[b]:
-                        t = int(t)
-                        s.tokens.append(t)
-                        if s.pending.eos_ids is not None and t in s.pending.eos_ids:
-                            break
-                        if len(s.tokens) >= s.budget:
-                            break
-                    self._finish_if_done(b, now_ns)
+                self._cache, self._carry = cache, (tok, lengths, keys)
+                for out in (toks, *routing):
+                    out.copy_to_host_async()
+                before, self._inflight = self._inflight, _Chunk(
+                    rows, toks, routing[0] if routing else None,
+                    getattr(chunk_span, "attrs", None), time.perf_counter_ns())
+                with self._lock:  # stats() reads the lengths
+                    self._lengths[active] += self._C
+                for s in rows:
+                    if s is not None:
+                        s.generated += self._C
+            if before is not None:
+                tel.counter("serving.cb.chunks_ahead").add(1)
+                self._land_chunk(before)
+
+    def _land_chunk(self, chunk: _Chunk) -> None:
+        """Fetch a launched chunk's tokens (``.sync``: the wait for it; near a
+        chunk's device time the host has slack, near zero the host is what the
+        chip waits for) and do its bookkeeping (``.post``): tokens to the
+        requests that still hold the rows they held at its launch, finishes,
+        routing counters onto the span that launched it."""
+        with tel.span("serving.cb.chunk.sync"):
+            toks = np.asarray(chunk.toks)  # [B, C]; returns when the chunk is done
+        with tel.span("serving.cb.chunk.post"):
+            now_ns = time.perf_counter_ns()
+            n_live = sum(1 for s in chunk.rows if s is not None)
+            # completion to completion while chunks run back to back; from its
+            # own launch for a chunk launched with nothing in flight
+            devperf.observe_step("paged_step",
+                                 (now_ns - max(chunk.t_launch_ns, self._t_landed_ns)) / 1e9,
+                                 tokens=n_live * self._C)
+            self._t_landed_ns = now_ns
+            tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
+            if chunk.routing is not None:
+                load = self._note_routing(np.asarray(chunk.routing), chunk.span_attrs,
+                                          ("tokens_routed", "local_picks", "experts_hit"))
+                with self._lock:
+                    self._moe_recent.append(load)
+                    recent = np.sum(self._moe_recent, axis=0)
+                if recent.sum() > 0:
+                    _gauge("serving.moe.load_imbalance", float(recent.max() / recent.mean()))
+            for b, s in enumerate(chunk.rows):
+                if s is None or self._slots[b] is not s:
+                    continue  # masked out, or its request ended or failed a chunk ago: nobody's tokens
+                eos = s.pending.eos_ids
+                for t in toks[b].tolist():
+                    s.tokens.append(t)
+                    if (eos is not None and t in eos) or len(s.tokens) >= s.budget:
+                        break
+                self._finish_if_done(b, now_ns)
 
     def _note_routing(self, packed: np.ndarray, span_attrs: Optional[dict], names: Tuple[str, ...]) -> np.ndarray:
         """One pass's packed routing (``models/moe.routing_stats``) into the

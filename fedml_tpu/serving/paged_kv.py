@@ -142,16 +142,21 @@ def snapshot_of(row_cache) -> dict:
 
 
 def _paged_admit_fn(cfg: TransformerConfig):
-    """Write one finished contiguous row cache into the cache pytree and
-    sample the request's first token. K/V leaves scatter into the pool at
-    runtime page ids: ``write_ids`` has one entry per logical block; blocks the
-    request does NOT own (shared prefix pages, unallocated tail) carry
-    TRASH_PAGE, so duplicate scatter indices only ever clobber the trash page.
-    Recurrent-state leaves (``STATE_LEAVES``) are written whole at the
-    request's ``slot``. Leaves only the row has (its snapshot) stay behind.
-    The row arrives packed (``models/mamba.pack_state``); ``first_logits`` is
-    the prefill's ``[1, vocab]``; ``seed`` is the request's seed as a uint32,
-    from which the program makes ``jax.random.PRNGKey(seed)`` itself.
+    """Write one finished contiguous row cache into the cache pytree, sample
+    the request's first token and write the request's row of the decode
+    step's carry. K/V leaves scatter into the pool at runtime page ids:
+    ``write_ids`` has one entry per logical block; blocks the request does NOT
+    own (shared prefix pages, unallocated tail) carry TRASH_PAGE, so duplicate
+    scatter indices only ever clobber the trash page. Recurrent-state leaves
+    (``STATE_LEAVES``) are written whole at the request's ``slot``. Leaves only
+    the row has (its snapshot) stay behind. The row arrives packed
+    (``models/mamba.pack_state``); ``first_logits`` is the prefill's
+    ``[1, vocab]``; ``seed`` is the request's seed as a uint32, from which the
+    program makes ``jax.random.PRNGKey(seed)`` itself. ``carry`` is what
+    ``_paged_step_fn`` carries from chunk to chunk, ``(tok, lengths, keys)``;
+    it comes back with row ``slot`` set to (the first token, ``length`` = the
+    prompt's, the key the first token's draw left), so the request can ride a
+    chunk launched before its first token has reached the host.
 
     The pool is DONATED where the backend donates: the caller's binding is
     dead once the call is made, and it rebinds to the returned pool. That
@@ -161,7 +166,7 @@ def _paged_admit_fn(cfg: TransformerConfig):
     ps = cfg.kv_page_size
 
     def build():
-        def run(pool, row_cache, write_ids, slot, first_logits, seed, temp):
+        def run(pool, row_cache, write_ids, slot, first_logits, seed, temp, carry, length):
             row_cache = unpack_state(cfg, row_cache)
 
             def insert(path, dst):
@@ -177,7 +182,9 @@ def _paged_admit_fn(cfg: TransformerConfig):
             new_pool = jax.tree_util.tree_map_with_path(insert, pool)
             key2, sub = jax.random.split(jax.random.PRNGKey(seed))
             tok0 = _sample(first_logits[0], sub, temp)
-            return new_pool, tok0, key2
+            tok, lengths, keys = carry
+            carry = tok.at[slot].set(tok0), lengths.at[slot].set(length), keys.at[slot].set(key2)
+            return new_pool, tok0, carry
 
         return jax.jit(track_compiles(run, name="paged_admit"),
                        donate_argnums=_donate(0))

@@ -1,9 +1,10 @@
 """Continuous batching (serving/continuous_batching.py): the engine against
 the reference ``generate()`` path: join/leave at token boundaries, EOS,
 the budget clamp at ``max_seq_len``, fail-fast rejects, the HTTP runner in
-front of it, and the request's span record. (Greedy exactness on ragged
-lengths and compile-once are tests/test_paged_kv.py's, at this file's
-geometry too.)"""
+front of it, the request's span record, and the loop's schedule: one decode
+chunk ahead of the host, riders launched before anything is waited for.
+(Greedy exactness on ragged lengths and compile-once are
+tests/test_paged_kv.py's, at this file's geometry too.)"""
 
 import json
 import threading
@@ -18,6 +19,7 @@ from fedml_tpu.core import telemetry as tel
 from fedml_tpu.models.transformer import TransformerConfig, TransformerLM
 from fedml_tpu.serving.continuous_batching import PagedContinuousBatchingEngine
 from fedml_tpu.train.llm.generation import generate
+from tests._engine_gate import hold
 
 CFG = TransformerConfig(
     vocab_size=89, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=64,
@@ -181,8 +183,9 @@ REQUEST_SPANS = (
     "serving.paged.transfer", "serving.paged.first_token_wait", "serving.paged.admit")
 
 #: the per-request stages of an admission wave, in the order a rider passes them
-WAVE_STAGES = ("serving.cb.prefill", "serving.paged.transfer", "serving.paged.first_token_wait",
-               "serving.paged.admit")
+LAUNCH_STAGES = ("serving.cb.prefill", "serving.paged.transfer")  # under the wave's span, waited for by nothing
+LANDING_STAGES = ("serving.paged.first_token_wait", "serving.paged.admit")  # behind the launch of the chunk that carries the rider
+WAVE_STAGES = LAUNCH_STAGES + LANDING_STAGES
 
 
 CALLER_ID = "cd" * 16
@@ -288,30 +291,41 @@ def test_worker_loop_spans_tile_an_iteration(served_requests):
     for s in spans:
         by_parent.setdefault(s["parent_seq"], []).append(s)
     covered = 0
+    landing = ["serving.cb.chunk.sync", "serving.cb.chunk.post"]  # of the chunk launched before
     for it in iters:
         kids = by_parent.get(it["seq"], [])
-        assert {k["name"] for k in kids} <= {"serving.paged.admit_wave", "serving.cb.chunk"}
+        # a wave's launches, the chunk (its launch, then the landing of the one before), or the
+        # last chunk's landing where nothing was left to launch, then the riders' first tokens
+        assert {k["name"] for k in kids} <= {"serving.paged.admit_wave", "serving.cb.chunk", *landing, *LANDING_STAGES}
         assert all(k["tid"] == it["tid"] for k in kids)
+        assert not ({"serving.cb.chunk", *landing} <= {k["name"] for k in kids})  # one or the other
         covered += sum(k["dur_ns"] for k in kids)
     assert covered >= 0.95 * sum(s["dur_ns"] for s in iters)
     chunks = [s for s in spans if s["name"] == "serving.cb.chunk"]
     assert chunks and all(1 <= s["attrs"]["slots"] <= 2 for s in chunks)
     for ch in chunks:
-        parts = by_parent.get(ch["seq"], [])
-        assert [p["name"] for p in parts] == ["serving.cb.chunk.dispatch", "serving.cb.chunk.sync",
-                                              "serving.cb.chunk.post"]
+        parts = [p["name"] for p in by_parent.get(ch["seq"], [])]
+        assert parts in (["serving.cb.chunk.dispatch"], ["serving.cb.chunk.dispatch"] + landing), parts
     parts_ns = sum(p["dur_ns"] for ch in chunks for p in by_parent[ch["seq"]])
     assert parts_ns >= 0.95 * sum(ch["dur_ns"] for ch in chunks)
-    # the stages of a wave run on the worker's own thread, as children of its wave
+    # every launched chunk is landed once: inside the span that launched the next, or on its own
+    assert len([s for s in spans if s["name"] == landing[0]]) == len(chunks)
+    # a wave's launches run on the worker's own thread, as children of its wave; a rider's
+    # first token lands later in the same pass of the loop, behind the chunk's launch
     waves = {s["seq"]: s for s in spans if s["name"] == "serving.paged.admit_wave"}
     assert waves and all(w["tid"] in worker for w in waves.values())
+    its = {s["seq"]: s for s in iters}
     for name in WAVE_STAGES:
         stages = [s for s in spans if s["name"] == name]
         assert len(stages) == 6, name  # one a request
         for st in stages:
-            w = waves[st["parent_seq"]]
+            w = (waves if name in LAUNCH_STAGES else its)[st["parent_seq"]]
             assert st["tid"] == w["tid"], st
             assert w["t0_ns"] <= st["t0_ns"] and st["t0_ns"] + st["dur_ns"] <= w["t0_ns"] + w["dur_ns"], st
+    for w in waves.values():  # the wave's riders land in the pass that launched them
+        mine = {s["attrs"]["request_id"] for s in by_parent[w["seq"]] if s["name"] == "serving.paged.transfer"}
+        landed = {s["attrs"]["request_id"] for s in by_parent[w["parent_seq"]] if s["name"] == "serving.paged.admit"}
+        assert mine <= landed
     assert any(s["name"] == "serving.engine.idle" and s["tid"] in worker for s in spans)
 
 
@@ -349,8 +363,14 @@ class _Wave:
             gate.set()
 
     def spans(self, name=None):
-        return [s for s in self.registry.snapshot()["spans"]
-                if s["seq"] > self._seq0 and name in (None, s["name"])]
+        found = [s for s in self.registry.snapshot()["spans"]
+                 if s["seq"] > self._seq0 and name in (None, s["name"])]
+        return sorted(found, key=lambda s: s["t0_ns"])
+
+    def chunks(self):
+        """(its ``.dispatch``, the ``.sync`` that landed it) of every chunk,
+        in launch order: chunks land in the order they were launched."""
+        return list(zip(self.spans("serving.cb.chunk.dispatch"), self.spans("serving.cb.chunk.sync")))
 
     def count(self, counter):
         return self.registry.snapshot()["counters"].get(counter, 0) - self._counters0.get(counter, 0)
@@ -371,7 +391,11 @@ def _ref(params, prompt, n):
     return np.asarray(generate(params, CFG, jnp.asarray([prompt], jnp.int32), n))[0].tolist()
 
 
-def test_wave_launches_the_next_rider_before_it_waits_for_the_one_before(wave):
+def _end(span):
+    return span["t0_ns"] + span["dur_ns"]
+
+
+def test_wave_launches_every_rider_and_the_chunk_that_carries_them_before_it_waits_for_a_first_token(wave):
     handles = wave.burst([(_prompt(5 + 3 * i, 200 + i), 6, {}) for i in range(3)])
     for h in handles:
         h.result(timeout=120)
@@ -381,10 +405,17 @@ def test_wave_launches_the_next_rider_before_it_waits_for_the_one_before(wave):
     ids = [h.request_id for h in handles]
     for rid in ids:  # a rider passes its four stages in order
         assert [at[(name, rid)] for name in WAVE_STAGES] == sorted(at[(name, rid)] for name in WAVE_STAGES)
-    for earlier, later in zip(ids, ids[1:]):
-        # both programs of the later rider are launched before the engine waits for the earlier one's token
-        assert at[("serving.paged.transfer", later)] < at[("serving.paged.first_token_wait", earlier)]
+    for earlier, later in zip(ids, ids[1:]):  # launched in the wave's order, landed in the same
+        assert at[("serving.paged.transfer", earlier)] < at[("serving.cb.prefill", later)]
         assert at[("serving.paged.first_token_wait", earlier)] < at[("serving.paged.first_token_wait", later)]
+    # both programs of every rider, then the chunk that carries all three, are launched before the
+    # engine waits for anybody's first token: each wait has that chunk queued behind it
+    first_wait = at[("serving.paged.first_token_wait", ids[0])]
+    assert at[("serving.paged.transfer", ids[-1])] < _end(w) <= first_wait
+    chunk, dispatch = wave.spans("serving.cb.chunk")[0], wave.spans("serving.cb.chunk.dispatch")[0]
+    assert chunk["attrs"]["slots"] == 3 and dispatch["parent_seq"] == chunk["seq"]
+    assert _end(w) <= dispatch["t0_ns"] and _end(dispatch) <= first_wait
+    assert at[("serving.paged.first_token_wait", ids[-1])] > dispatch["t0_ns"]  # the last rider's wait most of all
     assert wave.count("serving.paged.launches_overlapped") == 2
 
 
@@ -402,7 +433,9 @@ def test_every_span_of_a_wave_is_the_workers_and_no_thread_outlives_it(wave):
     (w,) = wave.spans("serving.paged.admit_wave")
     staged = [s for s in wave.spans() if s["name"] in WAVE_STAGES]
     assert len(staged) == 3 * len(WAVE_STAGES)
-    assert all(s["tid"] == w["tid"] == wave.eng._worker.ident and s["parent_seq"] == w["seq"] for s in staged)
+    assert all(s["tid"] == w["tid"] == wave.eng._worker.ident for s in staged)
+    # the launches are the wave's children, the landings its siblings: the same pass of the loop
+    assert all(s["parent_seq"] == (w["seq"] if s["name"] in LAUNCH_STAGES else w["parent_seq"]) for s in staged)
     names = set().union(*seen) | {t.name for t in threading.enumerate()}
     assert len(seen) == 3 and not [n for n in names if n.startswith("paged_admit")], names
 
@@ -492,8 +525,8 @@ def test_a_failure_in_rider_two_of_three_is_rider_twos_alone(wave, params, stage
 def test_a_failure_that_consumed_the_pool_fails_the_wave_and_the_live_riders(wave):
     """What donation makes possible: the admit program raised after it took
     the pool. Nobody can be served from a deleted pool, so nobody hangs: the
-    wave fails its unadmitted riders (two and three), the loop's boundary the
-    one already decoding (one: admitted once rider two was launched)."""
+    wave fails its unlaunched rider (three), the loop's boundary the two that
+    hold slots (launched, their first tokens not yet fetched)."""
     prompts = [_prompt(5 + i, 250 + i) for i in range(3)]
     inner = wave.eng._stage_transfer
 
@@ -509,8 +542,174 @@ def test_a_failure_that_consumed_the_pool_fails_the_wave_and_the_live_riders(wav
     for h in handles:
         with pytest.raises(RuntimeError, match="planted after the pool was donated"):
             h.result(timeout=120)
-    assert wave.count("serving.cb.admissions") == 1
+    assert wave.count("serving.cb.admissions") == 0 and wave.count("serving.cb.chunks_ahead") == 0
     assert wave.eng.stats()["slots_active"] == 0 and wave.eng._alloc.check_leaks()["accounted"]
+
+
+# -- the loop's schedule: one decode chunk ahead of the host ---------------------
+
+C = 4  # the fixture's chunk
+
+
+def _generate(params, prompt, n, kw):
+    want = generate(params, CFG, jnp.asarray([prompt], jnp.int32), n, temperature=kw.get("temperature", 0.0),
+                    key=jax.random.PRNGKey(kw.get("seed", 0)))
+    return np.asarray(want)[0].tolist()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_riders_admitted_while_a_chunk_is_in_flight_are_served_generates_tokens(wave, params, temperature):
+    """Mixed lengths and budgets (one of a single token, one past the first's
+    end, more riders than free slots): the carried rows never leave the device
+    and an admission writes its row there, the same tokens come out."""
+    kw = [({"temperature": temperature, "seed": 300 + i} if temperature else {}) for i in range(6)]
+    first = (_prompt(7, 300), 30, kw[0])
+    later = [(_prompt(4 + 5 * i, 301 + i), n, kw[i + 1]) for i, n in enumerate((5, 17, 1, 9, 12))]
+    reached, release = hold(wave.eng, "_land_chunk")  # chunk 2 is launched, chunk 1 not yet fetched
+    handles = [wave.eng.submit(first[0], first[1], **first[2])]
+    assert reached.wait(timeout=60)
+    handles += [wave.eng.submit(p, n, **k) for p, n, k in later]
+    release.set()
+    for (prompt, n, k), h in zip([first] + later, handles):
+        assert h.result(timeout=120) == _generate(params, prompt, n, k)
+    # the two riders the free slots took were launched between chunk 2's launch and its fetch,
+    # and rode chunk 3, launched before their first tokens were waited for
+    transfer = {s["attrs"]["request_id"]: s["t0_ns"] for s in wave.spans("serving.paged.transfer")}
+    wait = {s["attrs"]["request_id"]: s["t0_ns"] for s in wave.spans("serving.paged.first_token_wait")}
+    flights = wave.chunks()
+    for h in handles[1:3]:
+        assert flights[1][0]["t0_ns"] < transfer[h.request_id] < _end(flights[1][1])
+        assert transfer[h.request_id] < flights[2][0]["t0_ns"] < wait[h.request_id]
+    assert [c["attrs"]["slots"] for c in wave.spans("serving.cb.chunk")][:3] == [1, 1, 3]
+    leaks = wave.eng._alloc.check_leaks()
+    assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["accounted"]
+    assert np.all(wave.eng._tables == 0) and wave.eng.stats()["kv_tokens_live"] == 0
+
+
+def test_chunk_n_plus_1_is_launched_before_chunk_n_is_fetched_and_the_counter_says_how_often(wave):
+    for requests in ([(_prompt(6, 310), 14, {}), (_prompt(9, 311), 7, {})], [(_prompt(5, 312), 10, {})]):
+        for h in wave.burst(requests):  # the second burst finds the loop with nothing in flight again
+            h.result(timeout=120)
+    flights = wave.chunks()
+    chunks = wave.spans("serving.cb.chunk")
+    by_parent = {}
+    for s in wave.spans():
+        by_parent.setdefault(s["parent_seq"], []).append(s["name"])
+    ahead = [c for c in chunks if "serving.cb.chunk.sync" in by_parent[c["seq"]]]
+    alone = [s for s in wave.spans("serving.cb.chunk.sync") if s["parent_seq"] not in {c["seq"] for c in chunks}]
+    # 14 and 7 tokens: 1 + 4 chunks; 10 tokens: 1 + 3 chunks; each burst's last chunk lands with nothing to launch
+    assert len(chunks) == len(flights) == 4 + 3 and len(alone) == 2
+    assert wave.count("serving.cb.chunks_ahead") == len(ahead) == len(chunks) - len(alone)
+    for (d0, s0), (d1, _) in zip(flights, flights[1:]):
+        if d1["t0_ns"] < _end(s0):  # launched ahead: before the chunk before it was fetched, not merely before it ended
+            assert d1["t0_ns"] < s0["t0_ns"]
+    assert sum(d1["t0_ns"] < s0["t0_ns"] for (_, s0), (d1, _) in zip(flights, flights[1:])) == len(ahead)
+    # every rider's first token is waited for behind the launch of the chunk that carries it
+    firsts = [d for d in wave.spans("serving.cb.chunk.dispatch") if by_parent[d["parent_seq"]] == ["serving.cb.chunk.dispatch"]]
+    waits = wave.spans("serving.paged.first_token_wait")
+    assert len(firsts) == 2 and len(waits) == 3
+    assert _end(firsts[0]) <= waits[0]["t0_ns"] and _end(firsts[1]) <= waits[2]["t0_ns"]
+
+
+def test_an_eos_is_seen_a_chunk_late_and_the_rows_later_tokens_reach_nobody(params):
+    w = _Wave(params, num_slots=1)
+    try:
+        prompt, then = _prompt(5, 7), _prompt(11, 320)
+        ref = _ref(params, prompt, 24)
+        eos = ref[2]  # among the first chunk's tokens
+        cut = ref.index(eos)
+        a, b = w.burst([(prompt, 24, {"eos_id": eos}), (then, 10, {})])  # one slot: the second rides it next
+        assert a.result(timeout=120) == ref[:cut + 1]
+        assert b.result(timeout=120) == _ref(params, then, 10)
+        decode = {s["attrs"]["request_id"]: s["attrs"] for s in w.spans("serving.request.decode")}
+        # chunk 2 was queued when chunk 1's tokens (the EOS among them) were seen; an EOS that is
+        # the first token itself is seen at the rider's landing, behind chunk 1's launch
+        assert decode[a.request_id]["wasted"] == (1 + 2 * C - (cut + 1) if cut else C) <= 2 * C - 1
+        assert decode[a.request_id]["tokens"] == cut + 1 and decode[b.request_id]["wasted"] == 1 + 3 * C - 10
+        assert w.count("serving.wasted_tokens") == decode[a.request_id]["wasted"] + decode[b.request_id]["wasted"]
+        # the second rider was launched with the chunk that still carried the first one's row in
+        # flight; what that chunk decoded for the row went to neither of them
+        transfer = w.spans("serving.paged.transfer")[1]["t0_ns"]
+        flights = w.chunks()
+        assert flights[1][0]["t0_ns"] < transfer < _end(flights[1][1])
+        st = w.eng.stats()
+        assert st["tokens_out"] == cut + 1 + 10 and st["requests_done"] == 2 and st["kv_tokens_live"] == 0
+        leaks = w.eng._alloc.check_leaks()
+        assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["accounted"]
+        assert np.all(w.eng._tables == 0)
+    finally:
+        w.close()
+
+
+@pytest.mark.parametrize("budget", [1, 2, 5, 6, 12])
+def test_a_budget_end_wastes_the_rest_of_its_last_chunk_and_no_more(wave, params, budget):
+    """The host counts a budget's end without seeing a token: the row is out of
+    the first chunk launched after the one that holds its last token."""
+    prompt = _prompt(6, 330 + budget)
+    h = wave.eng.submit(prompt, budget)
+    assert h.result(timeout=120) == _ref(params, prompt, budget)
+    (decode,) = wave.spans("serving.request.decode")
+    n_chunks = -(-(budget - 1) // C)
+    assert decode["attrs"]["wasted"] == 1 + C * n_chunks - budget == wave.count("serving.wasted_tokens")
+    assert len(wave.spans("serving.cb.chunk")) == n_chunks
+
+
+def test_a_chunk_that_raises_at_fetch_fails_both_chunks_riders_and_the_engine_serves_on(wave, params):
+    inner, reached, release, calls = wave.eng._land_chunk, threading.Event(), threading.Event(), [0]
+
+    def planted(chunk):
+        calls[0] += 1
+        if calls[0] == 1:  # chunk 2 is launched: the second request joins chunk 3
+            reached.set()
+            assert release.wait(timeout=60)
+        if calls[0] == 2:  # chunk 2's fetch, with chunk 3 queued behind it
+            raise RuntimeError("planted at the fetch")
+        return inner(chunk)
+
+    wave.eng._land_chunk = planted
+    a = wave.eng.submit(_prompt(6, 340), 30)
+    assert reached.wait(timeout=60)
+    b = wave.eng.submit(_prompt(9, 341), 30)
+    release.set()
+    for h in (a, b):  # a rode both chunks, b the one queued behind
+        with pytest.raises(RuntimeError, match="planted at the fetch"):
+            h.result(timeout=120)
+    assert [c["attrs"]["slots"] for c in wave.spans("serving.cb.chunk")] == [1, 1, 2]
+    leaks = wave.eng._alloc.check_leaks()
+    assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["accounted"]
+    assert np.all(wave.eng._tables == 0) and wave.eng.stats()["slots_active"] == 0 and wave.eng._inflight is None
+    fresh = _prompt(8, 342)
+    assert wave.eng.generate(fresh, 9) == _ref(params, fresh, 9)  # the unfetched chunk's rows are nobody's
+
+
+def test_shutdown_with_a_chunk_in_flight_returns_and_fails_its_riders(params):
+    w = _Wave(params)
+    reached, release = hold(w.eng, "_land_chunk")
+    h = w.eng.submit(_prompt(6, 350), 30)
+    assert reached.wait(timeout=60)
+    stopper = threading.Thread(target=w.close)
+    stopper.start()
+    release.set()
+    stopper.join(timeout=60)
+    assert not stopper.is_alive() and not w.eng._worker.is_alive()
+    with pytest.raises(RuntimeError, match="shutting down"):
+        h.result(timeout=5)
+    assert w.eng._inflight is None and w.eng.stats()["slots_active"] == 0
+    assert w.eng._alloc.check_leaks()["accounted"] and np.all(w.eng._tables == 0)
+
+
+def test_kv_tokens_live_is_the_live_rows_lengths_as_of_the_last_launched_chunk(wave):
+    reached, release = hold(wave.eng, "_land_chunk", nth=2)  # chunk 3 is launched, chunk 2 not yet fetched
+    handles = wave.burst([(_prompt(5, 360), 30, {}), (_prompt(9, 361), 30, {})])
+    assert reached.wait(timeout=60)
+    st = wave.eng.stats()
+    seen = [len(s.tokens) for s in wave.eng._slots if s is not None]
+    release.set()
+    assert st["slots_active"] == 2 and st["kv_tokens_live"] == (5 + 9) + 2 * 3 * C
+    assert seen == [1 + C, 1 + C]  # what the host has of them: the device is two chunks past it
+    for h in handles:
+        assert len(h.result(timeout=120)) == 30
+    assert wave.eng.stats()["kv_tokens_live"] == 0
 
 
 @pytest.mark.parametrize("label", ["prefill", "paged_step", "paged_admit",

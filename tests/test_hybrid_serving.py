@@ -18,6 +18,7 @@ from fedml_tpu.models.transformer import TransformerLM
 from fedml_tpu.serving.continuous_batching import PagedContinuousBatchingEngine
 from fedml_tpu.serving.paged_kv import PagedKVAllocator
 from fedml_tpu.train.llm.checkpoint_import import config_from_hf_keys
+from tests._engine_gate import hold
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for p in (REPO, os.path.join(REPO, "benchmark")):
@@ -167,6 +168,55 @@ def test_one_wave_of_a_snapshot_hit_an_unshared_and_a_snapshot_leaving_rider(par
         for r, out in zip(riders, served):
             assert out == [int(t) for t in generate(params, CFG, jnp.asarray([r], jnp.int32), 8)[0]]
             assert _gap(params, r, out) < GAP_TOL
+        leaks = eng._alloc.check_leaks()
+        assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["state_leaked"] == [] and leaks["accounted"]
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_riders_written_by_slot_while_a_chunk_is_in_flight_are_served_generates_tokens(params, temperature):
+    """The loop runs one chunk ahead: a rider's recurrent state is written at
+    its slot, and its row of the carry on the device, while the chunk before
+    is still unfetched; it rides the next chunk beside the row that was live."""
+    from fedml_tpu.train.llm.generation import generate
+
+    kw = [({"temperature": temperature, "seed": 70 + i} if temperature else {}) for i in range(4)]
+    reqs = [(_toks(21, 70), 22, kw[0]), (_toks(5, 71), 9, kw[1]), (_toks(33, 72), 1, kw[2]), (_toks(17, 73), 6, kw[3])]
+    eng = _engine(params, num_slots=3)
+    try:
+        reached, release = hold(eng, "_land_chunk")  # chunk 2 launched, chunk 1 not yet fetched
+        handles = [eng.submit(reqs[0][0], reqs[0][1], **reqs[0][2])]
+        assert reached.wait(timeout=60)
+        assert eng._inflight is not None
+        handles += [eng.submit(p, n, **k) for p, n, k in reqs[1:]]
+        release.set()
+        for (p, n, k), h in zip(reqs, handles):
+            want = generate(params, CFG, jnp.asarray([p], jnp.int32), n, temperature=k.get("temperature", 0.0),
+                            key=jax.random.PRNGKey(k.get("seed", 0)))
+            out = h.result(timeout=300)
+            assert out == [int(t) for t in want[0]]
+            if not temperature:
+                assert _gap(params, p, out) < GAP_TOL
+        leaks = eng._alloc.check_leaks()
+        assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["state_leaked"] == [] and leaks["accounted"]
+    finally:
+        eng.shutdown()
+
+
+def test_a_row_past_its_eos_updates_the_slots_state_and_the_next_rider_overwrites_it(params):
+    """An EOS is seen a chunk late: the chunk already queued still advances the
+    ended request's state at its slot. The slot's next rider is admitted behind
+    that chunk (program order) and writes the slot's state whole."""
+    eng = _engine(params, num_slots=1)
+    try:
+        first, second = _toks(30, 1), _toks(9, 2)
+        ref = eng.generate(first, 12)
+        eos = ref[2]
+        a = eng.submit(first, 12, eos_id=eos)
+        b = eng.submit(second, 10)
+        assert a.result(timeout=300) == ref[:ref.index(eos) + 1]
+        assert _gap(params, second, b.result(timeout=300)) < GAP_TOL
         leaks = eng._alloc.check_leaks()
         assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["state_leaked"] == [] and leaks["accounted"]
     finally:

@@ -22,6 +22,7 @@ from fedml_tpu.serving.continuous_batching import PagedContinuousBatchingEngine
 from fedml_tpu.serving.fedml_predictor import LLMPredictor
 from fedml_tpu.train.llm.checkpoint_import import config_from_hf_keys
 from fedml_tpu.train.llm.generation import generate
+from tests._engine_gate import hold
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for p in (REPO, os.path.join(REPO, "benchmark")):
@@ -117,6 +118,7 @@ def test_a_fresh_prompt_then_decode_through_the_latent_pool_equals_the_reference
 
 
 def test_a_prefix_hit_is_a_suffix_pass_over_shared_latent_pages(params):
+    tel.reset()  # prefill spans a dense or hybrid test left in this worker's registry carry no routing
     eng = _engine(params)
     try:
         system = _toks(32, 7)
@@ -156,6 +158,39 @@ def test_a_batch_of_two_lengths_equals_the_reference_and_counts_its_routing(para
         assert tel.counter("serving.moe.tokens_routed").value == st["moe_tokens_routed"]
         assert tel.counter("serving.moe.local_picks").value == st["moe_local_picks"]
         assert tel.counter("serving.moe.experts_hit").value == st["moe_experts_hit"] <= st["moe_local_picks"]
+    finally:
+        eng.shutdown()
+
+
+def test_a_chunks_routing_lands_a_chunk_later_on_the_span_that_launched_it(params):
+    """The loop runs one chunk ahead: chunk n's packed routing is fetched with
+    chunk n's tokens, while chunk n+1 runs, and noted on chunk n's OWN span.
+    Riders join while a chunk is in flight, so consecutive chunks differ."""
+    tel.reset()
+    eng = _engine(params, num_slots=3)
+    try:
+        reached, release = hold(eng, "_land_chunk")  # chunk 2 is launched, chunk 1 not yet fetched
+        reqs = [(_toks(21, 5), 18), (_toks(7, 6), 6), (_toks(40, 7), 11)]
+        handles = [eng.submit(*reqs[0])]
+        assert reached.wait(timeout=60)
+        (unlanded,) = [s["attrs"] for s in tel.snapshot()["spans"] if s["name"] == "serving.cb.chunk"]
+        assert "tokens_routed" not in unlanded  # chunk 1's span closed at its launch; chunk 2's is still open
+        handles += [eng.submit(p, n) for p, n in reqs[1:]]
+        release.set()
+        for (p, n), h in zip(reqs, handles):
+            served = h.result(timeout=300)
+            assert served == [int(t) for t in generate(params, CFG, jnp.asarray([p]), n)[0]]
+            assert _gap(params, p, served) < GAP_TOL
+        chunks = [s["attrs"] for s in sorted(tel.snapshot()["spans"], key=lambda s: s["t0_ns"])
+                  if s["name"] == "serving.cb.chunk"]
+        assert [c["slots"] for c in chunks[:3]] == [1, 1, 3] and len({c["slots"] for c in chunks}) > 1
+        for c in chunks:  # every live row's 4 steps through the 2 routed layers: this chunk's rows, not its neighbour's
+            assert c["tokens_routed"] == c["slots"] * 4 * 2 and 0 < c["experts_hit"] <= c["local_picks"]
+        st = eng.stats()
+        assert st["moe_tokens_routed"] == sum(c["tokens_routed"] for c in chunks) + (21 + 7 + 40) * 2
+        assert tel.counter("serving.cb.chunks_ahead").value == len(chunks) - 1  # one start from nothing in flight
+        leaks = eng._alloc.check_leaks()
+        assert leaks["accounted"] and not leaks["leaked"]
     finally:
         eng.shutdown()
 
@@ -236,8 +271,9 @@ def test_the_seam_takes_the_latent_leaf_without_a_fork(params):
                                    "idx": jnp.int32(40)}} for i in range(3)}
     write = np.zeros((8,), np.int32)
     write[:3] = [5, 2, 9]
+    carry = (jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32), jnp.zeros((2, 2), jnp.uint32))
     pool, _, _ = paged_kv._paged_admit_fn(pcfg)(pool, row, write, np.int32(0), jnp.zeros((1, 101)), np.uint32(0),
-                                               np.float32(0))
+                                               np.float32(0), carry, np.int32(40))
     back = paged_kv._paged_gather_fn(pcfg)(pool, write, np.int32(32))
     for i in range(3):
         np.testing.assert_array_equal(np.asarray(back[f"layer_{i}"]["attn"]["latent"][0, :48]),

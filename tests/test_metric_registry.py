@@ -126,6 +126,7 @@ EXPORTED = {
     "fedml_serving_cb_requests_total": "counter",
     "fedml_serving_cb_admissions_total": "counter",
     "fedml_serving_paged_launches_overlapped_total": "counter",
+    "fedml_serving_cb_chunks_ahead_total": "counter",
     "fedml_serving_cb_tokens_generated_total": "counter",
     "fedml_serving_cb_ttft_seconds": "histogram",
     "fedml_serving_cb_tpot_seconds": "histogram",

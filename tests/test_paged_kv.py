@@ -279,7 +279,9 @@ def _admit_operands(params, seed=3, temp=0.8, slot=0):
     ids[0, :19] = _prompt(19, 5)
     row, first = _prefill_fn(CFG, 1, 32)(params, ids, np.int32(19))
     write_ids = np.array([3, 4, 0, 0], np.int32)
-    return pcfg, (pool, row, write_ids, np.int32(slot), first, np.uint32(seed), np.float32(temp))
+    carry = (jnp.asarray([7, 8], jnp.int32), jnp.asarray([30, 40], jnp.int32),
+             jnp.asarray([[1, 2], [3, 4]], jnp.uint32))  # the decode step's (tok, lengths, keys), both rows another request's
+    return pcfg, (pool, row, write_ids, np.int32(slot), first, np.uint32(seed), np.float32(temp), carry, np.int32(19))
 
 
 def test_paged_admit_donates_the_pool_where_the_backend_donates(params, monkeypatch):
@@ -289,7 +291,7 @@ def test_paged_admit_donates_the_pool_where_the_backend_donates(params, monkeypa
     pcfg, args = _admit_operands(params)
     plain = paged_kv._paged_admit_fn(pcfg)  # this backend's: the CPU's is not donated
     assert not any(a.donated for a in jax.tree_util.tree_leaves(plain.lower(*args).args_info))
-    want_pool, want_tok, want_key = plain(*args)
+    want_pool, want_tok, want_carry = plain(*args)
     assert not any(x.is_deleted() for x in jax.tree_util.tree_leaves(args[0]))
 
     monkeypatch.setattr(generation, "_COMPILED", {})  # build again, as on the chip
@@ -304,21 +306,27 @@ def test_paged_admit_donates_the_pool_where_the_backend_donates(params, monkeypa
     header = lowered.compile().as_text().split("\n", 1)[0]
     if "input_output_alias" in header:  # a backend that aliases says so: every pool leaf, and nothing else
         assert header.count("-alias)") == n_pool, header
-    got_pool, got_tok, got_key = donating(*args)
-    jax.tree_util.tree_map(np.testing.assert_array_equal, got_pool, want_pool)
-    assert int(got_tok) == int(want_tok) and np.array_equal(got_key, want_key)
+    got_pool, got_tok, got_carry = donating(*args)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, (got_pool, got_carry), (want_pool, want_carry))
+    assert int(got_tok) == int(want_tok)
+    assert not any(x.is_deleted() for x in args[7])  # the carry is small: taken and returned, not donated
 
 
 @pytest.mark.parametrize("seed", [0, 42, 2**31 + 5, 2**32 - 1, 2**32 + 7, -1])
 def test_admit_program_makes_the_requests_key_from_its_seed(params, seed):
     """``jax.random.PRNGKey(seed)`` is built inside the program from a uint32
     (the engine passes ``seed & 0xFFFFFFFF``): the key the slot decodes on and
-    the first sampled token are what the eager key gave."""
+    the first sampled token are what the eager key gave, and they are the
+    slot's row of the carry the decode step takes next, beside the prompt's
+    length; the other slot's row is as it came."""
     from fedml_tpu.serving import paged_kv
     from fedml_tpu.train.llm.generation import _sample
 
-    pcfg, args = _admit_operands(params, seed=seed & 0xFFFFFFFF)
-    _, tok0, key2 = paged_kv._paged_admit_fn(pcfg)(*args)
+    slot = seed % 2
+    pcfg, args = _admit_operands(params, seed=seed & 0xFFFFFFFF, slot=slot)
+    _, tok0, (tok, lengths, keys) = paged_kv._paged_admit_fn(pcfg)(*args)
     want_key2, sub = jax.random.split(jax.random.PRNGKey(seed))
-    assert np.array_equal(key2, want_key2)
-    assert int(tok0) == int(_sample(args[4][0], sub, args[6]))
+    assert np.array_equal(keys[slot], want_key2)
+    assert int(tok0) == int(tok[slot]) == int(_sample(args[4][0], sub, args[6])) and int(lengths[slot]) == 19
+    for got, came in zip((tok, lengths, keys), args[7]):
+        assert got.dtype == came.dtype and np.array_equal(got[1 - slot], came[1 - slot])

@@ -25,6 +25,7 @@ from ._util import matches_file
 HOT_LOOPS: tuple = (
     ("continuous_batching.py", "PagedContinuousBatchingEngine._admit_all"),
     ("continuous_batching.py", "PagedContinuousBatchingEngine._step_chunk"),
+    ("continuous_batching.py", "PagedContinuousBatchingEngine._land_chunk"),
     ("continuous_batching.py", "PagedContinuousBatchingEngine._stage_prefill"),
     ("replica_controller.py", "InferenceGateway.predict"),
 )
