@@ -210,12 +210,19 @@ def routing_stats(sown, n_live) -> jnp.ndarray:
     return jnp.concatenate([head, jnp.sum(loads, axis=0)]).astype(jnp.int32)
 
 
-def route(router_logits: jnp.ndarray, top_k: int, scaling: float, norm_topk: bool):
+def route(router_logits: jnp.ndarray, top_k: int, scaling: float, norm_topk: bool,
+          select_bias: Optional[jnp.ndarray] = None):
     """``(experts [N, k] int32, gates [N, k] f32)`` from logits ``[N, E]``:
     sigmoid scores, the k largest, gates ``scaling * s_e / (sum of the picked
-    s + 1e-20)`` (``norm_topk``) or ``scaling * s_e``."""
+    s + 1e-20)`` (``norm_topk``) or ``scaling * s_e``. With ``select_bias``
+    ``[E]`` the picks are the k largest of ``s + bias``; the gates are still
+    made of the picked ``s``: the bias moves the choice and nothing else."""
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
-    top, experts = jax.lax.top_k(scores, top_k)
+    if select_bias is None:
+        top, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
         top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), top * scaling
@@ -312,7 +319,10 @@ class RoutedMoE(nn.Module):
 
         # the router keeps its published width and its picks, in float32
         logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32), precision=HIGHEST)
-        experts, gates = route(logits, k, cfg.moe_routed_scaling, cfg.moe_norm_topk)
+        more = {}
+        if cfg.moe_select_bias:
+            more["select_bias"] = self.param("router_bias", nn.initializers.zeros, (E,), jnp.float32)
+        experts, gates = route(logits, k, cfg.moe_routed_scaling, cfg.moe_norm_topk, **more)
         tm = row_tile(N, k, E)
         row_token, pair_row, mine, tile_group, n_live, load = sort_pairs(
             experts, live.reshape(-1), first, held, tm)

@@ -111,13 +111,42 @@ class TransformerConfig:
     # an RMSNorm on each sublayer's OUTPUT before the residual add, beside the
     # one on its input: x + N(mixer(N(x))), then h + N(ffn(N(h)))
     sandwich_norm: bool = False
+    # Width of one attention head, a value of its own: 0 = d_model // n_heads
+    # (what every config above builds), resolved when the config is made, so
+    # ``cfg.head_dim`` always reads the width in use.
+    head_dim: int = 0
+    # What an attention layer sees, a layer: () = every one attends to its whole
+    # prefix ("full"); else one entry a layer (read where the layer is of kind
+    # "attention"), "full" or "window": a window layer's query at position t
+    # sees keys ``t - sliding_window < j <= t`` and always rotates q and k; a
+    # full layer rotates them iff ``use_rope``. In the paged engine the window
+    # layers' K/V live in a page group of their own (``kv_window_pages`` pages;
+    # serving/paged_kv.py), whose pages go back behind a request's horizon.
+    attn_kinds: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    kv_window_pages: int = 0
+    qk_norm: bool = False         # an RMSNorm over head_dim on q and on k, before the rotation
+    attn_gate: bool = False       # out = W_o(attn * sigmoid(W_g x)), W_g n_heads * head_dim wide
+    embed_scale: float = 1.0      # x0 = E[token] * embed_scale
+    # a per-expert bias added to the router's scores for the CHOICE of the
+    # picks only (the gates come from the scores without it)
+    moe_select_bias: bool = False
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else "attention"
+
+    def attn_kind(self, i: int) -> str:
+        return self.attn_kinds[i] if self.attn_kinds else "full"
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """Indices of the attention layers that see a window."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i) == "attention" and self.attn_kind(i) == "window")
 
     def ffn_kind(self, i: int) -> str:
         """"routed" or "dense" (the Switch layer of ``moe_experts`` is the
@@ -273,15 +302,19 @@ def repeat_kv(k: jnp.ndarray, v: jnp.ndarray, n_heads: int) -> Tuple[jnp.ndarray
     return k, v
 
 
-def xla_attention(q, k, v, causal: bool = True, mask: Optional[jnp.ndarray] = None):
+def xla_attention(q, k, v, causal: bool = True, mask: Optional[jnp.ndarray] = None,
+                  window: int = 0):
     """Plain einsum attention; XLA fuses + tiles this well for short T.
     ``mask`` overrides the causal triangle (decode path: [T_q, T_k] valid
-    positions); both paths share this one body so they cannot diverge."""
+    positions); both paths share this one body so they cannot diverge. With
+    ``window`` the triangle keeps only the ``window`` newest keys of a query."""
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if mask is None and causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), jnp.bool_), tk - tq)
+        if window:
+            mask = jnp.logical_and(mask, jnp.triu(jnp.ones((tq, tk), jnp.bool_), tk - tq - window + 1))
     if mask is not None:
         logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
@@ -332,7 +365,31 @@ def _paged_attention_impl(platform: str, head_dim: int, page_size: int,
     return pa.paged_attention
 
 
-def _sharded_flash_attention(q, k, v):
+#: bytes of float32 scores ``[n_heads, T, S]`` past which a pass over a
+#: contiguous row cache no longer builds them: the rows' kernel runs instead
+PREFILL_SCORE_BYTES = 512 * 2 ** 20
+
+
+@functools.lru_cache(maxsize=None)
+def _row_attention_impl(platform: str, T: int, S: int, n_heads: int, head_dim: int):
+    """Which formulation a multi-token pass over a contiguous row cache (a
+    prefill, a suffix pass) attends with, logged once per distinct case: the
+    masked einsum over the whole row while its ``[n_heads, T, S]`` float32
+    scores stay under ``PREFILL_SCORE_BYTES`` (every pass of a 2,048-token
+    row), and past that ``ops.flash_attention.flash_attention_rows`` where it
+    runs compiled and tiles the shape: the scores never exist and only the
+    blocks a query can see are visited. Decided here, from shapes."""
+    from ..ops.flash_attention import rows_tile
+
+    big = 4 * n_heads * T * S > PREFILL_SCORE_BYTES
+    impl = "pallas" if platform == "tpu" and big and rows_tile(S) else "xla"
+    if big:
+        log.info("row-cache attention -> %s (platform=%s, T=%d, S=%d, n_heads=%d, head_dim=%d)",
+                 impl, platform, T, S, n_heads, head_dim)
+    return impl
+
+
+def _sharded_flash_attention(q, k, v, window: int = 0):
     """The pallas kernel under whatever mesh the train step is sharded over.
 
     GSPMD has no partitioning rule for a Mosaic custom call: left bare inside
@@ -344,7 +401,7 @@ def _sharded_flash_attention(q, k, v):
     from ..ops.flash_attention import flash_attention
     from ..parallel.ring_attention import get_active_mesh
 
-    kernel = functools.partial(flash_attention, causal=True)
+    kernel = functools.partial(flash_attention, causal=True, window=window)
     mesh = get_active_mesh()
     if mesh is None or mesh.size == 1:
         return kernel(q, k, v)
@@ -358,50 +415,67 @@ def _sharded_flash_attention(q, k, v):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    kind: str = "full"  # TransformerConfig.attn_kind: "full" or "window"
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, positions: jnp.ndarray,
                  cache_idx: Optional[jnp.ndarray] = None,
                  block_tables: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+        """``block_tables``: the tables of THIS layer's page group (the window
+        group's for a window layer; ``Block`` picks)."""
         cfg = self.cfg
         B, T, _ = x.shape
         hd = cfg.head_dim
+        window = cfg.sliding_window if self.kind == "window" else 0
         q = LoRALinear(cfg.n_heads * hd, cfg, name="q_proj")(x).reshape(B, T, cfg.n_heads, hd)
         k = LoRALinear(cfg.n_kv_heads * hd, cfg, name="k_proj")(x).reshape(B, T, cfg.n_kv_heads, hd)
         v = LoRALinear(cfg.n_kv_heads * hd, cfg, name="v_proj")(x).reshape(B, T, cfg.n_kv_heads, hd)
-        if cfg.use_rope:
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
+        if window or cfg.use_rope:
             q = rotary_embedding(q, positions, cfg.rope_theta)
             k = rotary_embedding(k, positions, cfg.rope_theta)
         if cfg.decode:
             if cfg.kv_page_size > 0:
-                return self._paged_decode_attention(q, k, v, B, T, cache_idx,
-                                                    block_tables)
-            return self._decode_attention(q, k, v, B, T)
-        impl = cfg.attention_impl
-        if impl == "auto":
-            impl = _auto_attention_impl(jax.default_backend(), T)
-        if impl == "pallas":
-            # GQA-native: the kernel maps query heads to kv heads itself —
-            # repeat_kv here would materialize G copies of K/V in HBM
-            out = _sharded_flash_attention(q, k, v)
-        elif impl == "ring":
-            from ..parallel.ring_attention import ring_attention_inner
-
-            k, v = repeat_kv(k, v, cfg.n_heads)
-            out = ring_attention_inner(q, k, v)
+                out = self._paged_decode_attention(q, k, v, B, T, cache_idx, block_tables, window)
+            else:
+                out = self._decode_attention(q, k, v, B, T, window)
         else:
-            k, v = repeat_kv(k, v, cfg.n_heads)
-            out = xla_attention(q, k, v, causal=True)
+            impl = cfg.attention_impl
+            if impl == "auto":
+                impl = _auto_attention_impl(jax.default_backend(), T)
+            if impl == "pallas":
+                # GQA-native: the kernel maps query heads to kv heads itself —
+                # repeat_kv here would materialize G copies of K/V in HBM
+                out = _sharded_flash_attention(q, k, v, window)
+            elif impl == "ring":
+                from ..parallel.ring_attention import ring_attention_inner
+
+                if window:
+                    raise ValueError("attention_impl='ring' has no window: a window layer's keys are local "
+                                     "to a few sequence shards; use 'pallas' or 'xla'")
+                k, v = repeat_kv(k, v, cfg.n_heads)
+                out = ring_attention_inner(q, k, v)
+            else:
+                k, v = repeat_kv(k, v, cfg.n_heads)
+                out = xla_attention(q, k, v, causal=True, window=window)
         out = out.reshape(B, T, cfg.n_heads * hd)
+        if cfg.attn_gate:
+            gate = LoRALinear(cfg.n_heads * hd, cfg, name="g_proj")(x)
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
         return LoRALinear(cfg.d_model, cfg, name="o_proj")(out)
 
-    def _decode_attention(self, q, k, v, B: int, T: int) -> jnp.ndarray:
+    def _decode_attention(self, q, k, v, B: int, T: int, window: int) -> jnp.ndarray:
         """KV-cache attention over contiguous rows (flax 'cache' collection):
         the engine's prefill rows and ``generation.generate``. Supports
         prefill (T = prompt length) and single-token steps (T = 1): new k/v
         are written at the running cache index, shared by every row, and
-        queries attend to everything written so far. Static shapes: the
-        cache is [B, max_seq_len, kv, hd] with an index mask."""
+        queries attend to everything written so far, a window layer's to the
+        ``window`` newest of it. Static shapes: the cache is
+        [B, max_seq_len, kv, hd] with an index mask; a pass whose scores over
+        the whole row would be too many runs the rows' kernel instead
+        (``_row_attention_impl``)."""
         cfg = self.cfg
         hd = cfg.head_dim
         S = cfg.max_seq_len
@@ -413,16 +487,21 @@ class Attention(nn.Module):
             ck.value = jax.lax.dynamic_update_slice(ck.value, k.astype(ck.value.dtype), (0, idx, 0, 0))
             cv.value = jax.lax.dynamic_update_slice(cv.value, v.astype(cv.value.dtype), (0, idx, 0, 0))
             cidx.value = idx + T
+        if _row_attention_impl(jax.default_backend(), T, S, cfg.n_heads, hd) == "pallas":
+            from ..ops.flash_attention import flash_attention_rows
+
+            return flash_attention_rows(q, ck.value, cv.value, idx, window=window)
         k_all, v_all = repeat_kv(ck.value, cv.value, cfg.n_heads)  # [B, S, h, hd]
         q_pos = idx + jnp.arange(T)  # absolute position of each query
-        valid = jnp.arange(S)[None, :] <= q_pos[:, None]  # [T, S] causal+written
-        out = xla_attention(q, k_all, v_all, mask=valid)
-        out = out.reshape(B, T, cfg.n_heads * hd)
-        return LoRALinear(cfg.d_model, cfg, name="o_proj")(out)
+        k_pos = jnp.arange(S)[None, :]
+        valid = k_pos <= q_pos[:, None]  # [T, S] causal+written
+        if window:
+            valid = jnp.logical_and(valid, k_pos > q_pos[:, None] - window)
+        return xla_attention(q, k_all, v_all, mask=valid)
 
     def _paged_decode_attention(self, q, k, v, B: int, T: int,
                                 cache_idx: Optional[jnp.ndarray],
-                                block_tables: Optional[jnp.ndarray]) -> jnp.ndarray:
+                                block_tables: Optional[jnp.ndarray], window: int) -> jnp.ndarray:
         """Block-table KV attention over a physical page pool (the paged
         serving engine's mode, serving/paged_kv.py). The cache collection is
         [kv_num_pages, kv_page_size, kv, hd] per layer — one pool shared by
@@ -444,17 +523,24 @@ class Attention(nn.Module):
         plus the token just scattered. Unallocated block-table entries point
         at the reserved trash page 0 and lie beyond every row's index, so
         their garbage is never read. ``paged_step`` hands a freed slot
-        ``cache_idx = -1``: length 0, no page read, output ignored."""
+        ``cache_idx = -1``: length 0, no page read, output ignored.
+
+        A WINDOW layer's pool is the window group's (``kv_window_pages``
+        pages) and its tables that group's: same logical indexing, but the
+        entries behind the row's horizon ``cache_idx - window`` point at the
+        trash page again (their pages went back), and the walk starts at the
+        horizon, not at 0: those entries are neither copied nor scored."""
         cfg = self.cfg
         hd = cfg.head_dim
         ps = cfg.kv_page_size
-        n_pages = cfg.kv_num_pages
+        n_pages = cfg.kv_window_pages if window else cfg.kv_num_pages
         if T != 1:
             raise ValueError(f"paged decode requires T=1 steps, got T={T}")
         if cache_idx is None or block_tables is None:
             raise ValueError("paged decode requires cache_idx and block_tables")
         if n_pages < 2:
-            raise ValueError("kv_num_pages must be >= 2 (page 0 is the trash page)")
+            raise ValueError("kv_num_pages (and with window layers kv_window_pages) must be >= 2 "
+                             "(page 0 is the trash page)")
         ck = self.variable("cache", "k", jnp.zeros, (n_pages, ps, cfg.n_kv_heads, hd), q.dtype)
         cv = self.variable("cache", "v", jnp.zeros, (n_pages, ps, cfg.n_kv_heads, hd), q.dtype)
         # the contiguous rows' shared scalar write index, kept so the two
@@ -471,9 +557,10 @@ class Attention(nn.Module):
             cv.value = cv.value.at[page, off].set(v[:, 0].astype(cv.value.dtype))
         attend = _paged_attention_impl(jax.default_backend(), hd, ps, cfg.n_heads,
                                        cfg.n_kv_heads, jnp.dtype(q.dtype).name)
-        out = attend(q[:, 0], ck.value, cv.value, block_tables, cache_idx + 1)
-        out = out.reshape(B, T, cfg.n_heads * hd)
-        return LoRALinear(cfg.d_model, cfg, name="o_proj")(out)
+        if window:
+            starts = jnp.maximum(cache_idx + 1 - window, 0)
+            return attend(q[:, 0], ck.value, cv.value, block_tables, cache_idx + 1, starts)
+        return attend(q[:, 0], ck.value, cv.value, block_tables, cache_idx + 1)
 
 
 class MLP(nn.Module):
@@ -493,13 +580,15 @@ class Block(nn.Module):
     cfg: TransformerConfig
     kind: str = "attention"  # the mixer before the feed-forward: LAYER_KINDS
     ffn: str = "dense"       # the feed-forward: TransformerConfig.ffn_kind
+    attn: str = "full"       # what an attention mixer sees: TransformerConfig.attn_kind
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, positions: jnp.ndarray,
                  cache_idx: Optional[jnp.ndarray] = None,
                  block_tables: Optional[jnp.ndarray] = None,
                  seq_lens: Optional[jnp.ndarray] = None,
-                 snap_lens: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 snap_lens: Optional[jnp.ndarray] = None,
+                 window_tables: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         cfg = self.cfg
         if self.kind == "mamba":
             from .mamba import MambaMixer
@@ -512,7 +601,9 @@ class Block(nn.Module):
             mixed = LatentAttention(cfg, name="attn")(
                 RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, cache_idx, block_tables)
         else:
-            mixed = Attention(cfg, name="attn")(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, cache_idx, block_tables)
+            mixed = Attention(cfg, self.attn, name="attn")(
+                RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, cache_idx,
+                window_tables if self.attn == "window" else block_tables)
         if cfg.sandwich_norm:
             mixed = RMSNorm(cfg.norm_eps, name="mixer_out_norm")(mixed)
         x = x + mixed
@@ -557,19 +648,33 @@ class TransformerLM(nn.Module):
                  cache_idx: Optional[jnp.ndarray] = None,
                  block_tables: Optional[jnp.ndarray] = None,
                  seq_lens: Optional[jnp.ndarray] = None,
-                 snap_lens: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 snap_lens: Optional[jnp.ndarray] = None,
+                 window_tables: Optional[jnp.ndarray] = None,
+                 logit_rows: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         """``seq_lens`` [B]: how many of this pass's T tokens are real (the
         rest is right padding; default all). ``snap_lens`` [B]: after how many
         of them a recurrent layer also keeps its state for the prefix cache.
         Attention layers ignore both (padded K/V are overwritten before they
-        can be read: ``generation._rewind_cache``)."""
+        can be read: ``generation._rewind_cache``). ``window_tables``: the
+        window page group's block tables (paged decode of a model with window
+        layers). ``logit_rows`` [B]: the one position a row whose logits are
+        wanted: the result is ``[B, 1, vocab]`` and the head runs over B
+        tokens, not B x T (a prefill samples one token)."""
         cfg = self.cfg
+        if cfg.attn_kinds and (len(cfg.attn_kinds) != cfg.n_layers or set(cfg.attn_kinds) - {"full", "window"}
+                               or ("window" in cfg.attn_kinds and cfg.sliding_window < 1)):
+            raise ValueError(f"attn_kinds must name 'full' or 'window' for each of {cfg.n_layers} layers, with "
+                             f"sliding_window >= 1 beside a window layer, got {cfg.attn_kinds!r} and "
+                             f"sliding_window={cfg.sliding_window}")
         if cfg.layer_pattern and (len(cfg.layer_pattern) != cfg.n_layers
                                   or set(cfg.layer_pattern) - set(LAYER_KINDS)):
             raise ValueError(f"layer_pattern must name one of {LAYER_KINDS} for each of "
                              f"{cfg.n_layers} layers, got {cfg.layer_pattern!r}")
         embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="embed")
-        x = embed(tokens).astype(cfg.dtype)
+        x = embed(tokens)
+        if cfg.embed_scale != 1.0:
+            x = x.astype(jnp.float32) * cfg.embed_scale
+        x = x.astype(cfg.dtype)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
         block = Block
@@ -583,8 +688,10 @@ class TransformerLM(nn.Module):
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             block = nn.remat(Block, static_argnums=(), policy=policy)
         for i in range(cfg.n_layers):
-            x = block(cfg, cfg.layer_kind(i), cfg.ffn_kind(i), name=f"layer_{i}")(
-                x, positions, cache_idx, block_tables, seq_lens, snap_lens)
+            x = block(cfg, cfg.layer_kind(i), cfg.ffn_kind(i), cfg.attn_kind(i), name=f"layer_{i}")(
+                x, positions, cache_idx, block_tables, seq_lens, snap_lens, window_tables)
+        if logit_rows is not None:
+            x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         if cfg.tie_embeddings:
             logits = embed.attend(x)

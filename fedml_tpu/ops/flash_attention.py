@@ -195,8 +195,16 @@ def _loop(lo, hi, body, carry):
     return jax.lax.fori_loop(lo, hi, body, carry)
 
 
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.maximum(a, b)
+
+
+def _imin(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
+
+
 def _visit_blocks(step, carry, i, block_i: int, block_j: int, num_j: int,
-                  causal: bool, visible_before: bool):
+                  causal: bool, visible_before: bool, window: int = 0):
     """Fold ``step(j, carry, masked)`` over the j-blocks that i-block ``i``
     sees — the causal geometry of all three kernels, in one place so that
     their visit sets cannot diverge. The diagonal crosses the j-blocks
@@ -205,25 +213,55 @@ def _visit_blocks(step, carry, i, block_i: int, block_j: int, num_j: int,
     straight-line code. For a q-block (forward, dQ: j runs over k-blocks,
     ``visible_before``) the blocks before them are wholly visible and the
     ones after are never streamed; for a k-block (dK/dV: j runs over
-    q-blocks) the ones before are skipped and the ones after wholly visible."""
+    q-blocks) the ones before are skipped and the ones after wholly visible.
+
+    With ``window`` (a query at row t sees the keys ``t - window < j <= t``)
+    the visible side is cut off at the horizon: the blocks wholly behind it
+    are not visited at all, so a row costs ``window`` keys whatever T, and the
+    few blocks the horizon crosses are masked like the diagonal's. For a
+    q-block rows ``[r0, r1]`` that is the k-blocks from the one holding column
+    ``r0 - window + 1``; those up to the one holding ``r1 - window`` are masked.
+    For a k-block columns ``[c0, c1]`` the q-blocks up to the one holding row
+    ``c1 + window - 1``; those from the one holding ``c0 + window`` are masked."""
     unmasked = functools.partial(step, masked=False)
     if not causal:
         return _loop(0, num_j, unmasked, carry)
     first, n = (i * block_i) // block_j, max(1, block_i // block_j)
+    if not window:
+        if visible_before:
+            carry = _loop(0, first, unmasked, carry)
+        for d in range(n):
+            carry = step(first + d, carry, masked=True)
+        if not visible_before:
+            carry = _loop(first + n, num_j, unmasked, carry)
+        return carry
+    masked = functools.partial(step, masked=True)
+    i_lo = i * block_i
+    i_hi = i_lo + block_i - 1
     if visible_before:
-        carry = _loop(0, first, unmasked, carry)
+        lo = _imax(i_lo - window + 1, 0) // block_j
+        # k-blocks whose first column is at or behind a row's horizon: the first ceil((r1 - W + 1) / bk)
+        edge = _imin(_imax((_imax(i_hi - window + 1, 0) + block_j - 1) // block_j, lo), first)
+        carry = _loop(lo, edge, masked, carry)
+        carry = _loop(edge, first, unmasked, carry)
     for d in range(n):
         carry = step(first + d, carry, masked=True)
     if not visible_before:
-        carry = _loop(first + n, num_j, unmasked, carry)
+        hi = _imin((i_hi + window - 1) // block_j + 1, num_j)
+        edge = _imin(_imax((i_lo + window) // block_j, first + n), hi)
+        carry = _loop(first + n, edge, unmasked, carry)
+        carry = _loop(edge, hi, masked, carry)
     return carry
 
 
-def _visible(row0, col0, shape, row_axis: int):
+def _visible(row0, col0, shape, row_axis: int, window: int = 0):
     """Causal mask of one tile whose first query row is ``row0`` and first
-    key column ``col0``; queries run along ``row_axis`` of ``shape``."""
+    key column ``col0``; queries run along ``row_axis`` of ``shape``. With
+    ``window`` a row also loses the columns at or behind ``row - window``."""
     row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, row_axis)
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - row_axis)
+    if window:
+        return jnp.logical_and(col <= row, col > row - window)
     return col <= row
 
 
@@ -251,7 +289,7 @@ def _each_block(index, count: int, causal: bool, program):
 # --- forward -----------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int, block_k: int,
-                causal: bool, scale: float):
+                causal: bool, scale: float, window: int = 0):
     q = q_ref[0]  # [block_q, D], input dtype — matmuls accumulate in f32
     T = k_ref.shape[1]
     D = q.shape[-1]
@@ -264,12 +302,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int, block_k: i
             # scale AFTER the matmul (in f32): pre-scaling bf16 q would round
             s = _dot_nt(q, k_blk) * scale  # [block_q, block_k] on the MXU
             if masked:
-                s = jnp.where(_visible(qi * block_q, kj * block_k, s.shape, 0), s, NEG_INF)
+                vis = _visible(qi * block_q, kj * block_k, s.shape, 0, window)
+                s = jnp.where(vis, s, NEG_INF)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             corr = jnp.exp(m - m_new)
             # a masked entry gives exp(NEG_INF - m_new) = 0 exactly: m_new is
             # finite from the first block on (column 0 is visible to every row)
             p = jnp.exp(s - m_new)
+            if masked and window:
+                # behind a horizon a row may see nothing of its first block:
+                # m_new is still NEG_INF there and exp(0) would count
+                p = jnp.where(vis, p, 0.0)
             l_new = l * corr + p.sum(axis=-1, keepdims=True)
             # p back to the input dtype for the AV matmul (f32 accumulate) —
             # the canonical flash mixed-precision recipe
@@ -282,7 +325,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int, block_k: i
         carry = (jnp.full((block_q, 1), NEG_INF, jnp.float32),
                  jnp.zeros((block_q, 1), jnp.float32),
                  jnp.zeros((block_q, D), jnp.float32))
-        carry = _visit_blocks(step, carry, qi, block_q, block_k, T // block_k, causal, True)
+        carry = _visit_blocks(step, carry, qi, block_q, block_k, T // block_k, causal, True, window)
         m, l, acc = carry
         l_safe = jnp.maximum(l, 1e-20)
         o_ref[0] = (acc * (1.0 / l_safe)).astype(o_ref.dtype)
@@ -312,9 +355,9 @@ def _vmem_limit(kind: str, block_q: int, block_k: int, D: int, resident_bytes: i
 
 # the impls are jitted so that the layers of a model (and the forward's second
 # run under remat) share ONE trace and ONE Mosaic lowering of each kernel
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "Hq", "Hkv", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "Hq", "Hkv", "interpret", "window"))
 def _fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int, Hq: int,
-              Hkv: int, interpret: bool):
+              Hkv: int, interpret: bool, window: int = 0):
     """q [B*Hq, T, D]; k/v [B*Hkv, T, D] -> (out [B*Hq, T, D], lse f32)."""
     BHq, T, D = q.shape
     scale = D ** -0.5
@@ -324,7 +367,7 @@ def _fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int, Hq: int,
     resident = 2 * T * D * item + 2 * block_q * D * item + block_q * 128 * 4
     return pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale, **({"window": window} if window else {})),
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             # the residual keeps a trailing singleton lane dim: Mosaic requires
@@ -354,7 +397,7 @@ def _fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int, Hq: int,
 # --- backward ----------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   block_q: int, block_k: int, causal: bool, scale: float):
+                   block_q: int, block_k: int, causal: bool, scale: float, window: int = 0):
     q = q_ref[0]                              # [block_q, D], input dtype
     do = do_ref[0]                            # [block_q, D], input dtype
     lse = lse_ref[0]                          # [block_q, 1]
@@ -368,13 +411,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
             s = _dot_nt(q, k_blk) * scale          # f32 accumulate, bf16 MXU rate
             p = jnp.exp(s - lse)
             if masked:
-                p = jnp.where(_visible(qi * block_q, kj * block_k, p.shape, 0), p, 0.0)
+                p = jnp.where(_visible(qi * block_q, kj * block_k, p.shape, 0, window), p, 0.0)
             dp = _dot_nt(do, v_blk)                # [block_q, block_k] f32
             ds = p * (dp - delta)
             return dq + _dot_nn(ds.astype(k_blk.dtype), k_blk)
 
         dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-        dq = _visit_blocks(step, dq, qi, block_q, block_k, T // block_k, causal, True)
+        dq = _visit_blocks(step, dq, qi, block_q, block_k, T // block_k, causal, True, window)
         dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
     _each_block(pl.program_id(1), T // block_q, causal, program)
@@ -382,7 +425,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, block_q: int, block_k: int,
-                    causal: bool, scale: float):
+                    causal: bool, scale: float, window: int = 0):
     """Grid over (B*Hkv, k blocks, G): the group dim is a GRID axis, not a
     VMEM block axis — q/do arrive one query head at a time (index-mapped
     ``i*G + g``), so VMEM stays O(T*D) regardless of the GQA group size.
@@ -407,7 +450,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s_t = _dot_nt(k, q_blk) * scale        # [block_k, block_q] f32
             p_t = jnp.exp(s_t - lse_ref[0, qj])
             if masked:
-                p_t = jnp.where(_visible(qj * block_q, ki * block_k, p_t.shape, 1), p_t, 0.0)
+                p_t = jnp.where(_visible(qj * block_q, ki * block_k, p_t.shape, 1, window), p_t, 0.0)
             dv_new = dv + _dot_nn(p_t.astype(do_blk.dtype), do_blk)
             dp_t = _dot_nt(v, do_blk)
             ds_t = p_t * (dp_t - delta_ref[0, qj])
@@ -415,7 +458,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             return dk_new, dv_new
 
         carry = (jnp.zeros((block_k, D), jnp.float32), jnp.zeros((block_k, D), jnp.float32))
-        carry = _visit_blocks(step, carry, ki, block_k, block_q, T // block_q, causal, False)
+        carry = _visit_blocks(step, carry, ki, block_k, block_q, T // block_q, causal, False, window)
         dk, dv = carry
 
         @pl.when(g == 0)
@@ -429,9 +472,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _each_block(pl.program_id(1), T // block_k, causal, program)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "dq_blocks", "dkv_blocks", "Hq", "Hkv", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "dq_blocks", "dkv_blocks", "Hq", "Hkv", "interpret", "window"))
 def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, dq_blocks, dkv_blocks,
-              Hq: int, Hkv: int, interpret: bool):
+              Hq: int, Hkv: int, interpret: bool, window: int = 0):
     BHq, T, D = q.shape
     BHkv = k.shape[0]
     G = Hq // Hkv
@@ -444,12 +487,13 @@ def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, dq_blocks, dkv_blocks,
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BHq, T, 1]
     kv_idx = _kv_index(Hq, Hkv)
+    win = {"window": window} if window else {}
 
     block_q, block_k = dq_blocks
     resident = 2 * T * D * item + 3 * block_q * D * item + 2 * block_q * 128 * 4
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale, **win),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(BHq, T // block_q),
         in_specs=[
@@ -484,7 +528,7 @@ def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, dq_blocks, dkv_blocks,
     resident = 2 * T * D * item + 2 * block_k * D * item + 2 * block_k * D * 4 + 2 * T * 8 * 4
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale, **win),
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, jnp.float32),
             jax.ShapeDtypeStruct(v.shape, jnp.float32),
@@ -514,22 +558,22 @@ def _bwd_impl(q, k, v, do, o, lse, *, causal: bool, dq_blocks, dkv_blocks,
 # --- custom_vjp wiring (on the [BH, T, D] layout) ----------------------------
 # ``blocks`` is ((block_q, block_k) of fwd, of dq, of dkv): static, hashable
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_r(q, k, v, causal, blocks, Hq, Hkv):
-    return _flash_r_fwd(q, k, v, causal, blocks, Hq, Hkv)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_r(q, k, v, causal, blocks, Hq, Hkv, window):
+    return _flash_r_fwd(q, k, v, causal, blocks, Hq, Hkv, window)[0]
 
 
-def _flash_r_fwd(q, k, v, causal, blocks, Hq, Hkv):
+def _flash_r_fwd(q, k, v, causal, blocks, Hq, Hkv, window):
     block_q, block_k = blocks[0]
     out, lse = _fwd_impl(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                         Hq=Hq, Hkv=Hkv, interpret=_interpret())
+                         Hq=Hq, Hkv=Hkv, interpret=_interpret(), window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_r_bwd(causal, blocks, Hq, Hkv, res, g):
+def _flash_r_bwd(causal, blocks, Hq, Hkv, window, res, g):
     q, k, v, o, lse = res
     return _bwd_impl(q, k, v, g, o, lse, causal=causal, dq_blocks=blocks[1],
-                     dkv_blocks=blocks[2], Hq=Hq, Hkv=Hkv, interpret=_interpret())
+                     dkv_blocks=blocks[2], Hq=Hq, Hkv=Hkv, interpret=_interpret(), window=window)
 
 
 _flash_r.defvjp(_flash_r_fwd, _flash_r_bwd)
@@ -553,6 +597,7 @@ def flash_attention(
     causal: bool = True,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """[B, T, Hq, D], [B, T, Hkv, D] x2 -> [B, T, Hq, D]. GQA-native: Hkv may
     divide Hq; K/V are consumed at their own head count (no repeat). Each of
@@ -560,8 +605,13 @@ def flash_attention(
     (local) shape; an explicit ``block_q`` AND ``block_k`` override all
     three. Raises when the blocks do not tile T (see :func:`tiles`) — a
     caller that asked for this kernel never silently gets einsum attention
-    instead."""
+    instead. ``window`` (causal only; 0 = none): a query sees its ``window``
+    newest keys, and the three kernels visit only the blocks those lie in
+    (``_visit_blocks``): ``T x window`` work, not ``T^2 / 2``."""
     B, T, Hq, D = q.shape
+    if window < 0 or (window and not causal):
+        raise ValueError(f"flash_attention: window {window} needs causal=True and must be >= 0")
+    window = 0 if window >= T else int(window)  # a window no row reaches is no window
     Hkv = k.shape[2]
     if Hq % Hkv:
         raise ValueError(f"q heads {Hq} not a multiple of kv heads {Hkv}")
@@ -579,5 +629,130 @@ def flash_attention(
     qr = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * Hq, T, D)
     kr = jnp.transpose(k, (0, 2, 1, 3)).reshape(B * Hkv, T, D)
     vr = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * Hkv, T, D)
-    out = _flash_r(qr, kr, vr, causal, blocks, Hq, Hkv)
+    out = _flash_r(qr, kr, vr, causal, blocks, Hq, Hkv, window)
     return jnp.transpose(out.reshape(B, Hq, T, D), (0, 2, 1, 3))
+
+
+# --- a pass over a contiguous row cache (serving: prefill, suffix pass) ---------
+
+def _rows_block(n: int) -> int:
+    """The largest rung of ``LADDER`` that divides ``n``; ``n`` itself below the smallest."""
+    return n if n < LADDER[-1] else next((r for r in LADDER if n % r == 0), 0)
+
+
+def rows_tile(S: int) -> bool:
+    """``flash_attention_rows``' shape rule: a rung of ``LADDER`` divides the
+    row length ``S`` (or it is one block). The pass's length is filled up to
+    whole blocks inside the call, so any goes."""
+    return _rows_block(S) > 0
+
+
+def _rows_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
+                 window: int, scale: float):
+    """One q block of a pass whose first query sits at row ``off_ref[0]`` of
+    the cache (a RUNTIME value: a suffix pass starts behind its shared prefix)
+    against the whole-``S`` K/V rows of its kv head. Row t sees the columns
+    ``t - window < j <= t`` (``window`` 0: ``j <= t``). Only the k-blocks that
+    hold such a column are visited, all bounds runtime values: the blocks the
+    horizon crosses and the blocks the diagonal crosses build the mask, those
+    between them do not."""
+    q = q_ref[0]
+    num_k = k_ref.shape[1] // block_k
+    D = q.shape[-1]
+    r0 = off_ref[0] + pl.program_id(1) * block_q
+    r1 = r0 + block_q - 1
+
+    def step(kj, carry, masked: bool):
+        m, l, acc = carry
+        k_blk = _block(k_ref, kj, block_k)
+        v_blk = _block(v_ref, kj, block_k)
+        s = _dot_nt(q, k_blk) * scale
+        if masked:
+            vis = _visible(r0, kj * block_k, s.shape, 0, window)
+            s = jnp.where(vis, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        if masked:  # a row may see nothing of a block: exp(NEG_INF - NEG_INF) would count
+            p = jnp.where(vis, p, 0.0)
+        return m_new, l * corr + p.sum(axis=-1, keepdims=True), acc * corr + _dot_nn(p.astype(v_blk.dtype), v_blk)
+
+    carry = (jnp.full((block_q, 1), NEG_INF, jnp.float32), jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, D), jnp.float32))
+    hi = jnp.minimum(r1 // block_k + 1, num_k)        # blocks that hold a column <= r1
+    diag = jnp.minimum((r0 + 1) // block_k, hi)       # the first block with a column > r0
+    lo = 0
+    if window:
+        lo = jnp.maximum(r0 - window + 1, 0) // block_k
+        edge = jnp.clip((jnp.maximum(r1 - window + 1, 0) + block_k - 1) // block_k, lo, diag)
+        carry = jax.lax.fori_loop(lo, edge, functools.partial(step, masked=True), carry)
+        lo = edge
+    carry = jax.lax.fori_loop(lo, diag, functools.partial(step, masked=False), carry)
+    m, l, acc = jax.lax.fori_loop(diag, hi, functools.partial(step, masked=True), carry)
+    o_ref[0] = (acc * (1.0 / jnp.maximum(l, 1e-20))).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _rows_impl(q, k_rows, v_rows, offset, *, window: int, interpret: bool):
+    B, T, Hq, D = q.shape
+    S, Hkv = k_rows.shape[1], k_rows.shape[2]
+    block_k = _rows_block(S)
+    block_q = _rows_block(-(-T // LADDER[-1]) * LADDER[-1]) if T >= LADDER[-1] else T
+    Tp = -(-T // block_q) * block_q
+    qr = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * Hq, T, D)
+    if Tp != T:
+        qr = jnp.pad(qr, ((0, 0), (0, Tp - T), (0, 0)))
+    kr = jnp.transpose(k_rows, (0, 2, 1, 3)).reshape(B * Hkv, S, D)
+    vr = jnp.transpose(v_rows, (0, 2, 1, 3)).reshape(B * Hkv, S, D)
+    kv_idx = _kv_index(Hq, Hkv)
+    item = q.dtype.itemsize
+    resident = 2 * S * D * item + 2 * block_q * D * item
+    out = pl.pallas_call(
+        functools.partial(_rows_kernel, block_q=block_q, block_k=block_k, window=window, scale=D ** -0.5),
+        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * Hq, Tp // block_q),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda i, j, *_: (i, j, 0)),
+                pl.BlockSpec((1, S, D), lambda i, j, *_: kv_idx(i, j)),
+                pl.BlockSpec((1, S, D), lambda i, j, *_: kv_idx(i, j)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, D), lambda i, j, *_: (i, j, 0)),
+        ),
+        compiler_params=_grid("parallel", "parallel",
+                              vmem_limit_bytes=_vmem_limit("fwd", block_q, block_k, D, resident)),
+        interpret=interpret,
+        name="flash_attention_rows",
+    )(jnp.reshape(offset, (1,)).astype(jnp.int32), qr, kr, vr)
+    return jnp.transpose(out[:, :T].reshape(B, Hq, T, D), (0, 2, 1, 3))
+
+
+def flash_attention_rows(q, k_rows, v_rows, offset, *, window: int = 0):
+    """q ``[B, T, Hq, D]``: the queries of one pass, row t of them at position
+    ``offset + t`` of a contiguous row cache ``k_rows`` / ``v_rows``
+    ``[B, S, Hkv, D]`` that already holds the pass's own keys and values at
+    ``offset .. offset + T - 1`` (``offset`` a runtime int32 scalar, shared by
+    the rows). A query sees the cache's positions ``<=`` its own, with
+    ``window`` only the ``window`` newest of them. Returns ``[B, T, Hq, D]``.
+    Forward only; GQA-native; what lies in the cache past a query is never
+    read into a score that counts."""
+    if not rows_tile(k_rows.shape[1]):
+        raise ValueError(f"flash_attention_rows: no block of {LADDER} tiles a row of {k_rows.shape[1]}")
+    return _rows_impl(q, k_rows, v_rows, offset, window=int(window), interpret=_interpret())
+
+
+def flash_attention_rows_reference(q, k_rows, v_rows, offset, *, window: int = 0):
+    """The plain formulation, same arguments and result: the masked einsum over the whole row."""
+    B, T, Hq, D = q.shape
+    S, Hkv = k_rows.shape[1], k_rows.shape[2]
+    k = jnp.repeat(k_rows, Hq // Hkv, axis=2)
+    v = jnp.repeat(v_rows, Hq // Hkv, axis=2)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * D ** -0.5
+    q_pos = offset + jnp.arange(T)[:, None]
+    k_pos = jnp.arange(S)[None, :]
+    valid = k_pos <= q_pos
+    if window:
+        valid = jnp.logical_and(valid, k_pos > q_pos - window)
+    probs = jax.nn.softmax(jnp.where(valid[None, None], logits, NEG_INF), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

@@ -30,8 +30,16 @@ every query head against the same row, whose first ``r`` columns are also the
 value. A page is DMA'd once and used for both
 contractions; there is no kv-head mask because there is one "head".
 
+A layer that sees a WINDOW hands the kernel a start position a row beside its
+length: the row attends to its positions ``starts[b] <= l < lengths[b]``, the
+walk begins at the block that holds ``starts[b]``, and the pages before that
+position's page are neither copied nor scored (their table entries may point
+anywhere: the engine has taken those pages back). Without ``starts`` the
+program is the one it was.
+
 Rows of a VMEM block that no copy of this row has filled (the dead pages of
-a row's last block) hold what an earlier block left there, and at the very
+a row's last block, the pages behind a window's start in its first) hold what
+an earlier block left there, and at the very
 start zeros: their probabilities are exactly 0, so they add 0 as long as the
 pool itself is finite — the assumption the plain formulation makes of the
 trash page too.
@@ -70,8 +78,12 @@ def tiles(head_dim: int, page_size: int, n_heads: int, n_kv_heads: int,
             and n_heads % n_kv_heads == 0)
 
 
-def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
-            page_size: int, n_kv: int, n_blocks: int, ppb: int, scale: float):
+def _kernel(*refs, page_size: int, n_kv: int, n_blocks: int, ppb: int, scale: float,
+            windowed: bool = False):
+    if windowed:
+        len_ref, bt_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
+    else:
+        len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
     b = pl.program_id(0)
     H, D = q_ref.shape[1], q_ref.shape[2]
     G = H // n_kv
@@ -81,6 +93,12 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
     length = len_ref[b]
     n_pages = pl.cdiv(length, page_size)
     n_blk = pl.cdiv(n_pages, ppb)
+    if windowed:
+        start = jnp.minimum(start_ref[b], jnp.maximum(length - 1, 0))
+        page0 = start // page_size   # the first page with a position the row sees
+        blk0 = page0 // ppb
+    else:
+        blk0 = 0
 
     @pl.when(b == 0)
     def _():
@@ -95,14 +113,18 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
 
     def for_live_pages(blk, slot, act):
         for i in range(ppb):
-            @pl.when(blk * ppb + i < n_pages)
+            live = blk * ppb + i < n_pages
+            if windowed:
+                live = jnp.logical_and(live, blk * ppb + i >= page0)
+
+            @pl.when(live)
             def _():
                 for copy in page_copies(blk, slot, i):
                     act(copy)
 
-    @pl.when(n_blk > 0)
+    @pl.when(n_blk > blk0)
     def _():
-        for_live_pages(0, 0, lambda c: c.start())
+        for_live_pages(blk0, blk0 % 2, lambda c: c.start())
 
     q = q_ref[0]  # [H, D]
     col = jax.lax.broadcasted_iota(jnp.int32, (H, NB), 1)
@@ -123,6 +145,8 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
         v = vbuf[slot]
         s = _dot_nt(q, k) * scale  # [H, NB] f32
         valid = jnp.logical_and(own_head, tok < length - blk * TB)
+        if windowed:
+            valid = jnp.logical_and(valid, tok >= start - blk * TB)
         s = jnp.where(valid, s, NEG_INF)
         # every block that runs holds a live position of every head, so
         # m_new is a real score and exp() of a masked column is exactly 0
@@ -136,26 +160,27 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
     m = jnp.full((H, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((H, 1), jnp.float32)
     acc = jnp.zeros((H, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m, l, acc))
+    m, l, acc = jax.lax.fori_loop(blk0, n_blk, body, (m, l, acc))
     o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, lengths):
+def paged_attention(q, k_pool, v_pool, block_tables, lengths, starts=None):
     """q ``[B, n_heads, head_dim]`` (one token a row); k_pool/v_pool
     ``[n_pages, page_size, n_kv_heads, head_dim]``; block_tables ``[B,
     n_blocks]`` int32 page ids; lengths ``[B]`` int32: row b attends to its
     logical positions ``< lengths[b]``, position l at page ``block_tables[b,
     l // page_size]``, slot ``l % page_size``. Returns ``[B, n_heads,
     head_dim]`` in q's dtype; a row of length 0 returns zeros and reads no
-    page."""
-    return _paged_attention(q, k_pool, v_pool, block_tables, lengths,
+    page. With ``starts`` ``[B]`` int32 row b attends to ``starts[b] <= l <
+    lengths[b]`` only and the pages wholly before ``starts[b]`` are not read."""
+    return _paged_attention(q, k_pool, v_pool, block_tables, lengths, starts,
                             interpret=_interpret())
 
 
 # jitted so that the layers of a model share ONE trace and ONE Mosaic lowering
 # of the kernel (about a second each on a chip's host)
 @functools.partial(jax.jit, static_argnames="interpret")
-def _paged_attention(q, k_pool, v_pool, block_tables, lengths, *, interpret: bool):
+def _paged_attention(q, k_pool, v_pool, block_tables, lengths, starts=None, *, interpret: bool):
     B, H, D = q.shape
     n_pages, ps, n_kv, _ = k_pool.shape
     n_blocks = block_tables.shape[1]
@@ -166,12 +191,17 @@ def _paged_attention(q, k_pool, v_pool, block_tables, lengths, *, interpret: boo
     buf = pltpu.VMEM((2, ppb * ps * n_kv, D), k_pool.dtype)
     # a length past the table would walk off it in SMEM: never, whatever the caller sent
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, n_blocks * ps)
+    scalars = (lengths, block_tables.astype(jnp.int32).reshape(-1))
+    windowed = {}
+    if starts is not None:
+        scalars += (jnp.clip(starts.astype(jnp.int32), 0, n_blocks * ps),)
+        windowed = {"windowed": True}
     return pl.pallas_call(
         functools.partial(_kernel, page_size=ps, n_kv=n_kv, n_blocks=n_blocks,
-                          ppb=ppb, scale=D ** -0.5),
+                          ppb=ppb, scale=D ** -0.5, **windowed),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
@@ -185,14 +215,15 @@ def _paged_attention(q, k_pool, v_pool, block_tables, lengths, *, interpret: boo
         compiler_params=_grid("arbitrary"),
         interpret=interpret,
         name="paged_attention",
-    )(lengths, block_tables.astype(jnp.int32).reshape(-1), q, k_rows, v_rows)
+    )(*scalars, q, k_rows, v_rows)
 
 
-def paged_attention_reference(q, k_pool, v_pool, block_tables, lengths):
+def paged_attention_reference(q, k_pool, v_pool, block_tables, lengths, starts=None):
     """The plain formulation, same arguments and result: gather each row's
     whole block table into logical order, repeat the kv heads, mask the
-    positions at or past the row's length. Materialises ``[B, S, n_heads,
-    head_dim]`` twice — what a shape the kernel cannot tile still runs."""
+    positions at or past the row's length (and before its start). Materialises
+    ``[B, S, n_heads, head_dim]`` twice — what a shape the kernel cannot tile
+    still runs."""
     B, H, D = q.shape
     _, ps, n_kv, _ = k_pool.shape
     S = block_tables.shape[1] * ps
@@ -200,6 +231,8 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, lengths):
     v = jnp.repeat(v_pool[block_tables].reshape(B, S, n_kv, D), H // n_kv, axis=2)
     logits = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * D ** -0.5
     valid = jnp.arange(S)[None, :] < lengths[:, None]  # [B, S]
+    if starts is not None:
+        valid = jnp.logical_and(valid, jnp.arange(S)[None, :] >= starts[:, None])
     logits = jnp.where(valid[:, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhk,bkhd->bhd", probs, v)

@@ -47,6 +47,15 @@ hold it to, token for token. What the engine does:
   routed expert layers (``models/moe.RoutedMoE``) has its programs hand back
   the routing of each pass packed in one small array, which becomes the
   ``serving.moe.*`` counters and span attributes;
+- a model with window layers (``cfg.window_layers``) keeps their K/V in a
+  page group of its own beside the full layers' (serving/paged_kv.py's
+  header): an admission reserves the full group's pages as above and, in the
+  window group, only the blocks of the prompt's last ``sliding_window``
+  tokens; from then on every chunk launch slides the request's window table
+  (``_slide_windows``): the blocks the chunk will write are mapped, the blocks
+  wholly behind the chunk's first horizon go back to the free list while the
+  request decodes, so it never holds more than ``window_bound`` window pages.
+  Admission defers when EITHER group is short;
 - an optional :class:`AdmissionController` gates the front door: submit-time
   token budgets + shed, dequeue-time weighted fair queueing + SLO-pressure
   deferral (serving/admission.py).
@@ -92,9 +101,10 @@ import numpy as np
 
 from ..core import telemetry as tel
 from ..core.telemetry import devperf, trace_context, tsdb
+from ..models.mamba import STATE_LEAVES
 from ..models.mamba import state_bytes as mamba_state_bytes
 from ..models.transformer import TransformerConfig
-from ..train.llm.generation import _prefill_fn
+from ..train.llm.generation import _leaf_name, _prefill_fn
 from ..train.llm.generation import _sample  # noqa: F401 - tests/benchmark_suite plants a fault by patching it here too
 from .admission import DEFAULT_TENANT, AdmissionController, AdmissionError
 from .admission import REASON_QUEUE_FULL, count_reject
@@ -109,12 +119,18 @@ from .paged_kv import (
     paged_pool_init,
     row_config,
     snapshot_of,
+    window_bound,
 )
 
 log = logging.getLogger(__name__)
 
 #: decode chunks whose expert loads the ``serving.moe.load_imbalance`` gauge sums
 MOE_GAUGE_CHUNKS = 64
+#: bytes of prefill rows (one contiguous ``[max_seq_len]`` K/V row a rider, allocated when its prefill is
+#: LAUNCHED, not when it runs) that may be launched and not yet scattered into pages: riders past that
+#: wait for the next iteration. At a 2,048-token row that is more riders than slots; at a 16,896-token row
+#: of 5 layers (173 MB) it is 6, where a wave of twenty had the chip out of memory
+ROWS_IN_FLIGHT_BYTES = 2 ** 30
 
 
 def _gauge(name: str, value: float) -> None:
@@ -196,6 +212,8 @@ class _AdmitWork:
     n_shared: int             # leading blocks served from the prefix cache
     shared_pages: List[int]   # one reference held per page
     private_pages: List[int]  # one reference held per page
+    window_shared: List[int] = dataclasses.field(default_factory=list)   # window group: the match's, as long as
+    window_private: List[int] = dataclasses.field(default_factory=list)  # shared_pages (TRASH behind the horizon); own
     state: object = None      # recurrent layers start from this snapshot (None: from zero)
     snap_blocks: int = 0      # block boundary whose trie node wants this prefill's state
     row_cache: object = None
@@ -240,6 +258,7 @@ class PagedContinuousBatchingEngine:
         max_queue: int = 4096,
         admission: Optional[AdmissionController] = None,
         state_snapshots: int = 8,
+        num_window_pages: Optional[int] = None,
     ):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
@@ -250,8 +269,14 @@ class PagedContinuousBatchingEngine:
             # default: room for every slot at max_seq_len (+trash);
             # deployments shrink this to realize the HBM win
             num_pages = num_slots * (base.max_seq_len // page_size) + 1
+        # window layers: a page group of their own, a request's share of it bounded
+        self._window = base.sliding_window if base.window_layers else 0
+        self._win_bound = window_bound(self._window, int(chunk), int(page_size)) if self._window else 0
+        if self._window and num_window_pages is None:
+            # every slot at its bound, one bound more for what the prefix cache retains, the trash page
+            num_window_pages = (num_slots + 1) * self._win_bound + 1
         self._paged_cfg = paged_config(
-            base, page_size=page_size, num_pages=num_pages)
+            base, page_size=page_size, num_pages=num_pages, window_pages=int(num_window_pages or 0))
         self._ps = int(page_size)
         self._n_blocks = base.max_seq_len // self._ps
         self._stateful = base.has_recurrent_state
@@ -267,7 +292,9 @@ class PagedContinuousBatchingEngine:
         self._moe_recent: "collections.deque" = collections.deque(maxlen=MOE_GAUGE_CHUNKS)
         self._alloc = PagedKVAllocator(
             num_pages, page_size, watermark_frac=watermark_frac,
-            state_budget_bytes=int(state_snapshots) * self._state_bytes)
+            state_budget_bytes=int(state_snapshots) * self._state_bytes,
+            window_pages=int(num_window_pages or 0), window=self._window)
+        self._admit_deferred = {"full": 0, "window": 0}
         self._admission = admission
         self._tenant_ttft: dict = {}
         self._params = params
@@ -277,6 +304,10 @@ class PagedContinuousBatchingEngine:
         self._max_queue = int(max_queue)
 
         self._cache = paged_pool_init(self._params, self._paged_cfg, self._B)
+        token_bytes = sum(int(np.prod(leaf.shape[2:])) * leaf.dtype.itemsize
+                          for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]
+                          if leaf.ndim >= 3 and _leaf_name(path) not in STATE_LEAVES)
+        self._max_riders = max(2, ROWS_IN_FLIGHT_BYTES // max(1, token_bytes * base.max_seq_len))
 
         # what a chunk hands the next, (tok, lengths, keys): on the device from
         # chunk to chunk, a row of it written by that slot's admission
@@ -289,6 +320,9 @@ class PagedContinuousBatchingEngine:
         self._lengths = np.zeros((self._B,), np.int32)
         self._temps = np.zeros((self._B,), np.float32)
         self._tables = np.full((self._B, self._n_blocks), TRASH_PAGE, np.int32)
+        # the window group's tables (same logical blocks) and, a slot, the mapped blocks [lo, hi)
+        self._wtables = np.full((self._B, self._n_blocks), TRASH_PAGE, np.int32) if self._window else None
+        self._wspan = np.zeros((self._B, 2), np.int64)
         self._uploaded: dict = {}  # name -> (the host array as uploaded, its device copy)
         # the worker's own: the chunk launched and not yet fetched, the riders
         # launched whose first tokens are still on the device, when the last
@@ -416,6 +450,18 @@ class PagedContinuousBatchingEngine:
         })
         if self._latent_token_bytes:
             out["kv_latent_bytes_live"] = live * self._latent_token_bytes
+        out["kv_admit_deferred_full"] = self._admit_deferred["full"]
+        if self._window:
+            with self._lock:
+                live_rows = [i for i, s in enumerate(self._slots) if s is not None]
+                lens = [int(self._lengths[i]) for i in live_rows]
+                held = int(np.count_nonzero(self._wtables[live_rows] != TRASH_PAGE))
+            out.update(kv_admit_deferred_window=self._admit_deferred["window"],
+                       kv_window_bound_pages=self._win_bound,
+                       # blocks the live requests map in the window group (the trie's retentions apart)
+                       kv_window_pages_held=held,
+                       # pages the live requests' window layers would hold with no horizon
+                       kv_window_pages_unbounded=int(sum(-(-n // self._ps) for n in lens)))
         if self._routed:
             with self._lock:
                 out.update(moe_tokens_routed=int(self._moe_totals[0]),
@@ -578,7 +624,7 @@ class PagedContinuousBatchingEngine:
             with self._lock:
                 free = next((i for i, s in enumerate(self._slots)
                              if s is None and i not in taken), None)
-                if free is None or not self._queue:
+                if free is None or not self._queue or len(wave) + len(self._riders) >= self._max_riders:
                     return wave
                 item = self._pick_locked()
             if item is None:  # every queued tenant is deferred right now
@@ -601,8 +647,28 @@ class PagedContinuousBatchingEngine:
                                       need_state=self._stateful)
             shared = match.pages
             private = self._alloc.alloc(n_req - len(shared))
-            if private is None:
+            short = "full" if private is None else None
+            window_private: List[int] = []
+            if private is not None and self._window:
+                # the window group: the blocks of the prompt's last ``window`` tokens that the match
+                # does not bring; and never more live requests than it holds bounds for
+                first_w = max(max(0, P - self._window + 1) // self._ps, len(shared))
+                with self._lock:
+                    live = sum(1 for s in self._slots if s is not None) + len(wave)
+                window_private = None
+                if (live + 1) * self._win_bound <= self._alloc.window_pages - 1:
+                    window_private = self._alloc.alloc_window(-(-P // self._ps) - first_w)
+                if window_private is None:
+                    self._alloc.free(private)
+                    short = "window"
+            if short is not None:
+                self._admit_deferred[short] += 1
+                if short == "window":
+                    tel.counter("serving.kv.admit_deferred_window").add(1)
+                else:
+                    tel.counter("serving.kv.admit_deferred_full").add(1)
                 self._alloc.free(shared)
+                self._alloc.free_window(match.window_pages)
                 with self._lock:
                     busy = any(s is not None for s in self._slots)
                     if busy or wave:
@@ -613,11 +679,12 @@ class PagedContinuousBatchingEngine:
                 # tried: this request can never fit — fail it, not the pool
                 item.handle._fail(RuntimeError(
                     f"prompt {P} + budget {budget} needs "
-                    f"{n_req - len(shared)} KV pages; the pool cannot free "
+                    f"{n_req - len(shared)} KV pages; the {short} page group cannot free "
                     "enough (raise num_pages or lower max_new_tokens)"))
                 continue
             wave.append(_AdmitWork(item, free, budget, len(shared),
-                                   shared, private, match.state, match.snap_blocks))
+                                   shared, private, match.window_pages, window_private,
+                                   match.state, match.snap_blocks))
             taken.add(free)
 
     def _run_wave(self, wave: List[_AdmitWork]) -> None:
@@ -676,6 +743,7 @@ class PagedContinuousBatchingEngine:
                 self._slots[r.slot] = None
         else:
             self._alloc.free(r.shared_pages + r.private_pages)
+            self._alloc.free_window(r.window_shared + r.window_private)
         r.item.handle._fail(e)
 
     def _launch(self, w: _AdmitWork) -> None:
@@ -696,6 +764,9 @@ class PagedContinuousBatchingEngine:
         # dense model's programs take neither it nor the snapshot below
         snap = np.int32(w.snap_blocks * self._ps) if self._stateful else None
         attrs = {"state_hit": w.state is not None} if self._stateful else {}
+        if self._window:  # key positions the pass reads, a layer of each kind
+            seen = np.arange(prefix_len + 1, P + 1, dtype=np.int64)
+            attrs.update(kv_tokens_full=int(seen.sum()), kv_tokens_window=int(np.minimum(seen, self._window).sum()))
         with tel.span("serving.cb.prefill", request_id=item.request_id,
                       prompt_len=P, shared=prefix_len, **attrs) as w.prefill_span:
             suffix = item.prompt[prefix_len:]
@@ -707,8 +778,13 @@ class PagedContinuousBatchingEngine:
             else:
                 table = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
                 table[:w.n_shared] = w.shared_pages
+                more = ()
+                if self._window:
+                    wtable = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
+                    wtable[:w.n_shared] = w.window_shared
+                    more = (wtable,)
                 row_cache = _paged_gather_fn(self._paged_cfg)(
-                    self._cache, table, np.int32(prefix_len), w.state)
+                    self._cache, table, np.int32(prefix_len), w.state, *more)
                 out = _suffix_prefill_fn(self._paged_cfg, T_b)(
                     self._params, row_cache, ids, np.int32(prefix_len),
                     np.int32(P), snap)
@@ -734,11 +810,24 @@ class PagedContinuousBatchingEngine:
             first_blk = w.n_shared
             last_blk = -(-P // self._ps)  # exclusive: block of the last token
             write_ids[first_blk:last_blk] = w.private_pages[:last_blk - first_blk]
+            more = ()
+            if self._window:
+                # only the blocks of the prompt's last ``window`` tokens: the rest of the row goes to trash
+                first_w = last_blk - len(w.window_private)
+                window_ids = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
+                window_ids[first_w:last_blk] = w.window_private
+                more = (window_ids,)
+                self._wtables[b, :] = window_ids
+                self._wtables[b, :w.n_shared] = w.window_shared
+                held = np.flatnonzero(self._wtables[b, :last_blk] != TRASH_PAGE)
+                self._wspan[b] = (int(held[0]), last_blk)
             self._cache, w.tok0, self._carry = _paged_admit_fn(self._paged_cfg)(
                 self._cache, w.row_cache, write_ids, np.int32(b), w.first,
                 np.uint32(item.seed & 0xFFFFFFFF), np.float32(item.temperature),
-                self._carry, np.int32(P))
+                self._carry, np.int32(P), *more)
             w.first = None
+            if not w.snap_blocks:  # the row is in its pages: nobody reads it again (a snapshot's taker does)
+                w.row_cache = None
             w.tok0.copy_to_host_async()
             n_own = w.n_shared + len(w.private_pages)
             self._tables[b, :w.n_shared] = w.shared_pages
@@ -773,7 +862,8 @@ class PagedContinuousBatchingEngine:
             self._observe_tenant_ttft(item.tenant, ttft)
             n_prompt_blocks = len(item.prompt) // self._ps  # FULL chunks only
             self._alloc.register_prefix(
-                item.prompt, [int(p) for p in self._tables[b, :n_prompt_blocks]])
+                item.prompt, [int(p) for p in self._tables[b, :n_prompt_blocks]],
+                None if not self._window else [int(p) for p in self._wtables[b, :n_prompt_blocks]])
             if w.snap_blocks:
                 # the trie node this prompt diverged at had pages and no
                 # snapshot: it keeps the state this prefill left there
@@ -804,10 +894,14 @@ class PagedContinuousBatchingEngine:
 
     def _device_copy(self, name: str, host: np.ndarray):
         """The device's copy of a per-slot array the host owns: uploaded again
-        only when the host's differs from what was uploaded last."""
+        only when the host's differs from what was uploaded last. What is
+        uploaded is a copy nobody writes: the CPU backend may alias a NumPy
+        buffer it is handed, and the host's own arrays change in place while a
+        chunk that was given them is still queued."""
         held = self._uploaded.get(name)
         if held is None or not np.array_equal(held[0], host):
-            held = self._uploaded[name] = (host.copy(), jax.device_put(host))
+            frozen = host.copy()
+            held = self._uploaded[name] = (frozen, jax.device_put(frozen))
         return held[1]
 
     def _step_chunk(self) -> None:
@@ -828,6 +922,13 @@ class PagedContinuousBatchingEngine:
             attrs["state_slots"] = n_live
         if self._latent_token_bytes:
             _gauge("serving.kv.latent_bytes_live", float((lens - 1).sum() * self._latent_token_bytes))
+        more = ()
+        if self._window:
+            self._slide_windows(active)
+            # key positions the chunk's C token-steps read, a layer of each kind
+            seen = lens[:, None] + np.arange(self._C)[None, :]
+            attrs.update(kv_tokens_full=int(seen.sum()), kv_tokens_window=int(np.minimum(seen, self._window).sum()))
+            more = (self._device_copy("wtables", self._wtables),)
         with tel.span("serving.cb.chunk", slots=n_live, **attrs) as chunk_span:
             with tel.span("serving.cb.chunk.dispatch"):
                 cache, tok, lengths, keys, toks, *routing = _paged_step_fn(
@@ -838,6 +939,7 @@ class PagedContinuousBatchingEngine:
                     *self._carry,
                     self._device_copy("temps", self._temps),
                     self._device_copy("active", active),
+                    *more,
                 )
                 self._cache, self._carry = cache, (tok, lengths, keys)
                 for out in (toks, *routing):
@@ -853,6 +955,38 @@ class PagedContinuousBatchingEngine:
             if before is not None:
                 tel.counter("serving.cb.chunks_ahead").add(1)
                 self._land_chunk(before)
+
+    def _slide_windows(self, active: np.ndarray) -> None:
+        """Before a chunk is launched: every riding row's window table maps
+        the blocks the chunk's C token-steps write and read, ``[(L - window +
+        1) // page, (L + C - 1) // page]`` for a row of length L, and nothing
+        before them. The pages of the blocks wholly behind the chunk's first
+        horizon go back to the window group now (a shared one loses this
+        request's reference): nothing launched so far reads them later than
+        this chunk's launch, and whoever gets them writes them in a program
+        launched after it. The group holds a bound for every live request, so
+        the pages for the blocks ahead are there."""
+        ps, tables = self._ps, self._wtables
+        for b in np.flatnonzero(active):
+            L = int(self._lengths[b])
+            lo, hi = int(self._wspan[b, 0]), int(self._wspan[b, 1])
+            new_lo = max(0, L - self._window + 1) // ps
+            new_hi = min((L + self._C - 1) // ps + 1, self._n_blocks)
+            if new_lo > lo:
+                self._alloc.free_window([int(p) for p in tables[b, lo:new_lo]], released=True)
+                tables[b, lo:new_lo] = TRASH_PAGE
+                lo = new_lo
+            if new_hi > hi:
+                pages = self._alloc.alloc_window(new_hi - hi)
+                if pages is None:
+                    raise RuntimeError(f"the window page group has no {new_hi - hi} pages for a live request: "
+                                       "it holds fewer than a bound a live request")
+                tables[b, hi:new_hi] = pages
+                hi = new_hi
+            self._wspan[b] = (lo, hi)
+        for group, (live, free) in self._alloc.group_pages().items():
+            _gauge("serving.kv.pages_live." + group, float(live))
+            _gauge("serving.kv.pages_free." + group, float(free))
 
     def _land_chunk(self, chunk: _Chunk) -> None:
         """Fetch a launched chunk's tokens (``.sync``: the wait for it; near a
@@ -950,6 +1084,11 @@ class PagedContinuousBatchingEngine:
         self._tables[b, :] = TRASH_PAGE
         if pages:
             self._alloc.free(pages)
+        if self._window:
+            lo, hi = (int(x) for x in self._wspan[b])
+            self._alloc.free_window([int(p) for p in self._wtables[b, lo:hi]])
+            self._wtables[b, :] = TRASH_PAGE
+            self._wspan[b] = (0, 0)
 
     def _observe_tenant_ttft(self, tenant: str, ttft: float) -> None:
         dq = self._tenant_ttft.get(tenant)

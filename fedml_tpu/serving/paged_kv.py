@@ -41,6 +41,25 @@ holds one. Snapshots arise without priming: a prefill that diverges from the
 trie at a node without one emits the state at that boundary
 (``snap_lens``), and the node keeps it.
 
+TWO PAGE GROUPS where the model has window layers (``TransformerConfig.
+attn_kinds``). A full layer needs every token of a request, a window layer only
+the ``sliding_window`` newest, so their K/V live in pools of their own page
+counts (``kv_num_pages`` / ``kv_window_pages``), with block tables, free lists
+and refcounts of their own. Both tables index a request's LOGICAL blocks (a
+sliding table: position ``l`` is at ``table[l // page_size]`` in either), but
+the window group's entries behind the request's horizon point at the trash
+page again: the engine hands those pages back while the request decodes
+(``PagedContinuousBatchingEngine._slide_windows``), so a live request holds at
+most ``window_bound`` window pages whatever its length. The admit program
+scatters a finished prefill row whole into the full group and only the blocks
+of its last ``sliding_window`` tokens into the window group. The prefix trie's
+nodes hold a page of each group (the window page where some request's window
+reached over the chunk when it registered it): a hit needs the window pages of
+the blocks its suffix pass can still see and takes a reference on those alone;
+a shared window page that a request's horizon passes loses that request's
+reference, not the trie's. A model with no window layers has one group and
+the tables it always had.
+
 Prefill reuses the contiguous executables (`generation._prefill_fn`) at
 B=1 and scatters the finished row into pages (``_paged_admit_fn``). A
 prefix HIT skips recomputing the shared prompt: gather the shared pages
@@ -54,6 +73,7 @@ branch already supports a runtime start position, so suffix lengths share
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -80,8 +100,9 @@ TRASH_PAGE = 0
 
 
 def paged_config(cfg: TransformerConfig, *, page_size: int,
-                 num_pages: int) -> TransformerConfig:
-    """The paged-decode twin of a config (same params)."""
+                 num_pages: int, window_pages: int = 0) -> TransformerConfig:
+    """The paged-decode twin of a config (same params). ``window_pages``: the
+    window group's page count, for a model with window layers."""
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
     if cfg.max_seq_len % page_size != 0:
@@ -92,14 +113,34 @@ def paged_config(cfg: TransformerConfig, *, page_size: int,
         raise ValueError(
             f"num_pages must be >= 2 (page {TRASH_PAGE} is reserved trash), "
             f"got {num_pages}")
+    if bool(cfg.window_layers) != (window_pages > 0) or window_pages == 1:
+        raise ValueError(
+            f"window_pages must be >= 2 for a model with window layers and 0 "
+            f"for one without, got {window_pages} beside {len(cfg.window_layers)} window layers")
     return dataclasses.replace(
-        cfg, kv_page_size=int(page_size), kv_num_pages=int(num_pages))
+        cfg, kv_page_size=int(page_size), kv_num_pages=int(num_pages),
+        kv_window_pages=int(window_pages))
 
 
 def row_config(cfg: TransformerConfig) -> TransformerConfig:
     """The contiguous (per-row cache) twin of a paged config — prefill and
     suffix-prefill run here, then scatter into the pool."""
-    return dataclasses.replace(cfg, kv_page_size=0, kv_num_pages=0)
+    return dataclasses.replace(cfg, kv_page_size=0, kv_num_pages=0, kv_window_pages=0)
+
+
+def window_bound(window: int, chunk: int, page_size: int) -> int:
+    """Most window-group pages one live request holds, a layer: the blocks
+    that ``window`` keys and the ``chunk`` positions a decode chunk writes can
+    lie in, plus one for where the first of them falls in its page."""
+    return -(-(window + chunk) // page_size) + 1
+
+
+def _window_layer_names(cfg: TransformerConfig) -> frozenset:
+    return frozenset(f"layer_{i}" for i in cfg.window_layers)
+
+
+def _in_window_layer(path, names: frozenset) -> bool:
+    return bool(names) and str(getattr(path[0], "key", path[0])) in names
 
 
 def _num_blocks(cfg: TransformerConfig) -> int:
@@ -127,6 +168,7 @@ def paged_pool_init(params, cfg: TransformerConfig, B: int):
             positions=jnp.zeros((B, 1), jnp.int32),
             cache_idx=jnp.zeros((B,), jnp.int32),
             block_tables=jnp.zeros((B, _num_blocks(cfg)), jnp.int32),
+            window_tables=jnp.zeros((B, _num_blocks(cfg)), jnp.int32) if cfg.window_layers else None,
             mutable=["cache"],
         )[1]["cache"]
 
@@ -156,7 +198,10 @@ def _paged_admit_fn(cfg: TransformerConfig):
     ``_paged_step_fn`` carries from chunk to chunk, ``(tok, lengths, keys)``;
     it comes back with row ``slot`` set to (the first token, ``length`` = the
     prompt's, the key the first token's draw left), so the request can ride a
-    chunk launched before its first token has reached the host.
+    chunk launched before its first token has reached the host. A model with
+    window layers passes ``window_write_ids`` too: the same for the window
+    group's pools, TRASH_PAGE for every block behind the prompt's last
+    ``sliding_window`` tokens as well.
 
     The pool is DONATED where the backend donates: the caller's binding is
     dead once the call is made, and it rebinds to the returned pool. That
@@ -164,9 +209,11 @@ def _paged_admit_fn(cfg: TransformerConfig):
     launched from one thread, in program order."""
     n_blocks = _num_blocks(cfg)
     ps = cfg.kv_page_size
+    windowed = _window_layer_names(cfg)
 
     def build():
-        def run(pool, row_cache, write_ids, slot, first_logits, seed, temp, carry, length):
+        def run(pool, row_cache, write_ids, slot, first_logits, seed, temp, carry, length,
+                window_write_ids=None):
             row_cache = unpack_state(cfg, row_cache)
 
             def insert(path, dst):
@@ -177,7 +224,8 @@ def _paged_admit_fn(cfg: TransformerConfig):
                     return jax.lax.dynamic_update_slice(
                         dst, src.astype(dst.dtype), (slot,) + (0,) * (dst.ndim - 1))
                 pages = src[0].reshape((n_blocks, ps) + src.shape[2:])
-                return dst.at[write_ids].set(pages.astype(dst.dtype))
+                ids = window_write_ids if _in_window_layer(path, windowed) else write_ids
+                return dst.at[ids].set(pages.astype(dst.dtype))
 
             new_pool = jax.tree_util.tree_map_with_path(insert, pool)
             key2, sub = jax.random.split(jax.random.PRNGKey(seed))
@@ -199,19 +247,23 @@ def _paged_gather_fn(cfg: TransformerConfig):
     trash page, their garbage is overwritten by the suffix pass before any
     query can attend to it (the ``_rewind_cache`` argument). Recurrent-state
     leaves: the prefix cache's snapshot ``state`` (``snapshot_of``'s shape),
-    never the pool's slots, which hold other requests. The row is packed."""
+    never the pool's slots, which hold other requests. The row is packed. A
+    window layer's row comes from the window group through ``window_table``:
+    the blocks the suffix pass can see hold the shared pages, the others trash."""
     ps = cfg.kv_page_size
     recurrent = mamba_layers(cfg)
+    windowed = _window_layer_names(cfg)
 
     def build():
-        def run(pool, block_table, prefix_len, state=None):
-            def gather(leaf):
+        def run(pool, block_table, prefix_len, state=None, window_table=None):
+            def gather(path, leaf):
                 if leaf.ndim == 0:
                     return leaf
-                pages = leaf[block_table]  # [n_blocks, ps, kv, hd]
+                table = window_table if _in_window_layer(path, windowed) else block_table
+                pages = leaf[table]  # [n_blocks, ps, kv, hd]
                 return pages.reshape((1, pages.shape[0] * ps) + leaf.shape[2:])
 
-            row = jax.tree_util.tree_map(
+            row = jax.tree_util.tree_map_with_path(
                 gather, {k: v for k, v in pool.items() if k not in recurrent})
             row = _rewind_cache(row, prefix_len)
             if state is not None:
@@ -246,8 +298,9 @@ def _suffix_prefill_fn(cfg: TransformerConfig, T_b: int):
                 seq_lens=jnp.reshape(true_total - prefix_len, (1,)),
                 snap_lens=(None if snap_total is None
                            else jnp.reshape(jnp.maximum(snap_total - prefix_len, 0), (1,))),
+                logit_rows=jnp.reshape(true_total - prefix_len - 1, (1,)),
             )
-            first = logits[:, true_total - prefix_len - 1]  # [1, vocab], as _prefill_fn's
+            first = logits[:, 0]  # [1, vocab], as _prefill_fn's
             out = pack_state(cfg, _rewind_cache(state["cache"], true_total)), first
             return out + _routing(cfg, state, true_total - prefix_len)
 
@@ -264,14 +317,16 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
     mix reuses it. The cache argument is the POOL (page-count-sized, not
     B-sized), so HBM scales with admitted tokens instead of worst-case rows.
     A model with routed layers returns a sixth result: the chunk's routing,
-    summed over its C token-steps, packed (``models/moe.routing_stats``)."""
+    summed over its C token-steps, packed (``models/moe.routing_stats``). A
+    model with window layers takes the window group's tables as one more
+    operand, runtime data like the others."""
 
     def build():
         model = decode_model(cfg)
         S = cfg.max_seq_len
         routed = bool(cfg.routed_layers)
 
-        def run(params, pool, block_tables, tok, lengths, keys, temps, active):
+        def run(params, pool, block_tables, tok, lengths, keys, temps, active, window_tables=None):
             n_active = jnp.sum(active.astype(jnp.int32)) if routed else None
 
             def step(carry, _):
@@ -290,6 +345,7 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
                     # the trash page and reads no page at all
                     cache_idx=jnp.where(active, idx, -1),
                     block_tables=block_tables,
+                    window_tables=window_tables,
                     mutable=_mutable(cfg),
                 )
                 nxt = jax.vmap(_sample)(logits[:, -1], subs, temps)
@@ -321,13 +377,16 @@ class _PrefixNode:
     node keeps one RETENTION reference on its page; live requests mapping
     the page add their own."""
 
-    __slots__ = ("chunk", "page", "parent", "children", "tick", "state",
+    __slots__ = ("chunk", "page", "wpage", "parent", "children", "tick", "state",
                  "state_bytes")
 
     def __init__(self, chunk: Tuple[int, ...], page: int,
                  parent: Optional["_PrefixNode"]):
         self.chunk = chunk
         self.page = page
+        # the window group's page of the same chunk (retained likewise), where
+        # a request's window reached over it at registration; TRASH_PAGE: none
+        self.wpage = TRASH_PAGE
         self.parent = parent
         self.children: Dict[Tuple[int, ...], "_PrefixNode"] = {}
         self.tick = 0
@@ -344,11 +403,15 @@ class PrefixMatch:
     recurrent state at ``len(pages) * page_size`` (None: the request needs
     none, or starts from zero). ``snap_blocks``: the block boundary, deeper
     than ``pages``, whose node matched but holds no snapshot: the prefill is
-    to emit the state there (0: none wanted)."""
+    to emit the state there (0: none wanted). ``window_pages`` (a model with
+    window layers): as long as ``pages``, the window group's page of each
+    block the suffix pass can still see, one reference held on each, and
+    TRASH_PAGE for the blocks behind its horizon."""
 
     pages: List[int]
     state: object = None
     snap_blocks: int = 0
+    window_pages: List[int] = dataclasses.field(default_factory=list)
 
 
 class PagedKVAllocator:
@@ -358,12 +421,29 @@ class PagedKVAllocator:
 
     Thread-safe: the engine worker allocates/frees while HTTP threads read
     ``stats()``. Page ``TRASH_PAGE`` is pinned out of circulation forever.
+
+    ``window_pages`` > 0 adds the WINDOW GROUP (module docstring): a second
+    free list and refcounts (``alloc_window`` / ``free_window``), for a model
+    whose window layers see ``window`` keys. The trie and the watermark stay
+    the full group's; a trie node may hold a page of the window group too.
     """
 
     def __init__(self, num_pages: int, page_size: int, *,
-                 watermark_frac: float = 0.05, state_budget_bytes: int = 0):
+                 watermark_frac: float = 0.05, state_budget_bytes: int = 0,
+                 window_pages: int = 0, window: int = 0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is trash)")
+        if window_pages and (window_pages < 2 or window < 1):
+            raise ValueError("a window group needs window_pages >= 2 (page 0 is trash) and window >= 1")
+        self.window_pages = int(window_pages)
+        self.window = int(window)
+        self._wfree: List[int] = list(range(self.window_pages - 1, TRASH_PAGE, -1))
+        self._wref = [0] * self.window_pages
+        if self.window_pages:
+            self._wref[TRASH_PAGE] = 1  # pinned
+        self._window_released = 0
+        self._window_evictions = 0
+        self._window_alloc_fail = 0
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         # pages below this stay in reserve: admission defers instead of
@@ -431,6 +511,56 @@ class PagedKVAllocator:
                 if self._ref[p] == 0:
                     self._free.append(p)
 
+    # -- the window group ----------------------------------------------------
+
+    def alloc_window(self, n: int) -> Optional[List[int]]:
+        """Take ``n`` fresh pages of the window group (refcount 1 each). When
+        the free list runs short the trie gives up window pages no live
+        request maps, least recently used first (the nodes keep their full
+        pages). None when that is not enough."""
+        with self._lock:
+            if len(self._wfree) < n:
+                self._evict_window_locked(n - len(self._wfree))
+            if len(self._wfree) < n:
+                self._window_alloc_fail += 1
+                return None
+            pages = [self._wfree.pop() for _ in range(n)]
+            for p in pages:
+                self._wref[p] = 1
+            return pages
+
+    def free_window(self, pages: Sequence[int], *, released: bool = False) -> None:
+        """Drop one reference per window-group page (``free``'s rules).
+        ``released``: the pages left a live request's window (counted)."""
+        with self._lock:
+            n = 0
+            for p in pages:
+                if p == TRASH_PAGE:
+                    continue
+                if self._wref[p] <= 0:
+                    raise RuntimeError(f"double-free of window page {p}")
+                self._wref[p] -= 1
+                n += 1
+                if self._wref[p] == 0:
+                    self._wfree.append(p)
+            if released and n:
+                self._window_released += n
+                tel.counter("serving.kv.window_pages_released").add(n)
+
+    def _evict_window_locked(self, need: int) -> None:
+        held = sorted((n for n in self._nodes if n.wpage != TRASH_PAGE and self._wref[n.wpage] == 1),
+                      key=lambda n: n.tick)
+        for node in held[:need]:
+            self._wref[node.wpage] = 0
+            self._wfree.append(node.wpage)
+            node.wpage = TRASH_PAGE
+            self._window_evictions += 1
+
+    def _window_tail(self, n_blocks: int) -> int:
+        """First block of a prefix ``n_blocks`` long that a pass starting
+        behind it can still see in a window layer."""
+        return max(0, n_blocks * self.page_size - self.window + 1) // self.page_size
+
     # -- prefix hash-consing -----------------------------------------------
 
     def _chunks(self, tokens: Sequence[int]) -> List[Tuple[int, ...]]:
@@ -471,6 +601,11 @@ class PagedKVAllocator:
                 tel.counter("serving.kv.prefix_misses").add(1)
             if max_blocks is not None:
                 nodes = nodes[:max_blocks]
+            if self.window_pages:
+                # only as deep as the window group still holds the blocks a
+                # suffix pass behind the match would see
+                while nodes and any(n.wpage == TRASH_PAGE for n in nodes[self._window_tail(len(nodes)):]):
+                    nodes.pop()
             for node in nodes:
                 self._tick += 1
                 node.tick = self._tick
@@ -491,6 +626,12 @@ class PagedKVAllocator:
             for node in nodes:
                 self._ref[node.page] += 1
                 out.pages.append(node.page)
+            if self.window_pages:
+                tail = self._window_tail(len(nodes))
+                for i, node in enumerate(nodes):
+                    if i >= tail:
+                        self._wref[node.wpage] += 1
+                    out.window_pages.append(node.wpage if i >= tail else TRASH_PAGE)
             return out
 
     def attach_state(self, tokens: Sequence[int], n_blocks: int, state,
@@ -533,12 +674,15 @@ class PagedKVAllocator:
             store.record_gauge("serving.state.snapshot_bytes", float(self._state_bytes))
 
     def register_prefix(self, tokens: Sequence[int],
-                        block_ids: Sequence[int]) -> None:
+                        block_ids: Sequence[int],
+                        window_ids: Optional[Sequence[int]] = None) -> None:
         """Hash-cons the prompt's full chunks, retaining one reference on
         each newly published page (already-registered chunks just refresh
         their LRU tick — including ones this request matched at admit).
         Only FULL chunks are registered, so a registered page is never a
-        write target (see module docstring)."""
+        write target (see module docstring). ``window_ids``: the request's
+        window-group page of each chunk (TRASH_PAGE where its window does not
+        reach); a node without one keeps it, retained likewise."""
         with self._lock:
             chunks = self._chunks(tokens)
             level = self._root
@@ -553,6 +697,11 @@ class PagedKVAllocator:
                     self._ref[page] += 1  # retention reference
                     level[chunk] = node
                     self._nodes.append(node)
+                if window_ids is not None and node.wpage == TRASH_PAGE:
+                    wp = window_ids[i]
+                    if wp != TRASH_PAGE and self._wref[wp] > 0:
+                        node.wpage = wp
+                        self._wref[wp] += 1  # retention reference
                 self._tick += 1
                 node.tick = self._tick
                 parent = node
@@ -562,18 +711,18 @@ class PagedKVAllocator:
         """Reclaim up to ``need`` pages by dropping LRU prefix retentions
         whose pages no live request maps (refcount 1 = retention only).
         Inner trie nodes are only evictable once their children are gone —
-        eviction order is leaves-first by last-use tick."""
+        eviction order is leaves-first by last-use tick (one pass over the
+        nodes and a heap: a parent joins it when its last child goes)."""
+        def evictable(node):
+            return not node.children and self._ref[node.page] == 1
+
+        heap = [(n.tick, id(n), n) for n in self._nodes if evictable(n)]
+        heapq.heapify(heap)
+        gone = set()
         reclaimed = 0
-        while reclaimed < need:
-            victim = None
-            for node in self._nodes:
-                if node.children or self._ref[node.page] != 1:
-                    continue
-                if victim is None or node.tick < victim.tick:
-                    victim = node
-            if victim is None:
-                return
-            self._nodes.remove(victim)
+        while reclaimed < need and heap:
+            victim = heapq.heappop(heap)[2]
+            gone.add(id(victim))
             if victim.state is not None:  # a snapshot goes with its node
                 self._drop_state_locked(victim)
             level = victim.parent.children if victim.parent else self._root
@@ -582,17 +731,43 @@ class PagedKVAllocator:
             if self._ref[victim.page] == 0:
                 self._free.append(victim.page)
                 reclaimed += 1
+            if victim.wpage != TRASH_PAGE:  # its window page goes with it
+                self._wref[victim.wpage] -= 1
+                if self._wref[victim.wpage] == 0:
+                    self._wfree.append(victim.wpage)
             self._evictions += 1
             tel.counter("serving.kv.prefix_evictions").add(1)
+            if victim.parent is not None and evictable(victim.parent):
+                heapq.heappush(heap, (victim.parent.tick, id(victim.parent), victim.parent))
+        if gone:
+            self._nodes = [n for n in self._nodes if id(n) not in gone]
 
     # -- introspection ------------------------------------------------------
+
+    def group_pages(self) -> Dict[str, Tuple[int, int]]:
+        """(live, free) pages of each page group."""
+        with self._lock:
+            out = {"full": (self.num_pages - 1 - len(self._free), len(self._free))}
+            if self.window_pages:
+                out["window"] = (self.window_pages - 1 - len(self._wfree), len(self._wfree))
+            return out
 
     def stats(self) -> dict:
         with self._lock:
             shared = sum(1 for n in self._nodes if self._ref[n.page] > 1)
+            window = {} if not self.window_pages else {
+                "kv_window_pages_total": self.window_pages - 1,
+                "kv_window_pages_free": len(self._wfree),
+                "kv_window_pages_live": self.window_pages - 1 - len(self._wfree),
+                "kv_window_pages_released": self._window_released,
+                "kv_window_prefix_evictions": self._window_evictions,
+                "kv_window_alloc_deferred": self._window_alloc_fail,
+            }
             return {
+                **window,
                 "kv_pages_total": self.num_pages - 1,  # trash excluded
                 "kv_pages_free": len(self._free),
+                "kv_pages_live": self.num_pages - 1 - len(self._free),
                 "kv_pages_shared": shared,
                 "kv_prefix_nodes": len(self._nodes),
                 "kv_watermark_pages": self.watermark,
@@ -630,8 +805,15 @@ class PagedKVAllocator:
             ]
             free_set = set(self._free)
             double = [p for p in free_set if self._ref[p] != 0]
+            accounted = (len(free_set) + len(retained) + 1 == self.num_pages
+                         and not (free_set & retained) and not state_leaked)
+            if self.window_pages:  # the window group, by the same rules
+                wretained = {n.wpage for n in self._nodes if n.wpage != TRASH_PAGE}
+                leaked += [("window", p) for p in range(1, self.window_pages)
+                           if self._wref[p] > 0 and (p not in wretained or self._wref[p] != 1)]
+                wfree = set(self._wfree)
+                double += [("window", p) for p in wfree if self._wref[p] != 0]
+                accounted = (accounted and len(wfree) + len(wretained) + 1 == self.window_pages
+                             and not (wfree & wretained))
             return {"leaked": leaked, "bad_free": double,
-                    "state_leaked": state_leaked,
-                    "accounted": len(free_set) + len(retained) + 1
-                    == self.num_pages and not (free_set & retained)
-                    and not state_leaked}
+                    "state_leaked": state_leaked, "accounted": accounted}

@@ -123,6 +123,35 @@ def config_from_hf_keys(hf: Dict[str, Any], **overrides: Any) -> TransformerConf
             moe_routed_scaling=float(hf.get("routed_scaling_factor", 1.0)),
             moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
         )
+    if "layer_types" in hf and "sliding_window" in hf:
+        kinds = {"sliding_attention": "window", "full_attention": "full"}
+        if set(hf["layer_types"]) - set(kinds) or len(hf["layer_types"]) != hf["num_hidden_layers"]:
+            raise ValueError(f"layer_types must name one of {sorted(kinds)} for each of "
+                             f"{hf['num_hidden_layers']} layers, got {hf['layer_types']!r}")
+        if hf.get("score_func", "sigmoid") != "sigmoid" or int(hf.get("n_group", 1)) != 1 \
+                or int(hf.get("topk_group", 1)) != 1:
+            raise ValueError(f"score_func={hf.get('score_func')!r}, n_group={hf.get('n_group')}, topk_group="
+                             f"{hf.get('topk_group')}: the routed layer scores with a sigmoid over one group")
+        held = int(hf.get("num_experts", 0))
+        base.update(
+            attn_kinds=tuple(kinds[t] for t in hf["layer_types"]),
+            sliding_window=int(hf["sliding_window"]),
+            use_rope=False,  # the full layers'; a window layer always rotates
+            head_dim=int(hf.get("head_dim", 0)),
+            qk_norm=True, attn_gate=True, sandwich_norm=True,
+            embed_scale=float(hf["hidden_size"]) ** 0.5 if hf.get("mup_enabled") else 1.0,
+            norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            first_k_dense_replace=int(hf.get("num_dense_layers", 0)),
+            moe_routed_experts=int(hf.get("router_width", held)), moe_held_experts=held,
+            moe_rank=int(hf.get("expert_rank", 0)),
+            moe_top_k=int(hf.get("num_experts_per_tok", 1)),
+            moe_d_ff=int(hf.get("moe_intermediate_size", 0)),
+            moe_shared_experts=int(hf.get("num_shared_experts", 0)),
+            moe_routed_scaling=float(hf.get("route_scale", 1.0)),
+            moe_norm_topk=bool(hf.get("route_norm", True)),
+            moe_select_bias=held > 0,
+        )
     base.update(overrides)
     return TransformerConfig(**base)
 

@@ -130,8 +130,9 @@ def _prefill_fn(cfg: TransformerConfig, B: int, P_bucket: int):
                 {"params": params}, prompt_padded, positions=positions, mutable=_mutable(cfg),
                 seq_lens=jnp.broadcast_to(true_len, (B,)),
                 snap_lens=None if snap_len is None else jnp.broadcast_to(snap_len, (B,)),
+                logit_rows=jnp.broadcast_to(true_len - 1, (B,)),  # the head over B tokens, not B x P
             )
-            first = logits[jnp.arange(B), true_len - 1]
+            first = logits[:, 0]
             out = pack_state(cfg, _rewind_cache(state["cache"], true_len)), first
             return out + _routing(cfg, state, B * true_len)
 

@@ -138,6 +138,9 @@ EXPORTED = {
     "fedml_serving_kv_prefix_misses_total": "counter",
     "fedml_serving_kv_prefix_evictions_total": "counter",
     "fedml_serving_kv_alloc_deferred_total": "counter",
+    "fedml_serving_kv_admit_deferred_full_total": "counter",
+    "fedml_serving_kv_admit_deferred_window_total": "counter",
+    "fedml_serving_kv_window_pages_released_total": "counter",
     # recurrent-state snapshots of the prefix trie (models with Mamba layers)
     "fedml_serving_state_prefix_hits_total": "counter",
     "fedml_serving_state_prefix_misses_total": "counter",
