@@ -178,8 +178,11 @@ class MoEMLP(nn.Module):
 
 #: the collection a routed layer sows its facts of the routing into, for
 #: programs that ask for it (``mutable=[..., ROUTING_STATS]``): ``load``, the
-#: live (token, held expert) pairs of this call by held expert, ``[held]``
+#: live (token, held expert) pairs of this call by held expert, ``[held]``, and
+#: ``row_tiles``, the row tiles those pairs were laid out in, ``[1]``
 ROUTING_STATS = "moe_stats"
+#: what ``routing_stats`` packs in front of the loads, in this order
+ROUTING_HEAD = ("tokens_routed", "local_picks", "experts_hit", "row_tiles")
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -197,16 +200,25 @@ def live_tokens(x: jnp.ndarray, seq_lens: Optional[jnp.ndarray],
     return jnp.ones((B, T), jnp.bool_)
 
 
+def _sown(sown, name: str) -> list:
+    """The leaves a pass's routed layers sowed under ``name``, one a layer."""
+    return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
+            if any(getattr(p, "key", None) == name for p in path)]
+
+
 def routing_stats(sown, n_live) -> jnp.ndarray:
     """What a program hands the host of one pass's routing, packed in ONE small
-    int32 array: ``[tokens_routed, local_picks, experts_hit, load_0 ..
-    load_{held-1}]`` from the ``ROUTING_STATS`` collection of that pass
-    (``sown``: one ``load`` a routed layer) and its live tokens. ``tokens_routed``
-    = live tokens x routed layers; ``local_picks`` the (token, held expert)
-    pairs computed; ``experts_hit`` the (layer, held expert) with at least one."""
-    loads = jnp.stack(jax.tree_util.tree_leaves(sown))  # [routed layers, held]
+    int32 array: ``[*ROUTING_HEAD, load_0 .. load_{held-1}]`` from the
+    ``ROUTING_STATS`` collection of that pass (``sown``: one ``load`` and one
+    ``row_tiles`` a routed layer) and its live tokens. ``tokens_routed`` = live
+    tokens x routed layers; ``local_picks`` the (token, held expert) pairs
+    computed; ``experts_hit`` the (layer, held expert) with at least one;
+    ``row_tiles`` the tiles the grouped matmuls multiplied: ``1 - experts_hit /
+    row_tiles`` of them shared their expert with the tile before (0 in a decode
+    step; where the matrix is one block, tiles that found it in VMEM)."""
+    loads = jnp.stack(_sown(sown, "load"))  # [routed layers, held]
     head = jnp.stack([jnp.asarray(n_live, jnp.int32) * loads.shape[0], jnp.sum(loads),
-                      jnp.sum((loads > 0).astype(jnp.int32))])
+                      jnp.sum((loads > 0).astype(jnp.int32)), jnp.sum(jnp.stack(_sown(sown, "row_tiles")))])
     return jnp.concatenate([head, jnp.sum(loads, axis=0)]).astype(jnp.int32)
 
 
@@ -327,6 +339,7 @@ class RoutedMoE(nn.Module):
         row_token, pair_row, mine, tile_group, n_live, load = sort_pairs(
             experts, live.reshape(-1), first, held, tm)
         self.sow(ROUTING_STATS, "load", load)
+        self.sow(ROUTING_STATS, "row_tiles", n_live)
 
         matmul = _grouped_matmul_impl(jax.default_backend(), D, F, tm, jnp.dtype(cfg.dtype).name)
         with jax.named_scope("moe_experts"):

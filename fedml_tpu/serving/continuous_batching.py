@@ -103,6 +103,7 @@ from ..core import telemetry as tel
 from ..core.telemetry import devperf, trace_context, tsdb
 from ..models.mamba import STATE_LEAVES
 from ..models.mamba import state_bytes as mamba_state_bytes
+from ..models.moe import ROUTING_HEAD
 from ..models.transformer import TransformerConfig
 from ..train.llm.generation import _leaf_name, _prefill_fn
 from ..train.llm.generation import _sample  # noqa: F401 - tests/benchmark_suite plants a fault by patching it here too
@@ -284,10 +285,10 @@ class PagedContinuousBatchingEngine:
         # bytes one live token holds in the latent layers' pages
         self._latent_token_bytes = (base.latent_layers * base.latent_width
                                     * jnp.dtype(base.dtype).itemsize)
-        # routed layers: [tokens_routed, local_picks, experts_hit] and the pairs by
+        # routed layers: the routing's facts (``ROUTING_HEAD``) and the pairs by
         # held expert since the engine started; the last chunks' loads for the gauge
         self._routed = bool(base.routed_layers)
-        self._moe_totals = np.zeros((3,), np.int64)
+        self._moe_totals = np.zeros((len(ROUTING_HEAD),), np.int64)
         self._moe_load = np.zeros((base.moe_held_experts or base.moe_routed_experts,), np.int64)
         self._moe_recent: "collections.deque" = collections.deque(maxlen=MOE_GAUGE_CHUNKS)
         self._alloc = PagedKVAllocator(
@@ -464,9 +465,7 @@ class PagedContinuousBatchingEngine:
                        kv_window_pages_unbounded=int(sum(-(-n // self._ps) for n in lens)))
         if self._routed:
             with self._lock:
-                out.update(moe_tokens_routed=int(self._moe_totals[0]),
-                           moe_local_picks=int(self._moe_totals[1]),
-                           moe_experts_hit=int(self._moe_totals[2]),
+                out.update({f"moe_{k}": int(v) for k, v in zip(ROUTING_HEAD, self._moe_totals)},
                            moe_expert_load=[int(x) for x in self._moe_load])
         if self._admission is not None:
             out["admission"] = self._admission.stats()
@@ -851,7 +850,7 @@ class PagedContinuousBatchingEngine:
             tok0 = int(np.asarray(w.tok0))  # fedlint: disable=host-sync one sync per admission, not per decode step, behind the launch of the chunk that carries the rider
             if w.routing is not None:  # the prefill ran before the admit program: already here
                 self._note_routing(np.asarray(w.routing), getattr(w.prefill_span, "attrs", None),
-                                   ("local_picks", "experts_hit"))
+                                   ("local_picks", "experts_hit", "row_tiles"))
                 w.routing = w.prefill_span = None
         with tel.span("serving.paged.admit", request_id=item.request_id):
             now_ns = time.perf_counter_ns()
@@ -1007,8 +1006,7 @@ class PagedContinuousBatchingEngine:
             self._t_landed_ns = now_ns
             tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
             if chunk.routing is not None:
-                load = self._note_routing(np.asarray(chunk.routing), chunk.span_attrs,
-                                          ("tokens_routed", "local_picks", "experts_hit"))
+                load = self._note_routing(np.asarray(chunk.routing), chunk.span_attrs, ROUTING_HEAD)
                 with self._lock:
                     self._moe_recent.append(load)
                     recent = np.sum(self._moe_recent, axis=0)
@@ -1029,16 +1027,18 @@ class PagedContinuousBatchingEngine:
         ``serving.moe.*`` counters, the engine's totals and, under ``names``,
         the attributes of the span that covered the pass. Returns the pairs by
         held expert."""
-        head = dict(zip(("tokens_routed", "local_picks", "experts_hit"), (int(x) for x in packed[:3])))
+        n = len(ROUTING_HEAD)
+        head = dict(zip(ROUTING_HEAD, (int(x) for x in packed[:n])))
         tel.counter("serving.moe.tokens_routed").add(head["tokens_routed"])
         tel.counter("serving.moe.local_picks").add(head["local_picks"])
         tel.counter("serving.moe.experts_hit").add(head["experts_hit"])
+        tel.counter("serving.moe.row_tiles").add(head["row_tiles"])
         if span_attrs is not None:
             span_attrs.update({k: head[k] for k in names})
         with self._lock:
-            self._moe_totals += packed[:3]
-            self._moe_load += packed[3:]
-        return packed[3:].astype(np.int64)
+            self._moe_totals += packed[:n]
+            self._moe_load += packed[n:]
+        return packed[n:].astype(np.int64)
 
     def _finish_if_done(self, b: int, now_ns: int) -> bool:
         """Free slot ``b`` and its pages if its request hit EOS or its token

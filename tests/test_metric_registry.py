@@ -152,6 +152,7 @@ EXPORTED = {
     "fedml_serving_moe_tokens_routed_total": "counter",
     "fedml_serving_moe_local_picks_total": "counter",
     "fedml_serving_moe_experts_hit_total": "counter",
+    "fedml_serving_moe_row_tiles_total": "counter",
     "fedml_serving_moe_load_imbalance": "gauge",
     # multi-tenant admission (serving/admission.py; {tenant}/{tenant,reason})
     "fedml_serving_admission_rejected_total": "counter",

@@ -13,9 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from fedml_tpu.core import telemetry as tel
 from fedml_tpu.models import moe
-from fedml_tpu.models.transformer import TransformerConfig
+from fedml_tpu.models.transformer import TransformerConfig, TransformerLM
 from fedml_tpu.ops import grouped_matmul as gm
+from fedml_tpu.serving.continuous_batching import PagedContinuousBatchingEngine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for p in (REPO, os.path.join(REPO, "benchmark")):
@@ -109,11 +111,40 @@ def test_no_token_is_dropped_at_any_imbalance(case):
     expect = {"all_to_one_held_expert": 40, "none_to_a_held_expert": 0, "half_the_tokens_dead": 20}[case]
     assert load.tolist() == [0, 0, 0, expect, 0, 0, 0, 0]
     stats = np.asarray(moe.routing_stats(sown[moe.ROUTING_STATS], int(keep.sum())))
-    assert stats[:3].tolist() == [int(keep.sum()), expect, int(expect > 0)] and stats[3:].tolist() == load.tolist()
+    tiles = -(-expect // moe.row_tile(40, 4, 32))  # one group: its pairs in whole row tiles
+    assert int(np.asarray(sown[moe.ROUTING_STATS]["row_tiles"][0])[0]) == tiles
+    head = dict(zip(moe.ROUTING_HEAD, stats.tolist()))
+    assert head == {"tokens_routed": int(keep.sum()), "local_picks": expect, "experts_hit": int(expect > 0), "row_tiles": tiles}
+    assert head["experts_hit"] <= head["row_tiles"] and stats[len(moe.ROUTING_HEAD):].tolist() == load.tolist()
     if case == "half_the_tokens_dead":  # a dead token's routed part is nothing: the shared expert alone
         shared = reference_pangu.swiglu(x[0], params["shared"]["gate_proj"]["kernel"], params["shared"]["up_proj"]["kernel"],
                                         params["shared"]["down_proj"]["kernel"], None)
         np.testing.assert_allclose(np.asarray(y[0])[~keep], np.asarray(shared)[~keep], atol=2e-5, rtol=2e-5)
+
+
+def test_the_row_tiles_of_every_pass_land_on_its_span_and_in_the_counter():
+    """Two experts, both picked by every token: a prefill of 300 tokens lays each expert's 300 pairs in
+    three 128-row tiles, a decode step in one 16-row tile. Both passes say so on their spans
+    (``row_tiles`` beside ``experts_hit``); the counter and ``stats()`` hold the sum."""
+    tel.reset()
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=2, n_kv_heads=2, d_ff=64, max_seq_len=512,
+                            dtype=jnp.float32, remat=False, moe_routed_experts=2, moe_top_k=2, moe_d_ff=16)
+    params = TransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = PagedContinuousBatchingEngine(params, cfg, num_slots=2, chunk=4, page_size=16, num_pages=65)
+    try:
+        assert len(eng.generate(np.random.default_rng(6).integers(1, 64, 300).tolist(), 6)) == 6
+        spans = tel.snapshot()["spans"]
+        (prefill,) = [s["attrs"] for s in spans if s["name"] == "serving.cb.prefill"]
+        chunks = [s["attrs"] for s in spans if s["name"] == "serving.cb.chunk"]
+        assert moe.row_tile(304, 2, 2) == 128  # the 304-token bucket's tile
+        assert (prefill["local_picks"], prefill["experts_hit"], prefill["row_tiles"]) == (300 * 2 * 2, 2 * 2, 3 * 2 * 2)
+        # a chunk of 4 token-steps: one tile an expert a step a layer, no tile shares a block with the one before
+        assert chunks and all(c["row_tiles"] == c["experts_hit"] == 4 * 2 * 2 for c in chunks)
+        total = prefill["row_tiles"] + sum(c["row_tiles"] for c in chunks)
+        assert tel.counter("serving.moe.row_tiles").value == eng.stats()["moe_row_tiles"] == total
+        assert eng.stats()["moe_experts_hit"] == total - 2 * 2 * 2  # the prefill's second and third tiles
+    finally:
+        eng.shutdown()
 
 
 def test_sort_pairs_lays_every_live_held_pair_in_a_tile_of_its_expert():
@@ -135,20 +166,89 @@ def test_sort_pairs_lays_every_live_held_pair_in_a_tile_of_its_expert():
     assert moe.row_tile(64, 8, 256) == 16 and moe.row_tile(1280, 8, 256) == 128 and moe.row_tile(272, 8, 256) == 32
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
-def test_the_grouped_matmul_kernel_equals_the_plain_formulation(dtype, tol):
-    """Interpreted: tiles of three experts out of five (one expert twice, one never), dead tiles behind."""
+# tile_group of the live tiles, dead tiles behind; K, N: "one_block" is the matrix whole, "cut" (6 MiB and
+# more at either dtype) is BLOCK_K x BLOCK_N blocks with two contraction steps into the accumulator
+GROUPS = {
+    "one_block_groups_of_1_2_1_tiles": dict(K=256, N=384, E=5, tiles=[0, 2, 2, 4], dead=4),
+    "one_block_groups_of_0_1_3_9_tiles": dict(K=256, N=384, E=5, tiles=[1] + [2] * 3 + [4] * 9, dead=3),
+    "cut_blocks_groups_of_0_1_3_tiles": dict(K=3072, N=1024, E=4, tiles=[1, 3, 3, 3], dead=2),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(GROUPS))
+def test_the_grouped_matmul_kernel_equals_the_plain_formulation(case, dtype, tol):
+    """Interpreted: groups of 0, 1, 3 and 9 tiles (an expert nobody picked, one tile, a block several
+    tiles share), the last tile of a group padded with rows of token 0, dead tiles behind."""
+    c = GROUPS[case]
     rng = np.random.default_rng(5)
-    tm, K, N, E = 16, 256, 384, 5
-    tile_group = jnp.asarray([0, 2, 2, 4, 4, 4, 4, 4], jnp.int32)  # 4 live tiles; the rest repeat the last
-    n_live = jnp.asarray([4], jnp.int32)
-    x = jnp.asarray(rng.normal(size=(8 * tm, K)), dtype)
-    w = jnp.asarray(rng.normal(size=(E, K, N)) / np.sqrt(K), dtype)
-    got = gm.grouped_matmul(x, w, tile_group, n_live, tm=tm)
-    want = gm.grouped_matmul_reference(x, w, tile_group, n_live, tm=tm)
-    assert got.shape == (8 * tm, N) and got.dtype == dtype
-    np.testing.assert_allclose(np.asarray(got[:4 * tm], np.float32), np.asarray(want[:4 * tm], np.float32), atol=tol, rtol=tol)
+    tm, K, N, E = 16, c["K"], c["N"], c["E"]
+    n_live, n_tiles = len(c["tiles"]), len(c["tiles"]) + c["dead"]
+    assert (gm.block_sizes(K, N, dtype) == (K, N)) == case.startswith("one_block")
+    tile_group = jnp.asarray(c["tiles"] + [c["tiles"][-1]] * c["dead"], jnp.int32)  # the rest repeat the last
+    x = rng.normal(size=(n_tiles * tm, K))
+    x[n_live * tm - 5:n_live * tm] = x[0]  # a padded last tile: rows of token 0, read by nobody
+    x, w = jnp.asarray(x, dtype), jnp.asarray(rng.normal(size=(E, K, N)) / np.sqrt(K), dtype)
+    live = jnp.asarray([n_live], jnp.int32)
+    got = gm.grouped_matmul(x, w, tile_group, live, tm=tm)
+    want = gm.grouped_matmul_reference(x, w, tile_group, live, tm=tm)
+    assert got.shape == (n_tiles * tm, N) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got[:n_live * tm], np.float32), np.asarray(want[:n_live * tm], np.float32),
+                               atol=tol, rtol=tol)
     none = gm.grouped_matmul(x, w, tile_group, jnp.asarray([0], jnp.int32), tm=tm)  # nothing live: nothing to read
-    assert none.shape == (8 * tm, N)
-    assert gm.tiles(7680, 2048, 16, jnp.bfloat16) and gm.tiles(2048, 7680, 128, jnp.bfloat16)
-    assert not gm.tiles(7680, 2048, 8, jnp.bfloat16) and gm._block(7680, 1536) == 1536 and gm._block(2048, 1536) == 1024
+    assert none.shape == (n_tiles * tm, N)
+
+
+@pytest.mark.parametrize("K,N,blocks", [
+    (2048, 1024, (2048, 1024)), (1024, 2048, (1024, 2048)),    # trinity-mini's gate / up and down: 4 MiB, whole
+    (7680, 2048, (1536, 1024)), (2048, 7680, (1024, 768)),     # openPangu's: 31.5 MB, the cut they have had since PR 33
+], ids=["trinity_gate_up", "trinity_down", "pangu_gate_up", "pangu_down"])
+def test_the_weight_block_follows_the_matrix(K, N, blocks):
+    assert gm.block_sizes(K, N, jnp.bfloat16) == blocks
+    assert K * N * 2 <= gm.WHOLE_MATRIX_BYTES or blocks == gm.cut_blocks(K, N)
+    assert gm.tiles(K, N, 16, jnp.bfloat16) and gm.tiles(K, N, 128, jnp.bfloat16) and not gm.tiles(K, N, 8, jnp.bfloat16)
+    assert 2 * blocks[0] * blocks[1] * 2 + 4 * 128 * (K + 2 * N) < gm.VMEM_LIMIT  # two blocks in flight beside the row tiles
+
+
+# --- the Mosaic form, compiled for a described chip ---------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("tm", [16, 128], ids=["decode_step_tiles", "prefill_tiles"])
+@pytest.mark.parametrize("K,N", [(2048, 1024), (1024, 2048)], ids=["gate_up", "down"])
+def test_the_kernel_alone_compiles_at_trinity_s_matrices(one_chip, no_cache, K, N, tm):
+    """128 experts of a layer, bf16, 40 tiles: the whole-matrix blocks lower through Mosaic inside
+    ``VMEM_LIMIT`` (what the chip's compiler would refuse, it refuses here), into one call that holds
+    no temporaries outside the kernel."""
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert gm.block_sizes(K, N, jnp.bfloat16) == (K, N)
+    compiled = jax.jit(lambda x, w, tg, nl: gm._grouped_matmul(x, w, tg, nl, tm=tm, interpret=False)).lower(
+        s((40 * tm, K), jnp.bfloat16), s((128, K, N), jnp.bfloat16), s((40,), jnp.int32), s((1,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
