@@ -83,7 +83,9 @@ runner exports as Prometheus gauges and ``/statusz`` fields. Spans
 (docs/observability.md, "Serving request lifecycle"): every request leaves
 ``serving.request.queue`` / ``.admit`` / ``.decode`` records carrying its
 ``request_id``; the worker loop leaves ``serving.engine.iteration`` with its
-children and ``serving.engine.idle``.
+children and ``serving.engine.idle``, and says when the chip had nothing queued
+and what its own thread was doing then (``_DeviceLedger``:
+``serving.device.starved``).
 """
 
 from __future__ import annotations
@@ -112,6 +114,7 @@ from .admission import REASON_QUEUE_FULL, count_reject
 from .paged_kv import (
     TRASH_PAGE,
     PagedKVAllocator,
+    WaitTimedLock,
     _paged_admit_fn,
     _paged_gather_fn,
     _paged_step_fn,
@@ -132,12 +135,149 @@ MOE_GAUGE_CHUNKS = 64
 #: wait for the next iteration. At a 2,048-token row that is more riders than slots; at a 16,896-token row
 #: of 5 layers (173 MB) it is 6, where a wave of twenty had the chip out of memory
 ROWS_IN_FLIGHT_BYTES = 2 ** 30
+#: what the worker's thread is doing, as the starvation ledger names it (``_DeviceLedger``): a closed set.
+#: ``no_work``: nothing to launch (the wait of ``serving.engine.idle``, the backpressure sleep); ``collect``:
+#: ``_collect_wave`` and the loop's top; ``launch``: ``_stage_prefill``, ``_stage_transfer``, ``_slide_windows`` +
+#: ``_device_copy`` + ``.dispatch``; ``land``: ``.sync`` / ``.post`` / ``first_token_wait`` /
+#: ``serving.paged.admit`` and what lies between them
+NO_WORK, COLLECT, LAUNCH, LAND = STARVED_PHASES = ("no_work", "collect", "launch", "land")
 
 
 def _gauge(name: str, value: float) -> None:
     store = tsdb.active()
     if store is not None:
         store.record_gauge(name, value)
+
+
+class _DeviceLedger:
+    """When the chip had nothing queued, and what the worker's thread was
+    doing then: the worker's own account, kept without a lock (one thread) and
+    read without the profiler.
+
+    The worker is the only thread that launches programs, and the chip runs
+    them in order: so when one output of the NEWEST launch is ready, the
+    device's queue is empty. ``launched(out)`` keeps that output after every
+    launch call (an array the engine never donates; a deleted one reads as
+    ready). ``mark(phase)`` is called at every phase boundary of the worker
+    (where its spans open and close; ``phase`` is what follows, one of
+    ``STARVED_PHASES``) and asks ``is_ready()`` once: it does not block,
+    launches nothing, fetches nothing. The first time it answers ready a
+    starvation interval is open from that instant; it closes when the next
+    launch call returns. Every boundary crossed meanwhile cuts a piece, so each
+    piece lies in ONE phase: a ``serving.device.starved`` record
+    (``phase``, ``first``, and on an interval's first piece ``unseen_ns``: this
+    look minus the look before it, which still read busy) and the counters
+    ``serving.device.starved_ns`` / ``serving.device.starvations``. There are
+    no boundaries inside a launch, so ``launched`` looks once more: if the
+    launch before is done when this one returns and no boundary saw it, the
+    queue may have emptied and filled again in between; that is an interval
+    of one piece of no length, all of it ``unseen_ns``.
+
+    The two bounds: the chip went idle somewhere inside an interval's
+    ``unseen_ns`` (if at all, for a piece of no length), so true idle time lies
+    between the pieces' sum and that sum plus the ``unseen_ns``; and a launch
+    call's return precedes the device's start by the runtime's own latency,
+    which no piece holds.
+
+    It also keeps the worker's account of one iteration (the attributes of
+    ``serving.engine.iteration``): ``cpu_ns`` of its thread, ``blocked_ns`` in
+    the fetches that wait for the chip, ``lock_wait_ns`` over ``locks``,
+    ``starved_ns``. With the registry off every call is one flag check."""
+
+    def __init__(self, locks: Sequence[WaitTimedLock]):
+        self._registry = tel.get_telemetry()
+        self._locks = tuple(locks)
+        self.newest = None       # one output of the newest launch (None: nothing launched yet)
+        self._phase = NO_WORK
+        self._t_busy = None      # the last look that read busy (a launch's return is one)
+        self._t_piece = None     # an interval is open: where its next piece starts
+        self._first = False
+        self._unseen_ns = 0
+        self._starved_ns = 0     # the four of the iteration under way
+        self._blocked_ns = 0
+        self._lock_wait0 = 0
+        self._cpu0 = None
+
+    def _queue_empty(self) -> bool:
+        out = self.newest
+        return out is None or out.is_deleted() or out.is_ready()
+
+    def _cut(self, now: int) -> None:
+        first = {"unseen_ns": self._unseen_ns} if self._first else {}
+        tel.record_span("serving.device.starved", self._t_piece, now,
+                        phase=self._phase, first=self._first, **first)
+        tel.counter("serving.device.starved_ns").add(now - self._t_piece)
+        if self._first:
+            tel.counter("serving.device.starvations").add(1)
+        self._starved_ns += now - self._t_piece
+        self._t_piece, self._first = now, False
+
+    def mark(self, phase: str) -> None:
+        """A phase boundary: ``phase`` is what the worker does from here on."""
+        if not self._registry.enabled:
+            self._t_piece = self._t_busy = None
+            return
+        now = time.perf_counter_ns()
+        if self._t_piece is not None:
+            self._cut(now)
+        elif self._queue_empty():
+            self._t_piece, self._first = now, True
+            self._unseen_ns = now - self._t_busy if self._t_busy is not None else 0
+        else:
+            self._t_busy = now
+        self._phase = phase
+
+    def launched(self, out) -> None:
+        """A launch call has returned: the chip has work again. Where no
+        interval is open and the launch BEFORE this one is done, the queue may
+        have run empty and been filled again inside this call, between two
+        boundaries: a piece of no length whose ``unseen_ns`` holds the doubt."""
+        unseen = self._t_piece is None and self._queue_empty()
+        self.newest = out
+        if not self._registry.enabled:
+            self._t_piece = self._t_busy = None
+            return
+        now = time.perf_counter_ns()
+        if unseen:
+            self._t_piece, self._first = now, True
+            self._unseen_ns = now - self._t_busy if self._t_busy is not None else 0
+        if self._t_piece is not None:
+            self._cut(now)
+            self._t_piece = None
+        self._t_busy = now
+
+    def before_fetch(self) -> int:
+        """A boundary of ``land`` in front of a span that waits for the chip
+        (``.sync``, ``first_token_wait``): the worker's CPU time so far."""
+        self.mark(LAND)
+        return time.thread_time_ns() if self._registry.enabled else 0
+
+    def after_fetch(self, span, cpu0: int) -> None:
+        """The boundary behind that span, once it has closed: what of it the
+        thread spent off the CPU is time blocked on the chip (the copy to NumPy
+        is ``cpu_ns``'s, so ``cpu_ns + blocked_ns`` never passes the
+        iteration's wall time)."""
+        if span.duration_ns is not None and self._cpu0 is not None:
+            self._blocked_ns += max(0, span.duration_ns - (time.thread_time_ns() - cpu0))
+        self.mark(LAND)
+
+    def begin_iteration(self) -> None:
+        """Inside the iteration's span, before anything else: what follows is ``_collect_wave``."""
+        self.mark(COLLECT)
+        if not self._registry.enabled:
+            self._cpu0 = None
+            return
+        self._starved_ns = self._blocked_ns = 0
+        self._lock_wait0 = sum(k.wait_ns for k in self._locks)
+        self._cpu0 = time.thread_time_ns()
+
+    def end_iteration(self, attrs: Optional[dict]) -> None:
+        """The iteration's account onto its span; what follows is the loop's top."""
+        if attrs is not None and self._cpu0 is not None:
+            attrs.update(cpu_ns=time.thread_time_ns() - self._cpu0, blocked_ns=self._blocked_ns,
+                         lock_wait_ns=sum(k.wait_ns for k in self._locks) - self._lock_wait0)
+            self.mark(COLLECT)
+            attrs["starved_ns"] = self._starved_ns
 
 
 class RequestHandle:
@@ -334,6 +474,9 @@ class PagedContinuousBatchingEngine:
 
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
+        # the engine lock as the WORKER takes it, and the worker's account of the chip's queue and of itself
+        self._wlock = WaitTimedLock(self._lock)
+        self._ledger = _DeviceLedger((self._wlock, self._alloc.worker_lock))
         self._queue: "collections.deque[_Pending]" = collections.deque()
         self._stopping = False
         self._requests_done = 0
@@ -547,9 +690,11 @@ class PagedContinuousBatchingEngine:
         while True:
             with self._work:
                 if self._idle_locked():
+                    self._ledger.mark(NO_WORK)
                     with tel.span("serving.engine.idle"):
                         while self._idle_locked():
                             self._work.wait()
+                    self._ledger.mark(COLLECT)
                 if self._stopping:
                     err = RuntimeError("engine is shutting down")
                     for item in self._queue:
@@ -561,37 +706,49 @@ class PagedContinuousBatchingEngine:
                 n_queued = len(self._queue)
             try:
                 with tel.span("serving.engine.iteration", slots=n_active,
-                              queue_depth=n_queued):
-                    self._admit_all()  # fedlint: disable=interproc-host-sync admission copies prompts host->device once per request, not per token, and waits for nothing
-                    if any(s is not None and s.generated < s.budget for s in self._slots):
-                        self._step_chunk()  # fedlint: disable=interproc-host-sync one bounded fetch per decode chunk, behind the next chunk's launch: tokens must reach the host to stream to callers
-                    elif self._inflight is not None:
-                        chunk, self._inflight = self._inflight, None
-                        self._land_chunk(chunk)  # fedlint: disable=interproc-host-sync the last chunk's fetch: nothing is left to launch ahead of it
-                    self._land_riders()  # fedlint: disable=interproc-host-sync one fetch per admission, behind the chunk that carries the rider
+                              queue_depth=n_queued) as iteration:
+                    self._ledger.begin_iteration()
+                    try:
+                        self._admit_all()  # fedlint: disable=interproc-host-sync admission copies prompts host->device once per request, not per token, and waits for nothing
+                        if any(s is not None and s.generated < s.budget for s in self._slots):
+                            self._step_chunk()  # fedlint: disable=interproc-host-sync one bounded fetch per decode chunk, behind the next chunk's launch: tokens must reach the host to stream to callers
+                        elif self._inflight is not None:
+                            chunk, self._inflight = self._inflight, None
+                            self._land_chunk(chunk)  # fedlint: disable=interproc-host-sync the last chunk's fetch: nothing is left to launch ahead of it
+                        self._land_riders()  # fedlint: disable=interproc-host-sync one fetch per admission, behind the chunk that carries the rider
+                    finally:
+                        self._ledger.end_iteration(getattr(iteration, "attrs", None))
             except Exception as e:  # noqa: BLE001 - engine thread boundary:
                 # fail every rider (of the chunk at fault and of the one queued
                 # behind it) rather than die silently with their futures
                 # hanging; next iteration serves fresh requests
                 log.exception("continuous-batching worker step failed")
-                with self._lock:
+                with self._wlock:
                     self._fail_live_locked(e)
 
     def _admit_all(self) -> None:
         while True:
-            wave = self._collect_wave()
+            deferred = sum(self._admit_deferred.values())
+            with tel.span("serving.engine.collect_wave") as collect:
+                wave = self._collect_wave()
+                attrs = getattr(collect, "attrs", None)
+                if attrs is not None:
+                    attrs.update(n=len(wave), deferred=sum(self._admit_deferred.values()) - deferred)
             if not wave:
-                with self._lock:
-                    starved = (bool(self._queue) and self._inflight is None
-                               and all(s is None for s in self._slots))
-                if starved:
+                with self._wlock:
+                    held_back = (bool(self._queue) and self._inflight is None
+                                 and all(s is None for s in self._slots))
+                if held_back:
                     # every queued tenant is deferred (or the pool is
                     # draining) and nothing is in flight: don't spin the
                     # worker loop hot while backpressure holds
+                    self._ledger.mark(NO_WORK)
                     time.sleep(0.005)  # fedlint: disable=bare-sleep backpressure idle, not a retry
+                    self._ledger.mark(COLLECT)
                 return
             with tel.span("serving.paged.admit_wave", n=len(wave)):
                 self._run_wave(wave)
+            self._ledger.mark(COLLECT)
 
     def _pick_locked(self) -> Optional[_Pending]:
         """Next request to admit (caller holds the engine lock): FIFO
@@ -620,7 +777,7 @@ class PagedContinuousBatchingEngine:
         wave: List[_AdmitWork] = []
         taken: set = set()
         while True:
-            with self._lock:
+            with self._wlock:
                 free = next((i for i, s in enumerate(self._slots)
                              if s is None and i not in taken), None)
                 if free is None or not self._queue or len(wave) + len(self._riders) >= self._max_riders:
@@ -652,7 +809,7 @@ class PagedContinuousBatchingEngine:
                 # the window group: the blocks of the prompt's last ``window`` tokens that the match
                 # does not bring; and never more live requests than it holds bounds for
                 first_w = max(max(0, P - self._window + 1) // self._ps, len(shared))
-                with self._lock:
+                with self._wlock:
                     live = sum(1 for s in self._slots if s is not None) + len(wave)
                 window_private = None
                 if (live + 1) * self._win_bound <= self._alloc.window_pages - 1:
@@ -668,7 +825,7 @@ class PagedContinuousBatchingEngine:
                     tel.counter("serving.kv.admit_deferred_full").add(1)
                 self._alloc.free(shared)
                 self._alloc.free_window(match.window_pages)
-                with self._lock:
+                with self._wlock:
                     busy = any(s is not None for s in self._slots)
                     if busy or wave:
                         # pages free as in-flight requests finish: defer
@@ -698,14 +855,9 @@ class PagedContinuousBatchingEngine:
         rider can be served from a deleted pool, so the wave's unlaunched
         riders are failed and the error goes on to ``_loop``'s boundary, which
         fails every rider that holds a slot."""
-        behind = False  # a rider of this wave is launched, its first token still on the device
         for w in wave:
-            if not self._try_stage(self._launch, w, wave):
-                continue
-            if behind:
-                tel.counter("serving.paged.launches_overlapped").add(1)
-            behind = True
-            self._riders.append(w)
+            if self._try_stage(self._launch, w, wave):
+                self._riders.append(w)
 
     def _land_riders(self) -> None:
         """Fetch the launched riders' first tokens, in launch order, and do
@@ -738,7 +890,7 @@ class PagedContinuousBatchingEngine:
             return
         if r.launched:  # its slot's table holds its pages
             self._release_slot(r.slot)
-            with self._lock:
+            with self._wlock:
                 self._slots[r.slot] = None
         else:
             self._alloc.free(r.shared_pages + r.private_pages)
@@ -746,7 +898,9 @@ class PagedContinuousBatchingEngine:
         r.item.handle._fail(e)
 
     def _launch(self, w: _AdmitWork) -> None:
+        self._ledger.mark(LAUNCH)
         self._stage_prefill(w)
+        self._ledger.mark(LAUNCH)
         self._stage_transfer(w)
 
     def _stage_prefill(self, w: _AdmitWork) -> None:
@@ -784,10 +938,12 @@ class PagedContinuousBatchingEngine:
                     more = (wtable,)
                 row_cache = _paged_gather_fn(self._paged_cfg)(
                     self._cache, table, np.int32(prefix_len), w.state, *more)
+                self._ledger.launched(jax.tree_util.tree_leaves(row_cache)[0])
                 out = _suffix_prefill_fn(self._paged_cfg, T_b)(
                     self._params, row_cache, ids, np.int32(prefix_len),
                     np.int32(P), snap)
             w.row_cache, w.first = out[:2]
+            self._ledger.launched(w.first)
             if self._routed:
                 w.routing = out[2]
                 w.routing.copy_to_host_async()
@@ -824,6 +980,7 @@ class PagedContinuousBatchingEngine:
                 self._cache, w.row_cache, write_ids, np.int32(b), w.first,
                 np.uint32(item.seed & 0xFFFFFFFF), np.float32(item.temperature),
                 self._carry, np.int32(P), *more)
+            self._ledger.launched(w.tok0)
             w.first = None
             if not w.snap_blocks:  # the row is in its pages: nobody reads it again (a snapshot's taker does)
                 w.row_cache = None
@@ -833,7 +990,7 @@ class PagedContinuousBatchingEngine:
             self._tables[b, w.n_shared:n_own] = w.private_pages
             self._tables[b, n_own:] = TRASH_PAGE
             self._temps[b] = item.temperature
-            with self._lock:
+            with self._wlock:
                 self._lengths[b] = P
                 self._slots[b] = _Active(item, w.budget, generated=1)
             w.launched = True
@@ -846,12 +1003,14 @@ class PagedContinuousBatchingEngine:
         this system prompt shares pages."""
         item = w.item
         b = w.slot
-        with tel.span("serving.paged.first_token_wait", request_id=item.request_id):
+        cpu0 = self._ledger.before_fetch()
+        with tel.span("serving.paged.first_token_wait", request_id=item.request_id) as wait:
             tok0 = int(np.asarray(w.tok0))  # fedlint: disable=host-sync one sync per admission, not per decode step, behind the launch of the chunk that carries the rider
             if w.routing is not None:  # the prefill ran before the admit program: already here
                 self._note_routing(np.asarray(w.routing), getattr(w.prefill_span, "attrs", None),
                                    ("local_picks", "experts_hit", "row_tiles"))
                 w.routing = w.prefill_span = None
+        self._ledger.after_fetch(wait, cpu0)
         with tel.span("serving.paged.admit", request_id=item.request_id):
             now_ns = time.perf_counter_ns()
             s = self._slots[b]
@@ -874,6 +1033,7 @@ class PagedContinuousBatchingEngine:
                         self._state_bytes)
             w.row_cache = None
             self._finish_if_done(b, now_ns)
+        self._ledger.mark(LAND)
 
     def _note_first_token(self, item: _Pending, now_ns: int, shared: int) -> float:
         """The request's first token is on the host: its queue and admit
@@ -909,6 +1069,7 @@ class PagedContinuousBatchingEngine:
         call launches exactly one ``jit_paged_step``. A row rides if its
         request still has tokens to be launched (``generated < budget``): a
         budget's end needs no token seen."""
+        self._ledger.mark(LAUNCH)
         rows = [s if s is not None and s.generated < s.budget else None for s in self._slots]
         active = np.asarray([s is not None for s in rows], bool)
         n_live = int(active.sum())
@@ -940,19 +1101,19 @@ class PagedContinuousBatchingEngine:
                     self._device_copy("active", active),
                     *more,
                 )
+                self._ledger.launched(toks)
                 self._cache, self._carry = cache, (tok, lengths, keys)
                 for out in (toks, *routing):
                     out.copy_to_host_async()
                 before, self._inflight = self._inflight, _Chunk(
                     rows, toks, routing[0] if routing else None,
                     getattr(chunk_span, "attrs", None), time.perf_counter_ns())
-                with self._lock:  # stats() reads the lengths
+                with self._wlock:  # stats() reads the lengths
                     self._lengths[active] += self._C
                 for s in rows:
                     if s is not None:
                         s.generated += self._C
             if before is not None:
-                tel.counter("serving.cb.chunks_ahead").add(1)
                 self._land_chunk(before)
 
     def _slide_windows(self, active: np.ndarray) -> None:
@@ -993,8 +1154,10 @@ class PagedContinuousBatchingEngine:
         chip waits for) and do its bookkeeping (``.post``): tokens to the
         requests that still hold the rows they held at its launch, finishes,
         routing counters onto the span that launched it."""
-        with tel.span("serving.cb.chunk.sync"):
+        cpu0 = self._ledger.before_fetch()
+        with tel.span("serving.cb.chunk.sync") as sync:
             toks = np.asarray(chunk.toks)  # [B, C]; returns when the chunk is done
+        self._ledger.after_fetch(sync, cpu0)
         with tel.span("serving.cb.chunk.post"):
             now_ns = time.perf_counter_ns()
             n_live = sum(1 for s in chunk.rows if s is not None)
@@ -1007,7 +1170,7 @@ class PagedContinuousBatchingEngine:
             tel.counter("serving.cb.tokens_generated").add(n_live * self._C)
             if chunk.routing is not None:
                 load = self._note_routing(np.asarray(chunk.routing), chunk.span_attrs, ROUTING_HEAD)
-                with self._lock:
+                with self._wlock:
                     self._moe_recent.append(load)
                     recent = np.sum(self._moe_recent, axis=0)
                 if recent.sum() > 0:
@@ -1021,6 +1184,7 @@ class PagedContinuousBatchingEngine:
                     if (eos is not None and t in eos) or len(s.tokens) >= s.budget:
                         break
                 self._finish_if_done(b, now_ns)
+        self._ledger.mark(LAND)
 
     def _note_routing(self, packed: np.ndarray, span_attrs: Optional[dict], names: Tuple[str, ...]) -> np.ndarray:
         """One pass's packed routing (``models/moe.routing_stats``) into the
@@ -1035,7 +1199,7 @@ class PagedContinuousBatchingEngine:
         tel.counter("serving.moe.row_tiles").add(head["row_tiles"])
         if span_attrs is not None:
             span_attrs.update({k: head[k] for k in names})
-        with self._lock:
+        with self._wlock:
             self._moe_totals += packed[:n]
             self._moe_load += packed[n:]
         return packed[n:].astype(np.int64)
@@ -1043,7 +1207,7 @@ class PagedContinuousBatchingEngine:
     def _finish_if_done(self, b: int, now_ns: int) -> bool:
         """Free slot ``b`` and its pages if its request hit EOS or its token
         budget."""
-        with self._lock:
+        with self._wlock:
             s = self._slots[b]
         if s is None:
             return False
@@ -1069,7 +1233,7 @@ class PagedContinuousBatchingEngine:
                         request_id=s.pending.request_id, tokens=len(s.tokens),
                         wasted=wasted)
         self._release_slot(b)
-        with self._lock:
+        with self._wlock:
             self._slots[b] = None
             self._requests_done += 1
             self._tokens_out += len(s.tokens)

@@ -75,6 +75,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -371,6 +372,33 @@ def _paged_step_fn(cfg: TransformerConfig, B: int, C: int):
 # ---------------------------------------------------------------------------
 
 
+class WaitTimedLock:
+    """``with`` over a ``threading.Lock`` that sums, in ``wait_ns``, how long
+    its acquisitions took while the telemetry registry is on: two clock reads
+    around the acquire, no span an acquisition. One thread's account: the
+    engine's worker takes its locks through these (the other threads take the
+    bare lock), so the sum is the worker's own wait
+    (``serving.engine.iteration``'s ``lock_wait_ns``)."""
+
+    __slots__ = ("_lock", "_registry", "wait_ns")
+
+    def __init__(self, lock):
+        self._lock = lock
+        self._registry = tel.get_telemetry()
+        self.wait_ns = 0
+
+    def __enter__(self):
+        if not self._registry.enabled:
+            self._lock.acquire()
+            return
+        t0 = time.perf_counter_ns()
+        self._lock.acquire()
+        self.wait_ns += time.perf_counter_ns() - t0
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
 class _PrefixNode:
     """One hash-consed prompt chunk: a trie edge labeled by ``chunk`` (a
     full page of token ids) holding the physical page that stores it. The
@@ -421,6 +449,9 @@ class PagedKVAllocator:
 
     Thread-safe: the engine worker allocates/frees while HTTP threads read
     ``stats()``. Page ``TRASH_PAGE`` is pinned out of circulation forever.
+    What the worker calls a rider (``match``, ``alloc``, ``alloc_window``,
+    ``free``, ``free_window``, ``register_prefix``) takes the lock through
+    ``worker_lock``, whose ``wait_ns`` is how long those calls waited for it.
 
     ``window_pages`` > 0 adds the WINDOW GROUP (module docstring): a second
     free list and refcounts (``alloc_window`` / ``free_window``), for a model
@@ -451,6 +482,7 @@ class PagedKVAllocator:
         # because every request reserves its full budget at admit)
         self.watermark = max(1, int((num_pages - 1) * watermark_frac))
         self._lock = threading.Lock()
+        self.worker_lock = WaitTimedLock(self._lock)
         self._free: List[int] = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._ref = [0] * num_pages
         self._ref[TRASH_PAGE] = 1  # pinned
@@ -475,7 +507,7 @@ class PagedKVAllocator:
         retentions if the free list runs short. Returns None — admission
         defers — when the pool cannot cover ``n`` plus the watermark
         reserve without touching pages live requests still map."""
-        with self._lock:
+        with self.worker_lock:
             floor = self.watermark if reserve else 0
             if len(self._free) < n + floor:
                 self._evict_locked(n + floor - len(self._free))
@@ -501,7 +533,7 @@ class PagedKVAllocator:
         """Drop one reference per page; pages reaching zero return to the
         free list. Double-frees fail loudly — a silent one would hand the
         same page to two requests and corrupt both caches."""
-        with self._lock:
+        with self.worker_lock:
             for p in pages:
                 if p == TRASH_PAGE:
                     continue
@@ -518,7 +550,7 @@ class PagedKVAllocator:
         the free list runs short the trie gives up window pages no live
         request maps, least recently used first (the nodes keep their full
         pages). None when that is not enough."""
-        with self._lock:
+        with self.worker_lock:
             if len(self._wfree) < n:
                 self._evict_window_locked(n - len(self._wfree))
             if len(self._wfree) < n:
@@ -532,7 +564,7 @@ class PagedKVAllocator:
     def free_window(self, pages: Sequence[int], *, released: bool = False) -> None:
         """Drop one reference per window-group page (``free``'s rules).
         ``released``: the pages left a live request's window (counted)."""
-        with self._lock:
+        with self.worker_lock:
             n = 0
             for p in pages:
                 if p == TRASH_PAGE:
@@ -584,7 +616,7 @@ class PagedKVAllocator:
         prefill should leave a snapshot (``attach_state``). A state hit is an
         admission that starts from a snapshot, a miss one that starts from
         zero whether or not pages matched."""
-        with self._lock:
+        with self.worker_lock:
             nodes: List[_PrefixNode] = []
             level = self._root
             for chunk in self._chunks(tokens):
@@ -683,7 +715,7 @@ class PagedKVAllocator:
         write target (see module docstring). ``window_ids``: the request's
         window-group page of each chunk (TRASH_PAGE where its window does not
         reach); a node without one keeps it, retained likewise."""
-        with self._lock:
+        with self.worker_lock:
             chunks = self._chunks(tokens)
             level = self._root
             parent: Optional[_PrefixNode] = None

@@ -186,6 +186,8 @@ REQUEST_SPANS = (
 LAUNCH_STAGES = ("serving.cb.prefill", "serving.paged.transfer")  # under the wave's span, waited for by nothing
 LANDING_STAGES = ("serving.paged.first_token_wait", "serving.paged.admit")  # behind the launch of the chunk that carries the rider
 WAVE_STAGES = LAUNCH_STAGES + LANDING_STAGES
+#: what the worker adds to ``serving.engine.iteration`` at its end
+ITERATION_ACCOUNT = ("cpu_ns", "blocked_ns", "lock_wait_ns", "starved_ns")
 
 
 CALLER_ID = "cd" * 16
@@ -284,7 +286,12 @@ def test_queue_plus_admit_is_ttft_and_the_reply_says_the_same(served_requests):
 def test_worker_loop_spans_tile_an_iteration(served_requests):
     _, spans = served_requests
     iters = [s for s in spans if s["name"] == "serving.engine.iteration"]
-    assert iters and all(set(s["attrs"]) == {"slots", "queue_depth"} for s in iters)
+    assert iters and all(set(s["attrs"]) == {"slots", "queue_depth", *ITERATION_ACCOUNT} for s in iters)
+    for it in iters:  # the worker's own account of the pass: parts of its wall time, each
+        a = it["attrs"]
+        assert all(a[k] >= 0 for k in ITERATION_ACCOUNT)
+        assert a["cpu_ns"] + a["blocked_ns"] <= it["dur_ns"] and a["lock_wait_ns"] <= it["dur_ns"]
+        assert a["starved_ns"] <= it["dur_ns"]
     worker = {s["tid"] for s in iters}
     assert len(worker) == 1
     by_parent = {}
@@ -296,7 +303,9 @@ def test_worker_loop_spans_tile_an_iteration(served_requests):
         kids = by_parent.get(it["seq"], [])
         # a wave's launches, the chunk (its launch, then the landing of the one before), or the
         # last chunk's landing where nothing was left to launch, then the riders' first tokens
-        assert {k["name"] for k in kids} <= {"serving.paged.admit_wave", "serving.cb.chunk", *landing, *LANDING_STAGES}
+        assert {k["name"] for k in kids} <= {"serving.engine.collect_wave", "serving.paged.admit_wave",
+                                             "serving.cb.chunk", *landing, *LANDING_STAGES}
+        assert kids[0]["name"] == "serving.engine.collect_wave"  # the loop's top has a name
         assert all(k["tid"] == it["tid"] for k in kids)
         assert not ({"serving.cb.chunk", *landing} <= {k["name"] for k in kids})  # one or the other
         covered += sum(k["dur_ns"] for k in kids)
@@ -314,6 +323,12 @@ def test_worker_loop_spans_tile_an_iteration(served_requests):
     # first token lands later in the same pass of the loop, behind the chunk's launch
     waves = {s["seq"]: s for s in spans if s["name"] == "serving.paged.admit_wave"}
     assert waves and all(w["tid"] in worker for w in waves.values())
+    # every wave was collected by the pass's `collect_wave` just before it; a pass ends its
+    # admissions on a collect that took nobody
+    collects = [s for s in spans if s["name"] == "serving.engine.collect_wave"]
+    assert all(set(c["attrs"]) == {"n", "deferred"} and c["tid"] in worker for c in collects)
+    assert sorted(c["attrs"]["n"] for c in collects if c["attrs"]["n"]) == sorted(w["attrs"]["n"] for w in waves.values())
+    assert sum(c["attrs"]["n"] == 0 for c in collects) == len(iters)
     its = {s["seq"]: s for s in iters}
     for name in WAVE_STAGES:
         stages = [s for s in spans if s["name"] == name]
@@ -416,7 +431,6 @@ def test_wave_launches_every_rider_and_the_chunk_that_carries_them_before_it_wai
     assert chunk["attrs"]["slots"] == 3 and dispatch["parent_seq"] == chunk["seq"]
     assert _end(w) <= dispatch["t0_ns"] and _end(dispatch) <= first_wait
     assert at[("serving.paged.first_token_wait", ids[-1])] > dispatch["t0_ns"]  # the last rider's wait most of all
-    assert wave.count("serving.paged.launches_overlapped") == 2
 
 
 def test_every_span_of_a_wave_is_the_workers_and_no_thread_outlives_it(wave):
@@ -453,15 +467,18 @@ def test_continuous_batching_imports_nothing_of_the_round_pipeline():
     assert not hasattr(continuous_batching, "PipelinedExecutor")
 
 
-def test_launches_overlapped_is_riders_less_waves_over_a_burst(wave):
+def test_a_burst_is_admitted_in_waves_of_whatever_was_free(wave):
     """Seven requests through three slots: the burst's first wave takes three,
-    the rest are admitted as slots free, in waves of whatever was free."""
+    the rest are admitted as slots free, in waves of whatever was free. The
+    waves' ``n`` say how many riders were launched behind an earlier rider's
+    unfetched first token: riders less waves."""
     handles = wave.burst([(_prompt(3 + i, 220 + i), 3 + 2 * i, {}) for i in range(7)])
     assert [len(h.result(timeout=120)) for h in handles] == [3 + 2 * i for i in range(7)]
     waves = wave.spans("serving.paged.admit_wave")
     assert sum(w["attrs"]["n"] for w in waves) == wave.count("serving.cb.admissions") == 7
     assert waves[0]["attrs"]["n"] == 3 and len(waves) >= 3
-    assert wave.count("serving.paged.launches_overlapped") == 7 - len(waves)
+    transfers = wave.spans("serving.paged.transfer")
+    assert [sum(t["parent_seq"] == w["seq"] for t in transfers) for w in waves] == [w["attrs"]["n"] for w in waves]
 
 
 @pytest.fixture(scope="module")
@@ -514,7 +531,9 @@ def test_a_failure_in_rider_two_of_three_is_rider_twos_alone(wave, params, stage
         handles[1].result(timeout=120)
     assert [w["attrs"]["n"] for w in wave.spans("serving.paged.admit_wave")] == [1, 3]
     # rider three was launched behind rider one whichever stage of rider two failed
-    assert wave.count("serving.paged.launches_overlapped") == (2 if stage == "_stage_admit" else 1)
+    second = wave.spans("serving.paged.admit_wave")[1]
+    launched = [t for t in wave.spans("serving.paged.transfer") if t["parent_seq"] == second["seq"]]
+    assert len(launched) == (3 if stage == "_stage_admit" else 2)
     leaks = wave.eng._alloc.check_leaks()
     assert leaks["leaked"] == [] and leaks["bad_free"] == [] and leaks["state_leaked"] == [] and leaks["accounted"]
     assert np.all(wave.eng._tables == 0) and wave.eng.stats()["slots_active"] == 0
@@ -542,7 +561,7 @@ def test_a_failure_that_consumed_the_pool_fails_the_wave_and_the_live_riders(wav
     for h in handles:
         with pytest.raises(RuntimeError, match="planted after the pool was donated"):
             h.result(timeout=120)
-    assert wave.count("serving.cb.admissions") == 0 and wave.count("serving.cb.chunks_ahead") == 0
+    assert wave.count("serving.cb.admissions") == 0 and wave.spans("serving.cb.chunk.sync") == []  # no chunk went out
     assert wave.eng.stats()["slots_active"] == 0 and wave.eng._alloc.check_leaks()["accounted"]
 
 
@@ -586,7 +605,7 @@ def test_riders_admitted_while_a_chunk_is_in_flight_are_served_generates_tokens(
     assert np.all(wave.eng._tables == 0) and wave.eng.stats()["kv_tokens_live"] == 0
 
 
-def test_chunk_n_plus_1_is_launched_before_chunk_n_is_fetched_and_the_counter_says_how_often(wave):
+def test_chunk_n_plus_1_is_launched_before_chunk_n_is_fetched_and_the_syncs_parents_say_how_often(wave):
     for requests in ([(_prompt(6, 310), 14, {}), (_prompt(9, 311), 7, {})], [(_prompt(5, 312), 10, {})]):
         for h in wave.burst(requests):  # the second burst finds the loop with nothing in flight again
             h.result(timeout=120)
@@ -599,7 +618,7 @@ def test_chunk_n_plus_1_is_launched_before_chunk_n_is_fetched_and_the_counter_sa
     alone = [s for s in wave.spans("serving.cb.chunk.sync") if s["parent_seq"] not in {c["seq"] for c in chunks}]
     # 14 and 7 tokens: 1 + 4 chunks; 10 tokens: 1 + 3 chunks; each burst's last chunk lands with nothing to launch
     assert len(chunks) == len(flights) == 4 + 3 and len(alone) == 2
-    assert wave.count("serving.cb.chunks_ahead") == len(ahead) == len(chunks) - len(alone)
+    assert len(ahead) == len(chunks) - len(alone)  # a `.sync` under a chunk's span: that chunk was launched ahead of the fetch
     for (d0, s0), (d1, _) in zip(flights, flights[1:]):
         if d1["t0_ns"] < _end(s0):  # launched ahead: before the chunk before it was fetched, not merely before it ended
             assert d1["t0_ns"] < s0["t0_ns"]
@@ -710,6 +729,232 @@ def test_kv_tokens_live_is_the_live_rows_lengths_as_of_the_last_launched_chunk(w
     for h in handles:
         assert len(h.result(timeout=120)) == 30
     assert wave.eng.stats()["kv_tokens_live"] == 0
+
+
+# -- the device-starvation ledger: when the chip had nothing queued, by the worker's account ------
+
+
+class _Out:
+    """Stands in for the newest launch's output: ``is_ready`` is the test's."""
+
+    def __init__(self, ready=False, deleted=False):
+        self.ready, self.deleted = ready, deleted
+
+    def is_deleted(self):
+        return self.deleted
+
+    def is_ready(self):
+        if self.deleted:
+            raise RuntimeError("Array has been deleted.")
+        return self.ready
+
+
+@pytest.fixture()
+def ledger(monkeypatch):
+    """A ledger on a clock the test turns (``tick``), with what it recorded
+    since (``pieces`` in order of recording, ``count``)."""
+    import types
+
+    from fedml_tpu.serving import continuous_batching as cb
+
+    clock = types.SimpleNamespace(now=1_000, cpu=0)
+
+    def tick(ns):
+        clock.now += ns
+        return clock.now
+
+    monkeypatch.setattr(cb, "time", types.SimpleNamespace(perf_counter_ns=lambda: clock.now,
+                                                          thread_time_ns=lambda: clock.cpu))
+    registry = tel.get_telemetry()
+    was = registry.enabled
+    registry.set_enabled(True)
+    seq0 = max((r["seq"] for r in registry.snapshot()["spans"]), default=0)
+    counters0 = dict(registry.snapshot()["counters"])
+    led = cb._DeviceLedger(())
+
+    def pieces():
+        return [r for r in registry.snapshot()["spans"] if r["seq"] > seq0 and r["name"] == "serving.device.starved"]
+
+    def count(name):
+        return registry.snapshot()["counters"].get(name, 0) - counters0.get(name, 0)
+
+    yield types.SimpleNamespace(led=led, tick=tick, clock=clock, pieces=pieces, count=count, cb=cb, registry=registry)
+    registry.set_enabled(was)
+
+
+def test_a_starvation_opens_at_the_first_ready_answer_and_closes_at_the_next_launchs_return(ledger):
+    led, tick, cb = ledger.led, ledger.tick, ledger.cb
+    out = _Out()
+    led.mark(cb.NO_WORK)                 # t = 1,000: nothing launched yet: starved from here, as an engine starts
+    led.launched(out)                    # the first launch returns at once: a piece of no length, the chip has work
+    tick(50), led.mark(cb.LAND)          # 1,050: still busy
+    tick(70), led.mark(cb.LAND)          # 1,120: still busy: the look before the one that reads ready
+    out.ready = True
+    tick(30), led.mark(cb.COLLECT)       # 1,150: ready: the interval opens HERE, in `collect`
+    assert len(ledger.pieces()) == 1     # nothing more is cut until a boundary is crossed
+    tick(400), led.mark(cb.LAUNCH)       # 1,550: a piece of `collect`
+    tick(25), led.mark(cb.LAUNCH)        # 1,575: a piece of `launch`
+    tick(600), led.launched(_Out())      # 2,175: the launch call returned: closed
+    tick(90), led.mark(cb.LAND)          # busy again: no piece
+    tick(10), led.launched(_Out())       # a launch with nothing open: no piece either
+    got = [(p["attrs"]["phase"], p["dur_ns"], p["attrs"]["first"], p["attrs"].get("unseen_ns")) for p in ledger.pieces()]
+    assert got == [("no_work", 0, True, 0),
+                   ("collect", 400, True, 30), ("launch", 25, False, None), ("launch", 600, False, None)]
+    starts = [p["t0_ns"] for p in ledger.pieces()[1:]]
+    assert [b - a for a, b in zip(starts, starts[1:])] == [400, 25]  # the pieces tile the interval
+    assert ledger.count("serving.device.starved_ns") == 1_025 and ledger.count("serving.device.starvations") == 2
+    assert all(p["attrs"]["phase"] in cb.STARVED_PHASES for p in ledger.pieces())
+
+
+def test_every_boundary_crossed_while_starved_cuts_a_piece_in_the_phase_it_ends(ledger):
+    led, tick, cb = ledger.led, ledger.tick, ledger.cb
+    led.mark(cb.NO_WORK)                 # nothing was ever launched: the queue is empty
+    for ns, phase in ((7_000, cb.COLLECT), (300, cb.LAUNCH), (40, cb.LAND), (5, cb.NO_WORK)):
+        tick(ns), led.mark(phase)
+    tick(11), led.launched(_Out())
+    assert [(p["attrs"]["phase"], p["dur_ns"]) for p in ledger.pieces()] == [
+        ("no_work", 7_000), ("collect", 300), ("launch", 40), ("land", 5), ("no_work", 11)]
+    first = [p["attrs"]["first"] for p in ledger.pieces()]
+    assert first == [True, False, False, False, False] and ledger.pieces()[0]["attrs"]["unseen_ns"] == 0
+    assert ledger.count("serving.device.starvations") == 1 and ledger.count("serving.device.starved_ns") == 7_356
+
+
+def test_a_queue_that_emptied_and_filled_again_inside_one_launch_leaves_a_piece_of_no_length(ledger):
+    """No boundary lies inside a launch: where the launch before is done when
+    this one returns and nothing saw it, the doubt is an ``unseen_ns``."""
+    led, tick, cb = ledger.led, ledger.tick, ledger.cb
+    led.mark(cb.NO_WORK)
+    first = _Out()
+    led.launched(first)                  # 1,000
+    tick(40), led.mark(cb.LAUNCH)        # 1,040: busy: the last look that read so
+    first.ready = True                   # done while the worker is inside the next launch call
+    second = _Out()
+    tick(900), led.launched(second)      # 1,940: returns; nothing was open, the launch before is done
+    tick(10), led.launched(_Out())       # `second` still runs: no doubt, no piece
+    got = [(p["attrs"]["phase"], p["dur_ns"], p["attrs"]["first"], p["attrs"].get("unseen_ns")) for p in ledger.pieces()[1:]]
+    assert got == [("launch", 0, True, 900)]
+    assert ledger.count("serving.device.starvations") == 2 and ledger.count("serving.device.starved_ns") == 0
+
+
+def test_a_deleted_output_reads_as_ready_and_never_raises_on_the_worker(ledger):
+    led, tick, cb = ledger.led, ledger.tick, ledger.cb
+    led.mark(cb.NO_WORK)
+    led.launched(_Out(deleted=True))     # closes the engine's first interval: no length on this clock
+    tick(10), led.mark(cb.LAND)          # the deleted output reads ready: opens
+    tick(20), led.mark(cb.LAND)
+    real = jnp.zeros((3,), jnp.int32)
+    real.delete()                        # what a donated array looks like
+    led.launched(real)
+    tick(5), led.mark(cb.COLLECT)
+    tick(8), led.launched(jnp.zeros((3,), jnp.int32))
+    assert [(p["dur_ns"], p["attrs"]["first"]) for p in ledger.pieces()] == [(0, True), (20, True), (0, False), (8, True)]
+
+
+def test_nothing_is_recorded_with_the_registry_off_and_no_interval_survives_it(ledger):
+    led, tick, cb = ledger.led, ledger.tick, ledger.cb
+    ready = _Out(ready=True)
+    led.mark(cb.NO_WORK)
+    led.launched(ready)
+    tick(10), led.mark(cb.LAND)          # opens
+    ledger.registry.set_enabled(False)
+    tick(1_000_000), led.mark(cb.COLLECT)
+    tick(10), led.launched(ready)
+    led.begin_iteration()
+    attrs = {}
+    led.end_iteration(attrs)
+    assert len(ledger.pieces()) == 1 and attrs == {} and led.before_fetch() == 0  # the one from before it went off
+    assert ledger.count("serving.device.starved_ns") == 0 and ledger.count("serving.device.starvations") == 1
+    ledger.registry.set_enabled(True)
+    tick(10), led.mark(cb.LAND)          # a fresh interval from here: nothing of the dark million
+    tick(4), led.launched(_Out())
+    assert [(p["dur_ns"], p["attrs"]["unseen_ns"]) for p in ledger.pieces()[1:]] == [(4, 0)]
+
+
+def test_the_iterations_account_counts_the_fetches_off_cpu_time_as_blocked_and_its_own_locks(ledger):
+    import types
+
+    led, tick, clock, cb = ledger.led, ledger.tick, ledger.clock, ledger.cb
+    lock = types.SimpleNamespace(wait_ns=500)
+    led = cb._DeviceLedger((lock,))
+    led.launched(_Out(ready=True))
+    led.begin_iteration()                # opens an interval, in `collect`
+    clock.cpu += 2_000
+    tick(2_500)
+    lock.wait_ns += 300
+    cpu0 = led.before_fetch()            # a boundary: the piece of `collect` ends here
+    clock.cpu += 100                     # the copy to NumPy inside a fetch of 10,000 ns
+    tick(10_000)
+    led.after_fetch(types.SimpleNamespace(duration_ns=10_000), cpu0)
+    led.after_fetch(types.SimpleNamespace(duration_ns=None), cpu0)  # a span opened with the registry off: nothing
+    attrs = {}
+    led.end_iteration(attrs)
+    assert attrs == {"cpu_ns": 2_100, "blocked_ns": 9_900, "lock_wait_ns": 300, "starved_ns": 12_500}
+    assert attrs["cpu_ns"] + attrs["blocked_ns"] <= 12_500
+    assert [(p["attrs"]["phase"], p["dur_ns"]) for p in ledger.pieces()] == [
+        ("no_work", 0),  # the first launch found nothing launched before it
+        ("collect", 2_500), ("land", 10_000), ("land", 0), ("land", 0)]  # the loop's top follows, still starved
+
+
+def test_the_engine_marks_its_phases_and_the_ledger_closes_inside_a_launch(wave, params):
+    """The live engine with the newest output swapped for a stub that is
+    always ready (a chip that finishes everything at once): every interval
+    closes inside a launch's span, its pieces tile it, each in one phase of
+    the closed set, and the iterations' ``starved_ns`` add up to the pieces
+    cut inside them."""
+    from fedml_tpu.serving.continuous_batching import STARVED_PHASES
+
+    inner = wave.eng._ledger.launched
+    wave.eng._ledger.launched = lambda out: inner(_Out(ready=True))
+    for h in wave.burst([(_prompt(5 + 2 * i, 350 + i), 9 + i, {}) for i in range(4)]):  # four riders, three slots
+        h.result(timeout=120)
+    wave.eng._ledger.launched = inner
+    wave.eng.shutdown()
+    pieces = wave.spans("serving.device.starved")
+    assert pieces and all(p["tid"] == wave.eng._worker.ident and p["depth"] == 0 for p in pieces)
+    assert {p["attrs"]["phase"] for p in pieces} == set(STARVED_PHASES) - {"no_work"} | {pieces[0]["attrs"]["phase"]}
+    intervals = []
+    for p in pieces:
+        assert set(p["attrs"]) == ({"phase", "first", "unseen_ns"} if p["attrs"]["first"] else {"phase", "first"})
+        if p["attrs"]["first"]:
+            intervals.append([p])
+        else:
+            assert p["t0_ns"] == _end(intervals[-1][-1])  # tiles: starts where the piece before ended
+            intervals[-1].append(p)
+    assert wave.count("serving.device.starvations") == len(intervals) > 4
+    assert wave.count("serving.device.starved_ns") == sum(p["dur_ns"] for p in pieces)
+    launches = [s for name in (*LAUNCH_STAGES, "serving.cb.chunk.dispatch") for s in wave.spans(name)]
+    for iv in intervals[:-1]:  # the last one is the idle engine's, cut by nothing yet
+        end = _end(iv[-1])
+        assert iv[-1]["attrs"]["phase"] == "launch" and any(s["t0_ns"] <= end <= _end(s) for s in launches)
+    # a launch's return is the only thing that closes one: every launch closed the interval open before it
+    assert len(intervals) - 1 <= len(launches)
+    for it in wave.spans("serving.engine.iteration"):
+        inside = [p for p in pieces if it["t0_ns"] <= p["t0_ns"] and _end(p) <= _end(it)]
+        assert sum(p["dur_ns"] for p in inside) <= it["attrs"]["starved_ns"] <= it["dur_ns"]
+        assert it["attrs"]["cpu_ns"] + it["attrs"]["blocked_ns"] <= it["dur_ns"]
+        assert it["attrs"]["lock_wait_ns"] <= it["dur_ns"]
+
+
+def test_a_chip_that_is_never_done_starves_nowhere_past_the_first_launch(wave):
+    inner = wave.eng._ledger.launched
+    wave.eng._ledger.launched = lambda out: inner(_Out(ready=False))
+    for h in wave.burst([(_prompt(6, 360), 7, {}), (_prompt(9, 361), 5, {})]):
+        h.result(timeout=120)
+    pieces = wave.spans("serving.device.starved")
+    # the engine had launched nothing when the burst came: one interval, `no_work` until the first launch returned
+    assert [p["attrs"]["first"] for p in pieces] == [True] + [False] * (len(pieces) - 1)
+    assert pieces[0]["attrs"]["phase"] == "no_work" and pieces[-1]["attrs"]["phase"] == "launch"
+    assert wave.count("serving.device.starvations") == 1
+    assert all(it["attrs"]["starved_ns"] == 0 for it in wave.spans("serving.engine.iteration")[1:])
+
+
+def test_the_engine_records_no_starvation_with_the_registry_off(wave):
+    wave.registry.set_enabled(False)
+    assert len(wave.eng.generate(_prompt(7, 370), 6)) == 6
+    wave.registry.set_enabled(True)
+    assert wave.spans("serving.device.starved") == [] and wave.spans("serving.engine.iteration") == []
+    assert wave.count("serving.device.starved_ns") == 0 == wave.count("serving.device.starvations")
+    assert wave.eng._wlock.wait_ns == 0 == wave.eng._alloc.worker_lock.wait_ns
 
 
 @pytest.mark.parametrize("label", ["prefill", "paged_step", "paged_admit",

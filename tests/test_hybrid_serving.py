@@ -163,7 +163,9 @@ def test_one_wave_of_a_snapshot_hit_an_unshared_and_a_snapshot_leaving_rider(par
         assert [s["attrs"]["n"] for s in snap["spans"] if s["name"] == "serving.paged.admit_wave"] == [3]
         prefills = [s["attrs"] for s in snap["spans"] if s["name"] == "serving.cb.prefill"]
         assert [(a["state_hit"], a["shared"]) for a in prefills] == [(True, 2 * PS), (False, 0), (False, 0)]
-        assert snap["counters"]["serving.paged.launches_overlapped"] == 2
+        (wave,) = [s for s in snap["spans"] if s["name"] == "serving.paged.admit_wave"]
+        launches = [s for s in snap["spans"] if s["name"] == "serving.paged.transfer" and s["parent_seq"] == wave["seq"]]
+        assert len(launches) == 3  # the second and third launched behind the first's unfetched token
         assert snap["counters"]["serving.state.snapshots"] == 1  # the third rider's, at sys_b's boundary
         for r, out in zip(riders, served):
             assert out == [int(t) for t in generate(params, CFG, jnp.asarray([r], jnp.int32), 8)[0]]

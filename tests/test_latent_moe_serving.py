@@ -188,7 +188,10 @@ def test_a_chunks_routing_lands_a_chunk_later_on_the_span_that_launched_it(param
             assert c["tokens_routed"] == c["slots"] * 4 * 2 and 0 < c["experts_hit"] <= c["local_picks"]
         st = eng.stats()
         assert st["moe_tokens_routed"] == sum(c["tokens_routed"] for c in chunks) + (21 + 7 + 40) * 2
-        assert tel.counter("serving.cb.chunks_ahead").value == len(chunks) - 1  # one start from nothing in flight
+        spans = tel.snapshot()["spans"]
+        launched = {s["seq"] for s in spans if s["name"] == "serving.cb.chunk"}
+        ahead = [s for s in spans if s["name"] == "serving.cb.chunk.sync" and s["parent_seq"] in launched]
+        assert len(ahead) == len(chunks) - 1  # one start from nothing in flight
         leaks = eng._alloc.check_leaks()
         assert leaks["accounted"] and not leaks["leaked"]
     finally:

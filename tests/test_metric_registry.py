@@ -125,8 +125,8 @@ EXPORTED = {
     "fedml_serving_request_errors_total": "counter",
     "fedml_serving_cb_requests_total": "counter",
     "fedml_serving_cb_admissions_total": "counter",
-    "fedml_serving_paged_launches_overlapped_total": "counter",
-    "fedml_serving_cb_chunks_ahead_total": "counter",
+    "fedml_serving_device_starved_ns_total": "counter",
+    "fedml_serving_device_starvations_total": "counter",
     "fedml_serving_cb_tokens_generated_total": "counter",
     "fedml_serving_cb_ttft_seconds": "histogram",
     "fedml_serving_cb_tpot_seconds": "histogram",
