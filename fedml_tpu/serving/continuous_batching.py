@@ -15,7 +15,8 @@ hold it to, token for token. What the engine does:
 - prefill is disaggregated: each request prefills alone at B=1 through the
   16-token-bucketed executables (``generation._prefill_fn``; on a prefix hit,
   a gather of the shared pages plus one suffix pass), then a jitted admit
-  scatters its row into its private pages, samples its first token and
+  moves the row's blocks the request owns into its private pages (the page
+  handoff: ``paged_kv._paged_admit_fn``), samples its first token and
   writes the request's row of the decode step's carry. An admission wave runs
   on the worker's own thread (``_run_wave``): every rider's programs are
   launched without waiting, so the device sees one chain ``prefill_0,
@@ -115,6 +116,7 @@ from .paged_kv import (
     TRASH_PAGE,
     PagedKVAllocator,
     WaitTimedLock,
+    _page_groups,
     _paged_admit_fn,
     _paged_gather_fn,
     _paged_step_fn,
@@ -449,6 +451,8 @@ class PagedContinuousBatchingEngine:
                           for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]
                           if leaf.ndim >= 3 and _leaf_name(path) not in STATE_LEAVES)
         self._max_riders = max(2, ROWS_IN_FLIGHT_BYTES // max(1, token_bytes * base.max_seq_len))
+        # K/V (or latent) leaves a page group: what one logical block costs the page handoff in page writes
+        self._group_leaves = [len(paths) for paths in _page_groups(self._paged_cfg, self._cache)]
 
         # what a chunk hands the next, (tok, lengths, keys): on the device from
         # chunk to chunk, a row of it written by that slot's admission
@@ -920,6 +924,9 @@ class PagedContinuousBatchingEngine:
         if self._window:  # key positions the pass reads, a layer of each kind
             seen = np.arange(prefix_len + 1, P + 1, dtype=np.int64)
             attrs.update(kv_tokens_full=int(seen.sum()), kv_tokens_window=int(np.minimum(seen, self._window).sum()))
+        if w.n_shared:  # a hit: the shared pages its suffix pass reads (a window layer's: those the match brought)
+            seen = [w.n_shared, sum(1 for page in w.window_shared if page != TRASH_PAGE)]
+            attrs["blocks_gathered"] = sum(n * leaves for n, leaves in zip(seen, self._group_leaves))
         with tel.span("serving.cb.prefill", request_id=item.request_id,
                       prompt_len=P, shared=prefix_len, **attrs) as w.prefill_span:
             suffix = item.prompt[prefix_len:]
@@ -949,26 +956,38 @@ class PagedContinuousBatchingEngine:
                 w.routing.copy_to_host_async()
 
     def _stage_transfer(self, w: _AdmitWork) -> None:
-        """Stage 2, launched and not waited for: scatter the row's PROMPT
-        blocks into the request's private pages (shared blocks stay untouched
-        behind TRASH write ids), write its recurrent state at its slot, sample
-        the first token and write the slot's row of the carry. This is the
-        page handoff, the only stage that writes the decode pool; the pool is
-        donated to it. Once it is launched the rider's row can ride a chunk, so
-        the host's side of the row is published here: block table, temperature,
-        length, and the slot (its reply still empty)."""
+        """Stage 2, launched and not waited for: move the row's blocks the
+        request OWNS into its private pages, one runtime range of logical blocks
+        a page group (the full group: the prompt's blocks behind the shared
+        ones; the window group: the blocks of the prompt's last ``window``
+        tokens that the match did not bring), write its recurrent state at its
+        slot, sample the first token and write the slot's row of the carry.
+        Blocks outside the ranges (shared pages, the row's tail) are neither
+        read nor written. This is the page handoff, the only stage that writes
+        the decode pool; the pool is donated to it. Once it is launched the
+        rider's row can ride a chunk, so the host's side of the row is published
+        here: block table, temperature, length, and the slot (its reply still
+        empty). The span says how far the handoff went: pages written by group
+        (``blocks_full``, ``blocks_window``) beside the row's own
+        (``blocks_row``: every block of every leaf)."""
         item = w.item
         b = w.slot
         P = len(item.prompt)
-        with tel.span("serving.paged.transfer", request_id=item.request_id):
+        first_blk = w.n_shared
+        last_blk = -(-P // self._ps)  # exclusive: block of the last token
+        spans = [(first_blk, last_blk - first_blk)]
+        if self._window:
+            spans.append((last_blk - len(w.window_private), len(w.window_private)))
+        written = [n * leaves for (_, n), leaves in zip(spans, self._group_leaves)]
+        attrs = {"blocks_full": written[0], "blocks_row": self._n_blocks * sum(self._group_leaves)}
+        if self._window:
+            attrs["blocks_window"] = written[1]
+        with tel.span("serving.paged.transfer", request_id=item.request_id, **attrs):
             write_ids = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
-            first_blk = w.n_shared
-            last_blk = -(-P // self._ps)  # exclusive: block of the last token
             write_ids[first_blk:last_blk] = w.private_pages[:last_blk - first_blk]
             more = ()
             if self._window:
-                # only the blocks of the prompt's last ``window`` tokens: the rest of the row goes to trash
-                first_w = last_blk - len(w.window_private)
+                first_w = spans[1][0]
                 window_ids = np.full((self._n_blocks,), TRASH_PAGE, np.int32)
                 window_ids[first_w:last_blk] = w.window_private
                 more = (window_ids,)
@@ -979,7 +998,7 @@ class PagedContinuousBatchingEngine:
             self._cache, w.tok0, self._carry = _paged_admit_fn(self._paged_cfg)(
                 self._cache, w.row_cache, write_ids, np.int32(b), w.first,
                 np.uint32(item.seed & 0xFFFFFFFF), np.float32(item.temperature),
-                self._carry, np.int32(P), *more)
+                self._carry, np.int32(P), np.asarray(spans, np.int32), *more)
             self._ledger.launched(w.tok0)
             w.first = None
             if not w.snap_blocks:  # the row is in its pages: nobody reads it again (a snapshot's taker does)

@@ -51,7 +51,8 @@ the window group's entries behind the request's horizon point at the trash
 page again: the engine hands those pages back while the request decodes
 (``PagedContinuousBatchingEngine._slide_windows``), so a live request holds at
 most ``window_bound`` window pages whatever its length. The admit program
-scatters a finished prefill row whole into the full group and only the blocks
+moves one runtime range of a finished prefill row's blocks a group: the
+prompt's blocks behind the shared prefix into the full group, only the blocks
 of its last ``sliding_window`` tokens into the window group. The prefix trie's
 nodes hold a page of each group (the window page where some request's window
 reached over the chunk when it registered it): a hit needs the window pages of
@@ -60,12 +61,21 @@ a shared window page that a request's horizon passes loses that request's
 reference, not the trie's. A model with no window layers has one group and
 the tables it always had.
 
-Prefill reuses the contiguous executables (`generation._prefill_fn`) at
-B=1 and scatters the finished row into pages (``_paged_admit_fn``). A
-prefix HIT skips recomputing the shared prompt: gather the shared pages
-back into a contiguous row (``_paged_gather_fn``), rewind the write index
-to the shared length, and run one multi-token decode-mode pass over just
-the suffix (``_suffix_prefill_fn``) — the transformer's scalar-index
+THE PAGE HANDOFF. Prefill reuses the contiguous executables
+(`generation._prefill_fn`) at B=1, and a finished row goes into pages through
+``_paged_admit_fn``: of each page group it moves the blocks the request OWNS
+(the ranges above, operands of the one executable), a DMA a page from the row
+to its page id in every leaf of the group, the pools updated in place
+(``ops/page_handoff.rows_to_pages``). The row is ``max_seq_len`` long whatever
+the prompt; the handoff's time follows the prompt, because a block outside the
+range is neither read nor written (a scatter of all ``max_seq_len /
+page_size`` blocks, the unowned ones onto the trash page, made XLA lay both
+pools out anew around it at 4 kv heads: 14.4 ms an admission at a 16,896-token
+row, all but 0.8 of them whole-pool copies). A prefix HIT skips recomputing
+the shared prompt: gather the request's pages back into a contiguous row
+(``_paged_gather_fn``: the whole table, at what the row's bytes cost), rewind
+the write index to the shared length, and run one multi-token decode-mode pass
+over just the suffix (``_suffix_prefill_fn``): the transformer's scalar-index
 branch already supports a runtime start position, so suffix lengths share
 16-token-bucketed executables exactly like fresh prefills.
 """
@@ -85,6 +95,7 @@ from ..core import telemetry as tel
 from ..core.telemetry import devperf, track_compiles, tsdb
 from ..models.mamba import PACKED, SNAPSHOT_LEAVES, STATE_LEAVES, mamba_layers, pack_state, unpack_state
 from ..models.transformer import TransformerConfig
+from ..ops.page_handoff import rows_to_pages
 from ..train.llm.generation import (
     _leaf_at,
     _leaf_name,
@@ -184,13 +195,33 @@ def snapshot_of(row_cache) -> dict:
     return {dst: row_cache[PACKED][src] for src, dst in SNAPSHOT_LEAVES.items()}
 
 
+def _page_groups(cfg: TransformerConfig, pool) -> List[list]:
+    """The pool's K/V (or latent) leaves by page group, as key paths: the full
+    group's first, then the window group's where the model has window layers.
+    Scalars (write indices) and recurrent-state leaves belong to neither."""
+    windowed = _window_layer_names(cfg)
+    groups: List[list] = [[], []] if windowed else [[]]
+    for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]:
+        if leaf.ndim and _leaf_name(path) not in STATE_LEAVES:
+            groups[int(_in_window_layer(path, windowed))].append(path)
+    return groups
+
+
 def _paged_admit_fn(cfg: TransformerConfig):
     """Write one finished contiguous row cache into the cache pytree, sample
     the request's first token and write the request's row of the decode
-    step's carry. K/V leaves scatter into the pool at runtime page ids:
-    ``write_ids`` has one entry per logical block; blocks the request does NOT
-    own (shared prefix pages, unallocated tail) carry TRASH_PAGE, so duplicate
-    scatter indices only ever clobber the trash page. Recurrent-state leaves
+    step's carry. K/V leaves: of each page group the program moves ONE RUNTIME
+    RANGE of the row's logical blocks, ``spans[g] = (first, count)``, block
+    ``blk`` to page ``ids[blk]`` of every leaf of the group (``write_ids`` for
+    the full group, ``window_write_ids`` for the window group's pools: one
+    entry per logical block, read inside the range only). The full group's
+    range is the prompt's blocks behind the shared prefix, the window group's
+    those of its last ``sliding_window`` tokens. A block outside the range is
+    neither read from the row nor written anywhere: what the request does not
+    own (shared prefix pages, the unallocated tail, the row's padding) costs
+    nothing, so the program's time follows the prompt and not ``max_seq_len``;
+    a range of no blocks writes nothing. The range is an operand: one
+    executable serves every prompt length. Recurrent-state leaves
     (``STATE_LEAVES``) are written whole at the request's ``slot``. Leaves only
     the row has (its snapshot) stay behind. The row arrives packed
     (``models/mamba.pack_state``); ``first_logits`` is the prefill's
@@ -199,34 +230,38 @@ def _paged_admit_fn(cfg: TransformerConfig):
     ``_paged_step_fn`` carries from chunk to chunk, ``(tok, lengths, keys)``;
     it comes back with row ``slot`` set to (the first token, ``length`` = the
     prompt's, the key the first token's draw left), so the request can ride a
-    chunk launched before its first token has reached the host. A model with
-    window layers passes ``window_write_ids`` too: the same for the window
-    group's pools, TRASH_PAGE for every block behind the prompt's last
-    ``sliding_window`` tokens as well.
+    chunk launched before its first token has reached the host.
 
-    The pool is DONATED where the backend donates: the caller's binding is
-    dead once the call is made, and it rebinds to the returned pool. That
-    holds in the engine because everything that reads or writes the pool is
-    launched from one thread, in program order."""
+    The pool is DONATED where the backend donates and updated in place (the
+    page moves are ``ops/page_handoff.rows_to_pages``: one kernel a page group,
+    a DMA a page, the pools aliased in to out; no op copies a pool): the
+    caller's binding is dead once the call is made, and it rebinds to the
+    returned pool. That holds in the engine because everything that reads or
+    writes the pool is launched from one thread, in program order."""
     n_blocks = _num_blocks(cfg)
     ps = cfg.kv_page_size
-    windowed = _window_layer_names(cfg)
 
     def build():
-        def run(pool, row_cache, write_ids, slot, first_logits, seed, temp, carry, length,
+        def run(pool, row_cache, write_ids, slot, first_logits, seed, temp, carry, length, spans,
                 window_write_ids=None):
             row_cache = unpack_state(cfg, row_cache)
 
+            moved = {}  # a page leaf's path -> the leaf with the request's blocks in their pages
+            for g, (paths, ids) in enumerate(zip(_page_groups(cfg, pool), (write_ids, window_write_ids))):
+                first = jnp.clip(spans[g, 0], 0, n_blocks)
+                count = jnp.clip(spans[g, 1], 0, n_blocks - first)
+                dsts = [_leaf_at(pool, path) for path in paths]
+                srcs = [_leaf_at(row_cache, path).astype(dst.dtype) for path, dst in zip(paths, dsts)]
+                moved.update(zip(paths, rows_to_pages(srcs, dsts, ids, jnp.stack([first, count]), page_size=ps)))
+
             def insert(path, dst):
-                if dst.ndim == 0:
+                if path in moved:
+                    return moved[path]
+                if _leaf_name(path) not in STATE_LEAVES:
                     return dst  # scalar write index: meaningless for pools
                 src = _leaf_at(row_cache, path)
-                if _leaf_name(path) in STATE_LEAVES:
-                    return jax.lax.dynamic_update_slice(
-                        dst, src.astype(dst.dtype), (slot,) + (0,) * (dst.ndim - 1))
-                pages = src[0].reshape((n_blocks, ps) + src.shape[2:])
-                ids = window_write_ids if _in_window_layer(path, windowed) else write_ids
-                return dst.at[ids].set(pages.astype(dst.dtype))
+                return jax.lax.dynamic_update_slice(
+                    dst, src.astype(dst.dtype), (slot,) + (0,) * (dst.ndim - 1))
 
             new_pool = jax.tree_util.tree_map_with_path(insert, pool)
             key2, sub = jax.random.split(jax.random.PRNGKey(seed))
@@ -250,7 +285,13 @@ def _paged_gather_fn(cfg: TransformerConfig):
     leaves: the prefix cache's snapshot ``state`` (``snapshot_of``'s shape),
     never the pool's slots, which hold other requests. The row is packed. A
     window layer's row comes from the window group through ``window_table``:
-    the blocks the suffix pass can see hold the shared pages, the others trash."""
+    the blocks the suffix pass can see hold the shared pages, the others trash.
+
+    The whole table is gathered, ``max_seq_len / page_size`` pages a leaf
+    whatever the prefix: the program makes a row of that length anyway, and a
+    gather of it runs at what the row's bytes cost (0.41 ms at a 16,896-token
+    row of 10 leaves, 0.05-0.16 ms at 2,048 tokens: TPU v5e), under what a
+    loop over the shared blocks alone costs at short rows."""
     ps = cfg.kv_page_size
     recurrent = mamba_layers(cfg)
     windowed = _window_layer_names(cfg)

@@ -276,7 +276,7 @@ def test_the_seam_takes_the_latent_leaf_without_a_fork(params):
     write[:3] = [5, 2, 9]
     carry = (jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32), jnp.zeros((2, 2), jnp.uint32))
     pool, _, _ = paged_kv._paged_admit_fn(pcfg)(pool, row, write, np.int32(0), jnp.zeros((1, 101)), np.uint32(0),
-                                               np.float32(0), carry, np.int32(40))
+                                               np.float32(0), carry, np.int32(40), np.array([[0, 3]], np.int32))
     back = paged_kv._paged_gather_fn(pcfg)(pool, write, np.int32(32))
     for i in range(3):
         np.testing.assert_array_equal(np.asarray(back[f"layer_{i}"]["attn"]["latent"][0, :48]),

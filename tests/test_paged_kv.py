@@ -281,7 +281,9 @@ def _admit_operands(params, seed=3, temp=0.8, slot=0):
     write_ids = np.array([3, 4, 0, 0], np.int32)
     carry = (jnp.asarray([7, 8], jnp.int32), jnp.asarray([30, 40], jnp.int32),
              jnp.asarray([[1, 2], [3, 4]], jnp.uint32))  # the decode step's (tok, lengths, keys), both rows another request's
-    return pcfg, (pool, row, write_ids, np.int32(slot), first, np.uint32(seed), np.float32(temp), carry, np.int32(19))
+    spans = np.array([[0, 2]], np.int32)  # the full group's (first, count): the prompt's two blocks
+    return pcfg, (pool, row, write_ids, np.int32(slot), first, np.uint32(seed), np.float32(temp), carry, np.int32(19),
+                  spans)
 
 
 def test_paged_admit_donates_the_pool_where_the_backend_donates(params, monkeypatch):
@@ -295,8 +297,9 @@ def test_paged_admit_donates_the_pool_where_the_backend_donates(params, monkeypa
     assert not any(x.is_deleted() for x in jax.tree_util.tree_leaves(args[0]))
 
     monkeypatch.setattr(generation, "_COMPILED", {})  # build again, as on the chip
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    donating = paged_kv._paged_admit_fn(pcfg)
+    with monkeypatch.context() as built_for_the_chip:  # the build alone: the page moves' kernel is traced for this backend
+        built_for_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        donating = paged_kv._paged_admit_fn(pcfg)
     assert donating is not plain
     lowered = donating.lower(*args)
     pool_info, *rest = lowered.args_info[0]
@@ -330,3 +333,210 @@ def test_admit_program_makes_the_requests_key_from_its_seed(params, seed):
     assert int(tok0) == int(tok[slot]) == int(_sample(args[4][0], sub, args[6])) and int(lengths[slot]) == 19
     for got, came in zip((tok, lengths, keys), args[7]):
         assert got.dtype == came.dtype and np.array_equal(got[1 - slot], came[1 - slot])
+
+
+# --- the page handoff alone: what the admit program moves, what the gather program brings back ------------
+
+
+def _handoff_kinds():
+    import dataclasses
+
+    from fedml_tpu.train.llm.checkpoint_import import config_from_hf_keys
+    from tests.test_hybrid_serving import HF as HYBRID
+    from tests.test_latent_moe_serving import HF as LATENT
+    from tests.test_window_serving import HF as WINDOW
+
+    hf = lambda keys: config_from_hf_keys(keys, max_seq_len=128, dtype=jnp.float32, remat=False)  # noqa: E731
+    # kind -> (config, page size): one group dense; two groups (window 8); state leaves beside pages; latent pages
+    return {"dense": (dataclasses.replace(CFG, max_seq_len=128), 16), "window": (hf(WINDOW), 4),
+            "hybrid": (hf(HYBRID), 16), "latent": (hf(LATENT), 16)}
+
+
+class _Handoff:
+    """A model kind's pool (random pages), its programs, and a prefilled row (a real prefill over 128 random
+    tokens, so every position of the row holds something), as ``_stage_transfer`` / ``_stage_prefill`` see them."""
+
+    B, SLOT = 3, 1
+
+    def __init__(self, kind):
+        from fedml_tpu.models.mamba import STATE_LEAVES, unpack_state
+        from fedml_tpu.serving import paged_kv
+        from fedml_tpu.train.llm.generation import _leaf_at, _leaf_name, _prefill_fn
+
+        self.pk, self.leaf_at = paged_kv, _leaf_at
+        cfg, self.ps = _handoff_kinds()[kind]
+        self.cfg = cfg
+        self.params = TransformerLM(cfg).init(jax.random.PRNGKey(1), jnp.zeros((1, 4), jnp.int32))["params"]
+        self.window = cfg.sliding_window if cfg.window_layers else 0
+        self.n_blocks = cfg.max_seq_len // self.ps
+        self.pcfg = paged_kv.paged_config(cfg, page_size=self.ps, num_pages=4 * self.n_blocks + 1,
+                                          **({"window_pages": 2 * self.n_blocks + 1} if self.window else {}))
+        rng = np.random.default_rng(5)
+        self.pool = jax.tree_util.tree_map(
+            lambda x: x if x.ndim == 0 else jnp.asarray(rng.normal(size=x.shape), x.dtype),
+            paged_kv.paged_pool_init(self.params, self.pcfg, self.B))
+        self.stateful = cfg.has_recurrent_state
+        self.ids = rng.integers(1, cfg.vocab_size, (1, cfg.max_seq_len)).astype(np.int32)
+        self.prefill = lambda P, snap: _prefill_fn(cfg, 1, cfg.max_seq_len)(
+            self.params, self.ids, np.int32(P), np.int32(snap) if self.stateful else None)[:2]
+        self.unpack = lambda row: unpack_state(self.pcfg, row)
+        # (path, is a window layer's) of every page leaf; the paths of the state leaves
+        self.pages = [(p, bool(g)) for g, paths in enumerate(paged_kv._page_groups(self.pcfg, self.pool)) for p in paths]
+        self.states = [p for p, _ in jax.tree_util.tree_flatten_with_path(self.pool)[0] if _leaf_name(p) in STATE_LEAVES]
+        self.rng = rng
+
+    def tables(self, P, n_shared):
+        """Write tables and spans as ``_stage_transfer`` builds them, over distinct pages."""
+        last = -(-P // self.ps)
+        t = {"write": np.zeros((self.n_blocks,), np.int32), "spans": [(n_shared, last - n_shared)]}
+        t["write"][n_shared:last] = self.rng.permutation(np.arange(1, 4 * self.n_blocks + 1))[:last - n_shared]
+        if self.window:
+            first_w = max(max(0, P - self.window + 1) // self.ps, n_shared)
+            t["wwrite"] = np.zeros((self.n_blocks,), np.int32)
+            t["wwrite"][first_w:last] = self.rng.permutation(np.arange(1, 2 * self.n_blocks + 1))[:last - first_w]
+            t["spans"].append((first_w, last - first_w))
+        return t
+
+    def admit(self, pool, row, first, t, P):
+        carry = (jnp.arange(self.B, dtype=jnp.int32), jnp.arange(self.B, dtype=jnp.int32) + 50,
+                 jnp.ones((self.B, 2), jnp.uint32))
+        more = (t["wwrite"],) if self.window else ()
+        return self.pk._paged_admit_fn(self.pcfg)(
+            pool, row, t["write"], np.int32(self.SLOT), first, np.uint32(9), np.float32(0.0), carry, np.int32(P),
+            np.asarray(t["spans"], np.int32), *more)
+
+
+@pytest.fixture(scope="module", params=["dense", "window", "hybrid", "latent"])
+def handoff(request):
+    return _Handoff(request.param)
+
+
+# (prompt tokens as a share of the row, shared blocks in front): a prompt ending mid-page behind a shared
+# prefix, one that fills max_seq_len, a short one behind nothing
+@pytest.mark.parametrize("prompt,shared", [(0.43, 2), (1.0, 0), (0.11, 0)], ids=["mid_page", "whole_row", "short"])
+def test_the_admit_program_moves_the_owned_blocks_and_nothing_else(handoff, prompt, shared):
+    """After the admit program the pages the request owns hold the row's blocks bit for bit in every leaf of
+    their group; every other page (shared prefix pages among them) is what it was; a recurrent layer's state
+    lands whole at the slot and nowhere else; the carry's row is the request's."""
+    h = handoff
+    P = int(prompt * h.cfg.max_seq_len) if prompt < 1 else h.cfg.max_seq_len
+    row, first = h.prefill(P, shared * h.ps)
+    t = h.tables(P, shared)
+    before = jax.tree_util.tree_map(np.asarray, h.pool)
+    pool, tok0, (tok, lengths, _) = h.admit(h.pool, row, first, t, P)
+    src = h.unpack(row)
+    for path, win in h.pages:
+        first_blk, count = t["spans"][int(win)]
+        assert count > 0
+        ids = t["wwrite" if win else "write"][first_blk:first_blk + count]
+        got, old, r = np.asarray(h.leaf_at(pool, path)), h.leaf_at(before, path), np.asarray(h.leaf_at(src, path))[0]
+        want = r[first_blk * h.ps:(first_blk + count) * h.ps].reshape((count, h.ps) + r.shape[1:])
+        np.testing.assert_array_equal(got[ids], want)
+        rest = np.setdiff1d(np.arange(got.shape[0]), np.append(ids, TRASH_PAGE))
+        np.testing.assert_array_equal(got[rest], old[rest])
+    for path in h.states:
+        got, old, r = np.asarray(h.leaf_at(pool, path)), h.leaf_at(before, path), np.asarray(h.leaf_at(src, path))
+        np.testing.assert_array_equal(got[h.SLOT], r[0])
+        np.testing.assert_array_equal(np.delete(got, h.SLOT, 0), np.delete(old, h.SLOT, 0))
+    assert int(tok[h.SLOT]) == int(tok0) == int(np.argmax(first[0])) and int(lengths[h.SLOT]) == P
+    assert [int(v) for v in np.delete(np.asarray(lengths), h.SLOT)] == [50, 52]
+
+
+def test_a_range_of_no_blocks_writes_no_page(handoff):
+    """``count`` 0 in every group: not one page of the pool changes, the trash page included, wherever
+    ``first`` points and whatever the tables hold; a range that runs past the row is cut at its end."""
+    h = handoff
+    row, first = h.prefill(40, 0)
+    t = h.tables(40, 0)
+    t["spans"] = [(first_blk, 0) for first_blk, _ in t["spans"]]
+    before = jax.tree_util.tree_map(np.asarray, h.pool)
+    pool, _, _ = h.admit(h.pool, row, first, t, 40)
+    for path, _ in h.pages:
+        np.testing.assert_array_equal(np.asarray(h.leaf_at(pool, path)), h.leaf_at(before, path))
+    t = h.tables(h.cfg.max_seq_len, 0)
+    last = h.n_blocks - 1
+    t["spans"] = [(last, 5)] * len(t["spans"])   # only block ``last`` exists behind ``last``
+    pool, _, _ = h.admit(h.pool, row, first, t, 40)
+    src = h.unpack(row)
+    for path, win in h.pages:
+        got, old = np.asarray(h.leaf_at(pool, path)), h.leaf_at(before, path)
+        page = t["wwrite" if win else "write"][last]
+        np.testing.assert_array_equal(got[page], np.asarray(h.leaf_at(src, path))[0, last * h.ps:])
+        rest = np.setdiff1d(np.arange(got.shape[0]), [page])
+        np.testing.assert_array_equal(got[rest], old[rest])
+
+
+def test_the_gather_program_brings_the_shared_blocks_and_nothing_behind_them_is_read(handoff):
+    """Gather behind ``k`` shared blocks: the row holds those pages at their positions (a window layer's:
+    the ones a pass behind the prefix can see), its write index at the prefix; and a suffix pass over it
+    gives, bit for bit, the logits it gives over a row that holds the shared blocks ALONE (zeros wherever the
+    whole-table gather left the trash page's contents): nothing behind the prefix, and in a window layer
+    nothing behind the horizon, reaches a query."""
+    from fedml_tpu.models.mamba import PACKED
+
+    h = handoff
+    k = 3
+    P = k * h.ps + 1   # the registering request: its window (8, over pages of 4) reaches over what a later pass sees
+    row, first = h.prefill(P, k * h.ps)
+    t = h.tables(P, 0)
+    pool, _, _ = h.admit(h.pool, row, first, t, P)
+    tail = max(0, k * h.ps - h.window + 1) // h.ps if h.window else 0
+    table, wtable = np.zeros((h.n_blocks,), np.int32), np.zeros((h.n_blocks,), np.int32)
+    table[:k] = t["write"][:k]
+    if h.window:
+        assert t["spans"][1][0] <= tail < k - 1
+        wtable[tail:k] = t["wwrite"][tail:k]
+    state = h.pk.snapshot_of(row) if h.stateful else None
+    got = h.pk._paged_gather_fn(h.pcfg)(pool, table, np.int32(k * h.ps), state, *((wtable,) if h.window else ()))
+    alone = jax.tree_util.tree_map(lambda x: x, got)
+    for path, win in h.pages:
+        leaf, tab, lo = np.asarray(h.leaf_at(pool, path)), (wtable if win else table), (tail if win else 0)
+        shared = leaf[tab[lo:k]].reshape((-1,) + leaf.shape[2:])
+        np.testing.assert_array_equal(np.asarray(h.leaf_at(got, path))[0, lo * h.ps:k * h.ps], shared)
+        only = np.zeros((1, h.cfg.max_seq_len) + leaf.shape[2:], leaf.dtype)
+        only[0, lo * h.ps:k * h.ps] = shared
+        node = alone
+        for key in path[:-1]:
+            node = node[key.key]
+        node[path[-1].key] = jnp.asarray(only)
+    idx = [x for p, x in jax.tree_util.tree_flatten_with_path(got)[0] if getattr(p[-1], "key", None) == "idx"]
+    assert idx and all(int(x) == k * h.ps for x in idx)
+    assert (PACKED in got) == h.stateful
+    suffix = np.zeros((1, 16), np.int32)
+    suffix[0, :7] = h.rng.integers(1, h.cfg.vocab_size, 7)
+    total = k * h.ps + 7
+    snap = np.int32(0) if h.stateful else None
+    run = h.pk._suffix_prefill_fn(h.pcfg, 16)
+    a = run(h.params, got, suffix, np.int32(k * h.ps), np.int32(total), snap)[1]
+    b = run(h.params, alone, suffix, np.int32(k * h.ps), np.int32(total), snap)[1]
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("tail,dtype", [((1, 128), jnp.bfloat16), ((4, 128), jnp.bfloat16), ((640,), jnp.bfloat16),
+                                        ((2, 8), jnp.float32)], ids=["one_kv_head", "four_kv_heads", "latent", "f32"])
+@pytest.mark.parametrize("span", [(0, 8), (3, 2), (5, 0)], ids=["whole_row", "inside", "nothing"])
+def test_the_page_moves_kernel_and_its_plain_formulation_agree(tail, dtype, span):
+    """``ops/page_handoff.rows_to_pages`` (interpreted here) against the loop of ``dynamic_update_slice`` it
+    falls back to where a page is not whole tiles, and both against NumPy: the span's blocks land on their
+    pages in every pool, nothing else changes; and which shapes the compiled kernel takes."""
+    from fedml_tpu.ops import page_handoff as ph
+
+    ps, n_blocks, pages = 16, 8, 20
+    rng = np.random.default_rng(3)
+    rows = [jnp.asarray(rng.normal(size=(1, ps * n_blocks) + tail), dtype) for _ in range(3)]
+    pools = [jnp.asarray(rng.normal(size=(pages, ps) + tail), dtype) for _ in range(3)]
+    ids = rng.permutation(np.arange(1, pages))[:n_blocks].astype(np.int32)
+    got = ph.rows_to_pages(rows, pools, ids, np.asarray(span, np.int32), page_size=ps)
+    width, page_rows = tail[-1], ps * int(np.prod(tail[:-1]))
+    plain = ph._plain([r.reshape(-1, width) for r in rows], [p.reshape(pages, page_rows, width) for p in pools],
+                      ids, jnp.asarray(span, jnp.int32), [page_rows] * 3)
+    for row, pool, a, b in zip(rows, pools, got, plain):
+        want = np.array(pool)
+        for blk in range(span[0], span[0] + span[1]):
+            want[ids[blk]] = np.asarray(row)[0, blk * ps:(blk + 1) * ps]
+        np.testing.assert_array_equal(np.asarray(a), want)
+        np.testing.assert_array_equal(np.asarray(b).reshape(want.shape), want)
+    assert ph.tiles(page_rows, width, dtype) == (tail != (2, 8))   # 8 lanes wide: the plain formulation on the chip
+    assert not ph.tiles(8, 128, jnp.bfloat16) and ph.tiles(8, 128, jnp.float32)
+    with pytest.raises(ValueError):
+        ph.rows_to_pages(rows, [p[:, :8] for p in pools], ids, np.asarray(span, np.int32), page_size=ps)

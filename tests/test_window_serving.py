@@ -259,6 +259,34 @@ def test_admission_defers_when_either_group_is_short_and_resumes(params, short):
         eng.shutdown()
 
 
+@pytest.mark.parametrize("tail,hit", [(3, False), (8, False), (27, False), (60, False), (1, True), (5, True), (30, True)])
+def test_an_admission_moves_the_blocks_it_owns_and_its_spans_say_how_many(params, tail, hit):
+    """The page handoff follows the prompt, a group a range: the full layer's (k, v) take the prompt's blocks
+    behind the shared ones, the four window layers' the blocks of its last 8 tokens (never more than 3 blocks of
+    4, whatever the prompt), and a hit gathers the shared blocks (6 in the full layer, the 2 its pass can see
+    in a window layer) where the row has 32 blocks in each of 10 leaves; what is served is the reference's."""
+    eng = _engine(params)
+    try:
+        system = _toks(24, 3) if hit else []
+        if hit:
+            eng.generate(system + _toks(2, 4), 5)                # registers the prefix: 6 blocks
+        prompt = system + _toks(tail, 70 + tail)
+        assert _gaps(params, prompt, eng.generate(prompt, 9)).max() < GAP_TOL
+        spans = tel.snapshot()["spans"]
+        moved = [s for s in spans if s["name"] == "serving.paged.transfer"][-1]["attrs"]
+        prefill = [s for s in spans if s["name"] == "serving.cb.prefill"][-1]["attrs"]
+        P, n_shared = len(prompt), 6 if hit else 0
+        last = -(-P // PS)
+        first_w = max((P - W + 1) // PS, n_shared) if P >= W else n_shared
+        assert moved["blocks_row"] == (S // PS) * 10
+        assert moved["blocks_full"] == (last - n_shared) * 2 and moved["blocks_window"] == (last - first_w) * 8
+        assert moved["blocks_window"] <= 3 * 8 < moved["blocks_row"]
+        assert prefill.get("blocks_gathered") == (6 * 2 + 2 * 8 if hit else None) and prefill["shared"] == n_shared * PS
+        assert eng._alloc.check_leaks()["accounted"]
+    finally:
+        eng.shutdown()
+
+
 def test_a_mixed_run_with_sharing_leaks_nothing_and_compiles_nothing_after_warm_up(params):
     labels = ("prefill", "paged_step", "paged_admit", "paged_gather", "paged_suffix_prefill")
     eng = _engine(params)
