@@ -27,11 +27,17 @@ trailing shape). ``W`` is ``r + d_r`` filled up with zero columns to whole
 Two formulations of the same mathematics, chosen by the config and the shapes
 of the call, never by name:
 
-* EXPANDED (``expanded_latent_attention``): ``k_n`` and ``v`` are rebuilt from
-  the latents a block of keys at a time inside a loop that stops at the last
-  block a query can see, with a running softmax. The cache-free forward, a
-  prefill or suffix pass over a row cache, and ``generate()``'s steps. The
-  ``[H, T, S]`` scores never exist: a block's are ``[H, T, KEY_BLOCK]``.
+* EXPANDED, two ways, ``latent_row_attention`` picking by shape. The loop
+  (``expanded_latent_attention``) rebuilds ``k_n`` and ``v`` from the latents a
+  block of keys at a time up to the pass's end, with a running softmax; every
+  query of the pass meets every block (``T x kv_len`` work): the cache-free
+  forward (a training step needs its gradient), ``generate()``'s steps, and
+  passes over a row cache that are short against the row. A long pass over a
+  row cache expands every head's keys and values once, only as far as the
+  pass's end (``expand_latent_rows``), and attends through
+  ``ops/flash_attention.flash_attention_rows``, which visits only the key
+  blocks a query can see (``kernel_latent_attention``). The ``[H, T, S]``
+  scores never exist either way.
 * ABSORBED (the paged decode step, one token a row): ``q~_i = W_uk,i^T q_n,i``
   turns the scores into ``q~_i . c + q_r,i . k_r``, multi-query attention of H
   heads over one ``W`` wide key whose first ``r`` columns are also the value;
@@ -152,6 +158,88 @@ def expanded_latent_attention(q_n, q_r, latents, w_kv, q_pos0, kv_len, *, rank: 
     return out.transpose(0, 2, 1, 3).astype(q_n.dtype)
 
 
+def expand_latent_rows(latents, w_kv, kv_len, *, rank: int, d_nope: int, d_rope: int):
+    """Every head's keys ``[B, S, H, d_k]`` (``[c W_uk,i ; k_r ; 0]``: the
+    ``d_n + d_r`` columns filled up with zeros to whole 128-lane tiles, else
+    the compiler lays the 192-wide row out anew, whole, for the kernel) and
+    values ``[B, S, H, d_v]`` (``c W_uv,i``) of a row cache's ``latents [B, S,
+    W]``, in the latents' dtype, expanded a block of ``flash_attention_rows``'
+    keys at a time only as far as ``kv_len`` (a runtime count: a 2,048-token
+    pass behind a 256-token prefix expands 5 of a 16,896-position row's 33
+    blocks). Positions at or past ``kv_len`` are zero, whatever the row held
+    there: the kernel multiplies them by ``p = 0``. Built head-major, which is
+    what the kernel reads: the transposes to this ``[B, S, H, .]`` interface
+    and the kernel's back cancel."""
+    from ..ops.flash_attention import rows_block
+
+    B, S, _ = latents.shape
+    H, d_v = w_kv.shape[1], w_kv.shape[2] - d_nope
+    d_k = -(-(d_nope + d_rope) // 128) * 128
+    blk = rows_block(S)
+
+    def body(j, kv):
+        k, v = kv
+        start = j * blk
+        lat = jax.lax.dynamic_slice_in_dim(latents, start, blk, axis=1)           # [B, blk, W]
+        lat = jnp.where((start + jnp.arange(blk) < kv_len)[None, :, None], lat, jnp.zeros((), lat.dtype))
+        kv_b = jnp.einsum("bsr,rhd->bhsd", lat[..., :rank], w_kv)                  # [B, H, blk, d_n + d_v]
+        k_r = jnp.broadcast_to(lat[:, None, :, rank:rank + d_rope], (B, H, blk, d_rope))
+        pad = jnp.zeros((B, H, blk, d_k - d_nope - d_rope), lat.dtype)
+        k = jax.lax.dynamic_update_slice(k, jnp.concatenate([kv_b[..., :d_nope], k_r, pad], axis=-1), (0, 0, start, 0))
+        v = jax.lax.dynamic_update_slice(v, kv_b[..., d_nope:], (0, 0, start, 0))
+        return k, v
+
+    init = (jnp.zeros((B, H, S, d_k), latents.dtype), jnp.zeros((B, H, S, d_v), latents.dtype))
+    n_blk = jnp.minimum(-(-jnp.asarray(kv_len, jnp.int32) // blk), S // blk)
+    k, v = jax.lax.fori_loop(0, n_blk, body, init)
+    return k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+
+
+def kernel_latent_attention(q_n, q_r, latents, w_kv, q_pos0, kv_len, *, rank: int, d_nope: int):
+    """``expanded_latent_attention``'s pass over a row cache, through
+    ``ops.flash_attention.flash_attention_rows``: the keys and values
+    expanded once (``expand_latent_rows``), then only the key blocks a query
+    can see are visited. The query is filled up with zeros as the keys are,
+    and the scores keep ``score_scale`` (``d_n + d_r``, not the filled
+    width). Forward only."""
+    from ..ops.flash_attention import flash_attention_rows
+
+    k, v = expand_latent_rows(latents, w_kv, kv_len, rank=rank, d_nope=d_nope, d_rope=q_r.shape[-1])
+    pad = jnp.zeros(q_n.shape[:-1] + (k.shape[-1] - d_nope - q_r.shape[-1],), q_n.dtype)
+    return flash_attention_rows(jnp.concatenate([q_n, q_r, pad], axis=-1), k, v, q_pos0,
+                                scale=(d_nope + q_r.shape[-1]) ** -0.5)
+
+
+#: a pass of ``T`` queries over a row of ``S`` positions takes the kernel where
+#: ``T * T > LATENT_KERNEL_T2_PER_KEY * S``. The loop's cost grows with the
+#: pass's ``T x kv_len`` scores, the kernel path's has a fixed part that grows
+#: with the row: its expanded keys and values are zero-filled, written and read
+#: whole. On a v5e the two cross at about 150 S at openPangu's widths (a
+#: 2,048 row: T 512 loop 1.6 ms, kernel 1.7; T 1,024 6.5 against 2.7) and 210 S
+#: at Kimi-Linear's (16,896: T 1,024 0.6 against 1.7; T 2,048 3.1 against 2.3):
+#: ``tools/mla_prefill_check.py``
+LATENT_KERNEL_T2_PER_KEY = 192
+
+
+@functools.lru_cache(maxsize=None)
+def latent_row_attention(platform: str, T: int, S: int, n_heads: int) -> str:
+    """Which formulation a ``T``-token pass of a latent layer over its row
+    cache of ``S`` positions attends with, logged once per distinct case:
+    ``"kernel"`` (``kernel_latent_attention``) on the TPU where the pass is
+    long against its row (``LATENT_KERNEL_T2_PER_KEY``) and the row tiles
+    the kernel's blocks; else ``"loop"`` (``expanded_latent_attention``).
+    Decided here, from shapes (the heads scale both sides alike); the engine
+    names the same answer on its prefill span (``latent_attn``)."""
+    from ..ops.flash_attention import rows_tile
+
+    long = T * T > LATENT_KERNEL_T2_PER_KEY * S
+    impl = "kernel" if platform == "tpu" and long and rows_tile(S) else "loop"
+    if T > 1:
+        log.info("latent row-cache attention -> %s (platform=%s, T=%d, S=%d, n_heads=%d)",
+                 impl, platform, T, S, n_heads)
+    return impl
+
+
 class LatentAttention(nn.Module):
     cfg: TransformerConfig
 
@@ -196,9 +284,13 @@ class LatentAttention(nn.Module):
             if self.is_mutable_collection("cache"):
                 cl.value = jax.lax.dynamic_update_slice(cl.value, latent.astype(cl.value.dtype), (0, idx, 0))
                 cidx.value = idx + T
+            if latent_row_attention(jax.default_backend(), T, S, H) == "kernel":
+                attend = functools.partial(kernel_latent_attention, rank=r, d_nope=d_n)
+            else:
+                attend = expanded
             with jax.named_scope("mla_prefill_attention"):
-                out = expanded(q_n, q_r, cl.value, w_kv, idx, idx + T)
-        else:
+                out = attend(q_n, q_r, cl.value, w_kv, idx, idx + T)
+        else:  # a training forward: the kernel has no gradient
             with jax.named_scope("mla_prefill_attention"):
                 out = expanded(q_n, q_r, latent, w_kv, 0, T)
         return LoRALinear(cfg.d_model, cfg, name="o_proj")(out.reshape(B, T, H * d_v))

@@ -635,7 +635,7 @@ def flash_attention(
 
 # --- a pass over a contiguous row cache (serving: prefill, suffix pass) ---------
 
-def _rows_block(n: int) -> int:
+def rows_block(n: int) -> int:
     """The largest rung of ``LADDER`` that divides ``n``; ``n`` itself below the smallest."""
     return n if n < LADDER[-1] else next((r for r in LADDER if n % r == 0), 0)
 
@@ -644,7 +644,7 @@ def rows_tile(S: int) -> bool:
     """``flash_attention_rows``' shape rule: a rung of ``LADDER`` divides the
     row length ``S`` (or it is one block). The pass's length is filled up to
     whole blocks inside the call, so any goes."""
-    return _rows_block(S) > 0
+    return rows_block(S) > 0
 
 
 def _rows_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
@@ -655,10 +655,11 @@ def _rows_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: 
     ``t - window < j <= t`` (``window`` 0: ``j <= t``). Only the k-blocks that
     hold such a column are visited, all bounds runtime values: the blocks the
     horizon crosses and the blocks the diagonal crosses build the mask, those
-    between them do not."""
+    between them do not. The values may be narrower than the keys: the
+    accumulator is ``[block_q, Dv]``."""
     q = q_ref[0]
     num_k = k_ref.shape[1] // block_k
-    D = q.shape[-1]
+    Dv = v_ref.shape[-1]
     r0 = off_ref[0] + pl.program_id(1) * block_q
     r1 = r0 + block_q - 1
 
@@ -678,7 +679,7 @@ def _rows_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: 
         return m_new, l * corr + p.sum(axis=-1, keepdims=True), acc * corr + _dot_nn(p.astype(v_blk.dtype), v_blk)
 
     carry = (jnp.full((block_q, 1), NEG_INF, jnp.float32), jnp.zeros((block_q, 1), jnp.float32),
-             jnp.zeros((block_q, D), jnp.float32))
+             jnp.zeros((block_q, Dv), jnp.float32))
     hi = jnp.minimum(r1 // block_k + 1, num_k)        # blocks that hold a column <= r1
     diag = jnp.minimum((r0 + 1) // block_k, hi)       # the first block with a column > r0
     lo = 0
@@ -692,58 +693,61 @@ def _rows_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: 
     o_ref[0] = (acc * (1.0 / jnp.maximum(l, 1e-20))).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def _rows_impl(q, k_rows, v_rows, offset, *, window: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("window", "interpret", "scale"))
+def _rows_impl(q, k_rows, v_rows, offset, *, window: int, interpret: bool, scale=None):
     B, T, Hq, D = q.shape
-    S, Hkv = k_rows.shape[1], k_rows.shape[2]
-    block_k = _rows_block(S)
-    block_q = _rows_block(-(-T // LADDER[-1]) * LADDER[-1]) if T >= LADDER[-1] else T
+    S, Hkv, Dv = k_rows.shape[1], k_rows.shape[2], v_rows.shape[-1]
+    block_k = rows_block(S)
+    block_q = rows_block(-(-T // LADDER[-1]) * LADDER[-1]) if T >= LADDER[-1] else T
     Tp = -(-T // block_q) * block_q
     qr = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * Hq, T, D)
     if Tp != T:
         qr = jnp.pad(qr, ((0, 0), (0, Tp - T), (0, 0)))
     kr = jnp.transpose(k_rows, (0, 2, 1, 3)).reshape(B * Hkv, S, D)
-    vr = jnp.transpose(v_rows, (0, 2, 1, 3)).reshape(B * Hkv, S, D)
+    vr = jnp.transpose(v_rows, (0, 2, 1, 3)).reshape(B * Hkv, S, Dv)
     kv_idx = _kv_index(Hq, Hkv)
     item = q.dtype.itemsize
-    resident = 2 * S * D * item + 2 * block_q * D * item
+    resident = S * (D + Dv) * item + block_q * (D + Dv) * item
     out = pl.pallas_call(
-        functools.partial(_rows_kernel, block_q=block_q, block_k=block_k, window=window, scale=D ** -0.5),
-        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
+        functools.partial(_rows_kernel, block_q=block_q, block_k=block_k, window=window,
+                          scale=D ** -0.5 if scale is None else scale),
+        out_shape=jax.ShapeDtypeStruct((B * Hq, Tp, Dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B * Hq, Tp // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda i, j, *_: (i, j, 0)),
                 pl.BlockSpec((1, S, D), lambda i, j, *_: kv_idx(i, j)),
-                pl.BlockSpec((1, S, D), lambda i, j, *_: kv_idx(i, j)),
+                pl.BlockSpec((1, S, Dv), lambda i, j, *_: kv_idx(i, j)),
             ],
-            out_specs=pl.BlockSpec((1, block_q, D), lambda i, j, *_: (i, j, 0)),
+            out_specs=pl.BlockSpec((1, block_q, Dv), lambda i, j, *_: (i, j, 0)),
         ),
         compiler_params=_grid("parallel", "parallel",
                               vmem_limit_bytes=_vmem_limit("fwd", block_q, block_k, D, resident)),
         interpret=interpret,
         name="flash_attention_rows",
     )(jnp.reshape(offset, (1,)).astype(jnp.int32), qr, kr, vr)
-    return jnp.transpose(out[:, :T].reshape(B, Hq, T, D), (0, 2, 1, 3))
+    return jnp.transpose(out[:, :T].reshape(B, Hq, T, Dv), (0, 2, 1, 3))
 
 
-def flash_attention_rows(q, k_rows, v_rows, offset, *, window: int = 0):
+def flash_attention_rows(q, k_rows, v_rows, offset, *, window: int = 0, scale=None):
     """q ``[B, T, Hq, D]``: the queries of one pass, row t of them at position
-    ``offset + t`` of a contiguous row cache ``k_rows`` / ``v_rows``
-    ``[B, S, Hkv, D]`` that already holds the pass's own keys and values at
-    ``offset .. offset + T - 1`` (``offset`` a runtime int32 scalar, shared by
-    the rows). A query sees the cache's positions ``<=`` its own, with
-    ``window`` only the ``window`` newest of them. Returns ``[B, T, Hq, D]``.
-    Forward only; GQA-native; what lies in the cache past a query is never
-    read into a score that counts."""
+    ``offset + t`` of a contiguous row cache ``k_rows`` ``[B, S, Hkv, D]`` /
+    ``v_rows`` ``[B, S, Hkv, Dv]`` that already holds the pass's own keys and
+    values at ``offset .. offset + T - 1`` (``offset`` a runtime int32 scalar,
+    shared by the rows). A query sees the cache's positions ``<=`` its own,
+    with ``window`` only the ``window`` newest of them; scores are scaled by
+    ``D ** -0.5``. Returns ``[B, T, Hq, Dv]``. Forward only; GQA-native; what
+    lies in the cache past a query is never read into a score that counts,
+    but a value there enters ``p @ v`` at ``p = 0``: it must be finite."""
     if not rows_tile(k_rows.shape[1]):
         raise ValueError(f"flash_attention_rows: no block of {LADDER} tiles a row of {k_rows.shape[1]}")
-    return _rows_impl(q, k_rows, v_rows, offset, window=int(window), interpret=_interpret())
+    return _rows_impl(q, k_rows, v_rows, offset, window=int(window), interpret=_interpret(), scale=scale)
 
 
 def flash_attention_rows_reference(q, k_rows, v_rows, offset, *, window: int = 0):
-    """The plain formulation, same arguments and result: the masked einsum over the whole row."""
+    """The plain formulation, same arguments and result (a value width of its
+    own included): the masked einsum over the whole row."""
     B, T, Hq, D = q.shape
     S, Hkv = k_rows.shape[1], k_rows.shape[2]
     k = jnp.repeat(k_rows, Hq // Hkv, axis=2)

@@ -50,7 +50,10 @@ hold it to, token for token. What the engine does:
   and, with latent layers, ``kv_tokens_latent`` (keys the live rows see in
   one latent layer, summed likewise);
 - a model with latent-attention layers keeps ONE latent leaf a layer in the
-  pool (``models/mla.py``) where attention layers keep K and V; a model with
+  pool (``models/mla.py``) where attention layers keep K and V, and its
+  prefill spans name the formulation the pass's latent layers attend with
+  (``latent_attn``: ``"kernel"`` / ``"loop"``, ``mla.latent_row_attention``
+  from the pass's bucket); a model with
   routed expert layers (``models/moe.RoutedMoE``) has its programs hand back
   the routing of each pass packed in one small array, which becomes the
   ``serving.moe.*`` counters and span attributes;
@@ -110,6 +113,7 @@ import numpy as np
 
 from ..core import telemetry as tel
 from ..core.telemetry import devperf, trace_context, tsdb
+from ..models import mla
 from ..models.recurrent import state_bytes as recurrent_state_bytes
 from ..models.recurrent import state_leaves
 from ..models.moe import ROUTING_HEAD
@@ -431,6 +435,7 @@ class PagedContinuousBatchingEngine:
         self._stateful = base.has_recurrent_state
         self._state_bytes = recurrent_state_bytes(base) if self._stateful else 0
         self._kda_layers = base.layer_pattern.count("kda")
+        self._latent = bool(base.latent_layers)
         # bytes one live token holds in the latent layers' pages
         self._latent_token_bytes = (base.latent_layers * base.latent_width
                                     * jnp.dtype(base.dtype).itemsize)
@@ -938,10 +943,12 @@ class PagedContinuousBatchingEngine:
         if w.n_shared:  # a hit: the shared pages its suffix pass reads (a window layer's: those the match brought)
             seen = [w.n_shared, sum(1 for page in w.window_shared if page != TRASH_PAGE)]
             attrs["blocks_gathered"] = sum(n * leaves for n, leaves in zip(seen, self._group_leaves))
+        suffix = item.prompt[prefix_len:]
+        T_b = min(-(-len(suffix) // 16) * 16, cfg.max_seq_len - prefix_len)
+        if self._latent:  # the formulation the pass's latent layers attend with
+            attrs["latent_attn"] = mla.latent_row_attention(jax.default_backend(), T_b, cfg.max_seq_len, cfg.n_heads)
         with tel.span("serving.cb.prefill", request_id=item.request_id,
                       prompt_len=P, shared=prefix_len, **attrs) as w.prefill_span:
-            suffix = item.prompt[prefix_len:]
-            T_b = min(-(-len(suffix) // 16) * 16, cfg.max_seq_len - prefix_len)
             ids = np.zeros((1, T_b), np.int32)
             ids[0, :len(suffix)] = suffix
             if w.n_shared == 0:
