@@ -28,9 +28,12 @@ Three ways in, chosen by the config and the shapes of the call, never by name:
   state at ``seq_lens`` in ``conv`` / ``ssm`` and the state at ``snap_lens`` in
   ``snap_conv`` / ``snap_ssm`` (row caches only: the slot pool has no such
   leaves). The recurrence runs in ``ops/selective_scan.py``.
-* decode mode, ``T == 1``: one token a row, plain XLA, state ``[B, ...]``
-  indexed by row: the engines' slots. ``cache_idx < 0`` marks a freed slot,
-  whose state stands still.
+* decode mode, ``T == 1``: one token a row, state ``[B, ...]`` indexed by
+  row: the engines' slots. ``cache_idx < 0`` marks a freed slot, whose state
+  stands still. With ``cache_idx`` (the engines' slot step) the update is the
+  kernel ``ops/ssm_step.ssm_step``, which reads and writes the live slots'
+  state alone, in place; without it (``generate()``'s loop, every row live),
+  or at widths the kernel cannot tile, the plain XLA ``ssm_step_reference``.
 """
 
 from __future__ import annotations
@@ -97,6 +100,30 @@ def _selective_scan_impl(platform: str, T: int, d: int, n: int):
     return ss.selective_scan
 
 
+@functools.lru_cache(maxsize=None)
+def _ssm_step_impl(platform: str, d: int, n: int) -> bool:
+    """Whether the engines' one-token slot step runs ``ops.ssm_step.ssm_step``
+    (compiled on the TPU wherever its blocks tile, interpreted on the CPU) or
+    the plain ``ssm_step_reference``, which reads and writes every slot;
+    logged once per distinct case. Decided here, from shapes, before anything
+    runs."""
+    from ..ops import ssm_step as st
+
+    shape = f"d_inner={d} d_state={n}"
+    if platform == "tpu" and not st.tiles(d, n):
+        log.warning("ssm one-token step -> plain XLA (every slot read and written): "
+                    "the kernel cannot tile %s on %s", shape, platform)
+        return False
+    log.info("ssm one-token step -> pallas kernel over the live slots (platform=%s, %s)", platform, shape)
+    return True
+
+
+def step_takes_kernel(cfg: TransformerConfig) -> bool:
+    """Whether the engines' decode step moves only the live slots' state in
+    the config's Mamba layers (``_ssm_step_impl``); False without such layers."""
+    return bool(mamba_layers(cfg)) and _ssm_step_impl(jax.default_backend(), d_inner(cfg), cfg.mamba_d_state)
+
+
 def _a_log_init(key, shape, dtype=jnp.float32):
     """The family's initialisation: A = -(1..N) for every channel."""
     n = jnp.arange(1, shape[0] + 1, dtype=jnp.float32)
@@ -161,14 +188,15 @@ class MambaMixer(nn.Module):
 
         if cfg.decode and T == 1:
             # one token a row: the engines' slot step (and generate()'s loop)
-            dt1, c1 = dt[:, 0], cf[:, 0]
-            h = jnp.exp(dt1[:, None, :] * a_t) * h0 + (dt1 * c1)[:, None, :] * b_t[:, 0, :, None]
-            y = (jnp.sum(h * c_t[:, 0, :, None], axis=1) + d_skip * c1)[:, None]
-            new_conv = ext[:, 1:]
-            if cache_idx is not None:  # a freed slot's state stands still
-                live = cache_idx >= 0
-                h = jnp.where(live[:, None, None], h, h0)
-                new_conv = jnp.where(live[:, None, None], new_conv, conv0)
+            from ..ops import ssm_step as st
+
+            live = None if cache_idx is None else cache_idx >= 0  # a freed slot's state stands still
+            kernel = live is not None and _ssm_step_impl(jax.default_backend(), d, N)
+            y, h = (st.ssm_step if kernel else st.ssm_step_reference)(
+                h0, dt[:, 0], cf[:, 0], b_t[:, 0], c_t[:, 0], a_t, d_skip, live)
+            y = y[:, None]
+            # the conv tail is a select over all slots either way (ops/ssm_step.py's header)
+            new_conv = ext[:, 1:] if live is None else jnp.where(live[:, None, None], ext[:, 1:], conv0)
             if self.is_mutable_collection("cache"):
                 conv.value, ssm.value = new_conv, h
         else:

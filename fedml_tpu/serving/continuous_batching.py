@@ -113,7 +113,7 @@ import numpy as np
 
 from ..core import telemetry as tel
 from ..core.telemetry import devperf, trace_context, tsdb
-from ..models import mla
+from ..models import mamba, mla
 from ..models.recurrent import state_bytes as recurrent_state_bytes
 from ..models.recurrent import state_leaves
 from ..models.moe import ROUTING_HEAD
@@ -434,6 +434,8 @@ class PagedContinuousBatchingEngine:
         self._n_blocks = base.max_seq_len // self._ps
         self._stateful = base.has_recurrent_state
         self._state_bytes = recurrent_state_bytes(base) if self._stateful else 0
+        # the Mamba layers' one-token step moves the live slots' state alone
+        self._state_skips = mamba.step_takes_kernel(base)
         self._kda_layers = base.layer_pattern.count("kda")
         self._latent = bool(base.latent_layers)
         # bytes one live token holds in the latent layers' pages
@@ -1117,6 +1119,10 @@ class PagedContinuousBatchingEngine:
         attrs = {"pages": int((-(-lens // self._ps)).sum())}
         if self._stateful:  # live slots whose recurrent state the step updates
             attrs["state_slots"] = n_live
+        if self._state_skips:  # slots whose state the step leaves where it lies
+            attrs["state_slots_skipped"] = self._B - n_live
+            tel.counter("serving.state.step_slots_moved").add(n_live * self._C)
+            tel.counter("serving.state.step_slots_skipped").add((self._B - n_live) * self._C)
         if self._kda_layers:
             attrs["kda_rows"] = n_live * self._kda_layers * self._C
             tel.counter("serving.kda.step_rows").add(attrs["kda_rows"])

@@ -155,6 +155,51 @@ def test_the_plain_formulation_is_chosen_from_shapes_and_said(caplog):
     mamba._selective_scan_impl.cache_clear()
 
 
+# ---- the one-token slot step (interpreted) against the plain lines -------------------------------
+
+def _step_inputs(key, B, N, D):
+    ks = jax.random.split(key, 5)
+    return (jax.nn.softplus(jax.random.normal(ks[0], (B, D)) - 3), jax.random.normal(ks[1], (B, D)),
+            jax.random.normal(ks[2], (B, N)), jax.random.normal(ks[3], (B, N)))
+
+
+@pytest.mark.parametrize("live", [[1] * 8, [0] * 8, [0, 1, 1, 0, 0, 0, 1, 1]], ids=["all", "none", "scattered"])
+def test_step_kernel_moves_the_live_slots_alone(live):
+    """Three consecutive token-steps over 8 slots, each formulation carrying its own state: the live
+    slots' state and ``y`` within float32 rounding of the plain lines, a dead slot's state bit for
+    bit what it was, ``y`` 0 on a dead row."""
+    from fedml_tpu.ops import ssm_step as st
+
+    B, N, D = 8, 16, 256
+    live = jnp.asarray(live, bool)
+    dead = ~np.asarray(live)
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    a_t, d_skip = -jnp.exp(0.5 * jax.random.normal(ks[0], (N, D))), jax.random.normal(ks[1], (D,))
+    h_k = h_p = jax.random.normal(ks[2], (B, N, D))
+    for key in ks[3:]:
+        args = (*_step_inputs(key, B, N, D), a_t, d_skip, live)
+        (y_k, new_k), (y_p, new_p) = st.ssm_step(h_k, *args), st.ssm_step_reference(h_p, *args)
+        assert np.array_equal(np.asarray(new_k)[dead], np.asarray(h_k)[dead])
+        assert not np.asarray(y_k)[dead].any()
+        for got, ref in ((y_k, y_p), (new_k, new_p)):
+            assert float(jnp.max(jnp.abs(got - ref))) < 1e-5 * max(1.0, float(jnp.max(jnp.abs(ref))))
+        h_k, h_p = new_k, new_p
+
+
+def test_the_step_kernel_is_chosen_from_shapes_and_said(caplog):
+    mamba._ssm_step_impl.cache_clear()
+    with caplog.at_level("INFO", logger="fedml_tpu.models.mamba"):
+        assert mamba._ssm_step_impl("cpu", 64, 12)           # interpreted: any width
+        assert not mamba._ssm_step_impl("tpu", 192, 16)      # lanes not filled
+        assert not mamba._ssm_step_impl("tpu", 256, 12)      # no whole sublane tile of states
+        assert mamba._ssm_step_impl("tpu", 5120, 16)
+        assert mamba._ssm_step_impl("tpu", 5120, 16)         # said once
+    said = [r.getMessage() for r in caplog.records]
+    assert sum("plain XLA" in m for m in said) == 2 and sum("pallas kernel" in m for m in said) == 2
+    assert mamba.step_takes_kernel(CFG) and not mamba.step_takes_kernel(DENSE)
+    mamba._ssm_step_impl.cache_clear()
+
+
 # ---- a dense configuration is what it was --------------------------------------------------------
 
 DENSE = TransformerConfig(vocab_size=97, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=64, max_seq_len=64,

@@ -147,6 +147,9 @@ EXPORTED = {
     "fedml_serving_state_snapshots_total": "counter",
     "fedml_serving_state_snapshot_evictions_total": "counter",
     "fedml_serving_state_snapshot_bytes": "gauge",
+    # slot-steps whose state the one-token step kernel moved (live) or left alone (dead)
+    "fedml_serving_state_step_slots_moved_total": "counter",
+    "fedml_serving_state_step_slots_skipped_total": "counter",
     # KDA layers (models/kda.py): prompt tokens through the chunked kernel, rows of the one-token step
     "fedml_serving_kda_prefill_tokens_total": "counter",
     "fedml_serving_kda_step_rows_total": "counter",

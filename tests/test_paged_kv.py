@@ -39,10 +39,20 @@ def _prompt(length, seed):
     return list(np.random.default_rng(seed).integers(1, CFG.vocab_size, length))
 
 
-def _ref(params, prompt, max_new):
+def _ref(params, prompt, max_new, cfg=CFG):
     return np.asarray(
-        generate(params, CFG, jnp.asarray([prompt], jnp.int32), max_new)
+        generate(params, cfg, jnp.asarray([prompt], jnp.int32), max_new)
     )[0].tolist()
+
+
+def _hybrid_model():
+    """tests/test_hybrid_serving.py's model (Mamba layers beside attention) and its weights."""
+    from tests.test_hybrid_serving import CFG as HYBRID
+    import weights_jamba  # on the path once that module is imported
+
+    shapes = jax.eval_shape(lambda k: TransformerLM(HYBRID).init(k, jnp.zeros((1, 8), jnp.int32))["params"],
+                            jax.random.PRNGKey(0))
+    return HYBRID, weights_jamba.make_params(weights_jamba.shapes_of(shapes), 11, jnp.float32)
 
 
 # --- allocator ---------------------------------------------------------------
@@ -143,17 +153,28 @@ GEOMETRIES = [pytest.param(dict(page_size=16), id="page16"),
               pytest.param(dict(page_size=8), id="page8")]
 
 
-@pytest.mark.parametrize("geometry", GEOMETRIES)
+# a hybrid model over tests/test_hybrid_serving.py's four slots: replies of several lengths free slots
+# mid-run, and the step moves the live slots' state alone (ops/ssm_step.py, interpreted)
+HYBRID_GEOMETRY = pytest.param(dict(page_size=16, num_slots=4, num_pages=48, state_snapshots=4), id="hybrid_page16")
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES + [HYBRID_GEOMETRY])
 def test_paged_engine_greedy_matches_generate_ragged(params, geometry):
     """Keystone: the engine (block-table scatter/gather decode, requests
-    interleaved across 2 slots) is token-exact vs the contiguous reference
-    path across ragged prompt lengths spanning page boundaries."""
-    eng = PagedContinuousBatchingEngine(params, CFG, num_slots=2, chunk=4, **geometry)
+    interleaved across 2 slots, 4 for the hybrid model) is token-exact vs the
+    contiguous reference path across ragged prompt lengths spanning page
+    boundaries."""
+    hybrid = "state_snapshots" in geometry
+    cfg, params = _hybrid_model() if hybrid else (CFG, params)
+    new = (12, 5, 9, 12, 3, 7) if hybrid else (12,) * 6
+    skipped = tel.counter("serving.state.step_slots_skipped").value
+    eng = PagedContinuousBatchingEngine(params, cfg, **{"num_slots": 2, "chunk": 4, **geometry})
     try:
         prompts = [_prompt(n, i) for i, n in enumerate((3, 15, 16, 17, 31, 40))]
-        handles = [eng.submit(p, 12) for p in prompts]
-        for p, h in zip(prompts, handles):
-            assert h.result(timeout=120) == _ref(params, p, 12)
+        handles = [eng.submit(p, n) for p, n in zip(prompts, new)]
+        for p, n, h in zip(prompts, new, handles):
+            assert h.result(timeout=120) == _ref(params, p, n, cfg)
+        assert (tel.counter("serving.state.step_slots_skipped").value > skipped) == hybrid
         # all pages returned (no retention yet for <1-page prompts; longer
         # prompts retain their full chunks at refcount exactly 1)
         leaks = eng._alloc.check_leaks()
